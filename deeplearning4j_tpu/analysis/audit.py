@@ -56,6 +56,7 @@ COLLECTIVE_PRIMS = frozenset({
 #: family stalls the device on the Python runtime
 CALLBACK_PRIMS = frozenset({
     "pure_callback", "debug_callback", "io_callback",
+    "debug_print",  # what jax.debug.print emits since jax 0.9
 })
 
 #: budget tolerance: flops / temp bytes may grow this factor over the

@@ -783,8 +783,10 @@ def default_audit_config() -> TransformerConfig:
     """The committed audit geometry's model config: small enough that
     the full surface traces + compiles in seconds on CPU, bf16 compute
     so the dtype-promotion lint has teeth, GQA + RoPE so the audited
-    forward is the feature-bearing one. ``decode_kernel=False``:
-    the auditor lowers on CPU, where the Pallas TPU kernel cannot."""
+    forward is the feature-bearing one. ``decode_kernel=False``: the
+    auditor budget-COMPILES on CPU. The Pallas TPU kernel can be lowered
+    there (``lowering_platforms=("tpu",)``, as
+    tests/test_tpu_lowering.py does) but not compiled."""
     return TransformerConfig(
         vocab_size=128,
         d_model=64,

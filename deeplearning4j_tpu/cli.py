@@ -16,6 +16,7 @@ Usage:
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import os
 import sys
@@ -230,7 +231,13 @@ def _train_transformer(args) -> int:
     print(f"final loss {loss:.4f}")
 
     if cfg.max_len >= 32:
-        gen = transformer_generate(cfg)
+        # the sample runs on the training mesh, and transformer_generate
+        # takes no mesh: the Pallas decode kernel would sit bare in a
+        # multi-device jit, which the TPU lowering refuses. The dense
+        # path is the one GSPMD partitions.
+        gen = transformer_generate(
+            dataclasses.replace(cfg, decode_kernel=False)
+        )
         prompt = jnp.asarray(arr[None, :16])
         out = gen(
             jax.device_get(params) if args.fsdp else params,
@@ -247,6 +254,9 @@ def cmd_train(args) -> int:
     import jax.numpy as jnp
     import numpy as np
 
+    from deeplearning4j_tpu.utils.compile_cache import use_compile_cache
+
+    use_compile_cache()
     if args.coordinator:
         from deeplearning4j_tpu.parallel.cluster import initialize_distributed
 
@@ -319,7 +329,6 @@ def _restore_decode_model(args):
         quantize_decode_params,
     )
 
-    import dataclasses
     from pathlib import Path
 
     # a read-only command must not mkdir its way past a typo'd path
@@ -391,7 +400,9 @@ def cmd_generate(args) -> int:
         transformer_beam_search,
         transformer_generate,
     )
+    from deeplearning4j_tpu.utils.compile_cache import use_compile_cache
 
+    use_compile_cache()
     restored = _restore_decode_model(args)
     if isinstance(restored, int):
         return restored
@@ -453,7 +464,9 @@ def cmd_serve(args) -> int:
         ServingServer,
         TenantRegistry,
     )
+    from deeplearning4j_tpu.utils.compile_cache import use_compile_cache
 
+    use_compile_cache()
     if args.log_json:
         configure_json_logging()
 

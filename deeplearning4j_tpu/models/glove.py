@@ -236,7 +236,7 @@ class Glove:
         updated tables (and accumulators) are averaged — the in-graph pmean
         equivalent of the master-side table merge in the reference's
         GloveJobAggregator (scaleout/perform/models/glove/, SURVEY §2-P8)."""
-        from deeplearning4j_tpu.utils.compat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
 
         from deeplearning4j_tpu.parallel import mesh as mesh_lib

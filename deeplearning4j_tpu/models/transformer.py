@@ -820,11 +820,28 @@ def transformer_apply(
             # same tensor twice and cost ~450MB at GPT-2-small scale)
             bq, bk = _flash_blocks(t)
             bbq, bbk = _flash_bwd_blocks(t)
-            o = flash_attention_trainable(
-                q_h, k_h, v_h, causal=True,
+            flash = functools.partial(
+                flash_attention_trainable, causal=True,
                 block_q=bq, block_k=bk, layout="bhtd",
                 bwd_block_q=bbq, bwd_block_k=bbk,
             )
+            if mesh is not None:
+                # compiled, the kernel is a custom call that GSPMD
+                # cannot partition (the TPU lowering refuses it outside
+                # a fully manual region): each device runs it on its
+                # own (batch, heads) shard — attention mixes neither
+                axes = mesh.axis_names
+                spec = P(
+                    mesh_lib.DATA_AXIS if mesh_lib.DATA_AXIS in axes
+                    else None,
+                    mesh_lib.MODEL_AXIS if mesh_lib.MODEL_AXIS in axes
+                    else None,
+                )
+                flash = jax.shard_map(
+                    flash, mesh=mesh, in_specs=(spec, spec, spec),
+                    out_specs=spec, check_vma=False,
+                )
+            o = flash(q_h, k_h, v_h)
         else:
             o = checkpoint_name(
                 attention(q_h, k_h, v_h, causal=True, layout="bhtd"),

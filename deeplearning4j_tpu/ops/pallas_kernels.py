@@ -18,18 +18,22 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-try:  # pallas TPU backend is optional at import time (CPU test envs)
-    from jax.experimental.pallas import tpu as pltpu
-except Exception:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
+def _default_interpret() -> bool:
+    """Compile for the TPU, interpret on the CPU (the test backend).
+    Any other backend is an error: a kernel quietly interpreted on a
+    device nobody chose would run, slowly, and report nothing."""
+    backend = jax.default_backend()
+    if backend == "tpu":
         return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"pallas kernels run compiled on 'tpu' or interpreted on 'cpu'; "
+        f"the default jax backend is {backend!r}"
+    )
 
 
 # -- flash attention ----------------------------------------------------------
@@ -46,16 +50,6 @@ def _causal_bias(q_start, k_start, block_q: int, block_k: int):
     rows = q_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
     cols = k_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
     return jnp.where(cols <= rows, 0.0, -jnp.inf).astype(jnp.float32)
-
-
-def _vmem(shape, dtype):
-    """VMEM scratch when the TPU backend is importable; generic
-    memory-space scratch otherwise (interpret-mode envs without pltpu).
-    ``pl.ANY(shape, dtype)`` is the public scratch-shape API (memory-space
-    enums are callable MemoryRef factories in jax>=0.9)."""
-    if pltpu is not None:
-        return pltpu.VMEM(shape, dtype)
-    return pl.ANY(shape, dtype)
 
 
 def _kv_block_visible(q_start, k_start, block_q: int):
@@ -117,7 +111,7 @@ _DQ_PARTIALS_F32 = False
 
 
 def _dim_semantics(interpret, semantics=("parallel", "parallel", "arbitrary")):
-    if interpret or pltpu is None:
+    if interpret:
         return None
     return pltpu.CompilerParams(dimension_semantics=semantics)
 
@@ -201,9 +195,9 @@ def _flash_fwd_call(qf, kf, vf, block_q, block_k, interpret, causal):
             pl.BlockSpec((1, block_q, 1), lambda i, j, kk: (i, j, 0)),
         ),
         scratch_shapes=[
-            _vmem((block_q, 1), jnp.float32),
-            _vmem((block_q, 1), jnp.float32),
-            _vmem((block_q, d), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, 1), jnp.float32),
+            pltpu.VMEM((block_q, d), jnp.float32),
         ],
         compiler_params=_dim_semantics(interpret),
         interpret=interpret,
@@ -318,7 +312,7 @@ def flash_attention(
     block_q = min(block_q, t)
     block_k = min(block_k, t)
     assert t % block_q == 0 and t % block_k == 0
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = _default_interpret() if interpret is None else interpret
 
     qf = q.transpose(0, 2, 1, 3).reshape(b * h, t, d)
     kf = k.transpose(0, 2, 1, 3).reshape(b * h, t, d)
@@ -415,8 +409,8 @@ def _flash_bwd_rule(
             pl.BlockSpec((1, block_k, d), lambda i, j, qq: (i, j, 0)),
         ),
         scratch_shapes=[
-            _vmem((block_k, d), jnp.float32),
-            _vmem((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, d), jnp.float32),
         ],
         # the kv dim must be SEQUENTIAL (not "parallel") in rmw mode:
         # dq tiles are revisited and accumulated across it — a megacore
@@ -463,7 +457,7 @@ def flash_attention_trainable(
     block_q = min(block_q, t)
     block_k = min(block_k, t)
     assert t % block_q == 0 and t % block_k == 0
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = _default_interpret() if interpret is None else interpret
     if layout == "bhtd":
         qf, kf, vf = (a.reshape(b * h, t, d) for a in (q, k, v))
     else:
@@ -535,7 +529,7 @@ def _flash_decode_kernel(
         o_ref, m_s, l_s, acc_s = rest
     tt = pl.program_id(1)
     t_start = tt * block_t
-    pos = pos_ref[0, 0]
+    pos = pos_ref[pl.program_id(0)]
 
     @pl.when(tt == 0)
     def _init():
@@ -642,6 +636,22 @@ def _flash_decode_kernel(
         o_ref[0] = (acc_s[:] / l_exp).astype(o_ref.dtype)
 
 
+# The per-row positions go to the kernel WHOLE, as a (B,) int32 vector
+# in scalar memory, and each grid row reads its own entry by
+# ``program_id(0)``. A batch-indexed (1, 1) block of a (B, 1) array is
+# what interpret mode accepts and the TPU lowering refuses for B > 1:
+# a block's last two dims must be (8, 128)-divisible or the array's own.
+_POS_SPEC = pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+def _decode_positions(pos, b: int) -> jax.Array:
+    """(B,) int32: a scalar ``pos`` broadcasts to every row, a (B,)
+    vector (serving) keeps per-slot depths."""
+    return jnp.broadcast_to(
+        jnp.reshape(jnp.asarray(pos, jnp.int32), (-1,)), (b,)
+    )
+
+
 def flash_decode_attention(
     q: jax.Array,
     kvcache: jax.Array,
@@ -711,7 +721,7 @@ def flash_decode_attention(
         block_t = t // n_t
     block_t = min(block_t, t)
     assert t % block_t == 0, (t, block_t)
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = _default_interpret() if interpret is None else interpret
     n_t = t // block_t
     quantized = kv_scales is not None
     kernel = functools.partial(
@@ -719,18 +729,7 @@ def flash_decode_attention(
         n_kv_heads=n_kv_heads, head_dim=head_dim, groups=g,
         scale=1.0 / (head_dim**0.5), quantized=quantized,
     )
-    # (B, 1) per-row positions: a scalar pos broadcasts to every row, a
-    # (B,) vector (serving) keeps per-slot depths. The kernel reads its
-    # row's block via the batch-indexed BlockSpec below.
-    pos_arr = jnp.broadcast_to(
-        jnp.reshape(jnp.asarray(pos, jnp.int32), (-1, 1)), (b, 1)
-    )
-    if pltpu is not None and not interpret:
-        params = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")
-        )
-    else:
-        params = None
+    pos_arr = _decode_positions(pos, b)
     in_specs = [
         pl.BlockSpec((1, g, hk), lambda i, tt: (i, 0, 0)),
         # the K and V planes of the one stacked cache buffer, as two
@@ -743,7 +742,7 @@ def flash_decode_attention(
             (1, 1, 1, block_t, hk),
             lambda i, tt: (layer, 1, i, tt, 0),
         ),
-        pl.BlockSpec((1, 1), lambda i, tt: (i, 0)),
+        _POS_SPEC,
     ]
     operands = [q, kvcache, kvcache, pos_arr]
     if quantized:
@@ -771,11 +770,11 @@ def flash_decode_attention(
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, g, hk), lambda i, tt: (i, 0, 0)),
         scratch_shapes=[
-            _vmem((1, g * n_kv_heads), jnp.float32),  # m (lane = g*n_kv+h)
-            _vmem((1, g * n_kv_heads), jnp.float32),  # l
-            _vmem((g, hk), jnp.float32),              # acc
+            pltpu.VMEM((1, g * n_kv_heads), jnp.float32),  # m (lane = g*n_kv+h)
+            pltpu.VMEM((1, g * n_kv_heads), jnp.float32),  # l
+            pltpu.VMEM((g, hk), jnp.float32),              # acc
         ],
-        compiler_params=params,
+        compiler_params=_dim_semantics(interpret, ("parallel", "arbitrary")),
         interpret=interpret,
     )(*operands)
 
@@ -823,33 +822,20 @@ def flash_decode_attention_paged(
     slab kernel, so the HBM stream is the int8 bytes plus the table
     ints.
     """
-    if pltpu is None:  # pragma: no cover - CPU envs ship pallas.tpu
-        raise NotImplementedError(
-            "flash_decode_attention_paged needs jax.experimental."
-            "pallas.tpu (PrefetchScalarGridSpec)"
-        )
     b, g, hk = q.shape
     bs = blocks.shape[3]
     bps = tables.shape[1]
     head_dim = hk // n_kv_heads
     assert tables.shape == (b, bps), (tables.shape, b)
     assert bs % 8 == 0, f"block_size must be a multiple of 8, got {bs}"
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = _default_interpret() if interpret is None else interpret
     quantized = block_scales is not None
     kernel = functools.partial(
         _paged_decode_kernel, block_t=bs, n_t=bps,
         n_kv_heads=n_kv_heads, head_dim=head_dim, groups=g,
         scale=1.0 / (head_dim**0.5), quantized=quantized,
     )
-    pos_arr = jnp.broadcast_to(
-        jnp.reshape(jnp.asarray(pos, jnp.int32), (-1, 1)), (b, 1)
-    )
-    if not interpret:
-        params = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")
-        )
-    else:
-        params = None
+    pos_arr = _decode_positions(pos, b)
     in_specs = [
         pl.BlockSpec((1, g, hk), lambda i, tt, tbl: (i, 0, 0)),
         # K and V planes of the one block pool, table-indexed on the
@@ -862,7 +848,7 @@ def flash_decode_attention_paged(
             (1, 1, 1, bs, hk),
             lambda i, tt, tbl: (layer, 1, tbl[i, tt], 0, 0),
         ),
-        pl.BlockSpec((1, 1), lambda i, tt, tbl: (i, 0)),
+        _POS_SPEC,
     ]
     operands = [q, blocks, blocks, pos_arr]
     if quantized:
@@ -887,16 +873,16 @@ def flash_decode_attention_paged(
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, g, hk), lambda i, tt, tbl: (i, 0, 0)),
         scratch_shapes=[
-            _vmem((1, g * n_kv_heads), jnp.float32),  # m (lane = g*n_kv+h)
-            _vmem((1, g * n_kv_heads), jnp.float32),  # l
-            _vmem((g, hk), jnp.float32),              # acc
+            pltpu.VMEM((1, g * n_kv_heads), jnp.float32),  # m (lane = g*n_kv+h)
+            pltpu.VMEM((1, g * n_kv_heads), jnp.float32),  # l
+            pltpu.VMEM((g, hk), jnp.float32),              # acc
         ],
     )
     return pl.pallas_call(
         kernel,
         out_shape=jax.ShapeDtypeStruct((b, g, hk), q.dtype),
         grid_spec=grid_spec,
-        compiler_params=params,
+        compiler_params=_dim_semantics(interpret, ("parallel", "arbitrary")),
         interpret=interpret,
     )(jnp.asarray(tables, jnp.int32), *operands)
 
@@ -924,7 +910,7 @@ def fused_embedding_dot(
     L = w_rows.shape[1]
     block_b = min(block_b, b)
     assert b % block_b == 0
-    interpret = (not _on_tpu()) if interpret is None else interpret
+    interpret = _default_interpret() if interpret is None else interpret
     return pl.pallas_call(
         _emb_dot_kernel,
         out_shape=jax.ShapeDtypeStruct((b, L), h.dtype),

@@ -36,7 +36,7 @@ import jax.numpy as jnp
 import optax
 from jax import lax
 from jax.sharding import NamedSharding, PartitionSpec as P
-from deeplearning4j_tpu.utils.compat import shard_map
+from jax import shard_map
 
 from deeplearning4j_tpu.parallel import mesh as mesh_lib
 from deeplearning4j_tpu.utils import tree_math as tm

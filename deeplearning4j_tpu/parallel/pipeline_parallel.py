@@ -31,7 +31,7 @@ import jax.numpy as jnp
 import optax
 from jax import lax
 
-from deeplearning4j_tpu.utils.compat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 import numpy as np

@@ -195,8 +195,9 @@ _log = logging.getLogger(__name__)
 
 
 #: Declared donation intent per program family: the argnums each
-#: family donates ON TPU (CPU jit cannot alias donated buffers, so the
-#: engine passes () there — same programs, no aliasing). This table IS
+#: family donates, on every backend (XLA:CPU honours donation too, so
+#: the CPU suite runs the programs the chip runs and a handle kept
+#: across a dispatch fails there first). This table IS
 #: the contract the static donation audit (analysis/audit.py) checks
 #: against each family's traced avals: every donated argument must be
 #: consumable by an output of matching shape/dtype, or the donation is
@@ -1446,20 +1447,17 @@ class ServingEngine:
         self.prefill_dispatches = 0
 
         # donating the cache + per-slot state lets XLA update them in
-        # place (the cache is the dominant allocation); CPU jit can't
-        # alias donated buffers and would warn every call. The donated
+        # place (the cache is the dominant allocation). The donated
         # argnums per family are DECLARED in PROGRAM_DONATION — the
         # static donation audit checks that table against the traced
         # programs, so drift between intent and program shape fails CI.
-        self._tpu = jax.devices()[0].platform == "tpu"
-        self._state_donate = self._donate(
+        self._state_donate = PROGRAM_DONATION[
             "paged_step" if self._paged else "step"
-        )
+        ]
         # every program below is shared process-wide through
         # _shared_program keyed on this tuple + the family's own
-        # statics (platform is constant within a process, so the
-        # _donate() results are a function of the family name and
-        # need not be keyed)
+        # statics (the donated argnums are a function of the family
+        # name and need not be keyed)
         self._prog_key = (
             self._cfg_key, self.tp, self._paged, self._block_size,
             self.max_total,
@@ -1474,16 +1472,16 @@ class ServingEngine:
                     make_paged_fwd1(self._fwd1) if self._paged
                     else self._fwd1
                 ),
-                donate_argnums=self._donate(
+                donate_argnums=PROGRAM_DONATION[
                     "paged_replay" if self._paged else "replay"
-                ),
+                ],
             ),
         )
         self._deact_fn = _shared_program(
             self._prog_key + ("deactivate",),
             lambda: jax.jit(
                 build_deact_program(),
-                donate_argnums=self._donate("deactivate"),
+                donate_argnums=PROGRAM_DONATION["deactivate"],
             ),
         )
         self._prefill_fns: dict[int, object] = {}
@@ -1496,7 +1494,7 @@ class ServingEngine:
         self._seg_fetch_fn = None
         self._seg_import_fn = None
         self._logit_row_fn = None
-        self._admit_donate = self._donate("prefill")
+        self._admit_donate = PROGRAM_DONATION["prefill"]
         # paged program caches. The SLAB prefill/insert/chunk caches
         # above stay live in paged mode too: the parity probes run the
         # slab programs on scratch state, and the chunked partial-hit
@@ -1507,7 +1505,7 @@ class ServingEngine:
         self._paged_seg_fetch_fn = None
         self._paged_seg_import_fn = None
         self._block_copy_fn = None
-        self._paged_admit_donate = self._donate("paged_prefill")
+        self._paged_admit_donate = PROGRAM_DONATION["paged_prefill"]
         # chunked-prefill piggyback: one fused program per (bucket, K)
         # actually used, gated by a construction-time bitwise parity
         # probe (ProbeCache'd) — probe failure falls back to blocking
@@ -1778,11 +1776,6 @@ class ServingEngine:
     # so the static auditor traces the exact functions the engine jits;
     # these methods only cache the jitted callables per family key.
 
-    def _donate(self, family: str) -> tuple[int, ...]:
-        """Declared donation for one program family — active on TPU,
-        () on CPU (jit can't alias donated buffers there)."""
-        return PROGRAM_DONATION[family] if self._tpu else ()
-
     def _step_fn_for(self, horizon: int):
         """The compiled fused-step program for ``horizon`` substeps
         (cached per K — the adaptive horizon alternates between the
@@ -1858,10 +1851,10 @@ class ServingEngine:
                         self._fwd_chunk, horizon, self.temperature,
                         self.top_k, self.approx_top_k,
                     ),
-                    donate_argnums=self._donate(
+                    donate_argnums=PROGRAM_DONATION[
                         "paged_piggyback_step" if self._paged
                         else "piggyback_step"
-                    ),
+                    ],
                 ),
             )
             self._piggyback_fns[(bucket, horizon)] = fn
@@ -1884,10 +1877,10 @@ class ServingEngine:
                         else self._fwd1,
                         horizon, self._n_logprobs,
                     ),
-                    donate_argnums=self._donate(
+                    donate_argnums=PROGRAM_DONATION[
                         "paged_masked_step" if self._paged
                         else "masked_step"
-                    ),
+                    ],
                 ),
             )
             self._masked_step_fns[horizon] = fn
@@ -1909,10 +1902,10 @@ class ServingEngine:
                         else self._fwd1,
                         self._fwd_chunk, horizon, self._n_logprobs,
                     ),
-                    donate_argnums=self._donate(
+                    donate_argnums=PROGRAM_DONATION[
                         "paged_masked_piggyback_step" if self._paged
                         else "masked_piggyback_step"
-                    ),
+                    ],
                 ),
             )
             self._masked_piggyback_fns[(bucket, horizon)] = fn
@@ -1926,7 +1919,7 @@ class ServingEngine:
                 self._prog_key + ("gstate_set",),
                 lambda: jax.jit(
                     build_gstate_set_program(),
-                    donate_argnums=self._donate("gstate_set"),
+                    donate_argnums=PROGRAM_DONATION["gstate_set"],
                 ),
             )
         return self._gstate_set_fn
@@ -1951,7 +1944,7 @@ class ServingEngine:
                 self._prog_key + ("insert",),
                 lambda: jax.jit(
                     build_insert_program(),
-                    donate_argnums=self._donate("insert"),
+                    donate_argnums=PROGRAM_DONATION["insert"],
                 ),
             )
         return self._insert_fn
@@ -1965,7 +1958,7 @@ class ServingEngine:
                 self._prog_key + ("hit_insert",),
                 lambda: jax.jit(
                     build_hit_insert_program(),
-                    donate_argnums=self._donate("hit_insert"),
+                    donate_argnums=PROGRAM_DONATION["hit_insert"],
                 ),
             )
         return self._hit_insert_fn
@@ -1988,7 +1981,7 @@ class ServingEngine:
                 self._prog_key + ("seg_store",),
                 lambda: jax.jit(
                     build_seg_store_program(),
-                    donate_argnums=self._donate("seg_store"),
+                    donate_argnums=PROGRAM_DONATION["seg_store"],
                 ),
             )
         return self._seg_store_fn
@@ -2001,7 +1994,7 @@ class ServingEngine:
                 self._prog_key + ("seg_import",),
                 lambda: jax.jit(
                     build_seg_import_program(),
-                    donate_argnums=self._donate("seg_import"),
+                    donate_argnums=PROGRAM_DONATION["seg_import"],
                 ),
             )
         return self._seg_import_fn
@@ -2042,7 +2035,7 @@ class ServingEngine:
                 self._prog_key + ("paged_insert",),
                 lambda: jax.jit(
                     build_paged_insert_program(),
-                    donate_argnums=self._donate("paged_insert"),
+                    donate_argnums=PROGRAM_DONATION["paged_insert"],
                 ),
             )
         return self._paged_insert_fn
@@ -2065,7 +2058,7 @@ class ServingEngine:
                 self._prog_key + ("paged_seg_import",),
                 lambda: jax.jit(
                     build_paged_seg_import_program(),
-                    donate_argnums=self._donate("paged_seg_import"),
+                    donate_argnums=PROGRAM_DONATION["paged_seg_import"],
                 ),
             )
         return self._paged_seg_import_fn
@@ -2078,7 +2071,7 @@ class ServingEngine:
                 self._prog_key + ("block_copy",),
                 lambda: jax.jit(
                     build_block_copy_program(),
-                    donate_argnums=self._donate("block_copy"),
+                    donate_argnums=PROGRAM_DONATION["block_copy"],
                 ),
             )
         return self._block_copy_fn
@@ -3375,13 +3368,17 @@ class ServingEngine:
             topps = jnp.ones((n,), jnp.float32)
             bidx = jnp.full((n, MAX_LOGIT_BIAS), -1, jnp.int32)
             bval = jnp.zeros((n, MAX_LOGIT_BIAS), jnp.float32)
-            gstate = jnp.zeros((n,), jnp.int32)
+
+            def gstate():
+                # donated by the masked programs: fresh per call
+                return jnp.zeros((n,), jnp.int32)
+
             mask_tab, trans_tab = self._grammar_device_tables()
             out_a = self._step_fn_for(k)(
                 self.params, *decode_state(), jnp.asarray(keys), ad
             )
             out_b = self._masked_step_fn_for(k)(
-                self.params, *decode_state(), gstate,
+                self.params, *decode_state(), gstate(),
                 jnp.asarray(keys), ad, temps, topks, topps, bidx,
                 bval, mask_tab, trans_tab,
             )
@@ -3404,7 +3401,7 @@ class ServingEngine:
                     jnp.int32(0), jnp.int32(b - 1), cad,
                 )
                 out_d = self._masked_piggyback_fn(b, k)(
-                    self.params, *decode_state(), gstate,
+                    self.params, *decode_state(), gstate(),
                     jnp.asarray(keys), ad, temps, topks, topps, bidx,
                     bval, mask_tab, trans_tab,
                     self._init_caches(1, self.max_total), ctoks,
@@ -3580,15 +3577,10 @@ class ServingEngine:
                 out.append(np.asarray(logits))
             return out
 
-        try:
-            ref = stream(None)
-            tpo = stream(mesh)
-        except Exception as e:  # pragma: no cover - backend-specific
-            # conservative: a backend that cannot even run the probe
-            # (e.g. the single-chip reference does not fit) serves
-            # unsharded unless tp_parity=True overrides
-            log_event(_log, "tp_parity_probe_error", error=repr(e))
-            return False
+        # compile and runtime errors propagate: only an honest
+        # inequality is a verdict
+        ref = stream(None)
+        tpo = stream(mesh)
         return all(np.array_equal(a, b) for a, b in zip(ref, tpo))
 
     def _probe_lora_zero(self) -> bool:
@@ -3629,12 +3621,8 @@ class ServingEngine:
                 out.append(np.asarray(logits))
             return out
 
-        try:
-            ref = stream(base)
-            lz = stream(self.params)
-        except Exception as e:  # pragma: no cover - backend-specific
-            log_event(_log, "lora_parity_probe_error", error=repr(e))
-            return False
+        ref = stream(base)
+        lz = stream(self.params)
         return all(np.array_equal(a, b) for a, b in zip(ref, lz))
 
     def _probe_paged_parity(self, block_size: int) -> bool:
@@ -3656,75 +3644,71 @@ class ServingEngine:
             return False
         seq = ((1 + np.arange(n)) % self.cfg.vocab_size).astype(np.int32)
         prompt = jnp.asarray(seq[None])
-        try:
-            shapes = jax.eval_shape(
-                lambda: self._init_caches(1, total)
-            )
-            tpad = jax.tree.leaves(shapes)[0].shape[3]
-            if tpad % block_size:
-                return False
-            bps = tpad // block_size
-            tmp, lg = jax.jit(self._do_prefill)(  # lint: retrace-ok one-shot parity probe
-                self.params, self._init_caches(1, total), prompt
-            )
-            # slab leg: the prefilled slab landed in both rows of a
-            # 2-slot pool
-            slab = self._init_caches(2, total)
-            place = jax.jit(  # lint: retrace-ok one-shot parity probe
-                lambda c, t, s: jax.tree.map(
-                    lambda cc, tt: lax.dynamic_update_slice(
-                        cc, tt, (0, 0, s, 0, 0)
-                    ),
-                    c, t,
-                )
-            )
-            for s in (0, 1):
-                slab = place(slab, tmp, jnp.int32(s))
-            # paged leg: the same rows scattered through shuffled
-            # tables, rows 0 and 1 aliasing one shared block
-            perm = np.random.default_rng(0).permutation(2 * bps) + 1
-            tables = perm.reshape(2, bps).astype(np.int32)
-            tables[1, 0] = tables[0, 0]
-            blocks = jax.tree.map(
-                lambda sh: jnp.zeros(
-                    (sh.shape[0], sh.shape[1], 2 * bps + 1,
-                     block_size, sh.shape[4]),
-                    sh.dtype,
-                ),
-                shapes,
-            )
-            dtab = jnp.asarray(tables)
-            scatter = jax.jit(paged_slot_scatter)  # lint: retrace-ok one-shot parity probe
-            for s in (0, 1):
-                blocks = scatter(blocks, dtab[s], tmp)
-            pcaches = {"blocks": blocks, "tables": dtab}
-
-            sstep = jax.jit(  # lint: retrace-ok one-shot parity probe
-                lambda c, l, p: self._fwd1(
-                    self.params, c,
-                    jnp.argmax(l, axis=-1).astype(jnp.int32), p,
-                )
-            )
-            pfwd1 = make_paged_fwd1(self._fwd1)
-            pstep = jax.jit(  # lint: retrace-ok one-shot parity probe
-                lambda c, l, p: pfwd1(
-                    self.params, c,
-                    jnp.argmax(l, axis=-1).astype(jnp.int32), p,
-                )
-            )
-            lg2 = jnp.concatenate([lg, lg], axis=0)
-            slg, plg = lg2, lg2
-            pos = jnp.full((2,), n, jnp.int32)
-            for _ in range(3):
-                slg, slab = sstep(slab, slg, pos)
-                plg, pcaches = pstep(pcaches, plg, pos)
-                pos = pos + 1
-                if not np.array_equal(np.asarray(slg), np.asarray(plg)):
-                    return False
-            return True
-        except Exception as e:  # pragma: no cover - backend-specific
-            log_event(_log, "paged_parity_probe_error", error=repr(e))
+        shapes = jax.eval_shape(
+            lambda: self._init_caches(1, total)
+        )
+        tpad = jax.tree.leaves(shapes)[0].shape[3]
+        if tpad % block_size:
             return False
+        bps = tpad // block_size
+        tmp, lg = jax.jit(self._do_prefill)(  # lint: retrace-ok one-shot parity probe
+            self.params, self._init_caches(1, total), prompt
+        )
+        # slab leg: the prefilled slab landed in both rows of a
+        # 2-slot pool
+        slab = self._init_caches(2, total)
+        place = jax.jit(  # lint: retrace-ok one-shot parity probe
+            lambda c, t, s: jax.tree.map(
+                lambda cc, tt: lax.dynamic_update_slice(
+                    cc, tt, (0, 0, s, 0, 0)
+                ),
+                c, t,
+            )
+        )
+        for s in (0, 1):
+            slab = place(slab, tmp, jnp.int32(s))
+        # paged leg: the same rows scattered through shuffled
+        # tables, rows 0 and 1 aliasing one shared block
+        perm = np.random.default_rng(0).permutation(2 * bps) + 1
+        tables = perm.reshape(2, bps).astype(np.int32)
+        tables[1, 0] = tables[0, 0]
+        blocks = jax.tree.map(
+            lambda sh: jnp.zeros(
+                (sh.shape[0], sh.shape[1], 2 * bps + 1,
+                 block_size, sh.shape[4]),
+                sh.dtype,
+            ),
+            shapes,
+        )
+        dtab = jnp.asarray(tables)
+        scatter = jax.jit(paged_slot_scatter)  # lint: retrace-ok one-shot parity probe
+        for s in (0, 1):
+            blocks = scatter(blocks, dtab[s], tmp)
+        pcaches = {"blocks": blocks, "tables": dtab}
+
+        sstep = jax.jit(  # lint: retrace-ok one-shot parity probe
+            lambda c, l, p: self._fwd1(
+                self.params, c,
+                jnp.argmax(l, axis=-1).astype(jnp.int32), p,
+            )
+        )
+        pfwd1 = make_paged_fwd1(self._fwd1)
+        pstep = jax.jit(  # lint: retrace-ok one-shot parity probe
+            lambda c, l, p: pfwd1(
+                self.params, c,
+                jnp.argmax(l, axis=-1).astype(jnp.int32), p,
+            )
+        )
+        lg2 = jnp.concatenate([lg, lg], axis=0)
+        slg, plg = lg2, lg2
+        pos = jnp.full((2,), n, jnp.int32)
+        for _ in range(3):
+            slg, slab = sstep(slab, slg, pos)
+            plg, pcaches = pstep(pcaches, plg, pos)
+            pos = pos + 1
+            if not np.array_equal(np.asarray(slg), np.asarray(plg)):
+                return False
+        return True
 
     def _prefix_reuse_ok(self) -> bool:
         if self.prefix_cache is None:

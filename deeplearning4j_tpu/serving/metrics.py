@@ -105,35 +105,31 @@ _PEAK_HBM_BW = (
     ("TPU v4", 1228e9),
 )
 
-#: generous non-TPU fallbacks (modern server CPU with all cores +
-#: AMX-class units / DDR5 channels) — on CI the gauges must stay
-#: defined and inside (0, 1], not be calibrated
-_FALLBACK_PEAK_FLOPS = 5e12
-_FALLBACK_PEAK_HBM_BW = 1e12
+#: off-TPU stand-ins (a generous server CPU): they keep the gauges
+#: defined and inside (0, 1] where tier-1 pins their range. They are
+#: NOT calibrated — the gauge help text says so.
+_UNCALIBRATED_PEAK_FLOPS = 5e12
+_UNCALIBRATED_PEAK_HBM_BW = 1e12
 
 
 def _device_peaks() -> tuple[float, float]:
     """``(peak flop/s, peak bytes/s)`` for device 0: table-resolved on
-    TPU, the generous fallback elsewhere (the gauge help strings say
-    which regime is calibrated)."""
-    try:
-        import jax
+    TPU, the uncalibrated stand-ins on CPU. A TPU kind the tables do not
+    name is an error — a default would publish utilization of a device
+    the code does not know."""
+    import jax
 
-        dev = jax.devices()[0]
-        if dev.platform == "tpu":
-            kind = getattr(dev, "device_kind", "")
-            flops = next(
-                (p for pre, p in _PEAK_FLOPS if kind.startswith(pre)),
-                _PEAK_FLOPS[-1][1],
-            )
-            bw = next(
-                (p for pre, p in _PEAK_HBM_BW if kind.startswith(pre)),
-                _PEAK_HBM_BW[-1][1],
-            )
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return _UNCALIBRATED_PEAK_FLOPS, _UNCALIBRATED_PEAK_HBM_BW
+    kind = dev.device_kind
+    for (prefix, flops), (_, bw) in zip(_PEAK_FLOPS, _PEAK_HBM_BW):
+        if kind.startswith(prefix):
             return flops, bw
-    except Exception:
-        pass
-    return _FALLBACK_PEAK_FLOPS, _FALLBACK_PEAK_HBM_BW
+    raise RuntimeError(
+        f"no peak FLOP/s / HBM bandwidth entry for TPU device_kind "
+        f"{kind!r}; add it to serving/metrics.py"
+    )
 
 
 def _pct(res: Reservoir, p: float) -> float:
@@ -421,14 +417,16 @@ class ServingMetrics:
             "Live model-flop utilization per program family: audited "
             "envelope flops x dispatches / measured seconds / device "
             "peak, clamped to 1. Exact at the committed audit "
-            "geometry; a scale reference otherwise.", ("family",),
+            "geometry; a scale reference otherwise. Off-TPU the peak "
+            "is an uncalibrated stand-in.", ("family",),
         )
         self._g_mbu = reg.gauge(
             "serve_mbu",
             "Live memory-bandwidth utilization per program family: "
             "audited arg+out bytes x dispatches / measured seconds / "
             "peak HBM bandwidth, clamped to 1. Exact at the committed "
-            "audit geometry; a scale reference otherwise.", ("family",),
+            "audit geometry; a scale reference otherwise. Off-TPU the "
+            "peak is an uncalibrated stand-in.", ("family",),
         )
 
     def _emit(self, tag: str, value: float, step: int | None = None) -> None:

@@ -28,19 +28,22 @@ import tempfile
 
 def probe_key(probe: str, cfg_json: str, **geometry) -> str:
     """Stable digest for one probe verdict: the probe name, the full
-    model config JSON, the backend platform + participating device
-    count, and any program-geometry knobs the probe's compiled programs
+    model config JSON, the backend platform and device kind (a verdict
+    from one TPU generation cannot vouch for another), and any
+    program-geometry knobs the probe's compiled programs
     depend on (bucket sizes, slot counts, TP width...). The JAX version
     participates too: a verdict reflects the compiler that produced it,
     and an upgrade may change fusion/reduction order, so stale verdicts
     must miss rather than vouch for programs they never saw."""
     import jax
 
+    dev = jax.devices()[0]
     payload = {
         "probe": probe,
         "cfg": cfg_json,
         "jax": jax.__version__,
-        "platform": jax.devices()[0].platform,
+        "platform": dev.platform,
+        "device_kind": dev.device_kind,
         **{k: geometry[k] for k in sorted(geometry)},
     }
     blob = json.dumps(payload, sort_keys=True, separators=(",", ":"))

@@ -9,6 +9,7 @@ Run (any host; uses however many devices jax exposes):
   python examples/transformer_char_lm.py
 """
 
+import dataclasses
 import sys
 from pathlib import Path
 
@@ -53,7 +54,10 @@ def main():
         if (i + 1) % 50 == 0:
             print(f"step {i + 1}: loss {float(loss):.3f}")
 
-    gen = transformer_generate(cfg)
+    # params live on the mesh and transformer_generate takes none: the
+    # Pallas decode kernel cannot sit bare in a multi-device jit on TPU
+    # (dense path under SPMD, as TransformerConfig.decode_kernel says)
+    gen = transformer_generate(dataclasses.replace(cfg, decode_kernel=False))
     out = gen(params, jnp.asarray(arr[None, :16]), jax.random.key(1), 64,
               temperature=0.8, top_k=20)
     print("sample:", bytes(np.asarray(out[0], np.uint8).tolist()).decode("latin-1"))

@@ -189,3 +189,27 @@ def test_s3_and_gcs_savers_via_injected_clients(tmp_path):
     uri = gcs.save(b"gcs-blob", "final.npz")
     assert uri == "gs://models/runs/b/final.npz"
     assert gcs.load("final.npz") == b"gcs-blob"
+
+
+def test_compile_cache_is_placed_from_outside(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR set: nothing is set in code. Unset: the
+    cache is <checkout>/.jax_cache — a fixed path, no temp names."""
+    from pathlib import Path
+
+    import jax
+
+    from deeplearning4j_tpu.utils.compile_cache import use_compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+        assert use_compile_cache() == "/some/dir"
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        checkout = Path(__file__).resolve().parent.parent
+        assert use_compile_cache() == str(checkout / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == str(
+            checkout / ".jax_cache"
+        )
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

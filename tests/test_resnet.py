@@ -66,7 +66,7 @@ def test_sync_bn_shard_map_matches_full_batch(devices):
     batch shard; the moments are averaged over the dp axis)."""
     from functools import partial
 
-    from deeplearning4j_tpu.utils.compat import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     from deeplearning4j_tpu.models.resnet import _batch_norm

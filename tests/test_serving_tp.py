@@ -163,11 +163,10 @@ def test_tp_crash_recovery_replay_parity():
 
 
 def test_tp_requires_dividing_heads():
-    """tp=3 cannot shard 4 heads: the engine must fall back to tp=1
-    (conservative gating), not crash or mis-shard."""
-    eng = _engine(tp=3)
-    assert eng.tp == 1
-    assert eng.tp_mesh is None
+    """tp=3 cannot shard 4 heads: construction says so. (The parity
+    probe used to swallow this error and serve on one chip.)"""
+    with pytest.raises(ValueError, match="dividing n_heads"):
+        _engine(tp=3)
 
 
 def test_tp1_is_the_unsharded_engine():

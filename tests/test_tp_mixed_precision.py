@@ -98,15 +98,29 @@ def test_mixed_bf16_loss_runs_in_accum_dtype():
         assert np.isfinite(l).all() and l[-1] < l[0] * 0.5
 
 
-@pytest.mark.slow
-def test_graft_dryrun_multichip(devices):
+def _graft_entry():
     import importlib.util
+    from pathlib import Path
 
+    # relative to this file, so the suite runs from any checkout
     spec = importlib.util.spec_from_file_location(
-        "graft_entry_test", "/root/repo/__graft_entry__.py"
+        "graft_entry_test",
+        Path(__file__).resolve().parent.parent / "__graft_entry__.py",
     )
     m = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(m)
+    return m
+
+
+def test_graft_dryrun_raises_when_devices_are_short(devices):
+    """No fallback to another platform: too few devices is an error."""
+    with pytest.raises(RuntimeError, match="needs 64 devices"):
+        _graft_entry().dryrun_multichip(64)
+
+
+@pytest.mark.slow
+def test_graft_dryrun_multichip(devices):
+    m = _graft_entry()
     fn, args = m.entry()
     out = jax.jit(fn)(*args)
     assert out.shape == (8, 10)

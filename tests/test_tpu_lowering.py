@@ -1,0 +1,189 @@
+"""Cross-lowering: every Pallas entry point and the serving programs that
+carry one must LOWER for the TPU, compiled (``interpret=False``), on the
+CPU runner.
+
+Interpret mode accepts block shapes the TPU lowering refuses (PR 1's
+``(1, 1)`` position block of a ``(B, 1)`` array lived 20 PRs that way),
+so interpret-mode parity tests cannot stand in for this. Lowering is the
+cheap half — Mosaic's own compile (VMEM limit, tiling) needs the chip,
+which is what ``chip_smoke.py`` is for.
+"""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from deeplearning4j_tpu.analysis.programs import (
+    ServingGeometry,
+    enumerate_programs,
+)
+from deeplearning4j_tpu.models.transformer import (
+    TransformerConfig,
+    init_transformer,
+    transformer_loss,
+    transformer_shardings,
+)
+from deeplearning4j_tpu.ops import pallas_kernels as pk
+from deeplearning4j_tpu.parallel.mesh import batch_sharding, dp_mp_mesh
+
+S = jax.ShapeDtypeStruct
+
+# the GPT-2s-GQA serving geometry (bench.py's serve rows, chip_smoke.py)
+SERVE_CFG = TransformerConfig(
+    vocab_size=50304, d_model=768, n_heads=6, n_kv_heads=2, n_layers=12,
+    d_ff=3072, max_len=577, rope=True, use_flash=True,
+    compute_dtype=jnp.bfloat16, decode_kernel=True,
+)
+SERVE_GEOM = ServingGeometry(
+    n_slots=16, max_total=577, decode_horizon=4, adaptive_horizon=False,
+    prefill_max_bucket=128, paged=True, block_size=8,
+)
+
+
+@pytest.fixture(autouse=True)
+def compiled_kernels(monkeypatch):
+    """Kernels built with ``interpret=None`` take the compiled path, as
+    they do on the chip."""
+    monkeypatch.setattr(pk, "_default_interpret", lambda: False)
+
+
+def lower_tpu(fn, *avals) -> str:
+    text = jax.jit(fn).trace(*avals).lower(
+        lowering_platforms=("tpu",)
+    ).as_text()
+    assert "tpu_custom_call" in text, "no Mosaic kernel in the lowering"
+    return text
+
+
+def test_flash_forward_and_backward_lower():
+    q = S((2, 6, 1024, 128), jnp.bfloat16)
+
+    def fwd(q, k, v):
+        return pk.flash_attention_trainable(
+            q, k, v, causal=True, block_q=512, block_k=512, layout="bhtd",
+        )
+
+    lower_tpu(fwd, q, q, q)
+    lower_tpu(
+        jax.grad(lambda q, k, v: fwd(q, k, v).astype(jnp.float32).sum(),
+                 argnums=(0, 1, 2)),
+        q, q, q,
+    )
+
+
+@pytest.mark.parametrize("batch", [1, 16])
+@pytest.mark.parametrize("int8", [False, True])
+def test_slab_decode_lowers(batch, int8):
+    layers, t, n_kv, hk = 2, 584, 2, 256
+    avals = [
+        S((batch, 3, hk), jnp.bfloat16),
+        S((layers, 2, batch, t, hk), jnp.int8 if int8 else jnp.bfloat16),
+        None,
+    ]
+    if int8:
+        avals.append(S((layers, 2, batch, t, 1), jnp.float32))
+    # per-slot positions (serving), then a scalar (generate/beam)
+    for pos_shape in ((batch,), ()):
+        avals[2] = S(pos_shape, jnp.int32)
+        lower_tpu(
+            lambda q, c, pos, sc=None: pk.flash_decode_attention(
+                q, c, pos, n_kv, layer=1, kv_scales=sc
+            ),
+            *avals,
+        )
+
+
+@pytest.mark.parametrize("block_size", [8, 16, 128])
+@pytest.mark.parametrize("int8", [False, True])
+def test_paged_decode_lowers(block_size, int8):
+    layers, batch, n_blocks, bps, n_kv, hk = 2, 16, 64, 5, 2, 256
+    avals = [
+        S((batch, 3, hk), jnp.bfloat16),
+        S((layers, 2, n_blocks, block_size, hk),
+          jnp.int8 if int8 else jnp.bfloat16),
+        S((batch, bps), jnp.int32),
+        S((batch,), jnp.int32),
+    ]
+    if int8:
+        avals.append(S((layers, 2, n_blocks, block_size, 1), jnp.float32))
+    lower_tpu(
+        lambda q, b, tbl, pos, sc=None: pk.flash_decode_attention_paged(
+            q, b, tbl, pos, n_kv, layer=1, block_scales=sc
+        ),
+        *avals,
+    )
+
+
+def _lower_spec(spec) -> str:
+    return spec.trace().lower(lowering_platforms=("tpu",)).as_text()
+
+
+@pytest.mark.parametrize("name", [
+    "step[K=4]", "replay", "prefill[b=128]",
+    "piggyback_step[b=128,K=4]", "paged_step[K=4]",
+])
+def test_serve_program_lowers_with_decode_kernel(name):
+    """The families that were refused before the position vector moved
+    to SMEM (``step``, ``replay``, every ``piggyback_step``), one
+    prefill (the flash forward inside a serving program) and the paged
+    step, at the serve geometry."""
+    spec = next(
+        s for s in enumerate_programs(SERVE_CFG, SERVE_GEOM)
+        if s.name == name
+    )
+    assert "tpu_custom_call" in _lower_spec(spec)
+
+
+@pytest.mark.slow
+def test_every_serve_family_lowers():
+    """All families of the serve geometry (slab + paged + sampling
+    surface): nothing is refused by the TPU lowering."""
+    geom = dataclasses.replace(SERVE_GEOM, sampling_surface=True)
+    for spec in enumerate_programs(SERVE_CFG, geom):
+        _lower_spec(spec)
+
+
+@pytest.mark.parametrize("seq,batch,d,heads,layers,d_ff,vocab,remat", [
+    (1024, 24, 768, 6, 12, 3072, 50304, True),   # bench "transformer"
+    (8192, 2, 512, 4, 8, 2048, 8192, False),     # "transformer-flash-8k"
+    (32768, 1, 512, 4, 8, 2048, 8192, False),    # "transformer-flash-32k"
+])
+def test_training_presets_lower(seq, batch, d, heads, layers, d_ff, vocab,
+                                remat):
+    cfg = TransformerConfig(
+        vocab_size=vocab, d_model=d, n_heads=heads, n_layers=layers,
+        d_ff=d_ff, max_len=seq + 1, use_flash=True, remat=remat,
+        scan_layers=False, compute_dtype=jnp.bfloat16,
+    )
+    params = jax.eval_shape(
+        lambda: init_transformer(jax.random.key(0), cfg)
+    )
+    lower_tpu(
+        jax.value_and_grad(transformer_loss(cfg)),
+        params, S((batch, seq + 1), jnp.int32),
+    )
+
+
+def test_flash_training_lowers_on_a_dp_tp_mesh(devices):
+    """A Mosaic kernel bare inside a multi-device jit is refused by the
+    TPU lowering ("cannot be automatically partitioned"); interpreted,
+    it is plain ops GSPMD partitions, so the CPU suite never saw it.
+    transformer_apply runs the kernel under a shard_map when it has a
+    mesh."""
+    mesh = dp_mp_mesh(2, 2)
+    cfg = TransformerConfig(
+        vocab_size=512, d_model=256, n_heads=2, n_layers=2, d_ff=512,
+        max_len=257, use_flash=True, remat=True, scan_layers=False,
+        compute_dtype=jnp.bfloat16,
+    )
+    params = jax.tree.map(
+        lambda a, sh: S(a.shape, a.dtype, sharding=sh),
+        jax.eval_shape(lambda: init_transformer(jax.random.key(0), cfg)),
+        transformer_shardings(mesh, cfg),
+    )
+    lower_tpu(
+        jax.value_and_grad(transformer_loss(cfg, mesh)),
+        params, S((4, 257), jnp.int32, sharding=batch_sharding(mesh)),
+    )
