@@ -880,7 +880,6 @@ def _bench_decode_serve(args, n_slots: int = 16, n_requests: int = 48,
             ),
             "phase_frac": s.get("phase_frac", {}),
             "phase_seconds": s.get("phase_seconds", {}),
-            "program_seconds": s.get("program_seconds", {}),
         }
         metric = ("transformer_gpt2s_h128_decode_serve_faults_"
                   "tokens_per_sec_per_chip")
@@ -921,7 +920,6 @@ def _bench_decode_serve(args, n_slots: int = 16, n_requests: int = 48,
         ),
         "phase_frac": s.get("phase_frac", {}),
         "phase_seconds": s.get("phase_seconds", {}),
-        "program_seconds": s.get("program_seconds", {}),
     }
     metric = "transformer_gpt2s_h128_decode_serve_tokens_per_sec_per_chip"
     return tok_per_sec, metric, extra

@@ -125,51 +125,8 @@ REHEARSAL = Size(
 )
 
 
-class CompileLog:
-    """Counts what jax compiles, from jax's own monitoring events: every
-    backend compile request (a persistent-cache hit is still a request),
-    the seconds spent tracing + lowering + compiling, and the cache's hits
-    and misses. Listeners fire on whichever thread compiles."""
-
-    _TIMED = (
-        "/jax/core/compile/jaxpr_trace_duration",
-        "/jax/core/compile/jaxpr_to_mlir_module_duration",
-        "/jax/core/compile/backend_compile_duration",
-    )
-
-    def __init__(self, jax):
-        self._lock = threading.Lock()
-        self.requests = 0
-        self.seconds = 0.0
-        self.hits = 0
-        self.misses = 0
-        self.names: list[str] = []
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event, seconds, **meta):
-        if event not in self._TIMED:
-            return
-        with self._lock:
-            self.seconds += seconds
-            if event.endswith("backend_compile_duration"):
-                self.requests += 1
-                self.names.append(str(meta.get("fun_name", "?")))
-
-    def _event(self, event, **meta):
-        with self._lock:
-            if event == "/jax/compilation_cache/cache_hits":
-                self.hits += 1
-            elif event == "/jax/compilation_cache/cache_misses":
-                self.misses += 1
-
-    def snapshot(self):
-        with self._lock:
-            return (self.requests, self.seconds, self.hits, self.misses)
-
-
 @contextlib.contextmanager
-def phase(log: CompileLog, name: str):
+def phase(log, name: str):
     """Print one line per phase: wall seconds, compile seconds, compile
     requests and persistent-cache hits/misses inside it. An exception
     passes through: the run ends there."""
@@ -288,7 +245,7 @@ def decode_reference(q, cache, pos, n_kv, scales=None):
     return o.reshape(b, g, hk)
 
 
-def kernels_leg(size: Size, log: CompileLog) -> None:
+def kernels_leg(size: Size, log) -> None:
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -441,7 +398,7 @@ def compiled_step_has_mosaic(size: Size, cfg, rehearse: bool) -> None:
           f"(>= one per layer)")
 
 
-def serve_leg(size: Size, log: CompileLog, rehearse: bool) -> None:
+def serve_leg(size: Size, log, rehearse: bool) -> None:
     import jax
     import numpy as np
 
@@ -503,6 +460,7 @@ def serve_leg(size: Size, log: CompileLog, rehearse: bool) -> None:
                              alone[name])
         with phase(log, "serve/concurrent"):
             before = log.snapshot()[0]
+            engine.mark_warm()  # from here a compile is a recompile
             names = ["short", "exact", "long", "pair", "pair"]
             with concurrent.futures.ThreadPoolExecutor(len(names)) as pool:
                 futures = [
@@ -524,7 +482,10 @@ def serve_leg(size: Size, log: CompileLog, rehearse: bool) -> None:
             compiled = log.snapshot()[0] - before
             check(compiled == 0,
                   f"{compiled} compile requests after warm-up "
-                  f"{log.names[len(log.names) - compiled:] if compiled else ''}")
+                  f"{log.names_since(before) if compiled else ''}")
+            check(not engine.metrics.recompiles,
+                  f"serve_recompiles_total empty after mark_warm() "
+                  f"{engine.metrics.recompiles or ''}")
         with phase(log, "serve/endpoints"):
             metrics = http_json(base + "/metrics")
             check("serve_requests_total" in metrics
@@ -570,7 +531,7 @@ def timed_steps(step, state, toks, n: int):
     return state, losses, secs
 
 
-def train_leg(size: Size, log: CompileLog) -> None:
+def train_leg(size: Size, log) -> None:
     import functools
 
     import jax
@@ -649,7 +610,7 @@ def train_leg(size: Size, log: CompileLog) -> None:
 # -- probes (builder's leg) ---------------------------------------------------
 
 
-def probes_leg(size: Size, log: CompileLog) -> None:
+def probes_leg(size: Size, log) -> None:
     """Every parity probe's verdict on this device, for D2. A verdict is
     information; a probe that cannot compile or run raises and fails the
     leg. Depth is cut to ``probe_layers`` (the probes compare two
@@ -784,7 +745,7 @@ def split_leaves(tree) -> int:
     )
 
 
-def multichip_leg(size: Size, log: CompileLog, rehearse: bool) -> None:
+def multichip_leg(size: Size, log, rehearse: bool) -> None:
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -932,7 +893,9 @@ def main(argv: list[str] | None = None) -> int:
         return 2
 
     size = REHEARSAL if args.rehearse else FULL
-    log = CompileLog(jax)
+    from deeplearning4j_tpu.obs import compile_log
+
+    log = compile_log.install()
     t0 = time.perf_counter()
     for leg in legs:
         if leg == "kernels":

@@ -822,55 +822,3 @@ def default_audit_geometry() -> ServingGeometry:
         sampling_surface=True,
         grammar_states=64,
     )
-
-
-def family_budgets(path: str | None = None) -> dict[str, dict]:
-    """Per-family static flop/byte budgets from the committed
-    ``.graftaudit.json`` baseline: ``{family: {"flops": int, "bytes":
-    int}}``, the denominators for the live MFU/MBU gauges.
-
-    Only each family's ENVELOPE program (the largest geometry variant)
-    carries ``flops``/``temp_bytes`` in the baseline, so exactly those
-    entries are picked up; the family name is the program name with
-    its ``[...]`` geometry suffixes stripped, and base variants win
-    over ``[tp=...]``/``[lora]`` ones (the live single-chip engine
-    dispatches base programs). ``bytes`` is the argument+output
-    traffic of the envelope — the honest HBM floor a perfectly fused
-    execution must move.
-
-    The budgets are EXACT for the committed audit geometry
-    (``default_audit_config``/``default_audit_geometry``, also the
-    bench geometry) and a scale reference otherwise — the gauge docs
-    on ``/metrics`` say so. Returns ``{}`` when no baseline is found
-    (installed package without the repo checkout), so callers degrade
-    to seconds-only attribution.
-    """
-    from deeplearning4j_tpu.analysis.audit import (
-        default_baseline_path, load_baseline,
-    )
-
-    data = load_baseline(path or default_baseline_path())
-    if not data:
-        return {}
-    out: dict[str, dict] = {}
-    for name, rec in data.get("programs", {}).items():
-        flops = rec.get("flops")
-        if flops is None:
-            continue  # not the family envelope
-        family = name.split("[", 1)[0]
-        variant = "[tp=" in name or "[lora" in name
-        prev = out.get(family)
-        if prev is not None and not prev["_variant"] and variant:
-            continue  # a base-variant envelope already won
-        if prev is None or variant == prev["_variant"]:
-            if prev is not None and int(flops) <= prev["flops"]:
-                continue  # keep the larger envelope
-        out[family] = {
-            "flops": int(flops),
-            "bytes": int(rec.get("arg_bytes", 0))
-            + int(rec.get("out_bytes", 0)),
-            "_variant": variant,
-        }
-    for rec in out.values():
-        del rec["_variant"]
-    return out

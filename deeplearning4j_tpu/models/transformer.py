@@ -1471,6 +1471,18 @@ def paged_block_copy(blocks, src, dst):
     )
 
 
+def decode_rows_streamed(batch: int, tpad: int) -> int:
+    """Cache rows (of one layer's K or V plane) that one ``forward_one``
+    call reads, whatever the slots hold: the decode kernel's grid walks
+    every ``block_t`` block of every slot's ``tpad`` rows and masks what
+    lies past ``pos`` after it is read, the dense path contracts over
+    the whole cache, and the paged wrapper below gathers every table
+    entry (sentinel blocks too) into that same slab before the step.
+    The engine books this as ``kv_rows_streamed`` beside the rows the
+    requests needed; a kernel that reads less says so here."""
+    return batch * tpad
+
+
 def make_paged_fwd1(fwd1):
     """Paged wrapper of a ``_decode_builder`` ``forward_one``: gather
     the block pool into the slab view, run the IDENTICAL slab step
@@ -2183,6 +2195,9 @@ def transformer_train_step(
     shards params/optimizer state over the data axis (ZeRO-3 layout via
     :func:`fsdp_shardings`).
     """
+    from deeplearning4j_tpu.obs import compile_log
+
+    compile_log.install()  # the step's compile is counted by the program
     optimizer = optimizer or optax.adamw(3e-4)
     loss_fn = transformer_loss(cfg, mesh)
     shardings = (

@@ -22,7 +22,11 @@ training orchestrator — can depend on it without cycles:
 - :class:`~deeplearning4j_tpu.obs.profiler.ProfileTrigger` — arms
   ``jax.profiler`` tracing around the next N engine steps
   (``POST /profile?s=N`` on the serving server, or a CLI flag).
+- :mod:`~deeplearning4j_tpu.obs.compile_log` — what jax compiles in
+  this process, by function and stage (``compile_log.install()``).
 """
+
+from deeplearning4j_tpu.obs import compile_log  # noqa: F401
 
 from deeplearning4j_tpu.obs.collect import (  # noqa: F401
     merge_trace_files,
@@ -42,6 +46,7 @@ from deeplearning4j_tpu.obs.registry import (  # noqa: F401
     Reservoir,
 )
 from deeplearning4j_tpu.obs.trace import (  # noqa: F401
+    PhaseRegions,
     Tracer,
     format_traceparent,
     new_span_id,

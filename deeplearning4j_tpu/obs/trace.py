@@ -27,6 +27,12 @@ Design constraints (this sits on the serving hot path):
   region costs exactly the two clock reads the region's metrics
   already paid.
 
+Loop phases (:class:`PhaseRegions`) are the one exception to "disabled
+means free": a phase of the engine loop is measured whether or not the
+tracer is on, because its seconds feed an always-on total and its name
+goes into any profiler capture that happens to be running. The ring
+span is still recorded only when the tracer is enabled.
+
 Export is the ``trace_event`` JSON format (the Trace Event Format spec
 both ``chrome://tracing`` and https://ui.perfetto.dev load): complete
 events (``ph: "X"``) with microsecond ``ts``/``dur``, one ``tid`` per
@@ -93,6 +99,99 @@ def parse_traceparent(header: str | None) -> tuple[str, str] | None:
     if set(trace_id) == {"0"} or set(span_id) == {"0"}:
         return None
     return trace_id, span_id
+
+
+class PhaseRegions:
+    """Names the phases of a loop, once, for everything that wants them.
+
+    ``regions("dispatch", n=7)`` is a context manager around one phase of
+    one turn of the loop. With the two clock reads it takes it
+
+    - opens a ``jax.profiler.TraceAnnotation`` called ``<prefix>.<name>``
+      with the keyword arguments as its arguments, so that any profiler
+      capture (an operator's ``POST /profile``, a benchmark's) shows the
+      phase on the host plane, on the clock of the device's own events;
+    - adds the phase's *self* seconds (its own, less those of regions
+      opened inside it) to ``totals[name]``: exact, always on, and
+      additive, so the phases of a turn sum to no more than its wall
+      time;
+    - when ``tracer`` is enabled, keeps a ring span ``name`` on ``track``
+      until :meth:`flush` says whether the turn is worth recording (an
+      idle loop polls every few milliseconds and would wash the ring
+      out).
+
+    ``on_phase`` is told the name of the outermost open region, and
+    ``None`` when it closes: the sanitizer's per-phase sync budgets hang
+    on it. One thread drives a loop, so nothing here locks.
+    """
+
+    def __init__(self, prefix: str, totals: dict[str, float],
+                 tracer: "Tracer", track: str, on_phase=None):
+        from jax.profiler import TraceAnnotation
+
+        self._annotation = TraceAnnotation
+        self.prefix = prefix
+        self.totals = totals
+        self.tracer = tracer
+        self.track = track
+        self.on_phase = on_phase
+        self._open: list[_Region] = []
+        self._spans: list[tuple] = []
+        self._full: dict[str, str] = {}  # phase -> annotation name
+
+    def __call__(self, name: str, **args) -> "_Region":
+        full = self._full.get(name)
+        if full is None:
+            full = self._full[name] = f"{self.prefix}.{name}"
+        return _Region(self, name, full, args)
+
+    def flush(self, keep: bool) -> None:
+        """End of a turn: hand its spans to the tracer, or drop them."""
+        if self._spans:
+            if keep:
+                for name, t0, dur, args in self._spans:
+                    self.tracer.span(self.track, name, t0, dur, **args)
+            self._spans.clear()
+
+
+class _Region:
+    __slots__ = ("_owner", "name", "_full", "args", "_annotation", "_t0",
+                 "_inner")
+
+    def __init__(self, owner: PhaseRegions, name: str, full: str,
+                 args: dict):
+        self._owner = owner
+        self.name = name
+        self._full = full
+        self.args = args
+
+    def __enter__(self) -> "_Region":
+        owner = self._owner
+        if not owner._open and owner.on_phase is not None:
+            owner.on_phase(self.name)
+        owner._open.append(self)
+        self._inner = 0.0
+        # outside a capture TraceMe checks one atomic flag and builds
+        # nothing from the name or the arguments
+        self._annotation = owner._annotation(self._full, **self.args)
+        self._annotation.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        dur = time.perf_counter() - self._t0
+        self._annotation.__exit__(*exc)
+        owner = self._owner
+        owner._open.pop()
+        totals = owner.totals
+        totals[self.name] = totals.get(self.name, 0.0) + dur - self._inner
+        if owner._open:
+            owner._open[-1]._inner += dur
+        elif owner.on_phase is not None:
+            owner.on_phase(None)
+        if owner.tracer.enabled:
+            owner._spans.append((self.name, self._t0, dur, self.args))
+        return False
 
 
 class Tracer:

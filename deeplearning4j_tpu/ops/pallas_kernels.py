@@ -201,6 +201,7 @@ def _flash_fwd_call(qf, kf, vf, block_q, block_k, interpret, causal):
         ],
         compiler_params=_dim_semantics(interpret),
         interpret=interpret,
+        name="flash_fwd",
     )(qf, kf, vf)
 
 
@@ -419,6 +420,7 @@ def _flash_bwd_rule(
             interpret, ("parallel", "arbitrary", "arbitrary")
         ),
         interpret=interpret,
+        name="flash_bwd",
     )(qf, kf, vf, do, lse, delta)
     if dq_partials:
         dq = jnp.sum(dq_raw.astype(jnp.float32), axis=0).astype(qf.dtype)
@@ -776,6 +778,7 @@ def flash_decode_attention(
         ],
         compiler_params=_dim_semantics(interpret, ("parallel", "arbitrary")),
         interpret=interpret,
+        name="decode_attn",
     )(*operands)
 
 
@@ -884,6 +887,7 @@ def flash_decode_attention_paged(
         grid_spec=grid_spec,
         compiler_params=_dim_semantics(interpret, ("parallel", "arbitrary")),
         interpret=interpret,
+        name="paged_decode_attn",
     )(jnp.asarray(tables, jnp.int32), *operands)
 
 
@@ -922,4 +926,5 @@ def fused_embedding_dot(
         ],
         out_specs=pl.BlockSpec((block_b, L), lambda i: (i, 0)),
         interpret=interpret,
+        name="emb_dot",
     )(h, w_rows, mask)

@@ -133,6 +133,7 @@ from deeplearning4j_tpu.models.transformer import (
     _chunk_builder,
     _decode_builder,
     _top_k_filter,
+    decode_rows_streamed,
     make_paged_fwd1,
     paged_block_copy,
     paged_slot_gather,
@@ -141,12 +142,14 @@ from deeplearning4j_tpu.models.transformer import (
     serving_tp_cache_sharding,
 )
 from deeplearning4j_tpu.parallel.mesh import model_parallel_mesh
+from deeplearning4j_tpu.obs import compile_log
 from deeplearning4j_tpu.obs.flight import FlightRecorder
 from deeplearning4j_tpu.obs.logs import log_event
 from deeplearning4j_tpu.obs.profiler import ProfileTrigger
 from deeplearning4j_tpu.obs.trace import (
     ENGINE_TRACK,
     SCHEDULER_TRACK,
+    PhaseRegions,
     Tracer,
     new_span_id,
     slot_track,
@@ -923,7 +926,8 @@ class _SlotState:
 
     __slots__ = ("req", "tokens", "t_first_token", "gen", "key_data",
                  "adapter", "segs", "gkey", "gstate0", "stop_matcher",
-                 "lp_out", "n_stripped")
+                 "lp_out", "n_stripped", "n_substeps", "t_boundary",
+                 "t_seated")
 
     def __init__(self, req: Request, gen: int, key_data,
                  adapter: int = 0):
@@ -951,6 +955,15 @@ class _SlotState:
         self.stop_matcher: StopMatcher | None = None
         self.lp_out: list | None = None
         self.n_stripped = 0
+        # decode substeps dispatched for this slot: with the prompt's
+        # length, the cache rows it holds on the device (the host's
+        # ``tokens`` lag the device by the horizon in flight)
+        self.n_substeps = 0
+        # the step boundary that popped the request and the moment its
+        # slot went live, for the time-to-first-token split; None for a
+        # state rebuilt by recovery or seated by migration
+        self.t_boundary: float | None = None
+        self.t_seated: float | None = None
 
 
 class _AdmitPlan:
@@ -960,11 +973,12 @@ class _AdmitPlan:
     the usable grain-aligned cached-token count)."""
 
     __slots__ = ("req", "slot", "kind", "seg", "matched", "admitted",
-                 "prefill_s", "t_pf")
+                 "prefill_s", "t_pf", "t_boundary")
 
-    def __init__(self, req: Request, slot: int):
+    def __init__(self, req: Request, slot: int, t_boundary: float):
         self.req = req
         self.slot = slot
+        self.t_boundary = t_boundary  # the step boundary that popped it
         self.kind = "miss"
         self.seg: Segment | None = None
         self.matched = 0
@@ -1016,12 +1030,13 @@ class _Inflight:
     the (slots, K) token block plus a snapshot of who occupied each
     slot at dispatch time."""
 
-    __slots__ = ("toks", "snaps", "t_dispatch")
+    __slots__ = ("toks", "snaps", "t_dispatch", "n")
 
-    def __init__(self, toks, snaps, t_dispatch):
+    def __init__(self, toks, snaps, t_dispatch, n):
         self.toks = toks
         self.snaps = snaps  # [(slot, _SlotState)] occupied at dispatch
         self.t_dispatch = t_dispatch
+        self.n = n  # horizon number: links dispatch, sync, decode spans
 
 
 class ServingEngine:
@@ -1055,8 +1070,11 @@ class ServingEngine:
     Observability: ``tracer`` (an :class:`~deeplearning4j_tpu.obs
     .trace.Tracer`) records the request lifecycle as spans — queued on
     the scheduler track, prefill/decode/first-token/terminal per slot
-    track, dispatch/sync/step on the engine track — defaulting to a
-    DISABLED tracer (every record call is one attribute check);
+    track, the loop's phases (sweep/admit/prefill/dispatch/sync/
+    process) and the whole step on the engine track — defaulting to a
+    DISABLED tracer (every record call is one attribute check). The
+    phases are also ``engine.<phase>`` annotations in any profiler
+    capture and exact totals in ``metrics.loop_seconds``, tracer or not;
     ``profile`` (an :class:`~deeplearning4j_tpu.obs.profiler
     .ProfileTrigger`) brackets engine steps so an armed XLA capture
     starts and stops on step boundaries.
@@ -1090,7 +1108,6 @@ class ServingEngine:
         results_cap: int = 1024,
         tracer: Tracer | None = None,
         flight: FlightRecorder | None = None,
-        attribution: bool = True,
         profile: ProfileTrigger | None = None,
         tp: int = 1,
         tp_parity: bool | str = "auto",
@@ -1112,14 +1129,16 @@ class ServingEngine:
     ):
         self.n_slots = n_slots
         self.max_total = int(min(max_total or cfg.max_len, cfg.max_len))
-        # per-program-family device-time attribution (see _attr /
-        # _flush_attr): armed at the END of __init__ so construction
-        # probes never count, same "probes don't count" contract as
-        # prefill_dispatches. _attr_suspend re-suspends during runtime
-        # probes and recovery replay.
-        self._attr_enabled = False
-        self._attr_suspend = 0
-        self._pending_attr: list[tuple[str, float]] = []
+        # programs dispatched while this is non-zero are not traffic
+        # and stay out of metrics.program_dispatches: construction and
+        # its parity probes (released at the END of __init__), runtime
+        # probes, recovery replay. Same "probes don't count" contract
+        # as prefill_dispatches.
+        self._uncounted = 1
+        # the process's compile log, installed before anything below
+        # compiles; _compiles_seen is its request count at mark_warm()
+        self._compile_log = compile_log.install()
+        self._compiles_seen: int | None = None
         # crash flight recorder: enabled by default (one deque.append
         # per horizon/admission — postmortems must exist BEFORE the
         # incident, so this is not opt-in like the tracer)
@@ -1332,6 +1351,18 @@ class ServingEngine:
             self.scheduler.max_total_tokens = self.max_total
         self.metrics = metrics or ServingMetrics()
         self.metrics.decode_horizon = self.decode_horizon
+        self.metrics.compile_log = self._compile_log
+        # the one place a phase of step() is named: profiler
+        # annotation, metrics.loop_seconds, ring span, sanitizer phase
+        self._regions = PhaseRegions(
+            "engine", self.metrics.loop_seconds, self.tracer,
+            ENGINE_TRACK, on_phase=self._set_phase,
+        )
+        # cache rows one decode substep reads: what the step program
+        # streams whatever the slots hold (metrics.kv_rows_streamed)
+        self._kv_rows_per_substep = decode_rows_streamed(
+            self.n_slots, self.pool.tpad
+        )
 
         # power-of-two prompt buckets: the largest must respect the
         # positional table (prefill embeds rows 0..bucket-1) and the
@@ -1623,40 +1654,33 @@ class ServingEngine:
                         _log, "masked_parity_probe_failed",
                         fallback="sampling surface disabled",
                     )
-        # arm attribution last: everything dispatched above was a probe
-        self._attr_enabled = bool(attribution)
+        # count programs from here on: everything dispatched above was
+        # a probe
+        self._uncounted -= 1
 
-    # -- per-program-family attribution ------------------------------------
+    def _count_program(self, family: str) -> None:
+        """One traffic dispatch of a compiled program family."""
+        if not self._uncounted:
+            self.metrics.record_program(family)
 
-    def _attr(self, family: str, t0: float | None = None) -> None:
-        """Mark one dispatched program for device-time attribution:
-        ``(family, dispatch timestamp)`` joins the pending list, and
-        ``_flush_attr`` prices it at the next horizon readback that
-        PROVES it complete (device stream ordering). One attribute
-        check + one list append on the hot path; nothing at all when
-        disabled, and never a device sync either way."""
-        if self._attr_enabled and not self._attr_suspend:
-            self._pending_attr.append(
-                (family, t0 if t0 is not None else time.perf_counter())
-            )
+    def mark_warm(self) -> None:
+        """Whoever warmed the engine calls this once, when every program
+        the traffic will need has compiled (``chip_smoke.py`` does after
+        its warm-up request; a benchmark that warms with its own traffic
+        may). A compile request after it, by any thread of the process,
+        is a recompile: ``serve_recompiles_total{fun}`` rises and one
+        ``recompile`` log line names the function, at the next step
+        boundary."""
+        self._compiles_seen = self._compile_log.requests
 
-    def _flush_attr(self, t_horizon: float, now: float) -> None:
-        """Attribute every pending program dispatched no later than
-        the just-synced horizon (``t_horizon`` is its dispatch stamp):
-        the designated readback proved all of them complete, so each
-        gets ``now - t0`` seconds — dispatch call to proven-complete,
-        an honest upper bound that includes async overlap with host
-        work rather than pretending per-program device intervals are
-        observable without extra syncs. Entries are time-ordered
-        (single engine thread), so this is a prefix flush."""
-        n = 0
-        for family, t0 in self._pending_attr:
-            if t0 > t_horizon:
-                break
-            self.metrics.record_program(family, now - t0)
-            n += 1
-        if n:
-            del self._pending_attr[:n]
+    def _note_recompiles(self) -> None:
+        seen, self._compiles_seen = (
+            self._compiles_seen, self._compile_log.requests
+        )
+        for fun in self._compile_log.names_since(seen):
+            self.metrics.record_recompile(fun)
+            log_event(_log, "recompile", level=logging.WARNING, fun=fun,
+                      horizon=self._steps)
 
     def _register_gauges(self) -> None:
         """Live-state gauges on the metrics registry: scrapes read
@@ -2920,6 +2944,7 @@ class ServingEngine:
         self._slot_adapters[slot] = req.adapter
         st = _SlotState(req, self.pool.generation(slot), kd, req.adapter)
         st.tokens = list(req.gen_tokens)
+        st.n_substeps = len(st.tokens)
         st.t_first_token = now if g else None
         self._slots[slot] = st
         req.status = RequestStatus.RUNNING
@@ -3037,7 +3062,7 @@ class ServingEngine:
             pad = np.zeros((1, b), np.int32)
             pad[0, :n] = seq
             self.prefill_dispatches += 1
-            self._attr("paged_prefill" if paged else "prefill")
+            self._count_program("paged_prefill" if paged else "prefill")
             pf = self._paged_prefill_fn(b) if paged else self._prefill_fn(b)
             return pf(
                 *state, self.params, jnp.asarray(pad), jnp.int32(n - 1),
@@ -3053,7 +3078,7 @@ class ServingEngine:
         for t0, ln, b in self._chunk_schedule(n):
             pad = np.zeros((1, b), np.int32)
             pad[0, :ln] = seq[t0:t0 + ln]
-            self._attr("chunk")
+            self._count_program("chunk")
             tmp, lg = self._chunk_fn(b)(
                 self.params, tmp, jnp.asarray(pad), jnp.int32(t0),
                 jnp.int32(ln - 1), ad,
@@ -3191,7 +3216,7 @@ class ServingEngine:
         if n <= L:
             return False
         _disp = self.prefill_dispatches  # probes don't count
-        self._attr_suspend += 1  # nor toward device-time attribution
+        self._uncounted += 1  # nor toward program_dispatches
         try:
             seq = ((1 + np.arange(n)) % self.cfg.vocab_size).astype(
                 np.int32
@@ -3233,7 +3258,7 @@ class ServingEngine:
             )
         finally:
             self.prefill_dispatches = _disp
-            self._attr_suspend -= 1
+            self._uncounted -= 1
 
     def _probe_piggyback_parity(self) -> bool:
         """One-time probe gating the piggyback path: does the FUSED
@@ -3250,7 +3275,7 @@ class ServingEngine:
         n = self.n_slots
         vs = self.cfg.vocab_size
         _disp = self.prefill_dispatches  # probes don't count
-        self._attr_suspend += 1  # nor toward device-time attribution
+        self._uncounted += 1  # nor toward program_dispatches
         try:
             def caches0():
                 if self._paged:
@@ -3311,7 +3336,7 @@ class ServingEngine:
             )
         finally:
             self.prefill_dispatches = _disp
-            self._attr_suspend -= 1
+            self._uncounted -= 1
 
     def _probe_masked_parity(self) -> bool:
         """One-time probe gating the sampling surface: does the MASKED
@@ -3329,7 +3354,7 @@ class ServingEngine:
         n = self.n_slots
         vs = self.cfg.vocab_size
         _disp = self.prefill_dispatches  # probes don't count
-        self._attr_suspend += 1  # nor toward device-time attribution
+        self._uncounted += 1  # nor toward program_dispatches
         try:
             def caches0():
                 if self._paged:
@@ -3418,7 +3443,7 @@ class ServingEngine:
             return ok
         finally:
             self.prefill_dispatches = _disp
-            self._attr_suspend -= 1
+            self._uncounted -= 1
 
     def _probe_batch_parity(self) -> bool:
         """One-time probe gating batched admission: do the batched
@@ -3434,7 +3459,7 @@ class ServingEngine:
         n1 = n0 - 1
         b = self._bucket_for(n0)
         _disp = self.prefill_dispatches  # probes don't count
-        self._attr_suspend += 1  # nor toward device-time attribution
+        self._uncounted += 1  # nor toward program_dispatches
         try:
             vs = self.cfg.vocab_size
             seq0 = ((1 + np.arange(n0)) % vs).astype(np.int32)
@@ -3508,7 +3533,7 @@ class ServingEngine:
             return self._states_equal(sh, sbh)
         finally:
             self.prefill_dispatches = _disp
-            self._attr_suspend -= 1
+            self._uncounted -= 1
 
     def _probe_verdict(self, name: str, compute, cfg=None,
                        **geometry) -> bool:
@@ -3740,7 +3765,7 @@ class ServingEngine:
         if n < 1:
             return False
         _disp = self.prefill_dispatches  # probes don't count
-        self._attr_suspend += 1  # nor toward device-time attribution
+        self._uncounted += 1  # nor toward program_dispatches
         try:
             seq = ((1 + np.arange(n)) % self.cfg.vocab_size).astype(
                 np.int32
@@ -3813,7 +3838,7 @@ class ServingEngine:
             )
         finally:
             self.prefill_dispatches = _disp
-            self._attr_suspend -= 1
+            self._uncounted -= 1
 
     def _disagg_ok(self) -> bool:
         if self._disagg_ok_memo is None:
@@ -3969,7 +3994,7 @@ class ServingEngine:
         for t0, ln, b in self._chunk_schedule(n, start=L):
             pad = np.zeros((1, b), np.int32)
             pad[0, :ln] = seq[t0:t0 + ln]
-            self._attr("chunk")
+            self._count_program("chunk")
             tmp, lg = self._chunk_fn(b)(
                 self.params, tmp, jnp.asarray(pad), jnp.int32(t0),
                 jnp.int32(ln - 1),
@@ -4016,7 +4041,7 @@ class ServingEngine:
                 eos_toks[r] = int(pl.req.eos_token)
             adapters[r] = pl.req.adapter
         self.prefill_dispatches += 1
-        self._attr("batch_prefill")
+        self._count_program("batch_prefill")
         self._set_state(self._batch_prefill_fn(bucket, nb)(
             *self._state(), self.params, jnp.asarray(prompts),
             jnp.asarray(last_idx), jnp.asarray(slots),
@@ -4055,7 +4080,7 @@ class ServingEngine:
                 eos_toks[r] = int(pl.req.eos_token)
             adapters[r] = pl.req.adapter
         self.prefill_dispatches += 1
-        self._attr("batch_hit")
+        self._count_program("batch_hit")
         self._set_state(self._batch_hit_fn(bucket, nb)(
             *self._state(), self.params, self.prefix_cache.region,
             jnp.asarray(seg_idx), jnp.asarray(toks), jnp.int32(L),
@@ -4064,6 +4089,17 @@ class ServingEngine:
             jnp.asarray(eos_toks), jnp.asarray(adapters),
         ))
         self.metrics.record_batched_admissions(len(group))
+
+    # lint: hot-path
+    def _split_slot_key(self, req_id: int) -> np.ndarray:
+        """Advance the master key chain by one admission and read the
+        new slot's key back. The split is a tiny device program queued
+        behind the horizon in flight, so the readback waits for up to a
+        whole step: the loop waiting on the device, booked as
+        ``key_sync`` and not as the phase it interrupts."""
+        self._key, sub = jax.random.split(self._key)
+        with self._regions("key_sync", req_id=req_id):
+            return np.asarray(jax.random.key_data(sub))  # lint: sync-ok per-admission key snapshot (tiny, off the decode critical section)
 
     # lint: hot-path
     def _seat_plan(self, pl: _AdmitPlan, now: float) -> None:
@@ -4076,8 +4112,7 @@ class ServingEngine:
         # master key chain; everyone else splits here, at seating
         kd = self._presplit_keys.pop(req.id, None)
         if kd is None:
-            self._key, sub = jax.random.split(self._key)
-            kd = np.asarray(jax.random.key_data(sub))  # lint: sync-ok per-admission key snapshot (tiny, off the decode critical section)
+            kd = self._split_slot_key(req.id)
         self._slot_keys[slot] = kd
         self._slot_adapters[slot] = req.adapter
         st = _SlotState(req, self.pool.generation(slot), kd,
@@ -4090,7 +4125,9 @@ class ServingEngine:
         pl.admitted = True
         req.status = RequestStatus.RUNNING
         self.metrics.record_prefill(req.id, pl.prefill_s)
-        delay = (time.perf_counter() - req.arrival_time
+        st.t_boundary = pl.t_boundary
+        st.t_seated = time.perf_counter()
+        delay = (st.t_seated - req.arrival_time
                  if req.arrival_time is not None else None)
         if delay is not None:
             self.metrics.record_admitted(req.id, delay,
@@ -4351,7 +4388,7 @@ class ServingEngine:
                     if req.status is RequestStatus.RUNNING:
                         used[req.tenant_id] = used.get(req.tenant_id, 0) + 1
                     continue
-                plans.append(_AdmitPlan(req, self.pool.acquire()))
+                plans.append(_AdmitPlan(req, self.pool.acquire(), now))
                 used[req.tenant_id] = used.get(req.tenant_id, 0) + 1
                 if self._paged:
                     reserved[0] += self.pool.blocks_needed(
@@ -4410,10 +4447,9 @@ class ServingEngine:
             # matching the blocking path (which never split either).
             for pl in live:
                 if pl.req.id not in self._presplit_keys:
-                    self._key, sub = jax.random.split(self._key)
-                    self._presplit_keys[pl.req.id] = np.asarray(
-                        jax.random.key_data(sub)
-                    )  # lint: sync-ok per-admission key snapshot (tiny, off the decode critical section)
+                    self._presplit_keys[pl.req.id] = (
+                        self._split_slot_key(pl.req.id)
+                    )
             # defer only prompts whose uncached suffix exceeds one
             # bucket — everything the blocking path serves in a single
             # prefill dispatch stays on the blocking path, bitwise
@@ -4445,10 +4481,12 @@ class ServingEngine:
                                 (b, pl.matched), []
                             ).append(pl)
         batched: set[int] = set()
+        region = self._regions
         for bucket, group in sorted(miss_groups.items()):
             if len(group) >= 2:
                 t0 = time.perf_counter()
-                self._batch_prefill_group(bucket, group)
+                with region("prefill", bucket=bucket, rows=len(group)):
+                    self._batch_prefill_group(bucket, group)
                 dt = (time.perf_counter() - t0) / len(group)
                 for pl in group:
                     pl.t_pf, pl.prefill_s = t0, dt
@@ -4456,7 +4494,9 @@ class ServingEngine:
         for (bucket, length), group in sorted(hit_groups.items()):
             if len(group) >= 2:
                 t0 = time.perf_counter()
-                self._batch_hit_group(bucket, length, group)
+                with region("prefill", bucket=bucket, rows=len(group),
+                            cached=length):
+                    self._batch_hit_group(bucket, length, group)
                 dt = (time.perf_counter() - t0) / len(group)
                 for pl in group:
                     pl.t_pf, pl.prefill_s = t0, dt
@@ -4466,17 +4506,21 @@ class ServingEngine:
             if id(pl) in batched or id(pl) in deferred:
                 continue
             t0 = time.perf_counter()
-            if pl.kind == "full":
-                self._admit_full_hit(pl)
-            elif pl.kind == "partial":
-                self._admit_partial_hit(pl)
-            else:
-                eos_tok = (_NO_EOS if pl.req.eos_token is None
-                           else int(pl.req.eos_token))
-                self._prefill_seq_into_slot(
-                    pl.req.prompt, pl.slot, pl.req.max_new, eos_tok,
-                    adapter=pl.req.adapter,
-                )
+            n = len(pl.req.prompt)
+            with region("prefill", req_id=pl.req.id, prompt_len=n,
+                        bucket=self._bucket_for(min(n, self._max_bucket)),
+                        prefix=pl.kind):
+                if pl.kind == "full":
+                    self._admit_full_hit(pl)
+                elif pl.kind == "partial":
+                    self._admit_partial_hit(pl)
+                else:
+                    eos_tok = (_NO_EOS if pl.req.eos_token is None
+                               else int(pl.req.eos_token))
+                    self._prefill_seq_into_slot(
+                        pl.req.prompt, pl.slot, pl.req.max_new, eos_tok,
+                        adapter=pl.req.adapter,
+                    )
             pl.t_pf, pl.prefill_s = t0, time.perf_counter() - t0
         # decode-stall accounting: admission prefill executed while
         # decode slots sat occupied is exactly the stall piggyback
@@ -4584,11 +4628,14 @@ class ServingEngine:
         t0, ln, b = rec.chunks[0]
         pad = np.zeros((1, b), np.int32)
         pad[0, :ln] = pl.req.prompt[t0:t0 + ln]
-        self._attr("chunk")
-        rec.tmp, rec.lg = self._chunk_fn(b)(
-            self.params, rec.tmp, jnp.asarray(pad), jnp.int32(t0),
-            jnp.int32(ln - 1), jnp.asarray([pl.req.adapter], jnp.int32),
-        )
+        self._count_program("chunk")
+        with self._regions("prefill", req_id=pl.req.id, chunk=ln,
+                           bucket=b):
+            rec.tmp, rec.lg = self._chunk_fn(b)(
+                self.params, rec.tmp, jnp.asarray(pad), jnp.int32(t0),
+                jnp.int32(ln - 1),
+                jnp.asarray([pl.req.adapter], jnp.int32),
+            )
         self._account_chunk(rec, ln, fused=False)
         return ln
 
@@ -4707,7 +4754,6 @@ class ServingEngine:
             pb_fn = (self._masked_piggyback_fn(cb, k) if surface
                      else self._piggyback_fn(cb, k))
         attempt, backoff = 0, self.retry_backoff_s
-        t_call = time.perf_counter()
         # .copy(): jnp.asarray can zero-copy alias the mutable host key
         # buffer on CPU, and dispatch is async — a later admission
         # writing a slot key must not race the in-flight step. The
@@ -4847,14 +4893,23 @@ class ServingEngine:
         self.metrics.record_step(
             len(snaps), self.n_slots, len(self.scheduler)
         )
-        self.tracer.span(
-            ENGINE_TRACK, "dispatch", t_call, now - t_call,
-            n_active=len(snaps),
-        )
+        # the cache rows each occupied slot holds when substep j reads
+        # them: its prompt, the substeps dispatched before, and the row
+        # substep j writes. A slot past its budget is frozen on the
+        # device and holds nothing the kernel needs (an EOS the host
+        # has not read back yet is counted until it has).
+        live = 0
+        for _, st in snaps:
+            j = min(k, st.req.max_new - st.n_substeps)
+            if j > 0:
+                held = len(st.req.prompt) + st.n_substeps
+                live += j * held + j * (j + 1) // 2
+            st.n_substeps += k
+        self.metrics.record_kv_rows(live, k * self._kv_rows_per_substep)
         fam = "step" if fused is None else "piggyback_step"
         if surface:
             fam = "masked_" + fam
-        self._attr(("paged_" + fam) if self._paged else fam, t_call)
+        self._count_program(("paged_" + fam) if self._paged else fam)
         if self.flight.enabled:
             self.flight.record(
                 "dispatch", k=k, n_active=len(snaps),
@@ -4865,7 +4920,7 @@ class ServingEngine:
                     "blocks_free": self.pool.n_free_blocks}
                    if self._paged else {}),
             )
-        return _Inflight(toks, snaps, now)
+        return _Inflight(toks, snaps, now, self._steps + 1)
 
     # lint: hot-path
     def _process(self, horizon: _Inflight) -> None:
@@ -4875,7 +4930,8 @@ class ServingEngine:
         tokens, retire finished slots. Blocks whose slot was retired or
         re-acquired since dispatch are discarded."""
         t_sync = time.perf_counter()
-        toks_host = np.asarray(horizon.toks)  # lint: sync-ok THE designated readback, 1/horizon
+        with self._regions("sync", n=horizon.n):
+            toks_host = np.asarray(horizon.toks)  # lint: sync-ok THE designated readback, 1/horizon
         aux_host = None
         if toks_host.ndim == 3:
             # masked-step horizons read back the packed aux block:
@@ -4892,11 +4948,6 @@ class ServingEngine:
             sync_wait_s=now - t_sync,
             overlap_s=max(0.0, t_sync - horizon.t_dispatch),
         )
-        self.tracer.span(ENGINE_TRACK, "sync", t_sync, now - t_sync)
-        # the sync above proved every program dispatched at or before
-        # this horizon complete — price the pending attribution entries
-        if self._pending_attr:
-            self._flush_attr(horizon.t_dispatch, now)
         # per-slot decode span for this horizon: dispatch → block
         # arrival, clipped at the NEXT horizon's dispatch (which already
         # happened — pipelining) so consecutive decode spans on one slot
@@ -4913,16 +4964,17 @@ class ServingEngine:
             self.tracer.span(
                 slot_track(slot), "decode", horizon.t_dispatch,
                 t_span_end - horizon.t_dispatch, req_id=req.id,
-                k=int(toks_host.shape[1]),
+                k=int(toks_host.shape[1]), n=horizon.n,
             )
             finished = False
+            first = st.t_first_token is None
             for k in range(toks_host.shape[1]):
                 tok = int(toks_host[slot, k])
                 if st.t_first_token is None:
                     st.t_first_token = now
                     self.tracer.instant(
                         slot_track(slot), "first_token", ts=now,
-                        req_id=req.id,
+                        req_id=req.id, n=horizon.n,
                     )
                     if req.arrival_time is not None:
                         self.metrics.record_first_token(
@@ -4976,6 +5028,16 @@ class ServingEngine:
                         or len(st.tokens) >= req.max_new):
                     finished = True
                     break  # device mask froze this slot here too
+            if (first and st.t_seated is not None
+                    and req.arrival_time is not None):
+                # this horizon's tokens are on the request's stream:
+                # its time to first token, cut where the engine sees it
+                self.metrics.record_ttft_segments(
+                    st.t_boundary - req.arrival_time,
+                    st.t_seated - st.t_boundary,
+                    now - st.t_seated,
+                    time.perf_counter() - now,
+                )
             if finished:
                 if stopped:
                     self._retire(slot, RequestStatus.FINISHED, now,
@@ -4992,6 +5054,8 @@ class ServingEngine:
         self._san = san
 
     def _set_phase(self, phase: str | None) -> None:
+        """The outermost open region of ``_regions``, for the
+        sanitizer's per-phase sync budgets."""
         san = self._san
         if san is not None:
             san.set_phase(phase)
@@ -5008,24 +5072,29 @@ class ServingEngine:
         if prof is not None:
             prof.step_start()
         now = time.perf_counter()
+        region = self._regions
+        progressed = True  # a step that raises is worth its spans
         try:
-            self._set_phase("sweep")
-            self._sweep_lifecycle(now)
-            self._set_phase("admit")
-            self._admit(now)
-            self._set_phase("dispatch")
-            prev, self._inflight = self._inflight, self._dispatch()
+            with region("sweep"):
+                self._sweep_lifecycle(now)
+            with region("admit"):
+                self._admit(now)
+            with region("dispatch", n=self._steps + 1):
+                prev, self._inflight = self._inflight, self._dispatch()
             if self._inflight is not None:
                 self._steps += 1
-            self._set_phase("process")
             if prev is not None:
-                self._process(prev)
+                with region("process", n=prev.n):
+                    self._process(prev)
+            progressed = (prev is not None or self._inflight is not None
+                          or self._pb_did_work)
         finally:
-            self._set_phase(None)
+            region.flush(progressed)
             if prof is not None:
                 prof.step_end()
-        progressed = (prev is not None or self._inflight is not None
-                      or self._pb_did_work)
+        if (self._compiles_seen is not None
+                and self._compile_log.requests != self._compiles_seen):
+            self._note_recompiles()
         if self.tracer.enabled and progressed:
             t_end = time.perf_counter()
             self.tracer.span(
@@ -5066,12 +5135,12 @@ class ServingEngine:
         if k < 1:
             return False
         _disp = self.prefill_dispatches  # probes don't count
-        self._attr_suspend += 1  # nor toward device-time attribution
+        self._uncounted += 1  # nor toward program_dispatches
         try:
             return self._probe_chunked_parity_inner(length, k)
         finally:
             self.prefill_dispatches = _disp
-            self._attr_suspend -= 1
+            self._uncounted -= 1
 
     def _probe_chunked_parity_inner(self, length: int, k: int) -> bool:
         seq = ((1 + np.arange(length)) % self.cfg.vocab_size).astype(
@@ -5147,15 +5216,12 @@ class ServingEngine:
             ), queue_depth=len(self.scheduler),
             restarts=self.metrics.n_restarts,
         )
-        # pending attribution entries lost their completion proof with
-        # the abandoned device state; replay dispatches don't count
-        # (recovery wall time is not serving device time)
-        self._pending_attr.clear()
-        self._attr_suspend += 1
+        # replay dispatches are not traffic
+        self._uncounted += 1
         try:
             return self._recover_inner(t_rec)
         finally:
-            self._attr_suspend -= 1
+            self._uncounted -= 1
 
     def _recover_inner(self, t_rec: float) -> int:
         self._inflight = None
@@ -5211,6 +5277,8 @@ class ServingEngine:
         for slot, st in live:
             self._slot_keys[slot] = st.key_data
             self._slot_adapters[slot] = st.adapter
+            # the rebuilt device state holds what the host recorded
+            st.n_substeps = len(st.tokens)
             if self._surface:
                 self._reseat_surface(slot, st)
         if self._surface and live:

@@ -49,6 +49,23 @@ batched same-bucket admission only if ``prefill`` does. Note ``sync``
 is a sub-interval of ``decode`` (fractions tell where time GOES, not a
 partition of wall time).
 
+The engine LOOP has its own exact books, next to the requests':
+``loop_seconds`` holds the self time of each phase of
+``ServingEngine.step`` (``sweep``, ``admit``, ``prefill``, ``key_sync``,
+``dispatch``, ``sync``, ``process`` — see :data:`LOOP_PHASES`; in the
+two ``sync`` phases the thread waits for the device), fed by the same
+regions that name the phases in a profiler capture;
+``kv_rows_live`` / ``kv_rows_streamed`` count, per decode substep, the
+cache rows the occupied slots hold against the rows the step program
+reads (their ratio is the share of the decode kernel's stream any
+request needed); ``ttft_segment_seconds`` splits every request's time
+to first token at the three points the engine can see
+(:data:`TTFT_SEGMENTS`). ``compile_log`` is the process's
+:class:`~deeplearning4j_tpu.obs.compile_log.CompileLog`, and
+``recompiles`` the compile requests seen after whoever warmed the
+engine said so (``ServingEngine.mark_warm``), by function: the one
+thing a warm server must never do.
+
 With a multi-step decode horizon (``decode_horizon`` > 1) a "step" in
 the series above is one K-substep horizon dispatch; TTFT is still
 measured to the host-visible first token, so it honestly includes the
@@ -84,52 +101,22 @@ PHASES = ("queue", "prefill", "decode", "sync")
 #: n/total/min/max are kept alongside)
 RESERVOIR_CAP = 4096
 
-#: peak dense matmul FLOP/s per chip by jax device_kind prefix (bf16
-#: inputs, f32 accumulation — the MXU-native rate; same table the
-#: bench harness reports MFU against, duplicated here because the
-#: package cannot import the repo-root bench script)
-_PEAK_FLOPS = (
-    ("TPU v6", 918e12),   # Trillium
-    ("TPU v5p", 459e12),
-    ("TPU v5 lite", 197e12),  # v5e
-    ("TPU v5", 459e12),
-    ("TPU v4", 275e12),
-)
+#: the phases of one turn of the engine loop, in the order they run
+#: (``prefill`` and ``key_sync`` inside ``admit`` or ``dispatch``,
+#: ``sync`` inside ``process``); each holds its self time, so they add
+#: up. In ``sync`` (the designated readback) and ``key_sync`` (the
+#: readback of a new slot's sampling key, a tiny program queued behind
+#: the horizon in flight) the loop's thread waits for the device; the
+#: rest is the host's own work.
+LOOP_PHASES = ("sweep", "admit", "prefill", "key_sync", "dispatch", "sync",
+               "process")
 
-#: peak HBM bandwidth per chip (bytes/s), by device_kind prefix
-_PEAK_HBM_BW = (
-    ("TPU v6", 1640e9),   # Trillium
-    ("TPU v5p", 2765e9),
-    ("TPU v5 lite", 819e9),  # v5e
-    ("TPU v5", 2765e9),
-    ("TPU v4", 1228e9),
-)
-
-#: off-TPU stand-ins (a generous server CPU): they keep the gauges
-#: defined and inside (0, 1] where tier-1 pins their range. They are
-#: NOT calibrated — the gauge help text says so.
-_UNCALIBRATED_PEAK_FLOPS = 5e12
-_UNCALIBRATED_PEAK_HBM_BW = 1e12
-
-
-def _device_peaks() -> tuple[float, float]:
-    """``(peak flop/s, peak bytes/s)`` for device 0: table-resolved on
-    TPU, the uncalibrated stand-ins on CPU. A TPU kind the tables do not
-    name is an error — a default would publish utilization of a device
-    the code does not know."""
-    import jax
-
-    dev = jax.devices()[0]
-    if dev.platform != "tpu":
-        return _UNCALIBRATED_PEAK_FLOPS, _UNCALIBRATED_PEAK_HBM_BW
-    kind = dev.device_kind
-    for (prefix, flops), (_, bw) in zip(_PEAK_FLOPS, _PEAK_HBM_BW):
-        if kind.startswith(prefix):
-            return flops, bw
-    raise RuntimeError(
-        f"no peak FLOP/s / HBM bandwidth entry for TPU device_kind "
-        f"{kind!r}; add it to serving/metrics.py"
-    )
+#: a request's time to first token, cut where the engine can see it:
+#: arrival -> the step boundary that popped it from the queue ->
+#: seated in its slot (prefill done) -> the readback that brought its
+#: first token -> that token put on its stream
+TTFT_SEGMENTS = ("arrival_to_boundary", "boundary_to_seated",
+                 "seated_to_readback", "readback_to_stream")
 
 
 def _pct(res: Reservoir, p: float) -> float:
@@ -153,13 +140,19 @@ class ServingMetrics:
         self.overlap = Reservoir(reservoir_cap)
         # exact per-phase wall-second totals (see module docstring)
         self.phase_seconds = {p: 0.0 for p in PHASES}
-        # per-program-family device-time attribution (record_program):
-        # measured at the horizon-readback boundary by the engine
-        # thread only, like phase_seconds, so no lock
-        self.program_seconds: dict[str, float] = {}
+        # exact engine-loop totals, written by the engine thread only
+        # (like phase_seconds, so no lock) and copied into the
+        # Prometheus counters when they are rendered
+        self.loop_seconds = {p: 0.0 for p in LOOP_PHASES}
+        self.kv_rows_live = 0
+        self.kv_rows_streamed = 0
+        self.ttft_segment_seconds = {s: 0.0 for s in TTFT_SEGMENTS}
+        self.n_ttft_segments = 0
         self.program_dispatches: dict[str, int] = {}
-        self._family_budgets: dict | None = None  # lazy .graftaudit.json
-        self._peaks: tuple[float, float] | None = None  # lazy device peek
+        # the process-wide compile log, set by the engine; recompiles
+        # are the requests it saw after ServingEngine.mark_warm()
+        self.compile_log = None
+        self.recompiles: dict[str, int] = {}
         # stamped by the engine at construction; reported in summary()
         # so a bench row records which horizon produced its numbers
         self.decode_horizon = 1
@@ -401,32 +394,50 @@ class ServingMetrics:
             "Requests finished by a stop-sequence match (host-side "
             "suffix match at readback).",
         )
-        self._c_prog_seconds = reg.counter(
-            "serve_program_seconds_total",
-            "Wall seconds attributed to compiled program families at "
-            "the horizon-readback boundary (dispatch call to "
-            "post-sync flush — an honest upper bound that includes "
-            "async overlap).", ("family",),
-        )
         self._c_prog_dispatches = reg.counter(
             "serve_program_dispatches_total",
             "Program dispatches by compiled family.", ("family",),
         )
-        self._g_mfu = reg.gauge(
-            "serve_mfu",
-            "Live model-flop utilization per program family: audited "
-            "envelope flops x dispatches / measured seconds / device "
-            "peak, clamped to 1. Exact at the committed audit "
-            "geometry; a scale reference otherwise. Off-TPU the peak "
-            "is an uncalibrated stand-in.", ("family",),
+        self._c_loop_seconds = reg.counter(
+            "serve_loop_seconds_total",
+            "Self seconds of each phase of the engine loop "
+            "(sweep|admit|prefill|key_sync|dispatch|sync|process). In "
+            "sync (the horizon's readback) and key_sync (a new slot's "
+            "sampling key, read back behind the horizon in flight) the "
+            "loop waits for the device; the others are host work.",
+            ("phase",),
         )
-        self._g_mbu = reg.gauge(
-            "serve_mbu",
-            "Live memory-bandwidth utilization per program family: "
-            "audited arg+out bytes x dispatches / measured seconds / "
-            "peak HBM bandwidth, clamped to 1. Exact at the committed "
-            "audit geometry; a scale reference otherwise. Off-TPU the "
-            "peak is an uncalibrated stand-in.", ("family",),
+        self._c_kv_rows_live = reg.counter(
+            "serve_kv_rows_live_total",
+            "Cache rows the occupied slots held, summed over decode "
+            "substeps dispatched.",
+        )
+        self._c_kv_rows_streamed = reg.counter(
+            "serve_kv_rows_streamed_total",
+            "Cache rows the dispatched step programs read, summed over "
+            "decode substeps (live / streamed = share of the decode "
+            "stream a request needed).",
+        )
+        self._c_compile_requests = reg.counter(
+            "serve_compile_requests_total",
+            "Backend compile requests of this process (a persistent-"
+            "cache hit is a request), by jitted function.", ("fun",),
+        )
+        self._c_compile_seconds = reg.counter(
+            "serve_compile_seconds_total",
+            "Seconds this process spent compiling, by stage "
+            "(trace|lower|backend; backend is the XLA compile or the "
+            "cache load).", ("stage",),
+        )
+        self._c_compile_cache = reg.counter(
+            "serve_compile_cache_total",
+            "Persistent compile cache look-ups that ended in a load "
+            "(hit) or a new entry (miss).", ("result",),
+        )
+        self._c_recompiles = reg.counter(
+            "serve_recompiles_total",
+            "Compile requests after the engine was declared warm, by "
+            "jitted function: alert on any increase.", ("fun",),
         )
 
     def _emit(self, tag: str, value: float, step: int | None = None) -> None:
@@ -454,20 +465,33 @@ class ServingMetrics:
         self.phase_seconds[phase] += seconds
         self._h_phase.observe(seconds, phase=phase)
 
-    def record_program(self, family: str, seconds: float) -> None:
-        """Attribute one program dispatch's measured wall interval to
-        its compiled family. The engine calls this at the horizon-
-        readback boundary (after THE designated sync), so ``seconds``
-        spans dispatch call → proven-complete — an honest upper bound
-        that includes whatever host work overlapped the device."""
-        self.program_seconds[family] = (
-            self.program_seconds.get(family, 0.0) + float(seconds)
-        )
+    def record_program(self, family: str) -> None:
+        """Count one dispatch of a compiled program family. What a
+        family costs on the device is read from a profiler capture
+        (``POST /profile``), not guessed from the host's clock."""
         self.program_dispatches[family] = (
             self.program_dispatches.get(family, 0) + 1
         )
-        self._c_prog_seconds.inc(float(seconds), family=family)
         self._c_prog_dispatches.inc(family=family)
+
+    def record_kv_rows(self, live: int, streamed: int) -> None:
+        """One decode dispatch: over its substeps the occupied slots
+        held ``live`` cache rows and the step program read
+        ``streamed``."""
+        self.kv_rows_live += live
+        self.kv_rows_streamed += streamed
+
+    def record_ttft_segments(self, *seconds: float) -> None:
+        """One request's time to first token, cut into
+        :data:`TTFT_SEGMENTS`."""
+        for name, s in zip(TTFT_SEGMENTS, seconds, strict=True):
+            self.ttft_segment_seconds[name] += s
+        self.n_ttft_segments += 1
+
+    def record_recompile(self, fun: str) -> None:
+        """One compile request after the engine was declared warm."""
+        self.recompiles[fun] = self.recompiles.get(fun, 0) + 1
+        self._c_recompiles.inc(fun=fun)
 
     def record_step(self, n_active: int, n_slots: int,
                     queue_depth: int) -> None:
@@ -768,49 +792,34 @@ class ServingMetrics:
                     burn = _pct(st["tpot"], 99) / target
                     self._g_slo_burn.set(burn, tenant=tid)
 
-    def _update_program_util(self) -> None:
-        """Refresh the per-family MFU/MBU gauges: measured seconds
-        (``record_program``) divided into the static flop/byte budgets
-        committed in ``.graftaudit.json``. The registry entry IS the
-        live program (graftaudit enforces the surface), so the
-        attribution is exact, not heuristic — exact at the audit
-        geometry, where the envelope budgets match the dispatched
-        shapes. Render-time only: the hot path never touches this."""
-        if not self.program_dispatches:
-            return
-        if self._family_budgets is None:
-            try:
-                from deeplearning4j_tpu.analysis.programs import (
-                    family_budgets,
-                )
+    def _update_exact_totals(self) -> None:
+        """Bring the Prometheus counters up to the exact totals the
+        engine thread keeps as plain attributes (and the compile log
+        keeps for the process), so the hot path pays no lock for
+        them."""
+        def raise_to(counter, value, **labels):
+            counter.inc(max(0.0, value - counter.value(**labels)), **labels)
 
-                self._family_budgets = family_budgets()
-            except Exception:
-                self._family_budgets = {}
-        if not self._family_budgets:
-            return  # no committed baseline: seconds-only attribution
-        if self._peaks is None:
-            self._peaks = _device_peaks()
-        peak_flops, peak_bw = self._peaks
-        for family, n in self.program_dispatches.items():
-            budget = self._family_budgets.get(family)
-            secs = self.program_seconds.get(family, 0.0)
-            if budget is None or secs <= 0.0:
-                continue
-            self._g_mfu.set(
-                min(1.0, budget["flops"] * n / secs / peak_flops),
-                family=family,
-            )
-            self._g_mbu.set(
-                min(1.0, budget["bytes"] * n / secs / peak_bw),
-                family=family,
-            )
+        for phase, secs in self.loop_seconds.items():
+            raise_to(self._c_loop_seconds, secs, phase=phase)
+        raise_to(self._c_kv_rows_live, self.kv_rows_live)
+        raise_to(self._c_kv_rows_streamed, self.kv_rows_streamed)
+        if self.compile_log is None:
+            return
+        totals = self.compile_log.totals()
+        for fun, per in totals["by_fun"].items():
+            raise_to(self._c_compile_requests, per["requests"], fun=fun)
+        for stage, secs in totals["stage_seconds"].items():
+            raise_to(self._c_compile_seconds, secs, stage=stage)
+        raise_to(self._c_compile_cache, totals["cache_hits"], result="hit")
+        raise_to(self._c_compile_cache, totals["cache_misses"],
+                 result="miss")
 
     def render_prometheus(self) -> str:
         """The backing registry in Prometheus text format (what the
         serving server returns at ``GET /metrics``)."""
         self._update_slo_burn()
-        self._update_program_util()
+        self._update_exact_totals()
         return self.registry.render()
 
     def summary(self) -> dict:
@@ -926,13 +935,25 @@ class ServingMetrics:
             out["occupancy_mean"] = self.occupancy.mean
             out["queue_depth_max"] = int(self.queue_depth.max)
         if self.program_dispatches:
-            out["program_seconds"] = {
-                f: round(v, 6)
-                for f, v in sorted(self.program_seconds.items())
-            }
             out["program_dispatches"] = dict(
                 sorted(self.program_dispatches.items())
             )
+        out["loop_seconds"] = {
+            p: round(v, 6) for p, v in self.loop_seconds.items()
+        }
+        out["kv_rows_live"] = self.kv_rows_live
+        out["kv_rows_streamed"] = self.kv_rows_streamed
+        if self.n_ttft_segments:
+            out["ttft_segments"] = {
+                "n": self.n_ttft_segments,
+                "seconds": {s: round(v, 6) for s, v in
+                            self.ttft_segment_seconds.items()},
+            }
+        if self.compile_log is not None:
+            totals = self.compile_log.totals()
+            del totals["by_fun"]  # per function: /metrics, or the log itself
+            totals["recompiles"] = dict(sorted(self.recompiles.items()))
+            out["compile"] = totals
         attributed = sum(self.phase_seconds.values())
         if attributed > 0:
             out["phase_seconds"] = {

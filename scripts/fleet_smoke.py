@@ -5,10 +5,10 @@ over real HTTP.
 Boots two ``serve --demo`` replica processes and one ``router`` process
 (each exporting its tracer via --trace-out), drives generate requests
 through the router, checks the live observability surfaces
-(``/debug/dump`` flight bundle, per-family ``serve_program_seconds``
-attribution on ``/metrics``). Then boots a ``controller`` over the SAME
-replicas with disaggregated roles (replica 0 = prefill, replica 1 =
-decode) and sends one long-prompt request through the transfer path —
+(``/debug/dump`` flight bundle, the engine loop's phase seconds and
+per-family dispatch counts on ``/metrics``). Then boots a ``controller``
+over the SAME replicas with disaggregated roles (replica 0 = prefill,
+replica 1 = decode) and sends one long-prompt request through the transfer path —
 prefill computes the KV segment and pushes it replica-to-replica to the
 decode target, whose generate full-hits. Shuts the fleet down, stitches
 the per-process trace exports with ``trace-merge``, and validates the
@@ -138,10 +138,11 @@ def main():
             rdump = json.loads(get(a, "/debug/dump"))
             assert rdump["reason"] == "debug_dump", rdump
         metrics = b"".join(get(a, "/metrics") for a in addrs).decode()
-        assert "serve_program_seconds_total" in metrics, \
-            "no per-family attribution on /metrics"
-        assert "serve_mfu{" in metrics, "no serve_mfu gauges"
-        print("debug dumps + attribution metrics OK")
+        assert "serve_program_dispatches_total{" in metrics, \
+            "no per-family dispatch counts on /metrics"
+        assert 'serve_loop_seconds_total{phase="dispatch"}' in metrics, \
+            "no engine loop phase seconds on /metrics"
+        print("debug dumps + engine loop metrics OK")
 
         # -- disaggregated phase: controller over the same replicas --
         cpf = os.path.join(tmp, "controller.port")

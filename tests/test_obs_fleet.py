@@ -1,5 +1,5 @@
-"""Fleet observability: distributed tracing, per-family device-time
-attribution (MFU/MBU gauges), and the crash flight recorder.
+"""Fleet observability: distributed tracing, per-family dispatch
+counts, and the crash flight recorder.
 
 The tentpole contract pinned here: one request through the router to a
 replica produces, after ``trace-merge``, a single Perfetto document in
@@ -7,8 +7,9 @@ which the router's dispatch span is the PARENT of the replica's
 admission span — verified structurally (the replica span's
 ``parent_span_id`` resolves to the router span's ``span_id`` on a
 different process track, and a flow arrow links the two). Plus the
-satellite contracts: MFU/MBU gauges stay in (0, 1], flight-recorder
-dumps never contain prompt text, and the disabled paths cost nothing.
+satellite contracts: traffic dispatches are counted per program family
+and probes and replays are not, flight-recorder dumps never contain
+prompt text, and the disabled paths cost nothing.
 """
 
 import json
@@ -227,7 +228,7 @@ def test_fleet_merged_trace_router_parents_admission():
     assert sum(1 for e in evs if e.get("ph") == "f") == 4
 
 
-# -- MFU / MBU attribution ------------------------------------------------
+# -- per-family dispatch counts ---------------------------------------------
 
 
 def _drive(engine, n=3, seed=11, max_new=5):
@@ -248,62 +249,41 @@ def _drive(engine, n=3, seed=11, max_new=5):
     return reqs
 
 
-def test_mfu_mbu_gauges_in_unit_interval():
-    """Attribution prices measured wall seconds against the static
-    audit budgets: every emitted family gets seconds + dispatch
-    counters, and the derived MFU/MBU gauges land in (0, 1] — the
-    clamp's upper bound and physics' lower one."""
+def test_program_dispatches_counted_per_family():
+    """Every traffic dispatch is counted under its compiled family, at
+    the dispatch: one ``step`` per horizon the metrics counted, one
+    prefill program per admission, and the same figures on /metrics."""
     engine = ServingEngine(CFG, _params(), n_slots=2, temperature=0.0,
                            decode_horizon=2)
     _drive(engine)
-    assert engine.metrics.program_seconds, "no families attributed"
-    assert set(engine.metrics.program_dispatches) == set(
-        engine.metrics.program_seconds)
-    assert "step" in engine.metrics.program_seconds
-    assert all(s > 0 for s in engine.metrics.program_seconds.values())
-
-    text = engine.metrics.render_prometheus()
-    import re
-
-    for fam in engine.metrics.program_seconds:
-        assert f'serve_program_seconds_total{{family="{fam}"}}' in text
-        assert f'serve_program_dispatches_total{{family="{fam}"}}' in text
-    vals = [float(v) for v in re.findall(
-        r'serve_m[fb]u\{family="[^"]+"\} ([0-9.e+-]+)', text)]
-    assert vals, "no serve_mfu/serve_mbu samples rendered"
-    assert all(0.0 < v <= 1.0 for v in vals), vals
-
-
-def test_attribution_flush_is_prefix_ordered():
-    """Entries flush only once a later horizon readback proves them
-    complete; after a full drain the pending list is empty (nothing
-    leaks) and dispatch counts match the metrics' dispatch counters."""
-    engine = ServingEngine(CFG, _params(), n_slots=2, temperature=0.0,
-                           decode_horizon=2)
-    _drive(engine)
-    assert engine._pending_attr == []
     md = engine.metrics.program_dispatches
-    assert md.get("step", 0) >= 1
+    assert md["step"] == engine.metrics.summary()["steps"] >= 1
     assert md.get("prefill", 0) + md.get("batch_prefill", 0) >= 1
-
-
-def test_attribution_disabled_records_nothing():
-    engine = ServingEngine(CFG, _params(), n_slots=2, temperature=0.0,
-                           decode_horizon=2, attribution=False)
-    _drive(engine, n=2)
-    assert engine.metrics.program_seconds == {}
-    assert engine.metrics.program_dispatches == {}
-    assert engine._pending_attr == []
-    # and the render carries no per-family series at all
+    assert engine.metrics.summary()["program_dispatches"] == dict(
+        sorted(md.items()))
     text = engine.metrics.render_prometheus()
-    assert 'serve_mfu{' not in text
-    assert 'serve_program_seconds_total{' not in text
+    for fam, n in md.items():
+        assert (f'serve_program_dispatches_total{{family="{fam}"}} {n}'
+                in text)
 
 
-def test_recovery_replay_not_attributed():
+def test_construction_probes_are_not_counted():
+    """Construction and its parity probes dispatch programs that are
+    not traffic: a fresh engine has counted none, and counts from its
+    first request on."""
+    engine = ServingEngine(CFG, _params(), n_slots=2, temperature=0.0,
+                           decode_horizon=2)
+    assert engine._uncounted == 0
+    assert engine.metrics.program_dispatches == {}
+    assert "program_dispatches" not in engine.metrics.summary()
+    _drive(engine, n=1)
+    assert engine.metrics.program_dispatches["step"] >= 1
+
+
+def test_recovery_replay_not_counted():
     """Crash-recovery replay re-dispatches prefills and steps that
-    already ran; pricing them again would double-count device time, so
-    recover() suspends attribution for its whole replay."""
+    already ran; counting them again would double-count traffic, so
+    recover() suspends the count for its whole replay."""
     inj = FaultInjector().plan("step", at=2, kind="crash")
     engine = ServingEngine(
         CFG, _params(), n_slots=2, temperature=0.0, decode_horizon=2,
@@ -320,12 +300,12 @@ def test_recovery_replay_not_attributed():
         reqs.append(r)
     engine.run()
     assert engine.metrics.n_restarts == 1
-    # attribution survived the crash (re-armed after recovery) and the
-    # books balance: fewer attributed step dispatches than total step
-    # calls would imply had the replay been counted too
-    assert engine._attr_suspend == 0
-    assert engine._pending_attr == []
-    assert engine.metrics.program_dispatches.get("step", 0) >= 1
+    # the count survived the crash (re-armed after recovery) and the
+    # books balance: one step per horizon dispatched as traffic, the
+    # replay's none
+    assert engine._uncounted == 0
+    assert (engine.metrics.program_dispatches["step"]
+            == engine.metrics.summary()["steps"] >= 1)
 
 
 # -- flight recorder ------------------------------------------------------
@@ -421,21 +401,19 @@ def test_disabled_flight_recorder_records_nothing():
     assert fr.dump("test")["events"] == []
 
 
-def test_disabled_tracer_and_attribution_zero_overhead():
-    """The acceptance guard: with tracing and attribution off and the
-    flight recorder off, serving records no observability events at
-    all — and the token streams are byte-identical to a fully
-    instrumented engine's."""
+def test_disabled_tracer_and_flight_zero_overhead():
+    """The acceptance guard: with tracing off and the flight recorder
+    off, serving records no observability events at all — and the
+    token streams are byte-identical to a fully instrumented
+    engine's."""
     flight_off = FlightRecorder(enabled=False)
     eng_off = ServingEngine(
         CFG, _params(), n_slots=2, temperature=0.0, decode_horizon=2,
         tracer=Tracer(enabled=False), flight=flight_off,
-        attribution=False,
     )
     reqs_off = _drive(eng_off)
     assert eng_off.tracer.n_events == 0
     assert flight_off.n_events == 0
-    assert eng_off.metrics.program_seconds == {}
 
     eng_on = ServingEngine(
         CFG, _params(), n_slots=2, temperature=0.0, decode_horizon=2,
