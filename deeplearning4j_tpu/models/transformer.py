@@ -1012,7 +1012,8 @@ def _decode_builder(cfg: TransformerConfig, tp_mesh=None):
             kv_all = kv_all.at[i, plane, bidx, pos].set(rows[plane])
         return kv_all
 
-    def block_decode(x, p, kv_all, i, pos, lora=None, adapter=None):
+    def block_decode(x, p, kv_all, i, pos, lora=None, adapter=None,
+                     active=None):
         # x: (B, D) one position; kv_all: the ONE stacked packed cache
         # (nl, 2, B, Tpad, Hkv*K) (axis 1: K then V) — this layer writes
         # its new K and V rows with a single dynamic_update_slice and
@@ -1082,7 +1083,7 @@ def _decode_builder(cfg: TransformerConfig, tp_mesh=None):
         # full-cache copy per layer (custom calls need dense operands)
         o = flash_decode_attention(
             qp, kv_buf, pos, n_kv_heads=cfg.kv_heads, layer=i,
-            kv_scales=sc_buf,
+            kv_scales=sc_buf, active=active,
         )
         o_flat = (
             o.reshape(b, grp, cfg.kv_heads, kd)
@@ -1110,13 +1111,21 @@ def _decode_builder(cfg: TransformerConfig, tp_mesh=None):
             x = x + _mlp(p, h_in)
         return x, kv_all
 
-    def forward_one(params, caches, token, pos, adapter=None):
+    def forward_one(params, caches, token, pos, adapter=None, active=None):
         """One position through all layers; returns (logits, caches).
 
         ``pos`` is a scalar (every batch row at the same depth — the
         generate/beam/speculative paths) or an (B,) int vector of
         per-row positions (the serving engine, where each slot decodes
         at its own depth).
+
+        ``active`` (B,) bool, optional: rows whose result somebody
+        reads. The decode kernel reads no cache row for a row that is
+        not active (its attention output is zeros, its logits are
+        whatever the residual stream then gives, and nobody samples
+        from them); the row it WRITES at ``pos`` stays. The dense path
+        reads every row whatever the mask says. Default: every row
+        active.
 
         ``adapter`` (B,) int rows (with a ``params["lora"]`` bank
         present) applies batched-LoRA deltas per row — dense path only;
@@ -1147,7 +1156,8 @@ def _decode_builder(cfg: TransformerConfig, tp_mesh=None):
             l_i = (None if lora is None
                    else jax.tree.map(lambda a: a[i], lora))
             x, kv_all = block_decode(
-                x, p_i, kv_all, i, pos, lora=l_i, adapter=adapter
+                x, p_i, kv_all, i, pos, lora=l_i, adapter=adapter,
+                active=active,
             )
         x = _layer_norm(x, params["lnf_scale"], params["lnf_bias"])
         # head matmul with bf16 (or dequantized-int8) OPERANDS — half/
@@ -1471,16 +1481,29 @@ def paged_block_copy(blocks, src, dst):
     )
 
 
-def decode_rows_streamed(batch: int, tpad: int) -> int:
+def decode_rows_streamed(cfg: TransformerConfig, batch: int, tpad: int,
+                         held, paged: bool = False) -> int:
     """Cache rows (of one layer's K or V plane) that one ``forward_one``
-    call reads, whatever the slots hold: the decode kernel's grid walks
-    every ``block_t`` block of every slot's ``tpad`` rows and masks what
-    lies past ``pos`` after it is read, the dense path contracts over
-    the whole cache, and the paged wrapper below gathers every table
-    entry (sentinel blocks too) into that same slab before the step.
-    The engine books this as ``kv_rows_streamed`` beside the rows the
-    requests needed; a kernel that reads less says so here."""
-    return batch * tpad
+    call over ``batch`` slots of ``tpad`` rows reads. ``held``: for each
+    ACTIVE batch row, the rows it attends to (its position + 1); a row
+    that is not active has no entry.
+
+    The decode kernel walks an active row's slab in blocks and stops at
+    the block that holds its position, so it reads ``held`` rounded up
+    to ``decode_block_rows`` (the kernel's own rule) and nothing for a
+    row that is not active. The dense path (``decode_kernel=False``)
+    contracts over the whole cache, and the paged wrapper below gathers
+    every table entry (sentinel blocks too) into a slab before the
+    step: both read every row of every slot whatever it holds. The
+    engine books this as ``kv_rows_streamed`` beside the rows the
+    requests needed."""
+    if paged or not cfg.decode_kernel:
+        return batch * tpad
+    from deeplearning4j_tpu.ops.pallas_kernels import decode_block_rows
+
+    itemsize = 1 if cfg.decode_int8 else jnp.dtype(cfg.compute_dtype).itemsize
+    block = decode_block_rows(tpad, cfg.kv_heads * cfg.head_dim, itemsize)
+    return sum(min(-(-h // block) * block, tpad) for h in held)
 
 
 def make_paged_fwd1(fwd1):
@@ -1491,10 +1514,12 @@ def make_paged_fwd1(fwd1):
     blocks_per_slot) int32}`` — tables thread through the jitted
     programs as traced data, so ONE compiled program serves every
     block mapping."""
-    def paged_fwd1(params, pcaches, token, pos, adapter=None):
+    def paged_fwd1(params, pcaches, token, pos, adapter=None, active=None):
         tables = pcaches["tables"]
         view = paged_gather(pcaches["blocks"], tables)
-        logits, view = fwd1(params, view, token, pos, adapter=adapter)
+        logits, view = fwd1(
+            params, view, token, pos, adapter=adapter, active=active
+        )
         return logits, {
             "blocks": paged_scatter(pcaches["blocks"], tables, view),
             "tables": tables,

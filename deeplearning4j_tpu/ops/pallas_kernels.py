@@ -482,17 +482,144 @@ def flash_attention_trainable(
 
 # -- flash decode attention (single-position KV-cache read) -------------------
 #
-# The decode hot loop reads the WHOLE KV cache every step, so its HBM
-# layout is the perf story. A (B, T, H, K) cache tiles on (H, K) =
-# (12, 64) which Mosaic/XLA pads to (16, 128) — 2.67x the logical bytes
-# streamed per step (measured: the QK einsum alone was 601us/step at
-# GPT-2-small B=16). This kernel reads a PACKED (B, T, H*K) cache whose
-# minor dim is a lane-aligned 768: padding ~1.01x, and the per-head
-# split happens in registers via an iota-built block-diagonal expansion
-# matrix (no lane-splitting relayout). The online softmax runs in VMEM
-# scratch across sequential T blocks, exactly like the training flash
-# kernel; masked positions (> pos, or cache padding) contribute nothing
-# and fully-invisible blocks skip compute.
+# The decode hot loop reads the KV cache every step, so its HBM layout
+# and how much of it is read are the perf story. A (B, T, H, K) cache
+# tiles on (H, K) = (12, 64) which Mosaic/XLA pads to (16, 128) — 2.67x
+# the logical bytes streamed per step (measured: the QK einsum alone was
+# 601us/step at GPT-2-small B=16). These kernels read a PACKED
+# (B, T, H*K) cache whose minor dim is a lane-aligned 768: padding
+# ~1.01x, and the per-head split happens in registers via an iota-built
+# block-diagonal expansion matrix (no lane-splitting relayout). The
+# online softmax runs across sequential T blocks, exactly like the
+# training flash kernel; masked positions (> pos, or cache padding)
+# contribute nothing, and a block that holds no row at or before ``pos``
+# is neither computed nor copied in.
+#
+# All ``groups`` query rows are folded into ONE pair of wide MXU
+# contractions per block (r5 rewrite): the per-group Python loop of the
+# original kernel ran `groups` iterations of (block_t, n_kv)-thin ops,
+# which made GQA (groups=3, n_kv=2) SLOWER than MHA despite a 3x smaller
+# cache stream (11.1K vs 11.5K tok/s measured in situ).
+#
+# - K side: s_all (block_t, G*n_kv) = KB @ M^T via one dot_general,
+#   where M[(g,h), j] = q_g[j] * (head(j)==h) — the query fold into the
+#   block-diagonal reducer. In int8 mode KB stays int8 and M is built
+#   int8 from the in-register-quantized queries (one scale per group),
+#   so the dot runs on the int8 MXU and the cache is never converted.
+# - V side: PV (G*n_kv, hk) = softmax-weights^T @ VB via one dot_general
+#   contracting the t axis (int8 mode: weights quantized per tile, VB
+#   stays int8), then an iota-built segment mask + one tiny (G, G*n_kv)
+#   dot collapse per-head rows into per-group outputs. No (block_t, hk)
+#   elementwise pass touches the V block in either mode.
+#
+# Softmax state (m, l) lives in (1, G*n_kv) lanes (lane = g*n_kv + h);
+# the accumulator is (G, hk). The four helpers below are that math, once:
+# the grid kernel (int8 slab, paged pool) keeps the state in VMEM
+# scratch across grid steps, the walk kernel (slab) carries it through
+# its loop.
+
+
+def _decode_structure(groups: int, n_kv_heads: int, head_dim: int):
+    """iota-built structure matrices (no data movement):
+    e_tile[r, j] = (head(j) == r % n_kv), the head-segment mask per
+    (group, head) row, (gh, hk); s_g[g, r] = (r // n_kv == g), the group
+    collapse, (groups, gh) — its transpose doubles as the row-repeat of
+    per-group values."""
+    gh = groups * n_kv_heads
+    hk = n_kv_heads * head_dim
+    row_h = jax.lax.broadcasted_iota(jnp.int32, (gh, hk), 0) % n_kv_heads
+    col_h = jax.lax.broadcasted_iota(jnp.int32, (gh, hk), 1) // head_dim
+    g_row = jax.lax.broadcasted_iota(jnp.int32, (groups, gh), 0)
+    g_col = jax.lax.broadcasted_iota(jnp.int32, (groups, gh), 1) // n_kv_heads
+    return (
+        (row_h == col_h).astype(jnp.float32),
+        (g_row == g_col).astype(jnp.float32),
+    )
+
+
+def _decode_fold_query(q, e_tile, s_g, cache_dtype, quantized: bool):
+    """The (G, hk) query rows as M (gh, hk) in the cache's dtype: row
+    (g, h) is query row g masked to head h's lane segment. int8 mode
+    quantizes in-register, one scale per group, and also returns that
+    scale per (g, h) lane, (1, gh); otherwise None."""
+    qf = q.astype(jnp.float32)
+    q_rep = jnp.dot(s_g.T, qf, preferred_element_type=jnp.float32)
+    if not quantized:
+        return (q_rep * e_tile).astype(cache_dtype), None
+    qmax = jnp.maximum(
+        jnp.max(jnp.abs(qf), axis=1, keepdims=True), 1e-8
+    )  # (G, 1)
+    qsc_rep = jnp.dot(
+        s_g.T, qmax / 127.0, preferred_element_type=jnp.float32
+    )  # (gh, 1): per-(group,head)-row q scale
+    m_t = (
+        jnp.clip(jnp.round(q_rep / qsc_rep), -127, 127) * e_tile
+    ).astype(jnp.int8)
+    return m_t, qsc_rep.reshape(1, -1)
+
+
+def _decode_block(state, kb, vb, scales, m_t, qsc_lane, e_tile, s_g,
+                  t_start, pos, scale: float):
+    """Fold one (block_t, hk) K/V block that starts at row ``t_start``
+    into the online-softmax ``state`` (m, l, acc); rows past ``pos`` are
+    masked. The block must hold a row at or before ``pos``.
+
+    Operands stay in the storage dtype (bf16 on TPU: the MXU fast path —
+    f32-operand dots measured ~4x slower); softmax state and
+    accumulators are f32. int8 mode (``scales`` = the (block_t, 1) f32
+    K and V scale columns): both cache planes feed the MXU directly as
+    int8 — converting a plane on the VPU costs more than the int8 DMA
+    saves (measured 43us/layer, bf16-equal, before this design)."""
+    m_prev, l_prev, acc = state
+    quantized = scales is not None
+    if quantized:
+        ksc, vsc = scales
+        s_all = jax.lax.dot_general(
+            kb, m_t, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.int32,
+        ).astype(jnp.float32) * (ksc * scale) * qsc_lane
+    else:
+        s_all = jax.lax.dot_general(
+            kb, m_t, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale  # (block_t, gh)
+    rows = t_start + jax.lax.broadcasted_iota(
+        jnp.int32, (kb.shape[0], 1), 0
+    )
+    s_all = jnp.where(rows > pos, -jnp.inf, s_all)
+    m_new = jnp.maximum(m_prev, jnp.max(s_all, axis=0, keepdims=True))
+    p = jnp.exp(s_all - m_new)  # (block_t, gh) f32
+    corr = jnp.exp(m_prev - m_new)  # (1, gh)
+    l_new = corr * l_prev + jnp.sum(p, axis=0, keepdims=True)
+    if quantized:
+        p_v = p * vsc
+        psc = jnp.maximum(jnp.max(p_v), 1e-30) / 127.0
+        p_low = jnp.clip(jnp.round(p_v / psc), -127, 127).astype(jnp.int8)
+    else:
+        p_low = p.astype(vb.dtype)
+    pv = jax.lax.dot_general(
+        p_low, vb, (((0,), (0,)), ((), ())),
+        preferred_element_type=jnp.int32 if quantized else jnp.float32,
+    )  # (gh, hk): row (g, h) valid only on head-segment h
+    pv_m = pv.astype(jnp.float32) * e_tile
+    if quantized:
+        pv_m = pv_m * psc
+    o_blk = jnp.dot(s_g, pv_m, preferred_element_type=jnp.float32)  # (G, hk)
+    # per-lane correction expanded to (G, hk): corr[g, head(j)]
+    corr_exp = jnp.dot(
+        s_g * corr, e_tile, preferred_element_type=jnp.float32
+    )
+    return m_new, l_new, acc * corr_exp + o_blk
+
+
+def _decode_output(l, acc, e_tile, s_g):
+    """(G, hk) attention output from the final state. A row that
+    attended to nothing (l = 0, acc = 0) gives zeros."""
+    l_exp = jnp.dot(
+        s_g * jnp.maximum(l, 1e-30), e_tile,
+        preferred_element_type=jnp.float32,
+    )
+    return acc / l_exp
 
 
 def _flash_decode_kernel(
@@ -500,31 +627,10 @@ def _flash_decode_kernel(
     block_t: int, n_t: int, n_kv_heads: int, head_dim: int,
     groups: int, scale: float, quantized: bool = False,
 ):
-    """One (batch, t-block) grid step of single-position decode attention.
-
-    All ``groups`` query rows are folded into ONE pair of wide MXU
-    contractions per block (r5 rewrite): the per-group Python loop of
-    the original kernel ran `groups` iterations of (block_t, n_kv)-thin
-    ops, which made GQA (groups=3, n_kv=2) SLOWER than MHA despite a 3x
-    smaller cache stream (11.1K vs 11.5K tok/s measured in situ).
-
-    - K side: s_all (block_t, G*n_kv) = KB @ M^T via one dot_general,
-      where M[(g,h), j] = q_g[j] * (head(j)==h) — the query fold into
-      the block-diagonal reducer. In int8 mode KB stays int8 and M is
-      built int8 from the in-register-quantized queries (one scale per
-      group), so the dot runs on the int8 MXU and the cache is never
-      converted.
-    - V side: PV (G*n_kv, hk) = softmax-weights^T @ VB via one
-      dot_general contracting the t axis (int8 mode: weights quantized
-      per tile, VB stays int8), then an iota-built segment mask + one
-      tiny (G, G*n_kv) dot collapse per-head rows into per-group
-      outputs. No (block_t, hk) elementwise pass touches the V block in
-      either mode.
-
-    Softmax state lives in (1, G*n_kv) lanes (lane = g*n_kv + h);
-    the accumulator is (G, hk).
-    rest = ([ks_ref, vs_ref,] o_ref, m_s, l_s, acc_s).
-    """
+    """One (batch, t-block) grid step of single-position decode
+    attention: the K/V (and int8 scale) blocks arrive through BlockSpecs,
+    the state lives in VMEM scratch across the row's grid steps.
+    rest = ([ks_ref, vs_ref,] o_ref, m_s, l_s, acc_s)."""
     if quantized:
         ks_ref, vs_ref, o_ref, m_s, l_s, acc_s = rest
     else:
@@ -539,103 +645,26 @@ def _flash_decode_kernel(
         l_s[:] = jnp.zeros_like(l_s)
         acc_s[:] = jnp.zeros_like(acc_s)
 
-    hk = n_kv_heads * head_dim
-    gh = groups * n_kv_heads
-    # iota-built structure matrices (no data movement):
-    # e_tile[r, j] = (head(j) == r % n_kv): head-segment mask per
-    # (group, head) row; s_g[g, r] = (r // n_kv == g): group collapse
-    # (its transpose doubles as the row-repeat of per-group values).
-    row_h = jax.lax.broadcasted_iota(jnp.int32, (gh, hk), 0) % n_kv_heads
-    col_h = jax.lax.broadcasted_iota(jnp.int32, (gh, hk), 1) // head_dim
-    e_tile = (row_h == col_h).astype(jnp.float32)  # (gh, hk)
-    g_row = jax.lax.broadcasted_iota(jnp.int32, (groups, gh), 0)
-    g_col = jax.lax.broadcasted_iota(jnp.int32, (groups, gh), 1) // n_kv_heads
-    s_g = (g_row == g_col).astype(jnp.float32)  # (groups, gh)
-
+    # the structure matrices are built only on the grid steps that use
+    # them: a skipped block builds none
     @pl.when(t_start <= pos)
     def _compute():
-        # operands stay in the storage dtype (bf16 on TPU: the MXU fast
-        # path — f32-operand dots measured ~4x slower); softmax state
-        # and accumulators are f32. int8 mode: both cache planes feed
-        # the MXU directly as int8 — converting a plane on the VPU
-        # costs more than the int8 DMA saves (measured 43us/layer,
-        # bf16-equal, before this design).
-        qf = q_ref[0].astype(jnp.float32)  # (G, hk)
-        # M^T rows (g, h): query row g replicated over its n_kv head
-        # rows, masked to each head's lane segment
-        q_rep = jnp.dot(s_g.T, qf, preferred_element_type=jnp.float32)
-        if quantized:
-            kb = k_ref[0, 0, 0]  # int8 (block_t, hk), never converted
-            vb = v_ref[0, 0, 0]  # int8, never converted
-            ksc = ks_ref[0, 0, 0]  # (block_t, 1) f32
-            vsc = vs_ref[0, 0, 0]
-            qmax = jnp.maximum(
-                jnp.max(jnp.abs(qf), axis=1, keepdims=True), 1e-8
-            )  # (G, 1)
-            qscale = qmax / 127.0
-            qsc_rep = jnp.dot(
-                s_g.T, qscale, preferred_element_type=jnp.float32
-            )  # (gh, 1): per-(group,head)-row q scale
-            qsc_lane = qsc_rep.reshape(1, gh)
-            q_rep_scaled = q_rep / qsc_rep
-            m_t = (
-                jnp.clip(jnp.round(q_rep_scaled), -127, 127) * e_tile
-            ).astype(jnp.int8)  # (gh, hk)
-            s_all = jax.lax.dot_general(
-                kb, m_t, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.int32,
-            ).astype(jnp.float32) * (ksc * scale) * qsc_lane
-        else:
-            kb = k_ref[0, 0, 0]
-            vb = v_ref[0, 0, 0]
-            m_t = (q_rep * e_tile).astype(kb.dtype)
-            s_all = jax.lax.dot_general(
-                kb, m_t, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale  # (block_t, gh)
-        rows = t_start + jax.lax.broadcasted_iota(
-            jnp.int32, (block_t, 1), 0
+        e_tile, s_g = _decode_structure(groups, n_kv_heads, head_dim)
+        m_t, qsc_lane = _decode_fold_query(
+            q_ref[0], e_tile, s_g, k_ref.dtype, quantized
         )
-        s_all = jnp.where(rows > pos, -jnp.inf, s_all)
-        m_prev = m_s[:]  # (1, gh)
-        m_new = jnp.maximum(m_prev, jnp.max(s_all, axis=0, keepdims=True))
-        p = jnp.exp(s_all - m_new)  # (block_t, gh) f32
-        corr = jnp.exp(m_prev - m_new)  # (1, gh)
-        l_s[:] = corr * l_s[:] + jnp.sum(p, axis=0, keepdims=True)
-        if quantized:
-            p_v = p * vsc
-            pmax = jnp.maximum(jnp.max(p_v), 1e-30)
-            psc = pmax / 127.0
-            p_low = jnp.clip(jnp.round(p_v / psc), -127, 127).astype(
-                jnp.int8
-            )
-        else:
-            psc = None
-            p_low = p.astype(vb.dtype)
-        pv = jax.lax.dot_general(
-            p_low, vb, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.int32 if quantized else jnp.float32,
-        )  # (gh, hk): row (g, h) valid only on head-segment h
-        pv_m = pv.astype(jnp.float32) * e_tile
-        if quantized:
-            pv_m = pv_m * psc
-        o_blk = jnp.dot(
-            s_g, pv_m, preferred_element_type=jnp.float32
-        )  # (G, hk)
-        # per-lane correction expanded to (G, hk): corr[g, head(j)]
-        corr_exp = jnp.dot(
-            s_g * corr, e_tile, preferred_element_type=jnp.float32
+        scales = (ks_ref[0, 0, 0], vs_ref[0, 0, 0]) if quantized else None
+        m_s[:], l_s[:], acc_s[:] = _decode_block(
+            (m_s[:], l_s[:], acc_s[:]), k_ref[0, 0, 0], v_ref[0, 0, 0],
+            scales, m_t, qsc_lane, e_tile, s_g, t_start, pos, scale,
         )
-        acc_s[:] = acc_s[:] * corr_exp + o_blk
-        m_s[:] = m_new
 
     @pl.when(tt == n_t - 1)
     def _finalize():
-        l_exp = jnp.dot(
-            s_g * jnp.maximum(l_s[:], 1e-30), e_tile,
-            preferred_element_type=jnp.float32,
-        )  # (G, hk)
-        o_ref[0] = (acc_s[:] / l_exp).astype(o_ref.dtype)
+        e_tile, s_g = _decode_structure(groups, n_kv_heads, head_dim)
+        o_ref[0] = _decode_output(
+            l_s[:], acc_s[:], e_tile, s_g
+        ).astype(o_ref.dtype)
 
 
 # The per-row positions go to the kernel WHOLE, as a (B,) int32 vector
@@ -654,6 +683,208 @@ def _decode_positions(pos, b: int) -> jax.Array:
     )
 
 
+# The block-size rule's constants, measured on a v5e (PERF.md, PR 26:
+# 48 x 1,024 x 1,280 bf16 MHA and 16 x 8,704 x 256 bf16 GQA).
+# A block under 128 rows half-fills the MXU pass that contracts over
+# its rows: 64-row blocks cost 1.4x the time of 128-row ones a row.
+_DECODE_MIN_ROWS = 128
+# What a block of the walk costs beside its rows (loop and copy
+# bookkeeping, the softmax state's update), written as the K and V
+# bytes the kernel moves in that time: 0.04-0.08 us.
+_DECODE_STEP_BYTES = 56 * 1024
+# Scoped VMEM the K and V block planes may take, with headroom under
+# the ~16MB limit for q, out and scratch: a single 8704-row bf16 block
+# at hk=256 OOMed at 17.04M under the grid pipeline, matching its
+# 4-plane estimate.
+_DECODE_VMEM_BYTES = 14 * 1024 * 1024
+# K/V blocks in flight or in use at once in the walk kernel: one under
+# the arithmetic, two on their way. With one on its way the copy's
+# latency (about 0.5 us) was exposed once a block: 165 us a call against
+# 120 at the benchmark's geometry; a fourth buffer gained nothing.
+_WALK_BUFFERS = 3
+
+
+@functools.lru_cache(maxsize=None)
+def decode_block_rows(t: int, hk: int, itemsize: int) -> int:
+    """Rows of one T block of :func:`flash_decode_attention` for a
+    ``t``-row slab of packed width ``hk`` and cache item size
+    ``itemsize``: the one rule both the kernel and the engine's row
+    counter (``decode_rows_streamed``) read.
+
+    bf16 / f32 (the walk kernel). A row reads whole blocks up to its
+    last needed row, so a block of ``r`` rows reads ``r / 2`` rows too
+    many on average, and a row half its slab long visits
+    ``t / 2r + 1/2`` blocks that each cost what ``_DECODE_STEP_BYTES``
+    of cache would: the rule takes the 8-aligned divisor of ``t``, of at
+    least ``_DECODE_MIN_ROWS`` rows and within the VMEM budget (K and V
+    planes in three buffers), that minimises the sum. A slab with no
+    such divisor is one block, the kernel of before PR 26 tile for tile.
+    That gives 128 rows at (1024, 1280, bf16) and 544 at (8704, 256,
+    bf16), where 512 and 1,088 were measured (PERF.md, PR 26): with
+    every row read, the worst case for small blocks, 340 us a call
+    against the single 1,024-row block's 344, and 217 (512) or 206
+    (1,088) against the two 4,352-row blocks' 209; with rows a quarter
+    to a half full, 120 against 344 and 129 (either) against 204.
+    Before the walk was bounded, and with one copy in flight, fewer and
+    larger blocks won (r5: +24.5% tok/s from 512 to 4,352 rows at
+    T=8704): every row was read anyway and each block exposed its
+    copy's latency.
+
+    int8 (the grid kernel): as few blocks as VMEM allows, the rule of
+    before PR 26. A grid step there costs about 1.4 us whatever it
+    holds (four block operands two deep, the query quantised again),
+    skipped steps 0.5 us, so small blocks lose more than their
+    round-up saves: at (1024, 1280) 512-row blocks measured 237 us a
+    call on the flood's lengths, 150 on the chat's and 247 with every
+    row read, against 241 / 241 / 246 before the index maps were
+    clamped; 128-row blocks 266 / 191 / 558. int8 streams 1 byte an
+    element but the in-register conversion keeps per-block scratch (a
+    single-block int8 OOM at 25.54M, T=8704, hk=256, works out to ~2.87
+    bytes an element-plane): VMEM budgets its four planes at 3.
+
+    An adversarial ``t`` (8 x prime) has only tiny and whole-slab
+    divisors; callers size ``t`` as a multiple of 512 above 1024
+    (``init_caches``)."""
+    assert t % 8 == 0, f"cache T dim must be a multiple of 8, got {t}"
+
+    def divisors(lo, hi):
+        return [r for r in range(lo, min(t, hi) + 1, 8) if t % r == 0]
+
+    if itemsize == 1:
+        cap = max(8, _DECODE_VMEM_BYTES // (hk * 3 * 4))
+        return max(divisors(8, cap))
+    cap = max(8, _DECODE_VMEM_BYTES // (hk * itemsize * 2 * _WALK_BUFFERS))
+    step_rows = _DECODE_STEP_BYTES / (2 * hk * itemsize)
+    fits = divisors(_DECODE_MIN_ROWS, cap)
+    if not fits:
+        # nothing between the floor and the budget divides t: the whole
+        # slab if it fits, else the largest divisor that does
+        return max(divisors(8, cap))
+    return min(fits, key=lambda r: (t / (2 * r) + 0.5) * step_rows + r / 2)
+
+
+def _decode_last_rows(pos, active, b: int, t: int) -> jax.Array:
+    """(B,) int32: the last cache row each batch row attends to (its
+    position, capped at the slab), -1 for a row that is not active."""
+    last = jnp.minimum(_decode_positions(pos, b), t - 1)
+    if active is None:
+        return last
+    return jnp.where(jnp.asarray(active, bool), last, -1)
+
+
+def _grid_walk(last, block_t: int):
+    """The (B,) vectors the grid kernel's K/V index maps read beside
+    ``last``. An active row walks its own blocks 0..``last // block_t``
+    and then repeats the last one; a row that is not active names the
+    block the active row before it ended on (the first block of the
+    first active row when there is none before it). A block index that
+    repeats the previous grid step's issues no copy, so only blocks
+    that hold a needed row are ever read. Returns ``(src, hi)``: the
+    slab row and the last block a batch row may name."""
+    live = last >= 0
+    rows = jnp.arange(last.shape[0], dtype=jnp.int32)
+    before = jax.lax.cummax(jnp.where(live, rows, -1))
+    src = jnp.where(before >= 0, before, jnp.argmax(live)).astype(jnp.int32)
+    hi = jnp.where(before >= 0, jnp.maximum(last[src], 0) // block_t, 0)
+    return src, hi
+
+
+def _bounded_grid_kernel(layer_ref, last_ref, src_ref, hi_ref, q_ref, k_ref,
+                         v_ref, *rest, **kw):
+    """int8 slab grid step: ``_flash_decode_kernel`` with the row's
+    position read from the scalar-prefetched ``last``; ``layer``,
+    ``src`` and ``hi`` are consumed by the index maps alone."""
+    del layer_ref, src_ref, hi_ref
+    _flash_decode_kernel(q_ref, k_ref, v_ref, last_ref, *rest, **kw)
+
+
+def _walk_decode_kernel(
+    layer_ref, last_ref, base_ref, nxt_ref, q_ref, kv_hbm, o_ref, buf, sem,
+    *, block_t: int, n_kv_heads: int, head_dim: int, groups: int,
+    scale: float,
+):
+    """One batch row of the bounded walk: the stacked cache stays in
+    HBM and the row's blocks 0..``last // block_t`` come in by explicit
+    copies into ``_WALK_BUFFERS`` buffers (K and V planes in one strided
+    copy), so no grid step and no copy is spent on a block nobody
+    reads; a row that is not active (``last`` = -1) copies nothing and
+    writes zeros.
+
+    The blocks of ALL rows form one sequence, and the copy of the block
+    ``_WALK_BUFFERS - 1`` places ahead in that sequence — the next rows'
+    first ones at a row's end — starts before this block's arithmetic:
+    the pipeline never drains between rows. ``base[i]`` is row i's
+    first index in that sequence (modulo the buffers it picks one),
+    ``nxt[k]`` the first row >= k with anything to read (B when
+    none)."""
+    b = last_ref.shape[0]
+    i = pl.program_id(0)
+    last = last_ref[i]
+
+    def n_blocks(row):
+        return (last_ref[row] + block_t) // block_t  # -1 -> 0
+
+    def copy(row, j, slot):
+        return pltpu.make_async_copy(
+            kv_hbm.at[
+                layer_ref[0], pl.ds(0, 2), row,
+                pl.ds(pl.multiple_of(j * block_t, block_t), block_t),
+            ],
+            buf.at[slot], sem.at[slot],
+        )
+
+    def after(row, j):
+        # the block that follows (row, j) in the sequence; row == B: none
+        r = jnp.minimum(row, b - 1)
+        more = j + 1 < n_blocks(r)
+        return jnp.where(more, row, nxt_ref[r + 1]), jnp.where(more, j + 1, 0)
+
+    def start(row, j, slot):
+        @pl.when(row < b)
+        def _():
+            copy(row, j, slot).start()
+
+    @pl.when(i == 0)
+    def _prime():
+        row, j = nxt_ref[0], 0
+        for slot in range(_WALK_BUFFERS - 1):
+            start(row, j, slot)
+            row, j = after(row, j)
+
+    @pl.when(last < 0)
+    def _idle():
+        o_ref[0] = jnp.zeros(o_ref.shape[1:], o_ref.dtype)
+
+    @pl.when(last >= 0)
+    def _walk():
+        e_tile, s_g = _decode_structure(groups, n_kv_heads, head_dim)
+        m_t, _ = _decode_fold_query(q_ref[0], e_tile, s_g, buf.dtype, False)
+
+        def block(j, state):
+            g = base_ref[i] + j
+            slot = g % _WALK_BUFFERS
+            copy(i, j, slot).wait()
+            ahead = (i, j)
+            for _ in range(_WALK_BUFFERS - 1):
+                ahead = after(*ahead)
+            start(*ahead, (g + _WALK_BUFFERS - 1) % _WALK_BUFFERS)
+            return _decode_block(
+                state, buf[slot, 0], buf[slot, 1], None, m_t, None, e_tile,
+                s_g, j * block_t, last, scale,
+            )
+
+        gh = groups * n_kv_heads
+        _, l, acc = jax.lax.fori_loop(
+            0, n_blocks(i), block,
+            (
+                jnp.full((1, gh), -jnp.inf, jnp.float32),
+                jnp.zeros((1, gh), jnp.float32),
+                jnp.zeros(q_ref.shape[1:], jnp.float32),
+            ),
+        )
+        o_ref[0] = _decode_output(l, acc, e_tile, s_g).astype(o_ref.dtype)
+
+
 def flash_decode_attention(
     q: jax.Array,
     kvcache: jax.Array,
@@ -663,123 +894,152 @@ def flash_decode_attention(
     block_t: int | None = None,
     interpret: bool | None = None,
     kv_scales: jax.Array | None = None,
+    active: jax.Array | None = None,
 ) -> jax.Array:
     """One decode step of causal attention against a packed KV cache.
 
     ``q``: (B, G, Hkv*K) — query heads grouped for GQA (G = H/Hkv; 1 for
     MHA), each group packed head-major. ``kvcache``: the FULL STACKED
-    (n_layers, 2, B, T, Hkv*K) cache (axis 1: K then V) — ``layer`` (a
-    static int) selects the layer inside the BlockSpec index map, so no
-    host-side slice is needed. (Slicing the stack outside the kernel
-    materializes a copy of the whole layer cache per call — a custom
-    call needs a dense operand buffer, so XLA cannot fuse the slice the
-    way it fuses one feeding an einsum: 521us/step at GPT-2-small,
-    measured.) T must be a multiple of ``block_t`` (callers pad; rows
-    beyond ``pos`` are masked so padding is free). ``pos``: scalar
-    int32, the position being decoded, or an (B,) vector of per-row
-    positions (continuous-batching serving, where each slot decodes at
-    its own depth) — rows > pos are invisible. Returns (B, G, Hkv*K)
+    (n_layers, 2, B, T, Hkv*K) cache (axis 1: K then V) — ``layer``
+    selects the layer inside the kernel, so no host-side slice is
+    needed. (Slicing the stack outside the kernel materializes a copy
+    of the whole layer cache per call — a custom call needs a dense
+    operand buffer, so XLA cannot fuse the slice the way it fuses one
+    feeding an einsum: 521us/step at GPT-2-small, measured.) ``pos``:
+    scalar int32, the position being decoded, or an (B,) vector of
+    per-row positions (continuous-batching serving, where each slot
+    decodes at its own depth) — rows > pos are invisible. ``active``:
+    optional (B,) bool; a row that is not active attends to nothing and
+    returns zeros (default: every row active). Returns (B, G, Hkv*K)
     attention output in q's dtype.
+
+    What is read: a row's slab is walked in T blocks of ``block_t`` rows
+    (default :func:`decode_block_rows`; T must be a multiple of it,
+    callers pad) and the walk, copies included, stops at the block that
+    holds ``pos``: a row reads ``pos + 1`` rows rounded up to the block,
+    a row that is not active reads nothing. Rows past ``pos`` inside the
+    last block are masked.
 
     ``kv_scales`` (int8 serving mode): per-row dequant scales
     (n_layers, 2, B, T, 1) f32 for an int8 ``kvcache`` — rows convert
     to q's dtype in-register and the scales fold into the logits (K) /
     softmax weights (V), so the HBM cache stream is the int8 bytes.
+    Mosaic refuses an explicit copy of a (rows, 1) slice of the scale
+    planes (a minor dim of 1 is not tile-aligned), so the int8 cache
+    keeps a (B, T / block_t) grid whose BlockSpec index maps stop at
+    the row's last block (:func:`_grid_walk`): the same reads, at the
+    price of an empty grid step for every block skipped.
+
+    ``layer`` reaches the kernel as data and the call is one jitted
+    function of the shapes: a step program that calls it once a layer
+    and substep (144 times at 36 layers x K=4) traces and lowers the
+    kernel once, not once a call.
     """
+    t, hk = kvcache.shape[3], q.shape[2]
+    if block_t is None:
+        block_t = decode_block_rows(t, hk, kvcache.dtype.itemsize)
+    block_t = min(block_t, t)
+    assert t % block_t == 0, (t, block_t)
+    return _decode_attention(
+        q, kvcache, jnp.asarray(pos, jnp.int32), active,
+        jnp.asarray(layer, jnp.int32), kv_scales, n_kv_heads=n_kv_heads,
+        block_t=block_t,
+        interpret=_default_interpret() if interpret is None else interpret,
+    )
+
+
+@functools.partial(
+    jax.jit, static_argnames=("n_kv_heads", "block_t", "interpret")
+)
+def _decode_attention(q, kvcache, pos, active, layer, kv_scales, *,
+                      n_kv_heads: int, block_t: int, interpret: bool):
     b, g, hk = q.shape
     t = kvcache.shape[3]
     head_dim = hk // n_kv_heads
-    # the block search below requires an 8-aligned T to terminate
-    assert t % 8 == 0, f"cache T dim must be a multiple of 8, got {t}"
-    if block_t is None:
-        # as FEW t blocks as VMEM allows: per-cell fixed costs dominate
-        # at this arithmetic intensity, so bigger blocks win as long as
-        # they fit — at T=8704 raising the block from 512 to 4352
-        # measured +24.5% tok/s (r5 "8k-context serving"). The ceiling
-        # is the ~16MB scoped VMEM budget: the K and V block planes,
-        # double-buffered by the pipeline, are the dominant allocation
-        # (a single 8704-row bf16 block OOMed at 17.04M, matching the
-        # 4-plane estimate), so cap rows at 14MiB / (hk * eff_bytes * 4)
-        # with headroom for q/out/scratch. int8 caches stream half the
-        # HBM bytes but the kernel's in-register conversion keeps extra
-        # per-block scratch: the measured single-block int8 OOM
-        # (25.54M at T=8704, hk=256) works out to ~2.87 bytes per
-        # element-plane, so int8 budgets at 3 — NOT its 1-byte stream
-        # size. The 14MB budget is sized so the measured-best bf16
-        # block (4352 at hk=256: 8.5M actual) and its int8 twin
-        # (12.8M actual) both land under the 16MB scoped limit with
-        # headroom. No floor overriding the budget: huge-hk geometries
-        # get correspondingly small blocks instead of an OOM. Then the
-        # smallest divisor count that keeps blocks under the cap and
-        # 8-aligned; callers size T as a multiple of 512 above 1024
-        # (init_caches), so the search lands on large blocks instead
-        # of walking down to 8-row blocks (an adversarial 8*prime T
-        # would pay ~100x per-cell).
-        eff_bytes = 3 if kvcache.dtype.itemsize == 1 else kvcache.dtype.itemsize
-        cap = max(8, (14 * 1024 * 1024) // (hk * eff_bytes * 4))
-        n_t = -(-t // cap)
-        while t % n_t or (t // n_t) % 8:
-            n_t += 1
-        block_t = t // n_t
-    block_t = min(block_t, t)
-    assert t % block_t == 0, (t, block_t)
-    interpret = _default_interpret() if interpret is None else interpret
-    n_t = t // block_t
-    quantized = kv_scales is not None
-    kernel = functools.partial(
-        _flash_decode_kernel, block_t=block_t, n_t=n_t,
-        n_kv_heads=n_kv_heads, head_dim=head_dim, groups=g,
-        scale=1.0 / (head_dim**0.5), quantized=quantized,
+    last = _decode_last_rows(pos, active, b, t)
+    layer = jnp.reshape(layer, (1,))
+    statics = dict(
+        block_t=block_t, n_kv_heads=n_kv_heads, head_dim=head_dim,
+        groups=g, scale=1.0 / (head_dim**0.5),
     )
-    pos_arr = _decode_positions(pos, b)
-    in_specs = [
-        pl.BlockSpec((1, g, hk), lambda i, tt: (i, 0, 0)),
-        # the K and V planes of the one stacked cache buffer, as two
-        # block views (XLA dedups the duplicated operand)
-        pl.BlockSpec(
-            (1, 1, 1, block_t, hk),
-            lambda i, tt: (layer, 0, i, tt, 0),
-        ),
-        pl.BlockSpec(
-            (1, 1, 1, block_t, hk),
-            lambda i, tt: (layer, 1, i, tt, 0),
-        ),
-        _POS_SPEC,
-    ]
-    operands = [q, kvcache, kvcache, pos_arr]
-    if quantized:
-        assert kvcache.dtype == jnp.int8, kvcache.dtype
-        assert kv_scales.shape == (kvcache.shape[0], 2, b, t, 1), (
-            kv_scales.shape
+    out_shape = jax.ShapeDtypeStruct((b, g, hk), q.dtype)
+
+    def row(i, *_):
+        return (i, 0, 0)
+
+    if kv_scales is None:
+        n_blocks = (last + block_t) // block_t
+        rows = jnp.arange(b, dtype=jnp.int32)
+        nxt = jnp.concatenate([
+            jax.lax.cummin(jnp.where(n_blocks > 0, rows, b), reverse=True),
+            jnp.full((1,), b, jnp.int32),
+        ])
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(b,),
+            in_specs=[
+                pl.BlockSpec((1, g, hk), row),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=pl.BlockSpec((1, g, hk), row),
+            scratch_shapes=[
+                pltpu.VMEM((_WALK_BUFFERS, 2, block_t, hk), kvcache.dtype),
+                pltpu.SemaphoreType.DMA((_WALK_BUFFERS,)),
+            ],
         )
-        # per-row scale planes for K and V (trailing singleton keeps the
-        # block Mosaic-legal: second-to-last dim block_t %8, last full)
-        in_specs += [
-            pl.BlockSpec(
-                (1, 1, 1, block_t, 1),
-                lambda i, tt: (layer, 0, i, tt, 0),
-            ),
-            pl.BlockSpec(
-                (1, 1, 1, block_t, 1),
-                lambda i, tt: (layer, 1, i, tt, 0),
-            ),
-        ]
-        operands += [kv_scales, kv_scales]
-    return pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((b, g, hk), q.dtype),
-        grid=(b, n_t),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, g, hk), lambda i, tt: (i, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((1, g * n_kv_heads), jnp.float32),  # m (lane = g*n_kv+h)
-            pltpu.VMEM((1, g * n_kv_heads), jnp.float32),  # l
-            pltpu.VMEM((g, hk), jnp.float32),              # acc
+        return pl.pallas_call(
+            functools.partial(_walk_decode_kernel, **statics),
+            out_shape=out_shape,
+            grid_spec=grid_spec,
+            # rows in order: each starts the next one's first copies
+            compiler_params=_dim_semantics(interpret, ("arbitrary",)),
+            interpret=interpret,
+            name="decode_attn",
+        )(layer, last, jnp.cumsum(n_blocks) - n_blocks, nxt, q, kvcache)
+
+    assert kvcache.dtype == jnp.int8, kvcache.dtype
+    assert kv_scales.shape == (kvcache.shape[0], 2, b, t, 1), kv_scales.shape
+
+    def plane(p, width):
+        # one K or V plane of the stacked buffer, walked per row: tt
+        # runs over every block, the map stops at the row's last one
+        def index(i, tt, layer, last, src, hi):
+            blk = jnp.where(last[i] < 0, hi[i], jnp.minimum(tt, hi[i]))
+            return (layer[0], p, src[i], blk, 0)
+        return pl.BlockSpec((1, 1, 1, block_t, width), index)
+
+    gh = g * n_kv_heads
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(b, t // block_t),
+        # the K and V planes of the one stacked cache buffer as two
+        # block views (XLA dedups the duplicated operand), then their
+        # per-row scale planes (trailing singleton keeps the block
+        # Mosaic-legal: second-to-last dim block_t %8, last full)
+        in_specs=[
+            pl.BlockSpec((1, g, hk), row),
+            plane(0, hk), plane(1, hk), plane(0, 1), plane(1, 1),
         ],
+        out_specs=pl.BlockSpec((1, g, hk), row),
+        scratch_shapes=[
+            pltpu.VMEM((1, gh), jnp.float32),  # m (lane = g*n_kv+h)
+            pltpu.VMEM((1, gh), jnp.float32),  # l
+            pltpu.VMEM((g, hk), jnp.float32),  # acc
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(
+            _bounded_grid_kernel, n_t=t // block_t, quantized=True, **statics
+        ),
+        out_shape=out_shape,
+        grid_spec=grid_spec,
         compiler_params=_dim_semantics(interpret, ("parallel", "arbitrary")),
         interpret=interpret,
         name="decode_attn",
-    )(*operands)
+    )(
+        layer, last, *_grid_walk(last, block_t),
+        q, kvcache, kvcache, kv_scales, kv_scales,
+    )
 
 
 def _paged_decode_kernel(tbl_ref, q_ref, k_ref, v_ref, pos_ref, *rest,

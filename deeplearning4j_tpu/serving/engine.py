@@ -302,7 +302,8 @@ def build_step_program(fwd1, horizon: int, temperature: float,
             # next admission's prefill insert
             toks = jnp.where(active, toks, 0)
             new_logits, caches = fwd1(
-                params, caches, toks, pos, adapter=adapters
+                params, caches, toks, pos, adapter=adapters,
+                active=active,
             )
             # advance only live slots, then deactivate in-program:
             # a slot that just emitted EOS or spent its budget
@@ -423,7 +424,8 @@ def build_piggyback_program(fwd1, fwd_chunk, horizon: int,
                 )(tok_keys, filt / temperature).astype(jnp.int32)
             toks = jnp.where(active, toks, 0)
             new_logits, caches = fwd1(
-                params, caches, toks, pos, adapter=adapters
+                params, caches, toks, pos, adapter=adapters,
+                active=active,
             )
             pos = jnp.where(active, pos + 1, pos)
             budget = jnp.where(active, budget - 1, budget)
@@ -565,7 +567,8 @@ def build_masked_step_program(fwd1, horizon: int, n_logprobs: int):
             nxt = trans_tab[gstate, toks]
             gstate = jnp.where(active & (gstate > 0), nxt, gstate)
             new_logits, caches = fwd1(
-                params, caches, toks, pos, adapter=adapters
+                params, caches, toks, pos, adapter=adapters,
+                active=active,
             )
             pos = jnp.where(active, pos + 1, pos)
             budget = jnp.where(active, budget - 1, budget)
@@ -599,7 +602,8 @@ def build_masked_piggyback_program(fwd1, fwd_chunk, horizon: int,
             nxt = trans_tab[gstate, toks]
             gstate = jnp.where(active & (gstate > 0), nxt, gstate)
             new_logits, caches = fwd1(
-                params, caches, toks, pos, adapter=adapters
+                params, caches, toks, pos, adapter=adapters,
+                active=active,
             )
             pos = jnp.where(active, pos + 1, pos)
             budget = jnp.where(active, budget - 1, budget)
@@ -1358,12 +1362,6 @@ class ServingEngine:
             "engine", self.metrics.loop_seconds, self.tracer,
             ENGINE_TRACK, on_phase=self._set_phase,
         )
-        # cache rows one decode substep reads: what the step program
-        # streams whatever the slots hold (metrics.kv_rows_streamed)
-        self._kv_rows_per_substep = decode_rows_streamed(
-            self.n_slots, self.pool.tpad
-        )
-
         # power-of-two prompt buckets: the largest must respect the
         # positional table (prefill embeds rows 0..bucket-1) and the
         # pooled slab row count (the insert window must fit Tpad)
@@ -4897,15 +4895,24 @@ class ServingEngine:
         # them: its prompt, the substeps dispatched before, and the row
         # substep j writes. A slot past its budget is frozen on the
         # device and holds nothing the kernel needs (an EOS the host
-        # has not read back yet is counted until it has).
-        live = 0
+        # has not read back yet is counted until it has). What the step
+        # program reads for them is decode_rows_streamed's to say.
+        held = [[] for _ in range(k)]
         for _, st in snaps:
-            j = min(k, st.req.max_new - st.n_substeps)
-            if j > 0:
-                held = len(st.req.prompt) + st.n_substeps
-                live += j * held + j * (j + 1) // 2
+            rows = len(st.req.prompt) + st.n_substeps
+            for j in range(min(k, st.req.max_new - st.n_substeps)):
+                held[j].append(rows + j + 1)
             st.n_substeps += k
-        self.metrics.record_kv_rows(live, k * self._kv_rows_per_substep)
+        self.metrics.record_kv_rows(
+            sum(map(sum, held)),
+            sum(
+                decode_rows_streamed(
+                    self.cfg, self.n_slots, self.pool.tpad, h,
+                    paged=self._paged,
+                )
+                for h in held
+            ),
+        )
         fam = "step" if fused is None else "piggyback_step"
         if surface:
             fam = "masked_" + fam

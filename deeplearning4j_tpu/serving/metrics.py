@@ -57,8 +57,11 @@ two ``sync`` phases the thread waits for the device), fed by the same
 regions that name the phases in a profiler capture;
 ``kv_rows_live`` / ``kv_rows_streamed`` count, per decode substep, the
 cache rows the occupied slots hold against the rows the step program
-reads (their ratio is the share of the decode kernel's stream any
-request needed); ``ttft_segment_seconds`` splits every request's time
+reads for them: with the decode kernel, each live slot's rows rounded
+up to the kernel's T block and nothing for a free or frozen slot;
+on the dense path and under the paged wrapper, every row of every slot
+(``models.transformer.decode_rows_streamed``). Their ratio is the share
+of the step's cache stream any request needed; ``ttft_segment_seconds`` splits every request's time
 to first token at the three points the engine can see
 (:data:`TTFT_SEGMENTS`). ``compile_log`` is the process's
 :class:`~deeplearning4j_tpu.obs.compile_log.CompileLog`, and

@@ -3,6 +3,7 @@ annotation + exact seconds + ring span from one helper), live cache rows
 against streamed rows, the time-to-first-token split, and the compile
 log with its warm latch."""
 
+import dataclasses
 import logging
 import re
 import threading
@@ -19,6 +20,7 @@ from deeplearning4j_tpu.models.transformer import (
     init_transformer,
 )
 from deeplearning4j_tpu.obs import ProfileTrigger, Tracer, compile_log
+from deeplearning4j_tpu.ops.pallas_kernels import decode_block_rows
 from deeplearning4j_tpu.obs.trace import ENGINE_TRACK, PhaseRegions
 from deeplearning4j_tpu.serving import Request, ServingEngine
 from deeplearning4j_tpu.serving.metrics import LOOP_PHASES, TTFT_SEGMENTS
@@ -213,29 +215,92 @@ def test_slot_key_readback_is_a_wait_of_its_own_inside_admit():
 # -- live rows against streamed rows ----------------------------------------
 
 
+def _rows_read(cfg, tpad, held):
+    """The hand count: a live substep reads its slot's rows rounded up
+    to the kernel's block."""
+    block = decode_block_rows(
+        tpad, cfg.kv_heads * cfg.head_dim,
+        jnp.dtype(cfg.compute_dtype).itemsize,
+    )
+    return -(-held // block) * block
+
+
 def test_kv_rows_equal_a_hand_count_over_three_horizons():
     """Four slots, K=2, three requests decoding 6 tokens each (three
     horizons): per substep a slot holds its prompt, what was decoded
-    before and the row the substep writes; the step program reads every
-    row of every slot."""
+    before and the row the substep writes; the decode kernel reads
+    those rows rounded up to its block, and nothing for the free fourth
+    slot."""
     engine = _engine(n_slots=4, decode_horizon=2)
     prompts = (5, 9, 12)
     _serve(engine, [_request(n, 6, seed=n) for n in prompts])
     m = engine.metrics
-    substeps = 2 * m.summary()["steps"]
     # all three are admitted at the first boundary and decode together:
     # 6 tokens at K=2 are 3 horizons of 2 substeps, and a fourth was in
     # flight when the third's readback showed them finished. Its slots
-    # are frozen on the device: it streams rows and nobody holds any.
+    # are frozen on the device: nobody holds a row and the kernel reads
+    # none.
     assert m.summary()["steps"] == 4
-    assert m.kv_rows_live == sum(
-        n + j + 1 for n in prompts for j in range(6)
+    held = [n + j + 1 for n in prompts for j in range(6)]
+    assert m.kv_rows_live == sum(held)
+    assert m.kv_rows_streamed == sum(
+        _rows_read(CFG, engine.pool.tpad, h) for h in held
     )
-    assert m.kv_rows_streamed == substeps * 4 * engine.pool.tpad
     assert m.summary()["kv_rows_live"] == m.kv_rows_live
     text = m.render_prometheus()
     assert f"serve_kv_rows_live_total {m.kv_rows_live}" in text
     assert f"serve_kv_rows_streamed_total {m.kv_rows_streamed}" in text
+
+
+def test_kv_rows_streamed_round_up_to_the_block_where_a_slab_has_several():
+    """A geometry whose slab the block rule cuts in several blocks (512
+    rows x 512 wide, float32): a request that crosses a block edge while
+    it decodes reads one block more after it than before, the short one
+    beside it reads one block throughout, and the free third slot reads
+    nothing."""
+    cfg = TransformerConfig(
+        vocab_size=64, d_model=512, n_heads=4, n_layers=1, d_ff=64,
+        max_len=512,
+    )
+    engine = ServingEngine(
+        cfg, init_transformer(jax.random.key(1), cfg), temperature=0.0,
+        batch_admission=False, n_slots=3, decode_horizon=2,
+    )
+    tpad = engine.pool.tpad
+    block = decode_block_rows(tpad, 512, 4)
+    assert 16 < block <= tpad // 2
+    # 4 of its 6 substeps end at or before row 2 * block, 2 after it
+    long_prompt = 2 * block - 4
+    rng = np.random.default_rng(7)
+    reqs = [
+        Request(prompt=rng.integers(1, 64, (n,)).astype(np.int32),
+                max_new=6, done=threading.Event())
+        for n in (long_prompt, 9)
+    ]
+    _serve(engine, reqs)
+    m = engine.metrics
+    held = [n + j + 1 for n in (long_prompt, 9) for j in range(6)]
+    assert m.kv_rows_live == sum(held)
+    assert m.kv_rows_streamed == (4 * 2 + 2 * 3 + 6 * 1) * block
+    assert m.kv_rows_streamed == sum(_rows_read(cfg, tpad, h) for h in held)
+
+
+def test_kv_rows_streamed_is_every_row_without_the_decode_kernel():
+    """The dense path contracts over the whole cache: every substep
+    reads every row of every slot, free and frozen ones too."""
+    engine = ServingEngine(
+        dataclasses.replace(CFG, decode_kernel=False), _params(),
+        temperature=0.0, batch_admission=False, n_slots=4,
+        decode_horizon=2,
+    )
+    prompts = (5, 9, 12)
+    _serve(engine, [_request(n, 6, seed=n) for n in prompts])
+    m = engine.metrics
+    assert m.kv_rows_live == sum(
+        n + j + 1 for n in prompts for j in range(6)
+    )
+    substeps = 2 * m.summary()["steps"]
+    assert m.kv_rows_streamed == substeps * 4 * engine.pool.tpad
 
 
 def test_kv_rows_stop_at_the_budget_inside_a_horizon():

@@ -4,6 +4,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from deeplearning4j_tpu.ops.attention import attention
 from deeplearning4j_tpu.ops.pallas_kernels import flash_attention, fused_embedding_dot
@@ -145,7 +146,9 @@ def _dense_decode_ref(q, kvcache, pos, n_kv_heads, layer):
     kr = kk.reshape(b, t, n_kv_heads, hd)
     vr = vv.reshape(b, t, n_kv_heads, hd)
     s = np.einsum("bghd,bthd->bght", qr, kr) / np.sqrt(hd)
-    s[..., pos + 1:] = -np.inf
+    # a scalar pos broadcasts; a (B,) vector masks each row at its own
+    cols = np.arange(t)[None, None, None, :]
+    s = np.where(cols > np.reshape(pos, (-1, 1, 1, 1)), -np.inf, s)
     p = np.exp(s - s.max(-1, keepdims=True))
     p /= p.sum(-1, keepdims=True)
     return np.einsum("bght,bthd->bghd", p, vr).reshape(b, g, hk)
@@ -179,6 +182,28 @@ def test_flash_decode_attention_matches_dense():
         np.testing.assert_allclose(np.asarray(out), ref, atol=2e-5)
 
 
+def _int8_cache(cache):
+    """Per-row int8 quantization of a float cache: (int8 rows, f32
+    scales (..., 1))."""
+    raw = np.asarray(cache)
+    amax = np.maximum(np.abs(raw).max(-1, keepdims=True), 1e-8)
+    scales = (amax / 127.0).astype(np.float32)
+    qcache = np.clip(np.round(raw / scales), -127, 127).astype(np.int8)
+    return jnp.asarray(qcache), jnp.asarray(scales)
+
+
+def _int8_decode_ref(q, qcache, scales, pos, n_kv_heads, layer):
+    """The dense oracle on the dequantized cache, with q quantized
+    exactly as the kernel does (one scale per group row)."""
+    qn = np.asarray(q)
+    qs = np.maximum(np.abs(qn).max(-1, keepdims=True), 1e-8) / 127.0
+    q_deq = (np.clip(np.round(qn / qs), -127, 127) * qs).astype(np.float32)
+    dequant = np.asarray(qcache, np.float32) * np.asarray(scales)
+    return _dense_decode_ref(
+        jnp.asarray(q_deq), jnp.asarray(dequant), pos, n_kv_heads, layer
+    )
+
+
 def test_flash_decode_attention_int8_cache_matches_dequant_oracle():
     """int8-cache mode (r5 serving path): the kernel runs BOTH cache
     dots natively int8 on the MXU — the query row is quantized
@@ -198,27 +223,152 @@ def test_flash_decode_attention_int8_cache_matches_dequant_oracle():
         hk = n_kv * 16
         n_layers = 2
         q = jnp.asarray(rng.normal(size=(b, g, hk)).astype(np.float32))
-        raw = rng.normal(size=(n_layers, 2, b, t, hk)).astype(np.float32)
-        amax = np.maximum(np.abs(raw).max(-1, keepdims=True), 1e-8)
-        scales = (amax / 127.0).astype(np.float32)
-        qcache = np.clip(np.round(raw / scales), -127, 127).astype(np.int8)
-        out = flash_decode_attention(
-            jnp.asarray(q), jnp.asarray(qcache), jnp.int32(pos), n_kv,
-            layer=layer, block_t=8, interpret=True,
-            kv_scales=jnp.asarray(scales),
+        qcache, scales = _int8_cache(
+            rng.normal(size=(n_layers, 2, b, t, hk)).astype(np.float32)
         )
-        # oracle: quantize q exactly as the kernel does (per-group row)
-        qmax = np.maximum(np.abs(q).max(-1, keepdims=True), 1e-8)
-        qs = qmax / 127.0
-        q_deq = np.clip(np.round(q / qs), -127, 127) * qs
-        dequant = qcache.astype(np.float32) * scales
-        ref = _dense_decode_ref(
-            jnp.asarray(q_deq.astype(np.float32)), jnp.asarray(dequant),
-            pos, n_kv, layer,
+        out = flash_decode_attention(
+            q, qcache, jnp.int32(pos), n_kv, layer=layer, block_t=8,
+            interpret=True, kv_scales=scales,
         )
         # residual = in-kernel softmax-weight quantization, which the
         # oracle does not model (bounded by pmax/254 per weight)
-        np.testing.assert_allclose(np.asarray(out), ref, atol=2.5e-2)
+        np.testing.assert_allclose(
+            np.asarray(out),
+            _int8_decode_ref(q, qcache, scales, pos, n_kv, layer),
+            atol=2.5e-2,
+        )
+
+
+# -- the bounded walk: a row reads the blocks up to its position, a row that
+# -- is not active reads nothing (PR 26)
+
+_WALK_T, _WALK_BLOCK = 32, 8
+# per-row positions that end in the first block, on both sides of a block
+# edge, and in the last block
+_WALK_POS = {
+    "first_block": [0, 3, 7],
+    "block_edge": [_WALK_BLOCK - 1, _WALK_BLOCK, 2 * _WALK_BLOCK],
+    "last_block": [_WALK_T - 1, _WALK_T - _WALK_BLOCK, 5],
+}
+
+
+def _walk_case(seed, b, g=2, n_kv=2, t=_WALK_T, n_layers=2):
+    rng = np.random.default_rng(seed)
+    hk = n_kv * 16
+    q = jnp.asarray(rng.normal(size=(b, g, hk)).astype(np.float32))
+    cache = jnp.asarray(
+        rng.normal(size=(n_layers, 2, b, t, hk)).astype(np.float32)
+    )
+    return q, cache, n_kv
+
+
+@pytest.mark.parametrize("where", sorted(_WALK_POS))
+def test_flash_decode_bounded_walk_matches_dense(where):
+    from deeplearning4j_tpu.ops.pallas_kernels import flash_decode_attention
+
+    pos = np.asarray(_WALK_POS[where], np.int32)
+    q, cache, n_kv = _walk_case(21, len(pos))
+    out = flash_decode_attention(
+        q, cache, jnp.asarray(pos), n_kv, layer=1, block_t=_WALK_BLOCK,
+        interpret=True,
+    )
+    ref = _dense_decode_ref(q, cache, pos, n_kv, 1)
+    np.testing.assert_allclose(np.asarray(out), ref, atol=2e-5)
+
+
+@pytest.mark.parametrize("where", sorted(_WALK_POS))
+def test_flash_decode_bounded_walk_int8_matches_dequant_oracle(where):
+    from deeplearning4j_tpu.ops.pallas_kernels import flash_decode_attention
+
+    pos = np.asarray(_WALK_POS[where], np.int32)
+    q, cache, n_kv = _walk_case(22, len(pos))
+    qcache, scales = _int8_cache(cache)
+    out = flash_decode_attention(
+        q, qcache, jnp.asarray(pos), n_kv, layer=1, block_t=_WALK_BLOCK,
+        interpret=True, kv_scales=scales,
+    )
+    ref = _int8_decode_ref(q, qcache, scales, pos, n_kv, 1)
+    np.testing.assert_allclose(np.asarray(out), ref, atol=2.5e-2)
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["float", "int8"])
+@pytest.mark.parametrize("mask", [
+    [True, False, True, True],    # a free slot between live ones
+    [False, False, True, True],   # the first rows free
+    [True, True, False, False],   # the last rows free
+])
+def test_flash_decode_inactive_row_is_zero_and_moves_no_other(mask, int8):
+    """A row that is not active returns zeros whatever position it was
+    frozen at, and the rows beside it are bit-equal to a run in which
+    every row is active."""
+    from deeplearning4j_tpu.ops.pallas_kernels import flash_decode_attention
+
+    pos = jnp.asarray([5, 17, _WALK_T - 1, _WALK_BLOCK], jnp.int32)
+    q, cache, n_kv = _walk_case(23, 4)
+    scales = None
+    if int8:
+        cache, scales = _int8_cache(cache)
+    kw = dict(layer=0, block_t=_WALK_BLOCK, interpret=True, kv_scales=scales)
+    full = np.asarray(flash_decode_attention(q, cache, pos, n_kv, **kw))
+    mask = np.asarray(mask)
+    out = np.asarray(flash_decode_attention(
+        q, cache, pos, n_kv, active=jnp.asarray(mask), **kw
+    ))
+    assert np.all(out[~mask] == 0.0)
+    np.testing.assert_array_equal(out[mask], full[mask])
+
+
+def test_flash_decode_scalar_pos_broadcasts_through_the_walk():
+    from deeplearning4j_tpu.ops.pallas_kernels import flash_decode_attention
+
+    q, cache, n_kv = _walk_case(24, 3)
+    kw = dict(layer=1, block_t=_WALK_BLOCK, interpret=True)
+    scalar = flash_decode_attention(q, cache, jnp.int32(13), n_kv, **kw)
+    vector = flash_decode_attention(
+        q, cache, jnp.full((3,), 13, jnp.int32), n_kv, **kw
+    )
+    np.testing.assert_array_equal(np.asarray(scalar), np.asarray(vector))
+    ref = _dense_decode_ref(q, cache, 13, n_kv, 1)
+    np.testing.assert_allclose(np.asarray(scalar), ref, atol=2e-5)
+
+
+def test_flash_decode_default_block_at_one_block_is_the_whole_slab():
+    """A slab no longer than one block of the rule is walked as one
+    block: bit-equal to ``block_t=T``, the kernel every toy geometry
+    (and every CPU test) ran before the walk was bounded."""
+    from deeplearning4j_tpu.ops.pallas_kernels import (
+        decode_block_rows,
+        flash_decode_attention,
+    )
+
+    q, cache, n_kv = _walk_case(25, 3)
+    assert decode_block_rows(_WALK_T, q.shape[-1], 4) == _WALK_T
+    pos = jnp.asarray([0, 9, _WALK_T - 1], jnp.int32)
+    default = flash_decode_attention(q, cache, pos, n_kv, interpret=True)
+    whole = flash_decode_attention(
+        q, cache, pos, n_kv, block_t=_WALK_T, interpret=True
+    )
+    np.testing.assert_array_equal(np.asarray(default), np.asarray(whole))
+
+
+@pytest.mark.parametrize("t,hk,itemsize", [
+    (1024, 1280, 2), (1024, 1280, 1), (1024, 256, 2), (8704, 256, 2),
+    (8704, 256, 1), (584, 256, 2), (4096, 4096, 2), (48, 32, 4),
+    (8 * 1031, 256, 2),
+])
+def test_decode_block_rows_divides_the_slab_and_fits_vmem(t, hk, itemsize):
+    from deeplearning4j_tpu.ops.pallas_kernels import decode_block_rows
+
+    r = decode_block_rows(t, hk, itemsize)
+    assert t % r == 0 and r % 8 == 0
+    # K and V planes under the scoped-VMEM budget: three buffers deep in
+    # the walk, two in the int8 grid (at 3 bytes an element)
+    planes_bytes = 4 * 3 if itemsize == 1 else 6 * itemsize
+    assert planes_bytes * r * hk <= 14 * 2**20
+    if t >= 1024 and t % 128 == 0:
+        # a long slab is walked in several blocks, none so small that
+        # it half-fills the MXU pass over its rows
+        assert 128 <= r <= t // 2
 
 
 def test_flash_attention_noncausal_unchanged():

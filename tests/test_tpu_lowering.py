@@ -95,6 +95,31 @@ def test_slab_decode_lowers(batch, int8):
         )
 
 
+@pytest.mark.parametrize("masked", [False, True], ids=["all_active", "mask"])
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+def test_bounded_decode_lowers_at_the_benchmark_geometry(int8, masked):
+    """The gpt2-large serve slab (48 slots x 1,024 rows x 1,280), where
+    the block rule walks several blocks a slot, with and without the
+    per-row active mask the step families pass."""
+    layers, batch, t, n_kv, hk = 2, 48, 1024, 20, 1280
+    assert pk.decode_block_rows(t, hk, 1 if int8 else 2) < t
+    avals = [
+        S((batch, 1, hk), jnp.bfloat16),
+        S((layers, 2, batch, t, hk), jnp.int8 if int8 else jnp.bfloat16),
+        S((batch,), jnp.int32),
+        S((batch,), jnp.bool_),
+    ]
+    if int8:
+        avals.append(S((layers, 2, batch, t, 1), jnp.float32))
+    lower_tpu(
+        lambda q, c, pos, act, sc=None: pk.flash_decode_attention(
+            q, c, pos, n_kv, layer=1, kv_scales=sc,
+            active=act if masked else None,
+        ),
+        *avals,
+    )
+
+
 @pytest.mark.parametrize("block_size", [8, 16, 128])
 @pytest.mark.parametrize("int8", [False, True])
 def test_paged_decode_lowers(block_size, int8):
