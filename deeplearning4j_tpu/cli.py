@@ -78,6 +78,20 @@ def _transformer_cfg_from_args(args):
     )
 
 
+def _transformer_cfg_from_file(path: str):
+    """A ``TransformerConfig`` from a JSON file of its fields, or from
+    the ``model`` group of a benchmark configuration file
+    (``benchmark/configs/<name>.json``): the model a cell runs, by the
+    same fields."""
+    import json
+
+    from deeplearning4j_tpu.models.transformer import TransformerConfig
+
+    with open(path) as f:
+        fields = json.load(f)
+    return TransformerConfig.from_json(json.dumps(fields.get("model", fields)))
+
+
 def _train_transformer(args) -> int:
     """Byte-level char-LM training for the flagship transformer: composed
     dp x tp mesh (``--tp``), optional MoE experts / FSDP, checkpointing via
@@ -479,7 +493,8 @@ def cmd_serve(args) -> int:
     if args.demo:
         from deeplearning4j_tpu.models.transformer import init_transformer
 
-        cfg = _transformer_cfg_from_args(args)
+        cfg = (_transformer_cfg_from_file(args.model_config)
+               if args.model_config else _transformer_cfg_from_args(args))
         params = init_transformer(jax.random.key(0), cfg)
         print(f"demo mode: random-init model ({cfg.d_model}d, "
               f"{cfg.n_layers}L, vocab {cfg.vocab_size})")
@@ -1351,6 +1366,12 @@ def main(argv: list[str] | None = None) -> int:
                    help="comma-separated zoo embedding models "
                    "(word2vec, glove) to serve at POST /v1/embeddings "
                    "over a small demo vocabulary")
+    v.add_argument("--model-config", default=None, metavar="FILE",
+                   help="with --demo: the model as a JSON file of "
+                   "TransformerConfig fields, or a benchmark configuration "
+                   "file whose 'model' group holds them (e.g. "
+                   "benchmark/configs/laguna-s-2.1.json), instead of the "
+                   "model flags below")
     # model flags for --demo / pre-config checkpoints
     v.add_argument("--seq-len", type=int, default=128)
     v.add_argument("--d-model", type=int, default=128)

@@ -37,6 +37,10 @@ from deeplearning4j_tpu.parallel.expert_parallel import MoEParams, moe_ffn
 from deeplearning4j_tpu.parallel.sequence_parallel import ring_attention
 
 
+#: a layer's published type -> the cache leaf its kind shares
+_KINDS = {"full_attention": "full", "sliding_attention": "window"}
+
+
 @dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 256
@@ -95,11 +99,73 @@ class TransformerConfig:
     # the 247MB/step weight stream and the ~345MB/step cache stream at
     # B=16 (PERF.md "0.60-MBU wall"). Training paths ignore this flag.
     decode_int8: bool = False
+    # -- the gated block with layers of two kinds (serving only) ---------
+    # ``layer_types`` set (one of "full_attention" / "sliding_attention"
+    # per layer) switches the whole stack to :func:`_gated_block`:
+    # RMSNorm (``norm_eps``), no biases, no learned positions, rotary
+    # (``rope`` must be on), a SwiGLU MLP of width ``d_ff`` in
+    # ``dense_layers`` and a routed expert layer everywhere else, KV
+    # caches grouped by layer kind. Parameters are one dict a layer
+    # (``params["layers"][l]``): shapes differ by layer.
+    layer_types: tuple | None = None
+    # explicit head size (None: d_model // n_heads)
+    head_size: int | None = None
+    # query heads per layer (None: n_heads everywhere); KV heads are
+    # n_kv_heads in every layer
+    layer_heads: tuple | None = None
+    # keys a sliding_attention layer sees, the query's own included
+    sliding_window: int | None = None
+    # per-head sigmoid gate on the attention output, from the normed
+    # layer input, applied before the output projection
+    attn_gate: bool = False
+    norm_eps: float = 1e-5
+    # rotary base of sliding_attention layers (whole head, plain)
+    rope_theta: float = 10000.0
+    # YaRN settings of full_attention layers: rope_theta, factor,
+    # original_max_position_embeddings, beta_fast, beta_slow,
+    # attention_factor, partial_rotary_factor (a dict; frozen to sorted
+    # pairs so the config stays hashable). None: as sliding layers
+    rope_full: Any = None
+    # layers whose MLP is dense (mlp_only_layers); the rest route
+    dense_layers: tuple = ()
+    # routed experts: ``n_experts`` above counts the experts HELD here,
+    # ids expert_first .. expert_first + n_experts - 1 of the
+    # n_experts_total the router scores; moe_k per token, weights
+    # renormalised over the top k and scaled by moe_scale
+    n_experts_total: int = 0
+    expert_first: int = 0
+    moe_scale: float = 1.0
+    d_expert: int = 0  # width of one routed expert
+    d_shared: int = 0  # width of the shared expert (0: none)
 
     @property
     def head_dim(self) -> int:
+        if self.head_size is not None:
+            return self.head_size
         assert self.d_model % self.n_heads == 0
         return self.d_model // self.n_heads
+
+    @property
+    def gated(self) -> bool:
+        """The stack of :func:`_gated_block` layers (``layer_types``
+        set): parameters and caches grouped by layer, not stacked."""
+        return self.layer_types is not None
+
+    def heads_of(self, layer: int) -> int:
+        return self.layer_heads[layer] if self.layer_heads else self.n_heads
+
+    def layers_of(self, kind: str) -> tuple:
+        """Indices of the layers of one kind ("full" / "window"), in
+        order: a layer's place in this tuple is its place in the
+        cache leaf of that kind."""
+        return tuple(
+            l for l, t in enumerate(self.layer_types or ())
+            if _KINDS[t] == kind
+        )
+
+    @property
+    def rope_full_settings(self) -> dict | None:
+        return None if self.rope_full is None else dict(self.rope_full)
 
     # JSON round-trip, matching the framework's config story (nn/conf.py
     # ≙ NeuralNetConfiguration.toJson): dtypes serialize by name
@@ -126,6 +192,22 @@ class TransformerConfig:
         return cls(**d)
 
     def __post_init__(self):
+        # lists and dicts arrive from JSON (``TransformerConfig(**model)``,
+        # ``from_json``); the dataclass is frozen and hashed
+        for name in ("layer_types", "layer_heads", "dense_layers"):
+            value = getattr(self, name)
+            if isinstance(value, list):
+                object.__setattr__(self, name, tuple(value))
+        if isinstance(self.rope_full, dict):
+            object.__setattr__(
+                self, "rope_full", tuple(sorted(self.rope_full.items()))
+            )
+        elif isinstance(self.rope_full, list):
+            object.__setattr__(
+                self, "rope_full", tuple((k, v) for k, v in self.rope_full)
+            )
+        if self.gated:
+            self._check_gated()
         if self.n_heads % self.kv_heads:
             raise ValueError(
                 f"n_kv_heads ({self.kv_heads}) must divide n_heads "
@@ -136,9 +218,49 @@ class TransformerConfig:
     def kv_heads(self) -> int:
         return self.n_kv_heads or self.n_heads
 
+    def _check_gated(self):
+        if len(self.layer_types) != self.n_layers or not (
+            set(self.layer_types) <= set(_KINDS)
+        ):
+            raise ValueError(
+                f"layer_types must name {self.n_layers} layers as one of "
+                f"{sorted(_KINDS)}, got {self.layer_types}"
+            )
+        if self.layer_heads and len(self.layer_heads) != self.n_layers:
+            raise ValueError("layer_heads must give one count a layer")
+        for l in range(self.n_layers):
+            if self.heads_of(l) % self.kv_heads:
+                raise ValueError(
+                    f"n_kv_heads ({self.kv_heads}) must divide layer {l}'s "
+                    f"{self.heads_of(l)} query heads"
+                )
+        if "sliding_attention" in self.layer_types and (
+            not self.sliding_window or self.sliding_window % 8
+        ):
+            raise ValueError(
+                "sliding_attention layers need a sliding_window that is a "
+                "multiple of 8 (the ring leaf's rows)"
+            )
+        if not self.rope:
+            raise ValueError(
+                "the gated block has no learned positions: set rope=True"
+            )
+        routed = [l for l in range(self.n_layers) if l not in self.dense_layers]
+        if routed and not (
+            0 < self.n_experts <= self.n_experts_total - self.expert_first
+            and 0 < self.moe_k <= self.n_experts_total and self.d_expert
+        ):
+            raise ValueError(
+                "routed layers need n_experts (held) within n_experts_total "
+                "from expert_first, moe_k and d_expert"
+            )
+
 
 def init_transformer(key, cfg: TransformerConfig):
-    """Params pytree; block tensors carry a leading (n_layers, ...) axis."""
+    """Params pytree; block tensors carry a leading (n_layers, ...) axis
+    (a gated stack: one dict a layer, :func:`_init_gated`)."""
+    if cfg.gated:
+        return _init_gated(key, cfg)
     ks = jax.random.split(key, 8)  # ks[7] only consumed by the MoE branch
     d, h, k, f, nl = (
         cfg.d_model, cfg.n_heads, cfg.head_dim, cfg.d_ff, cfg.n_layers,
@@ -230,7 +352,10 @@ def quantize_decode_params(params, cfg: TransformerConfig):
     """
     if cfg.n_experts:
         raise NotImplementedError(
-            "int8 decode quantization does not cover MoE experts yet"
+            "int8 decode quantization does not cover MoE experts yet: "
+            "neither MoEParams (moe_ffn) nor the held experts' "
+            "we_gate / we_up / we_down of a gated layer (moe_held_ffn) "
+            "have scale leaves or a dequantising grouped product"
         )
     blocks = dict(params["blocks"])
     for name, axes in _INT8_BLOCK_AXES.items():
@@ -514,6 +639,14 @@ def _apply_rope(x, cos, sin):
 _DECODE_PAD_T = 8
 
 
+def _decode_tpad(total: int) -> int:
+    """Rows of a decode slab that holds ``total`` positions (the rule
+    ``init_caches`` explains)."""
+    if total <= 1024:
+        return -(-total // _DECODE_PAD_T) * _DECODE_PAD_T
+    return -(-total // 512) * 512
+
+
 def _flash_seq_ok(t: int) -> bool:
     """Sequence lengths the training flash kernel accepts: sublane-
     aligned (%8 — Mosaic rejects e.g. a 100-row block shape on real
@@ -737,6 +870,12 @@ def transformer_apply(
     (:mod:`deeplearning4j_tpu.ops.fused_ce`) so no f32 (B, T, V) copy is
     ever materialized.
     """
+    _gated_refuses(
+        cfg, "training (transformer_apply / transformer_train_step)",
+        "_gated_block has no backward-ready attention for a window, "
+        "moe_held_ffn no auxiliary load-balancing loss, and "
+        "transformer_shardings no layout for per-layer parameter dicts",
+    )
     if (cfg.n_experts or cfg.sequence_parallel) and mesh is None:
         raise ValueError("MoE / sequence-parallel modes need a mesh")
     if cfg.use_flash and cfg.sequence_parallel:
@@ -950,6 +1089,562 @@ def transformer_loss(cfg: TransformerConfig, mesh: Mesh | None = None):
     return loss
 
 
+# -- the gated block: layers of two kinds, experts held as a share ----------
+#
+# One stack (``cfg.layer_types`` set) whose layers differ in query heads,
+# rotary scheme, attention span and MLP: RMSNorm, GQA at an explicit head
+# size, a per-head sigmoid gate on the attention output, a SwiGLU MLP in
+# the dense layers and routed experts plus a shared expert elsewhere
+# (``parallel/expert_parallel.py: moe_held_ffn``). The arithmetic of a
+# layer is ONE function, :func:`_gated_block`; ``forward_one``, ``prefill``
+# and ``forward_chunk`` hand it their own way to attend (one row against
+# the cache, the whole sequence, a chunk against the cache). Serving only.
+#
+# The cache is two leaves, one a layer kind, each (layers of that kind, 2,
+# B, rows, Hkv*K): "full" at the decode length, "window" a ring of
+# ``sliding_window`` rows in which position t lives at t % window. Keys are
+# cached rotated, so a ring's order does not matter to the softmax.
+
+def full_cache_leaf(caches):
+    """The leaf of a cache pytree (arrays or shapes) whose rows run to
+    the decode length: the array itself, ``kv`` of an int8 cache,
+    ``full`` of a cache grouped by layer kind."""
+    if isinstance(caches, dict):
+        return caches["kv"] if "kv" in caches else caches["full"]
+    return caches
+
+
+def _rms_norm(x, scale, eps):
+    x32 = x.astype(jnp.float32)
+    out = x32 * jax.lax.rsqrt(
+        jnp.mean(x32 * x32, axis=-1, keepdims=True) + eps
+    ) * scale.astype(jnp.float32)
+    return out.astype(x.dtype)
+
+
+def yarn_inv_freq(rot_dim: int, theta: float, factor: float,
+                  original_max: int, beta_fast: float, beta_slow: float):
+    """YaRN inverse frequencies of ``rot_dim // 2`` channel pairs, as
+    ``transformers``' ``_compute_yarn_parameters`` gives them: pair i
+    keeps its own frequency ``theta^(-2i/rot_dim)`` below the pair whose
+    wavelength makes ``beta_fast`` turns in ``original_max`` positions,
+    is divided by ``factor`` above the pair that makes ``beta_slow``, and
+    blends linearly between. float64 on the host: a constant table."""
+    half = rot_dim // 2
+    i = np.arange(half, dtype=np.float64)
+    freq = theta ** (-i / half)
+
+    def pair_of(turns):
+        return (rot_dim * np.log(original_max / (2 * np.pi * turns))
+                / (2 * np.log(theta)))
+
+    low = max(np.floor(pair_of(beta_fast)), 0)
+    high = min(np.ceil(pair_of(beta_slow)), rot_dim - 1)
+    keep = 1.0 - np.clip((i - low) / max(high - low, 1e-3), 0.0, 1.0)
+    return (1.0 - keep) * freq / factor + keep * freq
+
+
+def _gated_rope(cfg: TransformerConfig, kind: str, positions, dtype):
+    """(cos, sin) of one layer kind at ``positions`` (...,): (..., rot/2)
+    tables in ``dtype``; rot = 2 x the last axis is how many leading
+    channels of a head rotate. Window layers: plain, whole head. Full
+    layers: ``cfg.rope_full`` (YaRN over part of the head, tables
+    scaled by its attention_factor), or as window layers without it."""
+    full = cfg.rope_full_settings if kind == "full" else None
+    if full is None:
+        half = cfg.head_dim // 2
+        inv = cfg.rope_theta ** (-np.arange(half, dtype=np.float64) / half)
+        mscale = 1.0
+    else:
+        rot = int(cfg.head_dim * full.get("partial_rotary_factor", 1.0))
+        inv = yarn_inv_freq(
+            rot, full["rope_theta"], full["factor"],
+            full["original_max_position_embeddings"],
+            full["beta_fast"], full["beta_slow"],
+        )
+        mscale = full.get("attention_factor")
+        if mscale is None:
+            mscale = 0.1 * np.log(full["factor"]) + 1.0
+    ang = (jnp.asarray(positions)[..., None].astype(jnp.float32)
+           * jnp.asarray(inv, jnp.float32))
+    return ((jnp.cos(ang) * mscale).astype(dtype),
+            (jnp.sin(ang) * mscale).astype(dtype))
+
+
+def _rotate(x, cos, sin):
+    """Rotate-half over the first ``2 * cos.shape[-1]`` channels of
+    ``x`` (..., K); the rest pass."""
+    rot = 2 * cos.shape[-1]
+    if rot == x.shape[-1]:
+        return _apply_rope(x, cos, sin)
+    return jnp.concatenate(
+        [_apply_rope(x[..., :rot], cos, sin), x[..., rot:]], axis=-1
+    )
+
+
+# scores of one dense attention above this many elements go one KV head
+# at a time (a 1,024-row chunk against 4,096 rows is 200 M scores)
+_DENSE_SCORES_AT_ONCE = 32 * 1024 * 1024
+
+
+def _attend_dense(q, k, v, mask):
+    """Masked attention without a kernel. ``q`` (B, C, H, K); ``k``,
+    ``v`` (B, T, Hkv, K), query head j reading KV head j // (H / Hkv);
+    ``mask`` (B or 1, C, T) bool, True where the key is visible (every
+    query sees at least one). Softmax in float32. Returns (B, C, H, K)."""
+    b, c, h, kd = q.shape
+    t, hkv = k.shape[1], k.shape[2]
+    qg = q.reshape(b, c, hkv, h // hkv, kd)
+    scale = 1.0 / float(np.sqrt(kd))
+
+    def one_kv_head(qkv):
+        qh, kh, vh = qkv  # (B, C, G, K), (B, T, K), (B, T, K)
+        att = jnp.einsum(
+            "bcgk,btk->bgct", qh, kh, preferred_element_type=jnp.float32
+        ) * scale
+        att = jnp.where(mask[:, None], att, -jnp.inf)
+        w = jax.nn.softmax(att, axis=-1).astype(vh.dtype)
+        return jnp.einsum("bgct,btk->bcgk", w, vh)
+
+    heads_first = (
+        qg.transpose(2, 0, 1, 3, 4), k.transpose(2, 0, 1, 3),
+        v.transpose(2, 0, 1, 3),
+    )
+    if b * h * c * t > _DENSE_SCORES_AT_ONCE:
+        o = lax.map(one_kv_head, heads_first)
+    else:
+        o = jax.vmap(one_kv_head)(heads_first)
+    return o.transpose(1, 2, 0, 3, 4).reshape(b, c, h, kd)
+
+
+def _attend_window(q, k, v, w: int):
+    """Causal attention over the last ``w`` keys (the query's own
+    included) of a whole sequence from position 0: ``q`` (B, T, H, K),
+    ``k``, ``v`` (B, T, Hkv, K). With T a multiple of ``w`` past it, the
+    queries go in blocks of ``w`` rows against their own block and the
+    one before: T x 2w scores instead of T x T."""
+    b, t, h, kd = q.shape
+    own = jnp.arange(t)
+    if t <= w or t % w:
+        mask = (own[None, :] <= own[:, None]) & (own[:, None] - own[None, :] < w)
+        return _attend_dense(q, k, v, mask[None])
+    nb = t // w
+
+    def blocks(x):  # (B, T, ...) -> (B * nb, w, ...)
+        return x.reshape((b * nb, w) + x.shape[2:])
+
+    def with_previous(x):  # (B, T, ...) -> (B * nb, 2w, ...)
+        before = jnp.concatenate([jnp.zeros_like(x[:, :w]), x[:, :-w]], axis=1)
+        return jnp.concatenate([blocks(before), blocks(x)], axis=1)
+
+    i, j = jnp.arange(w)[:, None], jnp.arange(2 * w)[None, :]
+    band = (j > i) & (j <= i + w)  # key j - w of the block, query i
+    first = band & (j >= w)  # block 0 has nothing before it
+    mask = jnp.tile(
+        jnp.where((jnp.arange(nb) == 0)[:, None, None], first, band), (b, 1, 1)
+    )
+    o = _attend_dense(blocks(q), with_previous(k), with_previous(v), mask)
+    return o.reshape(b, t, h, kd)
+
+
+def _ring_write(leaf, idx: int, k, v, positions, last):
+    """Write the rows of ``k``, ``v`` (B, C, Hkv*K) that a ring of
+    ``leaf.shape[3]`` rows must hold once position ``last`` is its
+    newest: those at ``positions`` (B or 1, C) within (last - rows,
+    last]. ``last`` (B or 1, 1). Rows past ``last`` (a bucket's
+    padding) and rows the window has left are not written: they would
+    land on rows decode still needs."""
+    rows = leaf.shape[3]
+    b = k.shape[0]
+    keep = (positions <= last) & (positions > last - rows)
+    slot = jnp.broadcast_to(
+        jnp.where(keep, positions % rows, rows), (b, k.shape[1])
+    )  # rows: out of bounds, dropped
+    bidx = jnp.arange(b)[:, None]
+    for plane, x in enumerate((k, v)):
+        leaf = leaf.at[idx, plane, bidx, slot].set(
+            x.astype(leaf.dtype), mode="drop"
+        )
+    return leaf
+
+
+def _gated_block(cfg: TransformerConfig, l: int, p, x, positions, attend,
+                 live=None):
+    """Layer ``l`` over ``x`` (B, T, D) at ``positions`` ((T,) shared or
+    (B, T) per row): RMSNorm, projections, the layer kind's rotary,
+    ``attend(q, k, v)`` (q (B, T, H_l, K), k / v (B, T, Hkv, K) rotated
+    -> (B, T, H_l, K); the caller's way to reach keys, and to cache
+    them), the per-head gate, the output projection, RMSNorm, then the
+    dense SwiGLU or the held experts' part plus the shared expert.
+    ``live`` (B,) bool: rows somebody reads (others route nowhere).
+    Returns (x, counts): ``moe_held_ffn``'s int32 (3,), zeros in a dense
+    layer."""
+    from deeplearning4j_tpu.parallel.expert_parallel import (
+        moe_held_ffn,
+        swiglu,
+    )
+
+    dt = x.dtype
+    kind = _KINDS[cfg.layer_types[l]]
+    h = _rms_norm(x, p["ln1_scale"], cfg.norm_eps)
+    q = jnp.einsum("btd,dhk->bthk", h, _w(p, "wq", dt))
+    kv = jnp.einsum("btd,dshk->sbthk", h, _w(p, "wkv", dt))
+    cos, sin = _gated_rope(cfg, kind, positions, dt)
+    cos, sin = cos[..., None, :], sin[..., None, :]  # over the head axis
+    q = _rotate(q, cos, sin)
+    k = _rotate(kv[0], cos, sin)
+    o = attend(q, k, kv[1])
+    if cfg.attn_gate:
+        gate = jax.nn.sigmoid(
+            jnp.einsum("btd,dh->bth", h, _w(p, "wg", dt)).astype(jnp.float32)
+        )
+        o = o * gate[..., None].astype(dt)
+    x = x + jnp.einsum("bthk,hkd->btd", o, _w(p, "wo", dt))
+    h2 = _rms_norm(x, p["ln2_scale"], cfg.norm_eps)
+    if l in cfg.dense_layers:
+        y = swiglu(h2, _w(p, "w_gate", dt), _w(p, "w_up", dt),
+                   _w(p, "w_down", dt))
+        return x + y, jnp.zeros((3,), jnp.int32)
+    b, t, d = h2.shape
+    y, counts = moe_held_ffn(
+        h2.reshape(b * t, d), p["router"], _w(p, "we_gate", dt),
+        _w(p, "we_up", dt), _w(p, "we_down", dt), first=cfg.expert_first,
+        k=cfg.moe_k, scale=cfg.moe_scale,
+        live=None if live is None else jnp.repeat(live, t),
+    )
+    y = y.reshape(b, t, d)
+    if cfg.d_shared:
+        y = y + swiglu(h2, _w(p, "ws_gate", dt), _w(p, "ws_up", dt),
+                       _w(p, "ws_down", dt))
+    return x + y, counts
+
+
+def _init_gated(key, cfg: TransformerConfig):
+    """Params of a gated stack: ``layers`` is one dict a layer."""
+    d, kd, hkv = cfg.d_model, cfg.head_dim, cfg.kv_heads
+    k_embed, k_head, k_layers = jax.random.split(key, 3)
+
+    def norm(key, shape, fan_in):
+        return jax.random.normal(key, shape, jnp.float32) / np.sqrt(fan_in)
+
+    def mlp(keys, prefix, lead, width):
+        return {
+            prefix + "_gate": norm(keys[0], (*lead, d, width), d),
+            prefix + "_up": norm(keys[1], (*lead, d, width), d),
+            prefix + "_down": norm(keys[2], (*lead, width, d), width),
+        }
+
+    layers = []
+    for l, kl in enumerate(jax.random.split(k_layers, cfg.n_layers)):
+        ks = jax.random.split(kl, 12)
+        h = cfg.heads_of(l)
+        p = {
+            "ln1_scale": jnp.ones((d,)), "ln2_scale": jnp.ones((d,)),
+            "wq": norm(ks[0], (d, h, kd), d),
+            "wkv": norm(ks[1], (d, 2, hkv, kd), d),
+            "wo": norm(ks[2], (h, kd, d), h * kd),
+        }
+        if cfg.attn_gate:
+            p["wg"] = norm(ks[3], (d, h), d)
+        if l in cfg.dense_layers:
+            p.update(mlp(ks[4:7], "w", (), cfg.d_ff))
+        else:
+            p["router"] = norm(ks[4], (d, cfg.n_experts_total), d)
+            p.update(mlp(ks[5:8], "we", (cfg.n_experts,), cfg.d_expert))
+            if cfg.d_shared:
+                p.update(mlp(ks[8:11], "ws", (), cfg.d_shared))
+        layers.append(p)
+    return {
+        "embed": jax.random.normal(
+            k_embed, (cfg.vocab_size, d), jnp.float32) * 0.02,
+        "layers": layers,
+        "lnf_scale": jnp.ones((d,)),
+        "head": norm(k_head, (d, cfg.vocab_size), d),
+    }
+
+
+def _gated_refuses(cfg: TransformerConfig, what: str, lacks: str):
+    if cfg.gated:
+        raise NotImplementedError(
+            f"{what} is not built for a stack of gated layers "
+            f"(layer_types set): {lacks}"
+        )
+
+
+def _gated_builder(cfg: TransformerConfig):
+    """``(forward_one, init_caches, prefill, cast_params, forward_chunk)``
+    of a gated stack: :func:`_decode_builder`'s and
+    :func:`_chunk_builder`'s contracts over a cache of two leaves."""
+    if cfg.decode_int8:
+        _gated_refuses(
+            cfg, "decode_int8",
+            "its ring leaf has no int8 rows or scale planes, and "
+            "quantize_decode_params does not cover its experts",
+        )
+    d, kd, hkv = cfg.d_model, cfg.head_dim, cfg.kv_heads
+    hk = hkv * kd
+    place = {  # layer -> (kind, index in that kind's leaf)
+        l: (kind, i) for kind in ("full", "window")
+        for i, l in enumerate(cfg.layers_of(kind))
+    }
+
+    def init_caches(batch: int, total: int):
+        tpad = _decode_tpad(total)
+        out = {"full": jnp.zeros(
+            (len(cfg.layers_of("full")), 2, batch, tpad, hk),
+            cfg.compute_dtype)}
+        if cfg.layers_of("window"):
+            out["window"] = jnp.zeros(
+                (len(cfg.layers_of("window")), 2, batch,
+                 cfg.sliding_window, hk), cfg.compute_dtype)
+        return out
+
+    def cast_params(params):
+        """Streamed weights to the compute dtype, once; the router
+        stays float32 (its product is float32, see ``route_top_k``)."""
+        def cast(path, a):
+            name = getattr(path[-1], "key", None)
+            if name == "router" or not jnp.issubdtype(a.dtype, jnp.floating):
+                return a
+            return a.astype(cfg.compute_dtype)
+        return jax.tree_util.tree_map_with_path(cast, params)
+
+    def run_layers(params, x, positions, attend_of, live=None):
+        counts = jnp.zeros((3,), jnp.int32)
+        for l in range(cfg.n_layers):
+            x, c = _gated_block(
+                cfg, l, params["layers"][l], x, positions, attend_of(l),
+                live=live,
+            )
+            counts = counts + c
+        return x, counts
+
+    def logits_of(params, x_last):
+        x_last = _rms_norm(x_last, params["lnf_scale"], cfg.norm_eps)
+        return jnp.einsum(
+            "...d,dv->...v", x_last, _w(params, "head", x_last.dtype),
+            preferred_element_type=jnp.float32,
+        )  # bf16 operands, f32 accumulation: see _decode_builder
+
+    def row_at(x, last_idx):  # (B, T, D) -> (B, D): the named row
+        if last_idx is None:
+            return x[:, -1]
+        if jnp.ndim(last_idx) == 1:
+            return jnp.take_along_axis(
+                x, last_idx[:, None, None], axis=1)[:, 0]
+        return lax.dynamic_index_in_dim(x, last_idx, axis=1, keepdims=False)
+
+    def packed(x):  # (B, C, Hkv, K) -> (B, C, Hkv*K)
+        return x.reshape(x.shape[0], x.shape[1], hk)
+
+    def rows_of(leaf, idx, plane):  # -> (B, rows, Hkv, K)
+        x = leaf[idx, plane]
+        return x.reshape(x.shape[0], x.shape[1], hkv, kd)
+
+    def chunk_attend(caches, l, positions, last):
+        """``attend`` of layer ``l`` for C rows at ``positions`` (B or 1,
+        C) against the cache, rows up to ``last`` (B or 1, 1) real. A
+        full layer writes its rows and reads the slab; a window layer
+        reads the ring as it stood plus its own rows, then writes."""
+        kind, idx = place[l]
+
+        def attend(q, k, v):
+            leaf = caches[kind]
+            b, c = q.shape[:2]
+            own = jnp.arange(c)
+            if kind == "full":
+                pos_b = jnp.broadcast_to(positions, (b, c))
+                bidx = jnp.arange(b)[:, None]
+                for plane, x in enumerate((k, v)):
+                    leaf = leaf.at[idx, plane, bidx, pos_b].set(
+                        packed(x).astype(leaf.dtype), mode="drop"
+                    )
+                caches[kind] = leaf
+                mask = jnp.arange(leaf.shape[3]) <= positions[..., None]
+                return _attend_dense(
+                    q, rows_of(leaf, idx, 0), rows_of(leaf, idx, 1), mask
+                )
+            ring = leaf.shape[3]
+            w = cfg.sliding_window
+            # ring row s holds the newest position before this chunk
+            # that is congruent to s, if there is one
+            before = positions[..., :1] - 1  # (B or 1, 1)
+            held = before - (before - jnp.arange(ring)) % ring
+            seen = (held >= 0)[..., None, :] & (
+                positions[..., None] - held[..., None, :] < w
+            )
+            mine = (own[None, :] <= own[:, None]) & (
+                own[:, None] - own[None, :] < w
+            )
+            mask = jnp.concatenate(
+                [seen, jnp.broadcast_to(mine, seen.shape[:-1] + (c,))],
+                axis=-1,
+            )
+            o = _attend_dense(
+                q,
+                jnp.concatenate([rows_of(leaf, idx, 0), k], axis=1),
+                jnp.concatenate([rows_of(leaf, idx, 1), v], axis=1),
+                mask,
+            )
+            caches[kind] = _ring_write(
+                leaf, idx, packed(k), packed(v), positions, last
+            )
+            return o
+
+        return attend
+
+    def kernel_attend(caches, l, pos, active):
+        """``attend`` of layer ``l`` for one row a slot through the
+        decode kernel: write the row (a ring's at ``pos % rows``), then
+        walk the leaf. The kernel caps ``pos`` at the leaf's last row, so
+        it reads ``min(pos + 1, rows)`` rows of a ring, rounded up to
+        its block, and nothing for a row that is not active."""
+        from deeplearning4j_tpu.ops.pallas_kernels import (
+            flash_decode_attention,
+        )
+
+        kind, idx = place[l]
+
+        def attend(q, k, v):
+            leaf = caches[kind]
+            b, _, h, _ = q.shape
+            at = pos if kind == "full" else pos % leaf.shape[3]
+            if jnp.ndim(pos) == 0:
+                leaf = lax.dynamic_update_slice(
+                    leaf,
+                    jnp.stack([packed(k), packed(v)])[None].astype(leaf.dtype),
+                    (idx, 0, 0, at, 0),
+                )
+            else:
+                bidx = jnp.arange(b)
+                for plane, x in enumerate((k, v)):
+                    leaf = leaf.at[idx, plane, bidx, at].set(
+                        packed(x)[:, 0].astype(leaf.dtype)
+                    )
+            caches[kind] = leaf
+            grp = h // hkv
+            # query head j = kv * G + g: (B, G, Hkv*K), packed head-major
+            qp = q[:, 0].reshape(b, hkv, grp, kd).transpose(
+                0, 2, 1, 3).reshape(b, grp, hk)
+            o = flash_decode_attention(
+                qp, leaf, pos, n_kv_heads=hkv, layer=idx, active=active,
+            )
+            return o.reshape(b, grp, hkv, kd).transpose(
+                0, 2, 1, 3).reshape(b, 1, h, kd)
+
+        return attend
+
+    def forward_one(params, caches, token, pos, adapter=None, active=None,
+                    stats=None):
+        """:func:`_decode_builder`'s ``forward_one`` contract. ``stats``:
+        a list that receives this call's MoE counters (int32 (3,):
+        pairs computed here, pairs routed, held experts hit), summed
+        over the expert layers, for a step program to carry out."""
+        del adapter  # no LoRA bank: the engine refuses one
+        caches = dict(caches)
+        x = params["embed"][token].astype(cfg.compute_dtype)[:, None, :]
+        positions = jnp.reshape(pos, (-1, 1))  # (B or 1, 1)
+        if cfg.decode_kernel:
+            def attend_of(l):
+                return kernel_attend(caches, l, pos, active)
+        else:
+            def attend_of(l):
+                return chunk_attend(caches, l, positions, positions)
+        x, counts = run_layers(params, x, positions, attend_of, live=active)
+        if stats is not None:
+            stats.append(counts)
+        return logits_of(params, x[:, 0]), caches
+
+    # what serving/engine.py: tallied() asks before it passes ``stats``
+    forward_one.counts_moe = bool(
+        set(range(cfg.n_layers)) - set(cfg.dense_layers)
+    )
+
+    def prefill(params, caches, prompt, last_idx=None, adapter=None):
+        """:func:`_decode_builder`'s ``prefill`` contract: one causal
+        pass over the bucket from position 0. Full layers cache every
+        row; a ring keeps the last ``rows`` real ones (``last_idx``
+        says where the padding starts)."""
+        del adapter
+        b, tp = prompt.shape
+        if tp == 0:
+            return caches, jnp.zeros((b, cfg.vocab_size), jnp.float32)
+        caches = dict(caches)
+        positions = jnp.arange(tp)
+        last = jnp.reshape(
+            tp - 1 if last_idx is None else last_idx, (-1, 1)
+        )
+        w = cfg.sliding_window
+        flash = cfg.use_flash and _flash_seq_ok(tp)
+
+        def attend_of(l):
+            kind, idx = place[l]
+
+            def attend(q, k, v):
+                leaf = caches[kind]
+                if kind == "full":
+                    caches[kind] = lax.dynamic_update_slice(
+                        leaf,
+                        jnp.stack([packed(k), packed(v)])[None].astype(
+                            leaf.dtype),
+                        (idx, 0, 0, 0, 0),
+                    )
+                else:
+                    caches[kind] = _ring_write(
+                        leaf, idx, packed(k), packed(v), positions[None],
+                        last,
+                    )
+                if kind == "window" and tp > w:
+                    return _attend_window(q, k, v, w)
+                if not flash:
+                    mask = positions[None, :] <= positions[:, None]
+                    return _attend_dense(q, k, v, mask[None])
+                from deeplearning4j_tpu.ops.pallas_kernels import (
+                    flash_attention_trainable,
+                )
+
+                grp = q.shape[2] // hkv
+                bq, bk = _flash_blocks(tp)
+                o = flash_attention_trainable(
+                    q.transpose(0, 2, 1, 3),
+                    jnp.repeat(k.transpose(0, 2, 1, 3), grp, axis=1),
+                    jnp.repeat(v.transpose(0, 2, 1, 3), grp, axis=1),
+                    causal=True, block_q=bq, block_k=bk, layout="bhtd",
+                )
+                return o.transpose(0, 2, 1, 3)
+
+            return attend
+
+        x = params["embed"][prompt].astype(cfg.compute_dtype)
+        x, _ = run_layers(params, x, positions, attend_of)
+        return caches, logits_of(params, row_at(x, last_idx))
+
+    def forward_chunk(params, caches, toks, pos0, last_idx=None,
+                      adapter=None):
+        """:func:`_chunk_builder`'s contract: C consecutive positions
+        from ``pos0`` (scalar or (B,)) against the cache. ``last_idx``:
+        the last real row of the chunk (the rest is a bucket's padding
+        and must not reach a ring); with it the logits are that row's,
+        (B, V), else every row's, (B, C, V)."""
+        del adapter
+        b, c = toks.shape
+        caches = dict(caches)
+        positions = jnp.reshape(pos0, (-1, 1)) + jnp.arange(c)  # (B|1, C)
+        last = positions[..., :1] + jnp.reshape(
+            c - 1 if last_idx is None else last_idx, (-1, 1)
+        )
+        x = params["embed"][toks].astype(cfg.compute_dtype)
+        x, _ = run_layers(
+            params, x, positions,
+            lambda l: chunk_attend(caches, l, positions, last),
+        )
+        if last_idx is None:
+            return logits_of(params, x), caches
+        return logits_of(params, row_at(x, last_idx)), caches
+
+    return forward_one, init_caches, prefill, cast_params, forward_chunk
+
+
 def _decode_builder(cfg: TransformerConfig, tp_mesh=None):
     """Shared KV-cache decode machinery: returns
     ``(forward_one, init_caches, prefill)`` used by sampling and beam
@@ -963,6 +1658,14 @@ def _decode_builder(cfg: TransformerConfig, tp_mesh=None):
     bitwise identical to the unsharded program. Requires the dense
     decode path (``decode_kernel=False``) — the Pallas decode kernel is
     a custom call GSPMD cannot partition."""
+    if tp_mesh is not None:
+        _gated_refuses(
+            cfg, "tensor-parallel serving (tp > 1)",
+            "serving_tp_shardings has no layout for per-layer head "
+            "counts, held experts or a ring leaf",
+        )
+    if cfg.gated:
+        return _gated_builder(cfg)[:4]
     if tp_mesh is not None and cfg.decode_kernel:
         raise ValueError(
             "tensor-parallel decode requires decode_kernel=False "
@@ -1216,10 +1919,7 @@ def _decode_builder(cfg: TransformerConfig, tp_mesh=None):
         # Tpad like 8*prime would otherwise degenerate the kernel's
         # block search to 8-row blocks: ~100x the per-cell fixed cost).
         # Packed (Tpad, Hkv*K) minor layout: see block_decode.
-        if total <= 1024:
-            tpad = -(-total // _DECODE_PAD_T) * _DECODE_PAD_T
-        else:
-            tpad = -(-total // 512) * 512
+        tpad = _decode_tpad(total)
         if cfg.decode_int8:
             # int8 rows + per-row f32 scales (trailing singleton keeps
             # the scale blocks Mosaic-legal: last dim 1 = full dim)
@@ -1497,13 +2197,37 @@ def decode_rows_streamed(cfg: TransformerConfig, batch: int, tpad: int,
     step: both read every row of every slot whatever it holds. The
     engine books this as ``kv_rows_streamed`` beside the rows the
     requests needed."""
-    if paged or not cfg.decode_kernel:
-        return batch * tpad
-    from deeplearning4j_tpu.ops.pallas_kernels import decode_block_rows
+    def leaf(rows: int) -> int:
+        if paged or not cfg.decode_kernel:
+            return batch * rows
+        from deeplearning4j_tpu.ops.pallas_kernels import decode_block_rows
 
-    itemsize = 1 if cfg.decode_int8 else jnp.dtype(cfg.compute_dtype).itemsize
-    block = decode_block_rows(tpad, cfg.kv_heads * cfg.head_dim, itemsize)
-    return sum(min(-(-h // block) * block, tpad) for h in held)
+        itemsize = (1 if cfg.decode_int8
+                    else jnp.dtype(cfg.compute_dtype).itemsize)
+        block = decode_block_rows(rows, cfg.kv_heads * cfg.head_dim, itemsize)
+        return sum(min(-(-h // block) * block, rows) for h in held)
+
+    if not cfg.gated:
+        return leaf(tpad)
+    # two leaves: a layer-weighted mean, so that the sum over the layers
+    # is what the kernels of both leaves read (a ring never more than
+    # its own rows: the kernel caps the position there)
+    n_full, n_win = len(cfg.layers_of("full")), len(cfg.layers_of("window"))
+    return (n_full * leaf(tpad)
+            + (n_win and n_win * leaf(cfg.sliding_window))) // cfg.n_layers
+
+
+def decode_rows_live(cfg: TransformerConfig, held) -> int:
+    """Cache rows (of one layer's K or V plane) the requests of one
+    ``forward_one`` call NEED: ``held`` summed; in a gated stack a
+    window layer needs at most ``sliding_window`` of them, and the count
+    is the same layer-weighted mean as :func:`decode_rows_streamed`'s."""
+    if not cfg.gated:
+        return sum(held)
+    n_full, n_win = len(cfg.layers_of("full")), len(cfg.layers_of("window"))
+    return sum(
+        n_full * h + n_win * min(h, cfg.sliding_window) for h in held
+    ) // cfg.n_layers
 
 
 def make_paged_fwd1(fwd1):
@@ -1593,6 +2317,11 @@ def transformer_beam_search(cfg: TransformerConfig):
     continuations of each beam from the W*V candidate pool, and gathers
     the caches of the surviving parents.
     """
+    _gated_refuses(
+        cfg, "beam search",
+        "the beam reorder gathers one stacked cache along its batch axis "
+        "and has not been tried on two leaves",
+    )
     forward_one, init_caches, do_prefill, cast_params = _decode_builder(cfg)
 
     def beam(params, prompt, beam_width: int, max_new: int):
@@ -1840,6 +2569,8 @@ def _chunk_builder(cfg: TransformerConfig, tp_mesh=None):
     queries ride the same streamed weights as a single wide MXU dot.
     Per-layer work delegates to :func:`_block_chunk` — the same code
     ``block_decode``'s non-kernel path runs at C=1."""
+    if cfg.gated:
+        return _gated_builder(cfg)[4]
 
     def forward_chunk(params, caches, toks, pos0, last_idx=None,
                       adapter=None):
@@ -1942,6 +2673,12 @@ def transformer_speculative_generate(
     """
     if draft_cfg is None:
         draft_cfg = cfg
+    for c in (cfg, draft_cfg):
+        _gated_refuses(
+            c, "speculative decoding",
+            "a rejected draft rewinds the position, and a ring leaf has "
+            "already overwritten the rows the rewound window needs",
+        )
     _, t_init, t_prefill, t_cast = _decode_builder(cfg)
     t_chunk = _chunk_builder(cfg)
     d_fwd1, d_init, d_prefill, d_cast = _decode_builder(draft_cfg)

@@ -276,3 +276,92 @@ def moe_reference(params: MoEParams, x, *, k: int = 2,
         return jnp.sum(g[:, None] * outs, axis=0)
 
     return jax.vmap(per_token)(x, top_idx, top_gates)
+
+
+# -- experts held as a chip's share ------------------------------------------
+#
+# The layer below is what expert parallelism asks of one chip, without the
+# exchange: it is TOLD which experts it holds (``first`` .. ``first`` +
+# E_held - 1 of ``n_total``), routes over all of them as the model
+# publishes, and computes the part of the result its own experts give. No
+# capacity, no dropped token, static shapes: the token-expert pairs that
+# land on a held expert are sorted by expert and go through one grouped
+# product per projection (``lax.ragged_dot``: XLA:TPU lowers it to a Mosaic
+# grouped matmul that streams each hit expert's weights once). The same
+# code serves a 64-row decode step and a 1,024-row prefill chunk.
+
+
+def route_top_k(h, router, *, k: int, scale: float):
+    """Softmax-then-top-k routing over every published expert.
+
+    ``h`` (N, D), ``router`` (D, E_total). The product, the softmax and
+    the weights are float32 whatever the compute dtype: a bf16 product
+    moves a token's k-th and (k+1)-th expert past each other far more
+    often than the rounding of the activations does. Returns ``(ids,
+    weights)``, both (N, k): the k largest probabilities' experts and
+    ``scale * p_e / sum_{e' in top k} p_e'``."""
+    logits = jnp.dot(
+        h.astype(jnp.float32), router.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST,
+    )
+    probs = jax.nn.softmax(logits, axis=-1)
+    top_p, top_i = lax.top_k(probs, k)
+    return top_i, scale * top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+
+
+def swiglu(h, w_gate, w_up, w_down):
+    """``(silu(h w_gate) * (h w_up)) w_down`` over (..., D) rows."""
+    a = jax.nn.silu(h @ w_gate) * (h @ w_up)
+    return a @ w_down
+
+
+def moe_held_ffn(h, router, w_gate, w_up, w_down, *, first: int, k: int,
+                 scale: float, live=None):
+    """The held experts' part of a routed SwiGLU layer.
+
+    ``h`` (N, D) rows; ``router`` (D, E_total); ``w_gate`` / ``w_up``
+    (E_held, D, F) and ``w_down`` (E_held, F, D): experts ``first`` ..
+    ``first + E_held - 1``. ``live`` (N,) bool, optional: rows nobody
+    reads (a free serving slot) are routed nowhere. Returns ``(y,
+    counts)``: ``y`` (N, D) = sum over the token's top-k experts THAT ARE
+    HELD of ``w_e F_e(h)`` (nothing stands in for the others), and
+    ``counts`` int32 (3,): token-expert pairs computed here, pairs routed
+    in all (k x live rows), held experts with at least one row."""
+    n, d = h.shape
+    e_held = w_gate.shape[0]
+    ids, weights = route_top_k(h, router, k=k, scale=scale)
+    here = (ids >= first) & (ids < first + e_held)
+    if live is not None:
+        here = here & live[:, None]
+    # pairs elsewhere sort behind every group and belong to none
+    local = jnp.where(here, ids - first, e_held).reshape(-1)
+    order = jnp.argsort(local, stable=True)
+    sizes = jnp.bincount(local, length=e_held + 1)[:e_held].astype(jnp.int32)
+    # rows of the grouped products: the sorted pairs, padded to an ODD
+    # number of 128-row tiles. XLA:TPU tiles a ragged dot's rows by the
+    # largest of 512 / 256 / 128 that divides them, and a tile computes
+    # all its rows for every group it touches: at 512 rows a tile a
+    # 1,024-token chunk's products were bound by that waste (2.7 ms a
+    # call against 1.3 ms for a decode step's 640 rows; PERF.md, PR 27)
+    tiles = -(-n * k // 128) | 1
+    rows = jnp.zeros((tiles * 128,), order.dtype).at[:n * k].set(order // k)
+    x = h[rows]  # (tiles * 128, D); the padding rows belong to no group
+    a = jax.nn.silu(lax.ragged_dot(x, w_gate, sizes)) * lax.ragged_dot(
+        x, w_up, sizes
+    )
+    out = lax.ragged_dot(a.astype(h.dtype), w_down, sizes)[:n * k]
+    # back to token order by a gather, then a sum over the token's k
+    # pairs in one fixed order (a scatter-add's is not); rows past the
+    # last group hold whatever the product left there, so they are
+    # selected away, not multiplied by zero
+    back = jnp.zeros_like(order).at[order].set(jnp.arange(n * k))
+    out = jnp.where(here[..., None], out[back].reshape(n, k, d), 0)
+    y = jnp.einsum(
+        "nkd,nk->nd", out, weights.astype(out.dtype),
+        preferred_element_type=jnp.float32,
+    )
+    n_live = n if live is None else jnp.sum(live)
+    counts = jnp.stack([
+        jnp.sum(here), k * n_live, jnp.sum(sizes > 0),
+    ]).astype(jnp.int32)
+    return y.astype(h.dtype), counts

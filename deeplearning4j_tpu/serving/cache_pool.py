@@ -36,6 +36,7 @@ from deeplearning4j_tpu.analysis.sanitizers import note_access, wrap_lock
 from deeplearning4j_tpu.models.transformer import (
     TransformerConfig,
     _decode_builder,
+    full_cache_leaf,
 )
 
 
@@ -64,9 +65,10 @@ class KVSlotPool:
         shapes = jax.eval_shape(
             lambda: init_caches(n_slots, max_total)
         )
-        kv = shapes["kv"] if isinstance(shapes, dict) else shapes
         self.n_slots = n_slots
-        self.tpad = kv.shape[3]  # rounded-up row count per slot
+        # rounded-up row count per slot, of the leaf that runs to
+        # max_total (a ring leaf is shorter)
+        self.tpad = full_cache_leaf(shapes).shape[3]
         self.caches = self._alloc_caches()
         # acquire/release/generation run on the engine thread while
         # n_free/n_active/occupancy feed metrics gauges scraped from

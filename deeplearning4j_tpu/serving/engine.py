@@ -133,7 +133,9 @@ from deeplearning4j_tpu.models.transformer import (
     _chunk_builder,
     _decode_builder,
     _top_k_filter,
+    decode_rows_live,
     decode_rows_streamed,
+    full_cache_leaf,
     make_paged_fwd1,
     paged_block_copy,
     paged_slot_gather,
@@ -267,6 +269,37 @@ PROGRAM_DONATION: dict[str, tuple[int, ...]] = {
 # programs are the live programs by construction, not by transcription.
 
 
+#: rows a counting ``fwd1`` adds under a horizon's token block
+MOE_COUNT_ROWS = 3
+
+
+def tallied(fwd1):
+    """``(fwd1, ride)`` for ONE trace of a step family. A ``fwd1`` that
+    counts its expert layers (``fwd1.counts_moe``: the gated stack's)
+    is called with a list that collects each substep's int32 (3,)
+    counters, and ``ride(block)`` appends them as ``MOE_COUNT_ROWS``
+    rows under the horizon's (slots, K[, width]) token block, so they
+    come back with the one readback a horizon has. Any other ``fwd1``
+    comes back as it is with an identity ``ride``: the program is the
+    one it was."""
+    if not getattr(fwd1, "counts_moe", False):
+        return fwd1, lambda block: block
+    counts = []
+
+    def counting(*args, **kwargs):
+        return fwd1(*args, stats=counts, **kwargs)
+
+    def ride(block):
+        rows = jnp.stack(counts, axis=1).astype(block.dtype)  # (3, K)
+        if block.ndim == 3:  # the masked families' aux block: column 0
+            rows = jnp.zeros(
+                rows.shape + block.shape[2:], block.dtype
+            ).at[:, :, 0].set(rows)
+        return jnp.concatenate([block, rows], axis=0)
+
+    return counting, ride
+
+
 def build_step_program(fwd1, horizon: int, temperature: float,
                        top_k: int | None, approx_top_k: bool):
     """K fused decode substeps in one program. The carry — caches,
@@ -287,6 +320,7 @@ def build_step_program(fwd1, horizon: int, temperature: float,
             if temperature != 0 else None
         )
         toks_all = []
+        fwd, ride = tallied(fwd1)
         for k in range(horizon):
             filt = _top_k_filter(logits, top_k, approx_top_k)
             if temperature == 0:
@@ -301,7 +335,7 @@ def build_step_program(fwd1, horizon: int, temperature: float,
             # write stays inside their own slab and is wiped by the
             # next admission's prefill insert
             toks = jnp.where(active, toks, 0)
-            new_logits, caches = fwd1(
+            new_logits, caches = fwd(
                 params, caches, toks, pos, adapter=adapters,
                 active=active,
             )
@@ -314,7 +348,7 @@ def build_step_program(fwd1, horizon: int, temperature: float,
             logits = new_logits
             toks_all.append(toks)
         return (caches, logits, pos, active, budget,
-                jnp.stack(toks_all, axis=1))
+                ride(jnp.stack(toks_all, axis=1)))
 
     return step
 
@@ -413,6 +447,7 @@ def build_piggyback_program(fwd1, fwd_chunk, horizon: int,
             if temperature != 0 else None
         )
         toks_all = []
+        fwd, ride = tallied(fwd1)
         for k in range(horizon):
             filt = _top_k_filter(logits, top_k, approx_top_k)
             if temperature == 0:
@@ -423,7 +458,7 @@ def build_piggyback_program(fwd1, fwd_chunk, horizon: int,
                     lambda kk, lg: jax.random.categorical(kk, lg)
                 )(tok_keys, filt / temperature).astype(jnp.int32)
             toks = jnp.where(active, toks, 0)
-            new_logits, caches = fwd1(
+            new_logits, caches = fwd(
                 params, caches, toks, pos, adapter=adapters,
                 active=active,
             )
@@ -437,7 +472,7 @@ def build_piggyback_program(fwd1, fwd_chunk, horizon: int,
             adapter=cadapter,
         )
         return (caches, logits, pos, active, budget,
-                jnp.stack(toks_all, axis=1), tmp, clg)
+                ride(jnp.stack(toks_all, axis=1)), tmp, clg)
 
     return pstep
 
@@ -555,6 +590,7 @@ def build_masked_step_program(fwd1, horizon: int, n_logprobs: int):
               bias_idx, bias_val, mask_words, trans_tab):
         keys = jax.random.wrap_key_data(slot_keys_raw)
         aux_all = []
+        fwd, ride = tallied(fwd1)
         for k in range(horizon):
             toks, aux = _masked_draw(  # lint: prng-ok _masked_draw folds pos into the key; pos advances every substep
                 logits, pos, active, gstate, keys, temps, top_ks,
@@ -566,7 +602,7 @@ def build_masked_step_program(fwd1, horizon: int, n_logprobs: int):
             # slots hold their state.
             nxt = trans_tab[gstate, toks]
             gstate = jnp.where(active & (gstate > 0), nxt, gstate)
-            new_logits, caches = fwd1(
+            new_logits, caches = fwd(
                 params, caches, toks, pos, adapter=adapters,
                 active=active,
             )
@@ -576,7 +612,7 @@ def build_masked_step_program(fwd1, horizon: int, n_logprobs: int):
             logits = new_logits
             aux_all.append(aux)
         return (caches, logits, pos, active, budget, gstate,
-                jnp.stack(aux_all, axis=1))
+                ride(jnp.stack(aux_all, axis=1)))
 
     return mstep
 
@@ -594,6 +630,7 @@ def build_masked_piggyback_program(fwd1, fwd_chunk, horizon: int,
                tmp, ctoks, cpos0, clast, cadapter):
         keys = jax.random.wrap_key_data(slot_keys_raw)
         aux_all = []
+        fwd, ride = tallied(fwd1)
         for k in range(horizon):
             toks, aux = _masked_draw(  # lint: prng-ok _masked_draw folds pos into the key; pos advances every substep
                 logits, pos, active, gstate, keys, temps, top_ks,
@@ -601,7 +638,7 @@ def build_masked_piggyback_program(fwd1, fwd_chunk, horizon: int,
             )
             nxt = trans_tab[gstate, toks]
             gstate = jnp.where(active & (gstate > 0), nxt, gstate)
-            new_logits, caches = fwd1(
+            new_logits, caches = fwd(
                 params, caches, toks, pos, adapter=adapters,
                 active=active,
             )
@@ -615,7 +652,7 @@ def build_masked_piggyback_program(fwd1, fwd_chunk, horizon: int,
             adapter=cadapter,
         )
         return (caches, logits, pos, active, budget, gstate,
-                jnp.stack(aux_all, axis=1), tmp, clg)
+                ride(jnp.stack(aux_all, axis=1)), tmp, clg)
 
     return mpstep
 
@@ -1133,6 +1170,28 @@ class ServingEngine:
     ):
         self.n_slots = n_slots
         self.max_total = int(min(max_total or cfg.max_len, cfg.max_len))
+        if cfg.gated:
+            # what a stack with caches of two lengths cannot do yet
+            # raises here, by name: none of it falls back in silence
+            for asked, what, lacks in (
+                (paged, "the paged pool (paged=True)",
+                 "PagedKVPool carves one slab length into blocks and "
+                 "has no block table for a ring leaf"),
+                (prefix_cache, "the prefix cache (prefix_cache=True)",
+                 "a cached segment holds rows 0..n of every layer, and "
+                 "a ring has already dropped all but the last window"),
+                (int(tp) > 1, "tensor-parallel serving (tp > 1)",
+                 "serving_tp_shardings has no layout for per-layer head "
+                 "counts, held experts or a ring leaf"),
+                (lora_bank is not None, "a LoRA bank (lora_bank=...)",
+                 "init_lora_bank stacks q and MLP factors of one shape a "
+                 "layer, and _gated_block has no delta attach point"),
+            ):
+                if asked:
+                    raise NotImplementedError(
+                        f"{what} is not built for a stack of gated layers "
+                        f"(layer_types set): {lacks}"
+                    )
         # programs dispatched while this is non-zero are not traffic
         # and stay out of metrics.program_dispatches: construction and
         # its parity probes (released at the END of __init__), runtime
@@ -1276,10 +1335,18 @@ class ServingEngine:
         # wire and in the prefix cache: a segment computed under a
         # different config hash must never be seated here
         self.config_hash = model_config_hash(cfg)
-        self.params = _shared_program(
-            (self._cfg_key, self.tp, "cast_params"),
-            lambda: jax.jit(cast_params),
-        )(params)
+        as_served = jax.eval_shape(cast_params, params)
+        if jax.tree.all(jax.tree.map(
+                lambda a, b: a.dtype == b.dtype, params, as_served)):
+            # already in the types they are served in: a jitted cast
+            # would only copy them, and a second copy of the weights is
+            # what does not fit beside the first (11 GB of experts)
+            self.params = params
+        else:
+            self.params = _shared_program(
+                (self._cfg_key, self.tp, "cast_params"),
+                lambda: jax.jit(cast_params),
+            )(params)
         if self.lora_bank is not None and lora_parity is not True:
             ok = self._probe_verdict(
                 "lora_zero", self._probe_lora_zero,
@@ -1310,9 +1377,9 @@ class ServingEngine:
         self._paged = False
         self._block_size = int(block_size or 8)
         if paged and paged_parity is not False:
-            tpad = jax.tree.leaves(jax.eval_shape(
+            tpad = full_cache_leaf(jax.eval_shape(
                 lambda: self._init_caches(1, self.max_total)
-            ))[0].shape[3]
+            )).shape[3]
             if tpad % self._block_size:
                 log_event(_log, "paged_disabled_bad_block_size",
                           block_size=self._block_size, tpad=tpad)
@@ -2197,6 +2264,13 @@ class ServingEngine:
                 f"request {req.id}: adapter {req.adapter} outside the "
                 f"loaded bank ({self.n_adapters} adapters)"
             )
+        if self.cfg.gated and req.kind.startswith("kv_"):
+            raise NotImplementedError(
+                f"request {req.id} ({req.kind}): disaggregated KVSG "
+                "frames are not built for a stack of gated layers "
+                "(layer_types set): a frame carries rows 0..n of one "
+                "slab, and a ring leaf holds the last window only"
+            )
         if getattr(req, "uses_sampling_surface", False):
             if not self._surface:
                 raise AdmissionError(
@@ -2328,6 +2402,13 @@ class ServingEngine:
         slot bookkeeping); the server services it between steps. A
         slot whose snapshot fails is skipped and left live — it falls
         back to the ordinary preempt/recovery path."""
+        if self.cfg.gated:
+            raise NotImplementedError(
+                "session export (KVSG frames) is not built for a stack "
+                "of gated layers (layer_types set): a frame carries rows "
+                "0..n of one slab, and a ring leaf holds the last window "
+                "only"
+            )
         if self._inflight is not None:
             # sync the pipelined horizon first so tokens-so-far and the
             # device logits row agree on the export position
@@ -3670,7 +3751,7 @@ class ServingEngine:
         shapes = jax.eval_shape(
             lambda: self._init_caches(1, total)
         )
-        tpad = jax.tree.leaves(shapes)[0].shape[3]
+        tpad = full_cache_leaf(shapes).shape[3]
         if tpad % block_size:
             return False
         bps = tpad // block_size
@@ -4904,7 +4985,7 @@ class ServingEngine:
                 held[j].append(rows + j + 1)
             st.n_substeps += k
         self.metrics.record_kv_rows(
-            sum(map(sum, held)),
+            sum(decode_rows_live(self.cfg, h) for h in held),
             sum(
                 decode_rows_streamed(
                     self.cfg, self.n_slots, self.pool.tpad, h,
@@ -4946,6 +5027,11 @@ class ServingEngine:
             # logprob rows — still ONE readback per horizon
             aux_host = toks_host
             toks_host = aux_host[:, :, 0]
+        if toks_host.shape[0] > self.n_slots:
+            # a counting fwd1's rows under the block (``tallied``)
+            self.metrics.record_moe(
+                *toks_host[self.n_slots:].sum(axis=1).tolist()
+            )
         if self._san is not None:
             # the program that read the dispatch-tracked buffers has
             # completed: verify nothing mutated them while in flight

@@ -61,7 +61,13 @@ reads for them: with the decode kernel, each live slot's rows rounded
 up to the kernel's T block and nothing for a free or frozen slot;
 on the dense path and under the paged wrapper, every row of every slot
 (``models.transformer.decode_rows_streamed``). Their ratio is the share
-of the step's cache stream any request needed; ``ttft_segment_seconds`` splits every request's time
+of the step's cache stream any request needed (a stack whose layers keep
+caches of two lengths counts both as layer-weighted means, so the ratio
+still is that share); ``moe_assignments_local`` / ``moe_assignments_total``
+/ ``moe_experts_hit`` are an expert stack's books, counted inside the step
+program and read back with its tokens: token-expert pairs computed by the
+experts held here, pairs routed in all, and held experts that saw a token
+(each streams its weights once); ``ttft_segment_seconds`` splits every request's time
 to first token at the three points the engine can see
 (:data:`TTFT_SEGMENTS`). ``compile_log`` is the process's
 :class:`~deeplearning4j_tpu.obs.compile_log.CompileLog`, and
@@ -149,6 +155,11 @@ class ServingMetrics:
         self.loop_seconds = {p: 0.0 for p in LOOP_PHASES}
         self.kv_rows_live = 0
         self.kv_rows_streamed = 0
+        # expert layers' books (a stack with routed experts only):
+        # counted on the device, read back with each horizon's tokens
+        self.moe_assignments_local = 0
+        self.moe_assignments_total = 0
+        self.moe_experts_hit = 0
         self.ttft_segment_seconds = {s: 0.0 for s in TTFT_SEGMENTS}
         self.n_ttft_segments = 0
         self.program_dispatches: dict[str, int] = {}
@@ -421,6 +432,24 @@ class ServingMetrics:
             "decode substeps (live / streamed = share of the decode "
             "stream a request needed).",
         )
+        self._c_moe = {
+            "moe_assignments_local": reg.counter(
+                "serve_moe_assignments_local_total",
+                "Token-expert pairs the experts held here computed, "
+                "summed over expert layers and decode substeps.",
+            ),
+            "moe_assignments_total": reg.counter(
+                "serve_moe_assignments_total",
+                "Token-expert pairs the router made (experts per token "
+                "x live tokens x expert layers), held here or not.",
+            ),
+            "moe_experts_hit": reg.counter(
+                "serve_moe_experts_hit_total",
+                "Held experts with at least one token, summed over "
+                "expert layers and decode substeps: each streams its "
+                "weights once.",
+            ),
+        }
         self._c_compile_requests = reg.counter(
             "serve_compile_requests_total",
             "Backend compile requests of this process (a persistent-"
@@ -483,6 +512,13 @@ class ServingMetrics:
         ``streamed``."""
         self.kv_rows_live += live
         self.kv_rows_streamed += streamed
+
+    def record_moe(self, local: int, total: int, experts_hit: int) -> None:
+        """One horizon's expert-layer counters, summed over its
+        substeps (``models.transformer``'s gated ``forward_one``)."""
+        self.moe_assignments_local += local
+        self.moe_assignments_total += total
+        self.moe_experts_hit += experts_hit
 
     def record_ttft_segments(self, *seconds: float) -> None:
         """One request's time to first token, cut into
@@ -807,6 +843,8 @@ class ServingMetrics:
             raise_to(self._c_loop_seconds, secs, phase=phase)
         raise_to(self._c_kv_rows_live, self.kv_rows_live)
         raise_to(self._c_kv_rows_streamed, self.kv_rows_streamed)
+        for name, counter in self._c_moe.items():
+            raise_to(counter, getattr(self, name))
         if self.compile_log is None:
             return
         totals = self.compile_log.totals()
@@ -946,6 +984,9 @@ class ServingMetrics:
         }
         out["kv_rows_live"] = self.kv_rows_live
         out["kv_rows_streamed"] = self.kv_rows_streamed
+        if self.moe_assignments_total:
+            for name in self._c_moe:
+                out[name] = getattr(self, name)
         if self.n_ttft_segments:
             out["ttft_segments"] = {
                 "n": self.n_ttft_segments,

@@ -212,3 +212,61 @@ def test_flash_training_lowers_on_a_dp_tp_mesh(devices):
         jax.value_and_grad(transformer_loss(cfg, mesh)),
         params, S((4, 257), jnp.int32, sharding=batch_sharding(mesh)),
     )
+
+
+# the laguna-s-2.1 serve geometry (benchmark/configs/laguna-s-2.1.json):
+# 64 slots, 8 KV heads x 128; a slab of 4,096 rows read by 6 query rows a
+# KV head, a ring of 512 read by 9
+@pytest.mark.parametrize(
+    "layers,rows,groups", [(2, 4096, 6), (3, 512, 9)], ids=["full", "ring"])
+def test_decode_kernel_lowers_at_the_gated_cell_s_two_leaves(
+        layers, rows, groups):
+    batch, n_kv, hk = 64, 8, 1024
+    assert pk.decode_block_rows(rows, hk, 2) < rows
+    lower_tpu(
+        lambda q, c, pos, act: pk.flash_decode_attention(
+            q, c, pos, n_kv, layer=layers - 1, active=act),
+        S((batch, groups, hk), jnp.bfloat16),
+        S((layers, 2, batch, rows, hk), jnp.bfloat16),
+        S((batch,), jnp.int32), S((batch,), jnp.bool_),
+    )
+
+
+def test_gated_step_program_lowers_with_both_leaves_and_the_counters():
+    """A step program of a gated stack at a quarter of the cell's widths:
+    the kernel once a leaf, the grouped products, and three counter rows
+    under the token block."""
+    from deeplearning4j_tpu.models.transformer import _decode_builder
+    from deeplearning4j_tpu.serving.engine import (
+        MOE_COUNT_ROWS,
+        build_step_program,
+    )
+
+    cfg = TransformerConfig(
+        vocab_size=2048, d_model=768, n_heads=12, n_kv_heads=4, head_size=128,
+        n_layers=3, d_ff=1024, max_len=1024, rope=True, use_flash=True,
+        compute_dtype=jnp.bfloat16, decode_kernel=True,
+        layer_types=("full_attention", "sliding_attention", "full_attention"),
+        layer_heads=(12, 16, 12), sliding_window=256, attn_gate=True,
+        norm_eps=1e-6, dense_layers=(0,), n_experts=8, n_experts_total=16,
+        moe_k=4, moe_scale=2.5, d_expert=256, d_shared=256,
+        rope_full={"rope_theta": 500000, "factor": 128,
+                   "original_max_position_embeddings": 8192, "beta_fast": 32,
+                   "beta_slow": 1, "partial_rotary_factor": 0.5},
+    )
+    fwd1, init_caches, _, cast = _decode_builder(cfg)
+    slots, k = 16, 2
+    params = jax.eval_shape(
+        lambda key: cast(init_transformer(key, cfg)), jax.random.key(0))
+    caches = jax.eval_shape(lambda: init_caches(slots, 1024))
+    step = build_step_program(fwd1, k, 1.0, 40, False)
+    avals = (
+        params, caches, S((slots, cfg.vocab_size), jnp.float32),
+        S((slots,), jnp.int32), S((slots,), jnp.bool_),
+        S((slots,), jnp.int32), S((slots,), jnp.int32),
+        S((slots, 2), jnp.uint32), S((slots,), jnp.int32),
+    )
+    text = lower_tpu(step, *avals)
+    assert "ragged_dot" in text
+    out = jax.eval_shape(step, *avals)
+    assert out[5].shape == (slots + MOE_COUNT_ROWS, k)
