@@ -2387,20 +2387,82 @@ def transformer_beam_search(cfg: TransformerConfig):
     return beam
 
 
+# chunk widths of the selection's levels: the lane width of the TPU's
+# vector registers over the row, then the sublane count over what the
+# first level leaves
+_SELECT_CHUNKS = (128, 8)
+
+
+def topk_select(vocab: int, top_k: int | None,
+                approx_top_k: bool = False) -> str:
+    """Which branch of ``_top_k_filter`` these static sizes take:
+    ``"none"`` (no filter), ``"approx"`` (``approx_max_k``), and for
+    the exact threshold ``"chunked"`` (the selection by chunks) where
+    the first level's candidates are at most a quarter of the row,
+    ``"sort"`` (``lax.top_k`` over the row) otherwise. The engine
+    reports it."""
+    if top_k is None:
+        return "none"
+    if approx_top_k:
+        return "approx"
+    if top_k * _SELECT_CHUNKS[0] * 4 <= vocab:
+        return "chunked"
+    return "sort"
+
+
+def _chunk_candidates(x, k: int, chunk: int):
+    """``[..., k * chunk]``: the k chunks of ``chunk`` neighbours (the
+    tail padded with ``-inf``) whose maxima are largest. They hold the
+    row's k-th largest value as their own k-th largest: every element
+    above the k-th largest chunk maximum lies in a chosen chunk, and
+    the chosen chunks hold at least k elements at or above it (their
+    maxima), ties and ``-inf`` rows included."""
+    lead, v = x.shape[:-1], x.shape[-1]
+    n = -(-v // chunk)
+    pad = [(0, 0)] * len(lead) + [(0, n * chunk - v)]
+    chunks = jnp.pad(x, pad, constant_values=-jnp.inf).reshape(
+        lead + (n, chunk)
+    )
+    top = lax.top_k(chunks.max(axis=-1), k)[1]
+    cand = jnp.take_along_axis(chunks, top[..., None], axis=-2)
+    return cand.reshape(lead + (k * chunk,))
+
+
+def _kth_largest(logits, k: int, chunks: tuple = ()):
+    """The k-th largest value of each row, ``[..., 1]``: the float
+    ``lax.top_k(logits, k)[0][..., -1:]`` returns, which is what this
+    is without ``chunks``. Each chunk width first keeps the k chunks
+    with the largest maxima (one pass and a sort of the chunk maxima),
+    which leaves the k-th largest value what it was, so the row is
+    never ordered: with ``_SELECT_CHUNKS`` at ``V = 50257``, ``k = 40``
+    the sorts are of 393, 640 and 320 a row."""
+    for chunk in chunks:
+        logits = _chunk_candidates(logits, k, chunk)
+    return lax.top_k(logits, k)[0][..., -1:]
+
+
 def _top_k_filter(logits, top_k: int | None, approx_top_k: bool):
     """Top-k threshold filter on logits — ONE implementation shared by
-    ``transformer_generate``'s sampler and speculative decoding's
-    draft/verify distributions, so the filter semantics (exact sort vs
-    the TPU-native ``approx_max_k`` threshold — the exact top-40 over
-    V=50304 measured 758us/step, 29% of decode device time, vs
-    recall~0.95 for the approximate; kth-logit tie handling) cannot
-    drift between the paths the bench compares row-to-row."""
-    if top_k is None:
+    ``transformer_generate``'s sampler, speculative decoding's
+    draft/verify distributions and the serving step programs, so the
+    filter semantics (kth-logit tie handling; exact against the
+    TPU-native ``approx_max_k`` threshold, recall~0.95) cannot drift
+    between the paths the bench compares row-to-row. The filter needs
+    one number of a row, the value of its k-th largest logit, and that
+    value is unique, so any exact selection filters alike. XLA:TPU
+    lowers ``lax.top_k`` to a key/value sort of the whole row (2.3-2.7
+    ms a substep at ``[48, 50257]``): ``topk_select`` reads the static
+    ``V`` and ``k`` and sends rows long enough for it to pay through
+    the chunk levels of ``_kth_largest`` instead (0.2 ms there)."""
+    how = topk_select(logits.shape[-1], top_k, approx_top_k)
+    if how == "none":
         return logits
-    if approx_top_k:
+    if how == "approx":
         kth = lax.approx_max_k(logits, top_k)[0][..., -1:]
     else:
-        kth = lax.top_k(logits, top_k)[0][..., -1:]
+        kth = _kth_largest(
+            logits, top_k, _SELECT_CHUNKS if how == "chunked" else ()
+        )
     return jnp.where(logits < kth, -jnp.inf, logits)
 
 

@@ -142,6 +142,7 @@ from deeplearning4j_tpu.models.transformer import (
     paged_slot_scatter,
     place_serving_tp_params,
     serving_tp_cache_sharding,
+    topk_select,
 )
 from deeplearning4j_tpu.parallel.mesh import model_parallel_mesh
 from deeplearning4j_tpu.obs import compile_log
@@ -1422,6 +1423,9 @@ class ServingEngine:
             self.scheduler.max_total_tokens = self.max_total
         self.metrics = metrics or ServingMetrics()
         self.metrics.decode_horizon = self.decode_horizon
+        self.metrics.topk_select = topk_select(
+            cfg.vocab_size, self.top_k, self.approx_top_k
+        )
         self.metrics.compile_log = self._compile_log
         # the one place a phase of step() is named: profiler
         # annotation, metrics.loop_seconds, ring span, sanitizer phase
@@ -1815,6 +1819,14 @@ class ServingEngine:
                 t = self.tenancy.get(tid)
                 if t.slo_p99_tpot_s is not None:
                     self.metrics.set_tenant_slo(tid, t.slo_p99_tpot_s)
+        reg.gauge(
+            "serve_topk_select",
+            "How the step programs' top-k filter finds its threshold, "
+            "decided when they are traced: chunked (selection by "
+            "chunks), sort (lax.top_k over the vocabulary), approx or "
+            "none.",
+            labelnames=("how",),
+        ).set(1, how=self.metrics.topk_select)
         reg.gauge(
             "serve_decode_horizon_current",
             "Decode substeps fused into the next horizon dispatch "

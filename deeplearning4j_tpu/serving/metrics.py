@@ -170,6 +170,9 @@ class ServingMetrics:
         # stamped by the engine at construction; reported in summary()
         # so a bench row records which horizon produced its numbers
         self.decode_horizon = 1
+        # how the step programs' top-k filter finds its threshold at
+        # the engine's vocabulary and k (transformer.topk_select)
+        self.topk_select = "none"
         self.n_finished = 0
         self.n_generated = 0
         # fault-tolerance counters (see serving.faults / engine docs):
@@ -876,6 +879,7 @@ class ServingMetrics:
             "n_expired": self.n_expired,
             "steps": self._step,
             "decode_horizon": self.decode_horizon,
+            "topk_select": self.topk_select,
         }
         lookups = (self.n_prefix_hits_full + self.n_prefix_hits_partial
                    + self.n_prefix_misses)
