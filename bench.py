@@ -2145,11 +2145,11 @@ def _bench_decode_serve_tenant(args, n_slots: int = 4,
     batched = ServingEngine(cfg, params, n_slots=lora_slots,
                             temperature=1.0, top_k=40,
                             approx_top_k=not args.exact_top_k,
-                            lora_bank=bank, lora_parity=True)
+                            lora_bank=bank)
     replica = ServingEngine(cfg, params, n_slots=lora_slots,
                             temperature=1.0, top_k=40,
                             approx_top_k=not args.exact_top_k,
-                            lora_bank=bank, lora_parity=True)
+                            lora_bank=bank)
     run_flood(batched, lora_requests())  # warmup/compile
     run_flood(replica, lora_requests(adapter=1))
     batched.metrics = ServingMetrics()

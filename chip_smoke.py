@@ -3,8 +3,8 @@
 
     python chip_smoke.py                    # one TPU chip; exits 0 and prints
                                             # {"ok": true, "device": ...} last
-    python chip_smoke.py --legs probes      # the nine parity probes + paged
-                                            # kernel survey (builder's run)
+    python chip_smoke.py --legs features    # every optional serving feature
+                                            # on against off, on logits
     python chip_smoke.py --legs multichip   # four chips: dryrun, dp x tp
                                             # training, tp=2 serving
     python chip_smoke.py --rehearse         # same code, toy geometry, CPU,
@@ -29,13 +29,18 @@ Legs (default: kernels, serve, train):
   remat, unrolled, bf16) through transformer_train_step on a one-device
   mesh, and bench.py's hand-rolled unsharded step beside it; then
   value_and_grad compiled at the flash-8k and flash-32k presets.
-- probes: every construction/run-time parity probe's verdict on this
-  device (depth cut to 2 layers, full width), a crash-replay round trip,
-  and which block sizes Mosaic accepts for the paged decode kernel.
+- features: the prefix cache (a full and a partial hit), batched
+  admission, chunked crash replay, piggyback prefill, the paged pool,
+  the sampling surface, a LoRA bank's adapter 0 and the KV wire: the
+  same requests with the feature on and with it off (depth cut to
+  ``feature_layers``, full width, K=1), the logits every token was
+  drawn from compared row by row and held to FEATURE_TOL; then which
+  block sizes Mosaic accepts for the paged decode kernel.
 - multichip: ``dryrun_multichip(4)``, three dp x tp train steps at
   GPT-2s width on ``dp_mp_mesh(2, 2)``, ``ServingEngine(tp=2)`` over
-  HTTP, shard placement asserted, and what the compiled HLO does with
-  the flash kernel under GSPMD.
+  HTTP, shard placement asserted, ``tp=2`` against ``tp=1`` on logits
+  as in the features leg, and what the compiled HLO does with the flash
+  kernel under GSPMD.
 """
 
 from __future__ import annotations
@@ -52,7 +57,7 @@ import threading
 import time
 import urllib.request
 
-LEGS = ("kernels", "serve", "train", "probes", "multichip")
+LEGS = ("kernels", "serve", "train", "features", "multichip")
 DEFAULT_LEGS = ("kernels", "serve", "train")
 
 #: kernel path vs dense einsum path, one decode step's logits at width:
@@ -64,6 +69,13 @@ LOGITS_REL, LOGITS_ABS = 0.03, 0.02
 #: (int8: per-row cache scales plus in-kernel q and p quantisation)
 KERNEL_REL = {"bfloat16": 0.02, "int8": 0.08}
 KERNEL_ABS = 0.01
+#: a feature on against the same requests with it off, over the logits
+#: every token was drawn from. The off side is the path a benchmark
+#: run's ``correct`` check holds to the float32 reference, so the on
+#: side is held to that configuration's tolerances against it
+#: (benchmark/configs/gpt2-large.json: an 8-bit path fails them, a
+#: rounding change does not).
+FEATURE_TOL = {"max_err_of_scale": 0.03, "rms_err_of_rms": 0.025}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -90,7 +102,7 @@ class Size:
     # (batch, cache rows) for the decode-kernel leg
     decode_shapes: tuple[tuple[int, int], ...]
     paged_block_sizes: tuple[int, ...]
-    probe_layers: int
+    feature_layers: int
 
     @property
     def max_total(self) -> int:
@@ -110,7 +122,7 @@ FULL = Size(
     # bench.py's 8kctx row (8192 prompt + 256 new, padded to 512s)
     decode_shapes=((16, 584), (64, 584), (16, 8704)),
     paged_block_sizes=(8, 16, 128),
-    probe_layers=2,
+    feature_layers=2,
 )
 
 REHEARSAL = Size(
@@ -121,7 +133,7 @@ REHEARSAL = Size(
     long_train=((256, 1, 64, 2, 2, 128, 256), (512, 1, 64, 2, 2, 128, 256)),
     decode_shapes=((4, 152), (8, 152), (2, 1536)),
     paged_block_sizes=(8, 16, 128),
-    probe_layers=2,
+    feature_layers=2,
 )
 
 
@@ -175,11 +187,10 @@ def train_config(seq, d_model, n_heads, n_layers, d_ff, vocab, remat):
 
 
 def engine_kwargs(size: Size) -> dict:
-    """The serve geometry every engine in this file is built with.
-    ``probe_cache=None``: verdicts are today's."""
+    """The serve geometry every engine in this file is built with."""
     return dict(
         n_slots=size.n_slots, max_total=size.max_total, temperature=0.0,
-        decode_horizon=4, prefill_max_bucket=size.bucket, probe_cache=None,
+        decode_horizon=4, prefill_max_bucket=size.bucket,
     )
 
 
@@ -432,9 +443,9 @@ def serve_leg(size: Size, log, rehearse: bool) -> None:
     try:
         with phase(log, "serve/warmup"):
             # two same-bucket requests queued BEFORE the loop starts are
-            # admitted together: the batched-admission probe and the
-            # group-of-2 prefill compile here, whatever the thread timing
-            # of the concurrent round below turns out to be
+            # admitted together: the group-of-2 prefill compiles here,
+            # whatever the thread timing of the concurrent round below
+            # turns out to be
             queued = [
                 Request(prompt=traffic["pair"][0],
                         max_new=traffic["pair"][1],
@@ -495,9 +506,6 @@ def serve_leg(size: Size, log, rehearse: bool) -> None:
             check(health["ok"] and health["restarts"] == 0
                   and health["last_error"] is None,
                   "GET /healthz ok, 0 restarts, no error")
-            print(f"  probes_run={engine.probes_run} "
-                  f"probes_from_cache={engine.probes_from_cache} "
-                  f"batch_admission={engine._batch_ok_memo}")
             summary = engine.metrics.summary()
             print("  engine summary: " + json.dumps({
                 k: summary[k] for k in (
@@ -607,14 +615,125 @@ def train_leg(size: Size, log) -> None:
                   f"{len(mosaic_calls(compiled.as_text()))} Mosaic calls")
 
 
-# -- probes (builder's leg) ---------------------------------------------------
+# -- features (builder's leg) -------------------------------------------------
 
 
-def probes_leg(size: Size, log) -> None:
-    """Every parity probe's verdict on this device, for D2. A verdict is
-    information; a probe that cannot compile or run raises and fails the
-    leg. Depth is cut to ``probe_layers`` (the probes compare two
-    schedules of the same per-layer arithmetic), width is full."""
+def record_sampled_logits(engine) -> dict:
+    """Every logits row a step program of ``engine`` draws a token from,
+    as ``{(request id, position): row}``. At K=1 that is every token's.
+    All four step families take ``(params, caches, logits, pos, active,
+    ...)``; the getters are wrapped on this one engine object, once: a
+    second call returns the same dict."""
+    import numpy as np
+
+    rows = getattr(engine, "sampled_rows", None)
+    if rows is not None:
+        return rows
+    rows = engine.sampled_rows = {}
+
+    def wrap(getter):
+        def get(*key):
+            fn = getter(*key)
+
+            def call(params, caches, logits, pos, active, *rest):
+                lg, po, live = (np.asarray(a) for a in (logits, pos, active))
+                for slot, st in enumerate(engine._slots):
+                    if st is not None and live[slot]:
+                        rows[(st.req.id, int(po[slot]))] = lg[slot]
+                return fn(params, caches, logits, pos, active, *rest)
+
+            return call
+        return get
+
+    for name in ("_step_fn_for", "_masked_step_fn_for", "_piggyback_fn",
+                 "_masked_piggyback_fn"):
+        setattr(engine, name, wrap(getattr(engine, name)))
+    return rows
+
+
+def served(engine, waves, crash_after: int | None = None):
+    """Serve ``waves`` (lists of requests; a wave is submitted whole,
+    ``steps`` engine steps after the one before, and the last is run to
+    the end) and return ``(rows, streams)``: the logits every token was
+    drawn from and each request's tokens. With ``crash_after`` the engine
+    loses its device state after that many steps of the last wave and
+    recovers; only what was drawn after the recovery is returned."""
+    rows = record_sampled_logits(engine)
+    reqs = []
+    for steps, wave in waves:
+        for _ in range(steps):
+            engine.step()
+        for r in wave:
+            engine.submit(r)
+            reqs.append(r)
+    if crash_after is not None:
+        for _ in range(crash_after):
+            engine.step()
+        engine.recover()
+        rows.clear()
+    out = engine.run()
+    return rows, {r.id: out[r.id].tolist() for r in reqs}
+
+
+def logit_errors(name: str, on, off, prompts: dict) -> dict:
+    """Compare what two engines drew the same requests' tokens from. A
+    row is compared as long as the tokens before it agree (a row past a
+    differing token answers another question); the first differing
+    token is reported with the gap between the off side's two largest
+    logits there."""
+    import numpy as np
+
+    (rows_on, toks_on), (rows_off, toks_off) = on, off
+    got, want, diverged = [], [], []
+    for rid in sorted(toks_off):
+        n = len(prompts[rid])
+        a, b = toks_on[rid][n:], toks_off[rid][n:]
+        same = next((i for i, (x, y) in enumerate(zip(a, b)) if x != y),
+                    min(len(a), len(b)))
+        if same < min(len(a), len(b)):
+            top = np.sort(rows_off[(rid, n + same)])[-2:]
+            diverged.append({"request": rid, "token": same,
+                             "top2_gap": float(top[1] - top[0])})
+        for pos in range(n, n + same + 1):
+            if (rid, pos) in rows_on and (rid, pos) in rows_off:
+                got.append(rows_on[(rid, pos)])
+                want.append(rows_off[(rid, pos)])
+    got, want = np.stack(got), np.stack(want)
+    return {
+        "feature": name, "rows": len(got),
+        "max_err_of_scale": float(np.max(np.abs(got - want)))
+        / float(np.max(np.abs(want))),
+        "rms_err_of_rms": float(np.sqrt(np.mean((got - want) ** 2)))
+        / float(np.sqrt(np.mean(want ** 2))),
+        "rows_bitwise": int(np.sum(np.all(got == want, axis=1))),
+        "streams_equal": not diverged, "diverged": diverged,
+    }
+
+
+def report_features(results: list[dict], path: str) -> None:
+    """Print every figure, leave them in ``chiprun_out/``, and only then
+    hold each to FEATURE_TOL: one feature over it does not hide the
+    others' numbers."""
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open(os.path.join("chiprun_out", path), "w") as f:
+        json.dump(results, f, indent=1)
+    for r in results:
+        print("  " + json.dumps(r), flush=True)
+    for r in results:
+        check(all(r[k] <= tol for k, tol in FEATURE_TOL.items()),
+              f"{r['feature']}: on against off over {r['rows']} rows, max "
+              f"error {r['max_err_of_scale']:.3e} of the largest logit "
+              f"(tol {FEATURE_TOL['max_err_of_scale']}), rms error "
+              f"{r['rms_err_of_rms']:.3e} of the rms logit (tol "
+              f"{FEATURE_TOL['rms_err_of_rms']}), {r['rows_bitwise']} rows "
+              f"bitwise, streams equal={r['streams_equal']}")
+
+
+def features_leg(size: Size, log) -> None:
+    """Every optional serving feature on against off on this device.
+    Depth is cut to ``feature_layers`` (a feature reschedules or reorders
+    the same per-layer arithmetic), width is full, K=1 so that every
+    token's logits pass through the recorder."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -627,67 +746,162 @@ def probes_leg(size: Size, log) -> None:
         flash_decode_attention_paged,
     )
     from deeplearning4j_tpu.serving import ServingEngine
-    from deeplearning4j_tpu.serving.scheduler import Request
+    from deeplearning4j_tpu.serving.disagg import (
+        decode_segment,
+        encode_segment,
+    )
+    from deeplearning4j_tpu.serving.scheduler import (
+        KVExportRequest,
+        KVIngestRequest,
+        Request,
+    )
 
-    cfg = serve_config(size, n_layers=size.probe_layers)
+    cfg = serve_config(size, n_layers=size.feature_layers)
+    dense = dataclasses.replace(cfg, decode_kernel=False)
     params = init_transformer(jax.random.key(0), cfg)
-    common = engine_kwargs(size)
-    verdicts: dict[str, object] = {}
-
-    with phase(log, "probes/slab engine (prefix, piggyback, surface)"):
-        eng = ServingEngine(
-            cfg, params, prefix_cache=True, piggyback=True,
-            sampling_surface=True, **common,
+    common = dict(engine_kwargs(size), decode_horizon=1)
+    rng = np.random.default_rng(2)
+    prompts = {
+        name: prompt_tokens(rng, size, n)
+        for name, n in (
+            ("short", size.short[0]), ("exact", size.exact[0]),
+            ("long", size.long[0]),
+            # four lengths of one bucket, admitted together
+            *((f"group{i}", size.bucket // 2 + 1 + 3 * i) for i in range(4)),
         )
-        verdicts["piggyback_parity"] = eng._piggyback
-        verdicts["masked_parity"] = eng._surface
-        verdicts["prefix_reuse"] = eng._prefix_reuse_ok()
-        verdicts["batch_admission"] = eng._batch_admission_ok()
-        verdicts["disagg"] = eng._disagg_ok()
-        print(f"  probes_run={eng.probes_run}")
+    }
+    prompts["again"] = list(prompts["exact"])  # a full hit
+    prompts["longer"] = prompts["exact"] + prompt_tokens(rng, size, 12)
+    max_new = size.short[1]
 
-    with phase(log, "probes/crash replay (chunked_replay)"):
-        # the recovery contract on this device: a crash mid-generation,
-        # replayed, yields the streams of an uninterrupted run
-        rng = np.random.default_rng(2)
-        prompts = [prompt_tokens(rng, size, size.short[0]),
-                   prompt_tokens(rng, size, size.exact[0])]
+    def requests(*names, **kw):
+        return [Request(prompt=prompts[n], max_new=max_new, id=n, **kw)
+                for n in names]
 
-        def run(crash_after: int | None):
-            e = ServingEngine(cfg, params, **common)
-            reqs = [Request(prompt=p, max_new=size.long[1])
-                    for p in prompts]
-            for r in reqs:
-                e.submit(r)
-            if crash_after is not None:
-                for _ in range(crash_after):
-                    e.step()
-                e.recover()
-            out = e.run()
-            return e, [out[r.id].tolist() for r in reqs]
+    def pair(name, waves, on: dict, off: dict | None = None, *,
+             off_cfg=cfg, crash_after=None, counted=None):
+        """Both engines see the same requests in the same order;
+        ``counted`` checks that the on side really took the path."""
+        eng_on = ServingEngine(cfg, params, **common, **on)
+        got = served(eng_on, waves(), crash_after)
+        if counted is not None:
+            counted(eng_on)
+        eng_off = ServingEngine(off_cfg, params, **common, **(off or {}))
+        return logit_errors(
+            name, got, served(eng_off, waves(), crash_after), prompts
+        )
 
-        _, clean = run(None)
-        eng2, replayed = run(2)
-        verdicts["chunked_replay"] = eng2._chunked_ok
-        check(eng2.last_recover_mode is not None and clean == replayed,
-              f"streams after recover() ({eng2.last_recover_mode}) equal "
-              "the uninterrupted run's")
+    results = []
+    with phase(log, "features/prefix_cache (full hit, partial hit)"):
+        # one request at a time: the second finds the first's segment,
+        # the third its first 128 rows
+        on, off = {}, {}
+        for side, kw in ((on, {"prefix_cache": True}), (off, {})):
+            eng = ServingEngine(cfg, params, **common, **kw)
+            for name in ("exact", "again", "longer"):
+                side[name] = served(eng, [(0, requests(name))])
+            if side is on:
+                check(eng.metrics.n_prefix_hits_full == 1
+                      and eng.metrics.n_prefix_hits_partial == 1,
+                      "the cache served one full and one partial hit")
+        for name, rid in (("prefix_cache full hit", "again"),
+                          ("prefix_cache partial hit", "longer")):
+            results.append(logit_errors(name, on[rid], off[rid], prompts))
 
-    with phase(log, "probes/paged engine"):
-        eng3 = ServingEngine(cfg, params, paged=True, **common)
-        verdicts["paged_parity"] = eng3._paged
-        print(f"  block_size={eng3._block_size} probes_run="
-              f"{eng3.probes_run}")
+    with phase(log, "features/batch_admission"):
+        group = [f"group{i}" for i in range(4)]
 
-    with phase(log, "probes/lora engine"):
+        def counted(eng):
+            check(eng.metrics.n_batched_admissions == 4,
+                  "four same-bucket prompts were admitted in one program")
+
+        results.append(pair(
+            "batch_admission", lambda: [(0, requests(*group))],
+            {"batch_admission": True}, {"batch_admission": False},
+            counted=counted,
+        ))
+
+    with phase(log, "features/chunked_replay"):
+        # both sides lose their device state at the same step; one
+        # rebuilds it by bucketed prefill, the other step by step
+        def counted(eng):
+            check(eng.last_recover_mode == "chunked",
+                  "the on side replayed by bucketed prefill")
+
+        results.append(pair(
+            "chunked_replay", lambda: [(0, requests("short", "exact"))],
+            {"chunked_replay": True}, {"chunked_replay": False},
+            crash_after=3, counted=counted,
+        ))
+
+    with phase(log, "features/piggyback"):
+        # two streams decoding when a prompt of several buckets arrives
+        def counted(eng):
+            check(bool(eng._piggyback_fns),
+                  "a chunk rode a decode dispatch")
+
+        results.append(pair(
+            "piggyback",
+            lambda: [(0, requests("short", "exact")),
+                     (3, requests("long"))],
+            {"piggyback": True}, counted=counted,
+        ))
+
+    with phase(log, "features/paged"):
+        results.append(pair(
+            "paged",
+            lambda: [(0, requests("short", "exact", "long"))],
+            {"paged": True},
+        ))
+
+    with phase(log, "features/sampling_surface"):
+        results.append(pair(
+            "sampling_surface",
+            lambda: [(0, requests("short", "exact"))],
+            {"sampling_surface": True},
+        ))
+
+    with phase(log, "features/lora adapter 0"):
+        # a bank switches the decode kernel off, so the side without
+        # one runs the dense path too
         bank = init_lora_bank(jax.random.key(1), cfg, n_adapters=3, rank=4)
-        eng4 = ServingEngine(cfg, params, lora_bank=bank, **common)
-        verdicts["lora_zero"] = eng4.lora_bank is not None
-        print(f"  a LoRA bank switches the decode kernel off: "
-              f"decode_kernel={eng4.cfg.decode_kernel}")
+        results.append(pair(
+            "lora adapter 0",
+            lambda: [(0, requests("short", "exact", adapter=0))],
+            {"lora_bank": bank}, off_cfg=dense,
+        ))
 
-    print("  verdicts (tp_parity is the multichip leg's): "
-          + json.dumps(verdicts), flush=True)
+    with phase(log, "features/KV wire"):
+        # prefill on one engine, a real frame, seated on another, which
+        # then decodes without a prefill of its own
+        def through(eng, req):
+            eng.submit(req)
+            eng.run()
+            return req.result
+
+        res = through(ServingEngine(cfg, params, **common), KVExportRequest(
+            prompt=np.asarray(prompts["exact"], np.int32),
+        ))
+        frame = encode_segment(**{k: res[k] for k in (
+            "config_hash", "tokens", "leaves", "logits", "layout",
+            "block_size",
+        )})
+        receiver = ServingEngine(cfg, params, prefix_cache=True, **common)
+        seated = through(receiver, KVIngestRequest(
+            segment=decode_segment(frame, expect_hash=receiver.config_hash),
+        ))
+        check(seated["stored"], f"the frame was seated ({seated['reason']})")
+        on = served(receiver, [(0, requests("exact"))])
+        check(receiver.prefill_dispatches == 0,
+              "the receiver decoded without a prefill of its own")
+        off = served(ServingEngine(cfg, params, **common),
+                     [(0, requests("exact"))])
+        results.append(logit_errors("KV wire", on, off, prompts))
+
+    report_features(
+        results,
+        "features.json" if size is FULL else "features_rehearsal.json",
+    )
 
     # which block sizes Mosaic accepts for the paged kernel (no engine
     # call site yet: S1). A refusal is recorded, not fatal — this is a
@@ -745,6 +959,44 @@ def split_leaves(tree) -> int:
     )
 
 
+def tp2_against_tp1(size: Size, log) -> None:
+    """As the features leg: the same requests sharded over two chips and
+    on one (tp > 1 serves the dense path, so both sides do), the logits
+    every token was drawn from."""
+    import jax
+    import numpy as np
+
+    from deeplearning4j_tpu.models.transformer import init_transformer
+    from deeplearning4j_tpu.serving import ServingEngine
+    from deeplearning4j_tpu.serving.scheduler import Request
+
+    with phase(log, "multichip/tp=2 against tp=1"):
+        cfg = dataclasses.replace(
+            serve_config(size, n_layers=size.feature_layers),
+            decode_kernel=False,
+        )
+        params = init_transformer(jax.random.key(0), cfg)
+        rng = np.random.default_rng(3)
+        prompts = {name: prompt_tokens(rng, size, getattr(size, name)[0])
+                   for name in ("short", "exact", "long")}
+        sides = []
+        for tp in (2, 1):
+            eng = ServingEngine(
+                cfg, params, tp=tp,
+                **dict(engine_kwargs(size), decode_horizon=1),
+            )
+            check(eng.tp == tp, f"engine serves with tp={tp}")
+            sides.append(served(eng, [(0, [
+                Request(prompt=p, max_new=size.short[1], id=name)
+                for name, p in prompts.items()
+            ])]))
+        report_features(
+            [logit_errors("tp=2", *sides, prompts)],
+            "features_tp.json" if size is FULL
+            else "features_tp_rehearsal.json",
+        )
+
+
 def multichip_leg(size: Size, log, rehearse: bool) -> None:
     import jax
     import jax.numpy as jnp
@@ -799,7 +1051,7 @@ def multichip_leg(size: Size, log, rehearse: bool) -> None:
         scfg = serve_config(size)
         params = init_transformer(jax.random.key(0), scfg)
         common = engine_kwargs(size)
-        engine = ServingEngine(scfg, params, tp=2, tp_parity=True, **common)
+        engine = ServingEngine(scfg, params, tp=2, **common)
         check(engine.tp == 2, "engine serves with tp=2")
         print(f"  tp > 1 switches the decode kernel off: "
               f"decode_kernel={engine.cfg.decode_kernel}")
@@ -828,11 +1080,7 @@ def multichip_leg(size: Size, log, rehearse: bool) -> None:
         finally:
             server.stop(drain_s=5.0)
 
-    with phase(log, "multichip/tp_parity probe (information)"):
-        probed = ServingEngine(scfg, params, tp=2, tp_parity="auto",
-                               **common)
-        print(f"  tp_parity verdict: {probed.tp == 2} "
-              f"(probes_run={probed.probes_run})")
+    tp2_against_tp1(size, log)
 
     # last: eleven modes of tiny programs, the longest part on a chip
     with phase(log, "multichip/dryrun_multichip(4)"):
@@ -869,8 +1117,6 @@ def main(argv: list[str] | None = None) -> int:
             os.environ.get("XLA_FLAGS", "")
             + " --xla_force_host_platform_device_count=8"
         ).strip()
-    # verdicts must be today's: no probe cache from the environment
-    os.environ.pop("DL4J_TPU_PROBE_CACHE", None)
 
     import jax
 
@@ -904,8 +1150,8 @@ def main(argv: list[str] | None = None) -> int:
             serve_leg(size, log, args.rehearse)
         elif leg == "train":
             train_leg(size, log)
-        elif leg == "probes":
-            probes_leg(size, log)
+        elif leg == "features":
+            features_leg(size, log)
         elif leg == "multichip":
             multichip_leg(size, log, args.rehearse)
     requests, seconds, hits, misses = log.snapshot()

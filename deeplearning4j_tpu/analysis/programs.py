@@ -101,7 +101,7 @@ class ServingGeometry:
     prefix_segments: int = 2
     # block-paged KV surface (``ServingEngine(paged=True)``): the paged
     # families ride ALONGSIDE the slab ones — a paged engine still
-    # compiles the chunk/scratch-slab programs (suffix path, probes)
+    # compiles the chunk/scratch-slab programs (suffix path)
     paged: bool = False
     block_size: int = 8
     # production sampling surface (``ServingEngine(sampling_surface=

@@ -541,9 +541,6 @@ def cmd_serve(args) -> int:
     if args.profile_steps > 0:
         d = profile.arm(args.profile_steps)
         print(f"profiling first {args.profile_steps} steps -> {d}")
-    probe_cache = None
-    if args.probe_cache and args.probe_cache.lower() not in ("off", "none"):
-        probe_cache = os.path.expanduser(args.probe_cache)
     sans = None
     if args.sanitize:
         from deeplearning4j_tpu.analysis.sanitizers import (
@@ -588,50 +585,25 @@ def cmd_serve(args) -> int:
         tracer=tracer,
         profile=profile,
         tp=args.tp,
-        tp_parity={"auto": "auto", "trust": True, "off": False}[
-            args.tp_parity],
-        probe_cache=probe_cache,
     )
     if sans is not None:
         engine.attach_sanitizer(sans[1])
-    if lora_bank is not None and engine.n_adapters == 0:
-        print("batched LoRA DISABLED (adapter-0 parity probe failed); "
-              "serving the base model", file=sys.stderr)
     if args.paged:
-        if engine._paged:
-            print(f"paged KV: {engine.pool.n_blocks} blocks x "
-                  f"{engine.pool.block_size} tokens (shared pool, "
-                  f"refcounted block tables)")
-        else:
-            print("paged KV DISABLED (parity probe failed or block "
-                  "size does not divide tokens/slot); slab slots",
-                  file=sys.stderr)
+        print(f"paged KV: {engine.pool.n_blocks} blocks x "
+              f"{engine.pool.block_size} tokens (shared pool, "
+              f"refcounted block tables)")
     if args.piggyback:
-        if engine._piggyback:
-            print(f"piggyback prefill: chunked admission fused into "
-                  f"decode dispatches ({engine.prefill_budget} "
-                  f"tokens/horizon budget)")
-        else:
-            print("piggyback prefill DISABLED (parity probe failed); "
-                  "blocking admission prefill", file=sys.stderr)
+        print(f"piggyback prefill: chunked admission fused into "
+              f"decode dispatches ({engine.prefill_budget} "
+              f"tokens/horizon budget)")
     if args.tp > 1:
-        if engine.tp == args.tp:
-            print(f"tensor parallel: decode sharded over {engine.tp} "
-                  f"devices (model axis)")
-        else:
-            print(f"tensor parallel DISABLED (parity probe failed or "
-                  f"geometry unsupported); serving on 1 device",
-                  file=sys.stderr)
+        print(f"tensor parallel: decode sharded over {engine.tp} "
+              f"devices (model axis)")
     if args.sampling_surface:
-        if engine._surface:
-            print(f"sampling surface: grammar-constrained decoding + "
-                  f"per-request temperature/top_k/top_p/stop/"
-                  f"logit_bias/logprobs "
-                  f"({engine._gtable.capacity} DFA table rows)")
-        else:
-            print("sampling surface DISABLED (masked parity probe "
-                  "failed or approx-top-k engine); per-request "
-                  "sampling fields will 400", file=sys.stderr)
+        print(f"sampling surface: grammar-constrained decoding + "
+              f"per-request temperature/top_k/top_p/stop/"
+              f"logit_bias/logprobs "
+              f"({engine._gtable.capacity} DFA table rows)")
     server = ServingServer(
         engine, host=args.host, port=args.port,
         request_timeout_s=args.request_timeout,
@@ -1197,8 +1169,7 @@ def main(argv: list[str] | None = None) -> int:
     v.add_argument("--prefix-cache", action="store_true",
                    help="radix-tree KV prefix cache: admissions whose "
                    "prompt shares a cached prefix copy those KV rows "
-                   "instead of recomputing them (gated by a one-time "
-                   "bitwise parity probe; falls back to full prefill). "
+                   "instead of recomputing them. "
                    "Hit rate and saved prefill tokens appear in "
                    "/metrics")
     v.add_argument("--prefix-cache-tokens", type=int, default=None,
@@ -1211,18 +1182,18 @@ def main(argv: list[str] | None = None) -> int:
                    "over one shared refcounted pool instead of fixed "
                    "slabs — prefix-cache hits alias blocks (zero-copy) "
                    "and long-context mixes fit more concurrent slots "
-                   "at the same HBM. Gated by a one-time bitwise "
-                   "parity probe; falls back to slab slots")
+                   "at the same HBM")
     v.add_argument("--block-size", type=int, default=None, metavar="T",
                    help="tokens per KV block with --paged (default: "
-                   "engine picks; must divide tokens-per-slot)")
+                   "8; one that does not divide tokens-per-slot is an "
+                   "error at start)")
     v.add_argument("--piggyback", action="store_true",
                    help="chunked-prefill piggyback: long prompts are "
                    "split into pow2 chunks and ride along with decode "
                    "dispatches (one fused program per horizon) instead "
                    "of stalling active streams behind a blocking "
                    "prefill. Token-budgeted per horizon; byte-identical "
-                   "streams, gated by a one-time parity probe")
+                   "streams")
     v.add_argument("--prefill-budget", type=int, default=None,
                    metavar="N",
                    help="piggyback prefill token budget per decode "
@@ -1234,7 +1205,7 @@ def main(argv: list[str] | None = None) -> int:
                    "top_k/top_p overrides, stop sequences, logit_bias "
                    "and logprobs. One masked program family serves "
                    "every request mix; unconstrained streams stay "
-                   "byte-identical, gated by a one-time parity probe")
+                   "byte-identical")
     v.add_argument("--grammar-states", type=int, default=256,
                    metavar="N",
                    help="device DFA table rows shared by all seated "
@@ -1326,24 +1297,9 @@ def main(argv: list[str] | None = None) -> int:
     v.add_argument("--tp", type=int, default=1,
                    help="tensor-parallel width: shard the fused decode "
                    "program (attention heads, MLP columns, vocab) and "
-                   "the KV slot pool over the first N devices. Gated "
-                   "by a construction-time bitwise parity probe "
-                   "(--tp-parity); needs N dividing n_heads and "
-                   "kv_heads. 1 = single device")
-    v.add_argument("--tp-parity", default="auto",
-                   choices=["auto", "trust", "off"],
-                   help="auto: probe TP-vs-single-chip bitwise parity "
-                   "once at startup and fall back to tp=1 on mismatch; "
-                   "trust: skip the probe (models too big for one "
-                   "chip); off: disable TP entirely")
-    v.add_argument("--probe-cache",
-                   default="~/.cache/dl4j_tpu/probes.json",
-                   metavar="PATH",
-                   help="persist parity-probe verdicts (prefix reuse, "
-                   "batched admission, chunked replay, TP) keyed by "
-                   "(config, backend, geometry), so replica fleets and "
-                   "restarts skip cold-start probe dispatches. "
-                   "'off' disables persistence")
+                   "the KV slot pool over the first N devices. Needs "
+                   "N devices and N dividing n_heads and kv_heads, or "
+                   "the server does not start. 1 = single device")
     v.add_argument("--tenants", default=None, metavar="PATH",
                    help="JSON tenant registry enabling multi-tenant "
                    "serving: API-key resolution (X-API-Key / Bearer), "

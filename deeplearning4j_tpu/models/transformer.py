@@ -2108,7 +2108,7 @@ def _decode_builder(cfg: TransformerConfig, tp_mesh=None):
 # contiguous per-slot view, run the UNCHANGED slab program, scatter the
 # view back block-by-block. Gather/scatter are pure data movement, so
 # the slab program's arithmetic — and therefore its token streams — is
-# byte-identical by construction; the engine's paged_parity probe pins
+# byte-identical by construction; tests/test_serving_paged.py pins
 # exactly that. Block 0 is the permanently-zero SENTINEL: unallocated
 # table entries point at it, inactive slots' dead decode writes land in
 # it, and every scatter re-zeroes it in the same program.

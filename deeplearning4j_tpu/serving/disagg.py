@@ -14,7 +14,7 @@ The frame is deliberately dumb: a fixed magic + version + JSON header
 (model-config hash, token ids, layout, per-leaf dtype/shape specs)
 followed by the raw array bytes, concatenated in header order. No
 compression, no chunking — dtype/shape round-trip EXACTNESS is the
-contract (the engine's disagg parity probe moves a segment through
+contract (``tests/test_serving_disagg.py`` moves a segment through
 ``encode_segment``/``decode_segment`` and asserts the seated state is
 bitwise identical to a local prefill), and raw bytes are the shortest
 path to that. int8 segments ship their f32 scale planes as ordinary

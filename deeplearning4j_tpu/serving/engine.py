@@ -97,12 +97,10 @@ supervises itself:
     slot instead of O(tokens)): re-prefill ``prompt + tokens_so_far``
     in one pass through the bucketed/chunked prefill path. The
     prefill-path logits can differ from the decode-path logits in the
-    last float bit (different XLA schedules), so ``chunked_replay=
-    "auto"`` runs a one-time parity probe at first recovery —
-    full-sequence prefill vs prefill+teacher-forcing on a synthetic
-    sequence — and only enables chunked replay when they agree
-    bitwise; otherwise it falls back to stepwise. ``True``/``False``
-    force a mode (``tests/test_serving_faults.py`` covers both).
+    last float bit (different XLA schedules), so it is what recovery
+    does only when asked: ``chunked_replay=True``
+    (``tests/test_serving_faults.py`` covers both modes;
+    ``tests/test_serving_schedules.py`` holds the two to a tolerance).
 
 Request lifecycle: ``Request.deadline_s`` and ``Request.cancel()`` are
 checked at every horizon boundary; a timed-out or cancelled request is
@@ -118,7 +116,6 @@ from __future__ import annotations
 
 import dataclasses
 import logging
-import os
 import threading
 import time
 from collections import deque
@@ -160,8 +157,6 @@ from deeplearning4j_tpu.obs.trace import (
 from deeplearning4j_tpu.serving.cache_pool import KVSlotPool, PagedKVPool
 from deeplearning4j_tpu.serving.disagg import (
     WireError,
-    decode_segment,
-    encode_segment,
     model_config_hash,
     slab_to_blocks,
 )
@@ -184,7 +179,6 @@ from deeplearning4j_tpu.serving.grammar import (
 )
 from deeplearning4j_tpu.serving.metrics import ServingMetrics
 from deeplearning4j_tpu.serving.prefix_cache import PrefixCache, Segment
-from deeplearning4j_tpu.serving.probe_cache import ProbeCache, probe_key
 from deeplearning4j_tpu.serving.scheduler import (
     AdmissionError,
     Backpressure,
@@ -437,8 +431,8 @@ def build_piggyback_program(fwd1, fwd_chunk, horizon: int,
     ``build_step_program`` body verbatim; the chunk leg is the
     ``build_chunk_program`` body verbatim, over the admitting slot's
     OWN batch-1 scratch cache — the two legs share no buffers, so
-    fusing them cannot perturb either side's numerics (the
-    construction-time piggyback parity probe proves it bitwise)."""
+    fusing them cannot perturb either side's numerics
+    (``tests/test_serving_schedules.py`` compares the two bitwise)."""
 
     def pstep(params, caches, logits, pos, active, budget, eos,
               slot_keys_raw, adapters, tmp, ctoks, cpos0, clast,
@@ -518,8 +512,8 @@ def _masked_draw(logits, pos, active, gstate, keys, temps, top_ks,
     select. Every per-request control sits behind a ``jnp.where`` at
     its neutral value (state 0, no bias rows, k=0, p=1, engine
     temperature) so a slot using none of them reproduces the base
-    step program's token stream bitwise — the construction-time
-    masked-parity probe gates exactly that.
+    step program's token stream bitwise
+    (``tests/test_serving_schedules.py`` compares the two programs).
 
     Returns ``(toks, aux)`` where ``aux`` is the packed int32 per-slot
     row ``[tok, bitcast(chosen logprob), top ids..., bitcast(top
@@ -1098,8 +1092,12 @@ class ServingEngine:
 
     ``prefill_max_bucket`` caps the power-of-two prompt padding bucket;
     longer prompts are chunked through the same buckets.
-    ``chunked_replay`` picks the crash-replay mode ("auto" probes for
-    bitwise prefill/decode parity at first recovery; see module doc).
+    ``chunked_replay`` picks the crash-replay mode (see module doc).
+
+    The engine does what its arguments say or raises at construction,
+    naming what is missing: no feature is switched off at run time.
+    Whether a schedule is correct is decided by a test (tier-1 on
+    XLA:CPU, ``chip_smoke.py --legs features`` on the chip).
 
     Supervision knobs: ``faults`` (an optional
     :class:`~.faults.FaultInjector`), ``max_retries`` transient retries
@@ -1135,8 +1133,8 @@ class ServingEngine:
         decode_horizon: int = 1,
         adaptive_horizon: bool = False,
         prefill_max_bucket: int = 128,
-        chunked_replay: bool | str = "auto",
-        batch_admission: bool | str = "auto",
+        chunked_replay: bool = False,
+        batch_admission: bool = True,
         prefix_cache: bool = False,
         prefix_cache_tokens: int | None = None,
         prefix_affinity_tokens: int = 0,
@@ -1152,20 +1150,14 @@ class ServingEngine:
         flight: FlightRecorder | None = None,
         profile: ProfileTrigger | None = None,
         tp: int = 1,
-        tp_parity: bool | str = "auto",
-        probe_cache: str | ProbeCache | None = None,
         lora_bank=None,
-        lora_parity: bool | str = "auto",
         tenancy=None,
         embedders=None,
         paged: bool = False,
         block_size: int | None = None,
-        paged_parity: bool | str = "auto",
         piggyback: bool = False,
         prefill_budget: int | None = None,
-        piggyback_parity: bool | str = "auto",
         sampling_surface: bool = False,
-        masked_parity: bool | str = "auto",
         grammar_states: int = 256,
         grammar_cache: str | GrammarCache | None = None,
     ):
@@ -1193,12 +1185,19 @@ class ServingEngine:
                         f"{what} is not built for a stack of gated layers "
                         f"(layer_types set): {lacks}"
                     )
+        for name, asked in (("chunked_replay", chunked_replay),
+                            ("batch_admission", batch_admission)):
+            if not isinstance(asked, bool):
+                raise ValueError(f"{name} is True or False, got {asked!r}")
+        if sampling_surface and approx_top_k:
+            raise ValueError(
+                "sampling_surface=True cannot be served with "
+                "approx_top_k=True: approx_max_k has no variant with a "
+                "traced k a slot and the exact filter's tie semantics"
+            )
         # programs dispatched while this is non-zero are not traffic
-        # and stay out of metrics.program_dispatches: construction and
-        # its parity probes (released at the END of __init__), runtime
-        # probes, recovery replay. Same "probes don't count" contract
-        # as prefill_dispatches.
-        self._uncounted = 1
+        # and stay out of metrics.program_dispatches: recovery replay
+        self._uncounted = 0
         # the process's compile log, installed before anything below
         # compiles; _compiles_seen is its request count at mark_warm()
         self._compile_log = compile_log.install()
@@ -1207,20 +1206,8 @@ class ServingEngine:
         # per horizon/admission — postmortems must exist BEFORE the
         # incident, so this is not opt-in like the tracer)
         self.flight = flight if flight is not None else FlightRecorder()
-        # parity-probe verdict persistence (per config x backend x
-        # program geometry): repeated engine instances — replica
-        # fleets, restarts, tests — skip the cold-start probe
-        # dispatches entirely. probes_run / probes_from_cache record
-        # which probes actually dispatched this instance.
-        # DL4J_TPU_PROBE_CACHE supplies a default path for library
-        # construction sites that don't thread the kwarg (the CLI
-        # passes its own --probe-cache); an explicit kwarg wins.
-        if probe_cache is None:
-            probe_cache = os.environ.get("DL4J_TPU_PROBE_CACHE") or None
-        self._probe_cache = (
-            probe_cache if isinstance(probe_cache, ProbeCache)
-            else ProbeCache(probe_cache) if probe_cache else None
-        )
+        # always empty: benchmark/serve.py's warm-up note still prints
+        # the two, and that file is a `benchmark` PR's to edit
         self.probes_run: list[str] = []
         self.probes_from_cache: list[str] = []
         # batched LoRA: the adapter bank (init_lora_bank pytree) rides
@@ -1229,13 +1216,11 @@ class ServingEngine:
         # every adapter mix (no per-adapter program families). Row 0 is
         # the zero adapter — the forward SELECTS the untouched base
         # activations for it (jnp.where, not +0.0), so adapter-0 output
-        # is bitwise the base model; lora_parity "auto" probes exactly
-        # that once (verdict persisted via probe_cache) and drops the
-        # bank on mismatch, as tp_parity falls back to tp=1.
-        self.lora_bank = None
+        # is the base model's (tests/test_serving_schedules.py: bitwise
+        # on XLA:CPU; a rounding apart on the v5e, PERF.md PR 29).
+        self.lora_bank = lora_bank
         self.n_adapters = 0
-        if lora_bank is not None and lora_parity is not False:
-            self.lora_bank = lora_bank
+        if lora_bank is not None:
             self.n_adapters = int(
                 jax.tree.leaves(lora_bank)[0].shape[1]
             )
@@ -1255,36 +1240,18 @@ class ServingEngine:
         # tensor parallelism: resolve the mesh BEFORE anything compiles.
         # tp > 1 shards the whole hot path — params per
         # serving_tp_shardings (exact head/column layout), the KV pool
-        # and prefix region per serving_tp_cache_sharding — behind the
-        # standing byte-parity bar: tp_parity "auto" probes the sharded
-        # programs bitwise against the single-chip ones once (verdict
-        # persisted via probe_cache) and falls back to tp=1 on
-        # mismatch, exactly as chunked_replay "auto" falls back to
-        # stepwise. True trusts the layout (skips the probe — the
-        # escape hatch when the model doesn't FIT on one chip, which is
-        # the point of TP); False forces single-chip.
+        # and prefix region per serving_tp_cache_sharding. A process
+        # with fewer than tp devices, or heads tp does not divide, is a
+        # ValueError here.
         self.tp = max(1, int(tp))
         self.tp_mesh = None
         if self.tp > 1:
-            if tp_parity is False:
-                self.tp = 1
-            else:
-                if cfg.decode_kernel:
-                    # the Pallas decode kernel is a custom call GSPMD
-                    # cannot partition; the dense fallback is the same
-                    # numerics (see block_decode)
-                    cfg = dataclasses.replace(cfg, decode_kernel=False)
-                mesh = model_parallel_mesh(self.tp)
-                ok = True if tp_parity is True else self._probe_verdict(
-                    "tp_parity",
-                    lambda: self._probe_tp_parity(cfg, params, mesh),
-                    cfg=cfg, tp=self.tp, max_total=self.max_total,
-                )
-                if ok:
-                    self.tp_mesh = mesh
-                else:
-                    log_event(_log, "tp_parity_probe_failed", tp=self.tp)
-                    self.tp = 1
+            if cfg.decode_kernel:
+                # the Pallas decode kernel is a custom call GSPMD
+                # cannot partition; the dense fallback is the same
+                # numerics (see block_decode)
+                cfg = dataclasses.replace(cfg, decode_kernel=False)
+            self.tp_mesh = model_parallel_mesh(self.tp)
         self.cfg = cfg
         self.temperature = temperature
         self.top_k = top_k
@@ -1348,55 +1315,26 @@ class ServingEngine:
                 (self._cfg_key, self.tp, "cast_params"),
                 lambda: jax.jit(cast_params),
             )(params)
-        if self.lora_bank is not None and lora_parity is not True:
-            ok = self._probe_verdict(
-                "lora_zero", self._probe_lora_zero,
-                n_adapters=self.n_adapters, tp=self.tp,
-                max_total=self.max_total,
-            )
-            if not ok:
-                # serve base-only rather than risk perturbing adapter-0
-                # traffic (cfg.decode_kernel stays off — same numerics,
-                # see block_decode)
-                log_event(_log, "lora_parity_probe_failed",
-                          n_adapters=self.n_adapters)
-                self.params = {
-                    k: v for k, v in self.params.items() if k != "lora"
-                }
-                self.lora_bank = None
-                self.n_adapters = 0
 
         # block-paged KV: the pool becomes a shared store of fixed-size
         # blocks with per-slot int32 block tables (vLLM-style), so
         # long-prompt traffic allocates ceil((prompt+max_new)/bs)
         # blocks instead of a full Tpad slab and cached prefixes are
-        # byte-SHARED by table aliasing. Behind the standing parity
-        # bar: paged_parity "auto" probes the paged step bitwise
-        # against the slab step once (verdict persisted via
-        # probe_cache, like tp_parity) and falls back to the slab
-        # layout on mismatch; True trusts the layout, False disables.
-        self._paged = False
+        # byte-SHARED by table aliasing. The paged step gathers a slab
+        # view, runs the slab compute and scatters back, so logits
+        # are the slab pool's (tests/test_serving_schedules.py).
+        self._paged = bool(paged)
         self._block_size = int(block_size or 8)
-        if paged and paged_parity is not False:
+        if self._paged:
             tpad = full_cache_leaf(jax.eval_shape(
                 lambda: self._init_caches(1, self.max_total)
             )).shape[3]
             if tpad % self._block_size:
-                log_event(_log, "paged_disabled_bad_block_size",
-                          block_size=self._block_size, tpad=tpad)
-            else:
-                ok = True if paged_parity is True else self._probe_verdict(
-                    "paged_parity",
-                    lambda: self._probe_paged_parity(self._block_size),
-                    cfg=cfg, block_size=self._block_size,
-                    n_slots=n_slots, max_total=self.max_total,
-                    tpad=tpad, tp=self.tp,
+                raise ValueError(
+                    f"block_size={self._block_size} does not divide the "
+                    f"{tpad} cache rows a slot of max_total="
+                    f"{self.max_total} pads to"
                 )
-                if ok:
-                    self._paged = True
-                else:
-                    log_event(_log, "paged_parity_probe_failed",
-                              block_size=self._block_size)
 
         pool_sharding = (serving_tp_cache_sharding(self.tp_mesh, cfg)
                          if self.tp_mesh is not None else None)
@@ -1457,11 +1395,10 @@ class ServingEngine:
         # dispatch itself. Default budget 2x the largest bucket: one
         # standalone chunk + one fused chunk per horizon, so a
         # deferred prompt always makes >= _max_bucket progress while
-        # decode keeps stepping. The path arms only after the
-        # construction-time parity probe below proves the fused
-        # program bitwise-identical to step + chunk run separately.
-        self._piggyback_requested = bool(piggyback)
-        self._piggyback = False
+        # decode keeps stepping. The fused program's two legs share
+        # no buffers, so it is step + chunk run separately, bit for
+        # bit (tests/test_serving_schedules.py).
+        self._piggyback = bool(piggyback)
         self.prefill_budget = max(1, int(
             prefill_budget if prefill_budget is not None
             else 2 * self._max_bucket
@@ -1474,10 +1411,9 @@ class ServingEngine:
         # the pool's slab layout (see serving.prefix_cache). Partial
         # hits are rounded DOWN to the bucket grain (_min_bucket) so
         # every suffix chunk window starts sublane-aligned and provably
-        # fits Tpad. Hit-path reuse is gated by a one-time bitwise
-        # parity probe (_prefix_reuse_ok), mirroring chunked_replay
-        # "auto": when the probe fails, every lookup is treated as a
-        # miss and admission falls back to the full prefill path.
+        # fits Tpad. A hit's suffix is chunk-computed where a miss is
+        # one prefill: another order of the same arithmetic, held to a
+        # tolerance in tests/test_serving_schedules.py.
         self.prefix_cache: PrefixCache | None = None
         if prefix_cache:
             self.prefix_cache = PrefixCache(
@@ -1535,15 +1471,11 @@ class ServingEngine:
         self._steps = 0
         self._admitting = 0  # requests between scheduler pop and slot
         self.last_dispatch_t: float | None = None  # watchdog heartbeat
-        self._chunked_ok: bool | None = None  # replay parity probe memo
-        self._prefix_ok_memo: bool | None = None  # hit-path parity memo
-        self._batch_ok_memo: bool | None = None   # batched-path memo
-        self._disagg_ok_memo: bool | None = None  # wire seat-path memo
         self.last_recover_mode: str | None = None
         # programs that COMPUTE prompt rows (bucketed prefill, chunk
         # windows, batched prefill groups) — a pure-copy admission
         # (full prefix hit: segment slab + stored logits) dispatches
-        # none, which tests assert on. Probes do not count.
+        # none, which tests assert on.
         self.prefill_dispatches = 0
 
         # donating the cache + per-slot state lets XLA update them in
@@ -1596,8 +1528,7 @@ class ServingEngine:
         self._logit_row_fn = None
         self._admit_donate = PROGRAM_DONATION["prefill"]
         # paged program caches. The SLAB prefill/insert/chunk caches
-        # above stay live in paged mode too: the parity probes run the
-        # slab programs on scratch state, and the chunked partial-hit
+        # above stay live in paged mode too: the chunked partial-hit
         # path computes suffix windows on batch-1 slab scratch in both
         # modes.
         self._paged_prefill_fns: dict[int, object] = {}
@@ -1607,44 +1538,16 @@ class ServingEngine:
         self._block_copy_fn = None
         self._paged_admit_donate = PROGRAM_DONATION["paged_prefill"]
         # chunked-prefill piggyback: one fused program per (bucket, K)
-        # actually used, gated by a construction-time bitwise parity
-        # probe (ProbeCache'd) — probe failure falls back to blocking
-        # admission prefill, never to wrong bytes
+        # actually used
         self._piggyback_fns: dict[tuple[int, int], object] = {}
-        if self._piggyback_requested and piggyback_parity is not False:
-            ok = (
-                True if piggyback_parity is True
-                else self._probe_verdict(
-                    "piggyback_parity",
-                    self._probe_piggyback_parity,
-                    n_slots=self.n_slots,
-                    max_total=self.max_total,
-                    max_bucket=self._max_bucket,
-                    tp=self.tp,
-                    paged=self._paged,
-                    temperature=self.temperature,
-                    top_k=self.top_k,
-                    horizon=self.decode_horizon,
-                )
-            )
-            if ok:
-                self._piggyback = True
-            else:
-                log_event(
-                    _log, "piggyback_parity_probe_failed",
-                    fallback="blocking admission prefill",
-                )
 
         # grammar-constrained decoding + per-request sampling surface:
         # per-slot FSM state / temperature / top-k / top-p / logit-bias
         # vectors threaded through masked step variants as traced data
-        # (the adapter-id idiom, one compiled family for every mix),
-        # behind the standing bitwise bar — masked_parity "auto" probes
-        # the masked program against the base step on neutral surface
-        # state once (ProbeCache'd) and leaves the surface off on
-        # mismatch, so base traffic can never be perturbed.
-        self._surface_requested = bool(sampling_surface)
-        self._surface = False
+        # (the adapter-id idiom, one compiled family for every mix).
+        # On neutral surface state the masked step is the base step,
+        # bit for bit (tests/test_serving_schedules.py).
+        self._surface = bool(sampling_surface)
         self._gtable: GrammarTable | None = None
         self.grammar_cache: GrammarCache | None = None
         self._masked_step_fns: dict[int, object] = {}
@@ -1676,56 +1579,20 @@ class ServingEngine:
             (n_slots, MAX_LOGIT_BIAS), np.float32
         )
         self._dgstate = jnp.zeros((n_slots,), jnp.int32)
-        if self._surface_requested and masked_parity is not False:
-            if self.approx_top_k:
-                # approx_max_k has no traced-k variant with identical
-                # tie semantics, so the parity bar is unmeetable;
-                # surface requests are rejected at submit instead
-                log_event(_log, "sampling_surface_disabled",
-                          reason="approx_top_k")
-            else:
-                self._gtable = GrammarTable(
-                    max(2, int(grammar_states)), cfg.vocab_size
-                )
-                ok = (
-                    True if masked_parity is True
-                    else self._probe_verdict(
-                        "masked_parity",
-                        self._probe_masked_parity,
-                        n_slots=self.n_slots,
-                        max_total=self.max_total,
-                        max_bucket=self._max_bucket,
-                        tp=self.tp,
-                        paged=self._paged,
-                        piggyback=self._piggyback,
-                        temperature=self.temperature,
-                        top_k=self.top_k,
-                        horizon=self.decode_horizon,
-                        grammar_states=self._gtable.capacity,
-                        n_logprobs=self._n_logprobs,
-                    )
-                )
-                if ok:
-                    self._surface = True
-                    self.grammar_cache = (
-                        grammar_cache
-                        if isinstance(grammar_cache, GrammarCache)
-                        else GrammarCache(grammar_cache)
-                    )
-                    self.metrics.registry.gauge(
-                        "serve_grammar_table_rows",
-                        "Grammar DFA table rows in use (incl. the "
-                        "unconstrained sentinel row).",
-                    ).set_function(lambda: self._gtable.rows_used)
-                else:
-                    self._gtable = None
-                    log_event(
-                        _log, "masked_parity_probe_failed",
-                        fallback="sampling surface disabled",
-                    )
-        # count programs from here on: everything dispatched above was
-        # a probe
-        self._uncounted -= 1
+        if self._surface:
+            self._gtable = GrammarTable(
+                max(2, int(grammar_states)), cfg.vocab_size
+            )
+            self.grammar_cache = (
+                grammar_cache
+                if isinstance(grammar_cache, GrammarCache)
+                else GrammarCache(grammar_cache)
+            )
+            self.metrics.registry.gauge(
+                "serve_grammar_table_rows",
+                "Grammar DFA table rows in use (incl. the "
+                "unconstrained sentinel row).",
+            ).set_function(lambda: self._gtable.rows_used)
 
     def _count_program(self, family: str) -> None:
         """One traffic dispatch of a compiled program family."""
@@ -1833,7 +1700,7 @@ class ServingEngine:
             "(shrinks to 1 under adaptive_horizon while the queue is "
             "non-empty).",
         ).set_function(lambda: self.decode_horizon_current)
-        if self._piggyback_requested:
+        if self._piggyback:
             reg.gauge(
                 "serve_prefill_budget_tokens",
                 "Chunk tokens the piggyback scheduler may spend per "
@@ -2709,10 +2576,10 @@ class ServingEngine:
     # validate the wire-decoded slab against its own cache geometry,
     # land it in the prefix cache (region import in slab mode, private
     # block scatter in paged mode), and let the follow-up generate
-    # full-hit — zero prefill dispatched for the covered prompt. Both
-    # paths are gated by the disagg parity probe (_disagg_ok), and
-    # every ingest decline is SOFT: the sender falls back to local
-    # prefill, which is byte-identical anyway.
+    # full-hit — zero prefill dispatched for the covered prompt. The
+    # wire moves bytes and computes nothing
+    # (tests/test_serving_disagg.py). An ingest that validation
+    # declines is SOFT: the sender falls back to local prefill.
 
     def _serve_kv_export(self, req, now: float) -> None:
         """Serve a :class:`KVExportRequest` at the admission boundary.
@@ -2723,12 +2590,6 @@ class ServingEngine:
         t0 = time.perf_counter()
         seq = np.asarray(req.prompt, np.int32)
         n = int(len(seq))
-        if not self._disagg_ok():
-            self._retire_unadmitted(
-                req, RequestStatus.FAILED,
-                "disagg wire parity probe failed on this backend",
-            )
-            return
         if n + 1 > self.max_total or n > self.pool.tpad:
             self._retire_unadmitted(
                 req, RequestStatus.FAILED,
@@ -2811,8 +2672,8 @@ class ServingEngine:
         dict) in the prefix cache so the follow-up generate request
         full-hits. Slotless and SOFT-failing: every decline reports
         ``{"stored": False, "reason": ...}`` and the sender falls back
-        to local prefill — byte-identical by the parity bar, so a
-        decline costs latency, never correctness."""
+        to local prefill, so a decline costs latency, never
+        correctness."""
         t0 = time.perf_counter()
         seg_data = req.segment
         tokens = np.asarray(seg_data["tokens"], np.int32)
@@ -2826,8 +2687,6 @@ class ServingEngine:
         elif n < self._hit_grain or n > self.pool.tpad:
             reason = (f"segment of {n} tokens not seatable "
                       f"(grain={self._hit_grain}, tpad={self.pool.tpad})")
-        elif not (self._prefix_reuse_ok() and self._disagg_ok()):
-            reason = "parity probes reject wire seating on this backend"
         stored = False
         if reason is None:
             try:
@@ -2959,8 +2818,8 @@ class ServingEngine:
         a fresh slot mid-generation. The wire slab covers rows
         [0, prompt+generated); seating it with pos0 = that length and
         budget = remaining is EXACTLY the full-hit insert of a
-        seq-so-far segment — an existing, parity-probed program family
-        — after which the ordinary decode loop continues the stream.
+        seq-so-far segment — an existing program family — after which
+        the ordinary decode loop continues the stream.
         The migrated sampling-key words are installed verbatim so
         fold_in(key, position) draws the same randomness the source
         would have: byte-identical continuation, greedy AND sampled.
@@ -2979,8 +2838,6 @@ class ServingEngine:
         reason = None
         if seg_data.get("config_hash") != self.config_hash:
             reason = "model config hash mismatch"
-        elif not self._disagg_ok():
-            reason = "disagg wire parity probe failed on this backend"
         elif int(len(seg_data["tokens"])) != m:
             reason = (f"frame covers {len(seg_data['tokens'])} tokens, "
                       f"session claims prompt {n0} + generated {g}")
@@ -3126,7 +2983,7 @@ class ServingEngine:
                             adapter: int = 0, paged: bool = False):
         """Land ``seq`` in ``slot`` of a pool-shaped ``state`` tuple
         through the bucketed prefill path and return the new state
-        (pure w.r.t. engine attributes — the parity probes run it on
+        (pure w.r.t. engine attributes — the schedule tests run it on
         scratch state). Dispatches O(1) programs for bucket-sized
         sequences and O(len/bucket) on the chunked long-prompt path.
         ``adapter`` selects the LoRA bank row (traced data, so every
@@ -3134,8 +2991,7 @@ class ServingEngine:
         ``paged`` the state's caches are the {"blocks", "tables"} dict
         and the two landing dispatches switch to the paged programs —
         everything else (bucketing, chunk windows, the batch-1 scratch
-        compute) is byte-for-byte the slab path, which is what keeps
-        the slab parity probes valid in a paged engine."""
+        compute) is byte-for-byte the slab path."""
         n = int(len(seq))
         ad = jnp.asarray([adapter], jnp.int32)
         insert = self._paged_insert() if paged else self._insert()
@@ -3265,710 +3121,6 @@ class ServingEngine:
                 req.error = str(e)
                 return False
 
-    # -- admission parity probes -------------------------------------------
-
-    def _scratch_state(self):
-        """A pool-shaped device state tuple over freshly zeroed scratch
-        buffers. The parity probes run the PRODUCTION compiled programs
-        on it — so probing never touches live pool state (unlike the
-        recovery-time chunked-replay probe, which runs on abandoned
-        buffers) and compiles nothing the serving path won't reuse."""
-        return (
-            self._init_caches(self.n_slots, self.max_total),
-            jnp.zeros((self.n_slots, self.cfg.vocab_size), jnp.float32),
-            jnp.zeros((self.n_slots,), jnp.int32),
-            jnp.zeros((self.n_slots,), bool),
-            jnp.zeros((self.n_slots,), jnp.int32),
-            jnp.full((self.n_slots,), _NO_EOS, jnp.int32),
-        )
-
-    @staticmethod
-    def _states_equal(x, y) -> bool:
-        return all(
-            np.array_equal(np.asarray(a), np.asarray(b))
-            for a, b in zip(jax.tree.leaves(x), jax.tree.leaves(y))
-        )
-
-    @staticmethod
-    def _slot_rows(caches, slot: int, n: int):
-        return [np.asarray(leaf[:, :, slot, :n])
-                for leaf in jax.tree.leaves(caches)]
-
-    def _probe_prefix_parity(self) -> bool:
-        """One-time probe gating hit-path reuse (the admission-side
-        mirror of ``chunked_replay="auto"``): is copy-cached-prefix-
-        rows + chunk-computed suffix bitwise identical — KV rows AND
-        logits — to the full bucketed prefill? On backends where the
-        differently-scheduled programs agree only to float-
-        reassociation level, every lookup is treated as a miss and
-        admission falls back to full prefill."""
-        L = self._min_bucket
-        n = min(L + 3, self.max_total, self.pool.tpad)
-        if n <= L:
-            return False
-        _disp = self.prefill_dispatches  # probes don't count
-        self._uncounted += 1  # nor toward program_dispatches
-        try:
-            seq = ((1 + np.arange(n)) % self.cfg.vocab_size).astype(
-                np.int32
-            )
-            # miss path: the full bucketed prefill
-            sa = self._prefill_into_state(
-                self._scratch_state(), seq, 0, 1, _NO_EOS
-            )
-            rows_a = self._slot_rows(sa[0], 0, n)
-            lg_a = np.asarray(sa[1][0])
-            # build the segment exactly as insert-on-completion does
-            sb = self._prefill_into_state(
-                self._scratch_state(), seq[:L], 0, 1, _NO_EOS
-            )
-            region = self.pool.alloc_region(1)
-            region = self._seg_store()(
-                region, sb[0], jnp.int32(0), jnp.int32(0)
-            )
-            # hit path: fetch + suffix chunks + insert
-            tmp = self._seg_fetch()(region, jnp.int32(0))
-            lg = None
-            for t0, ln, b in self._chunk_schedule(n, start=L):
-                pad = np.zeros((1, b), np.int32)
-                pad[0, :ln] = seq[t0:t0 + ln]
-                tmp, lg = self._chunk_fn(b)(
-                    self.params, tmp, jnp.asarray(pad), jnp.int32(t0),
-                    jnp.int32(ln - 1), jnp.zeros((1,), jnp.int32),
-                )
-            sc = self._insert()(
-                *self._scratch_state(), tmp, lg, jnp.int32(0),
-                jnp.int32(n), jnp.int32(1), jnp.int32(_NO_EOS),
-            )
-            rows_c = self._slot_rows(sc[0], 0, n)
-            lg_c = np.asarray(sc[1][0])
-            return bool(
-                np.array_equal(lg_a, lg_c)
-                and all(np.array_equal(a, c)
-                        for a, c in zip(rows_a, rows_c))
-            )
-        finally:
-            self.prefill_dispatches = _disp
-            self._uncounted -= 1
-
-    def _probe_piggyback_parity(self) -> bool:
-        """One-time probe gating the piggyback path: does the FUSED
-        chunk+decode program reproduce, bitwise, what the production
-        step program and chunk program produce when run separately
-        over identical inputs — every decode-state leaf, the sampled
-        token matrix, the scratch slab, and the chunk logits row? The
-        legs share no buffers, so this holds by construction unless
-        the backend schedules the fused graph differently; when it
-        does not hold bitwise, piggyback stays off and admission
-        prefill keeps blocking (slow, never wrong)."""
-        b = self._max_bucket
-        k = self.decode_horizon
-        n = self.n_slots
-        vs = self.cfg.vocab_size
-        _disp = self.prefill_dispatches  # probes don't count
-        self._uncounted += 1  # nor toward program_dispatches
-        try:
-            def caches0():
-                if self._paged:
-                    # sentinel-only tables: same avals as the live
-                    # operand (no new compile surface), every row
-                    # scatters to block 0 identically on both sides
-                    return {
-                        "blocks": jax.tree.map(
-                            jnp.zeros_like, self.pool.caches
-                        ),
-                        "tables": jnp.zeros(
-                            (n, self.pool.blocks_per_slot), jnp.int32
-                        ),
-                    }
-                return self._init_caches(n, self.max_total)
-
-            def decode_state():
-                # donation safety: each side gets fresh buffers
-                lg = (
-                    jnp.arange(n * vs, dtype=jnp.float32)
-                    .reshape(n, vs) % 7.0
-                )
-                return (
-                    caches0(), lg,
-                    jnp.arange(n, dtype=jnp.int32) % 3,
-                    jnp.ones((n,), bool),
-                    jnp.full((n,), 5, jnp.int32),
-                    jnp.full((n,), _NO_EOS, jnp.int32),
-                )
-
-            keys = np.arange(
-                self._slot_keys.size, dtype=self._slot_keys.dtype
-            ).reshape(self._slot_keys.shape)
-            ad = jnp.zeros((n,), jnp.int32)
-            ctoks = jnp.asarray(
-                ((1 + np.arange(b)) % vs).astype(np.int32)[None, :]
-            )
-            cad = jnp.zeros((1,), jnp.int32)
-            # separate: the production step + chunk programs
-            out_a = self._step_fn_for(k)(
-                self.params, *decode_state(), jnp.asarray(keys), ad
-            )
-            tmp_a, lg_a = self._chunk_fn(b)(
-                self.params, self._init_caches(1, self.max_total),
-                ctoks, jnp.int32(0), jnp.int32(b - 1), cad,
-            )
-            # fused: one piggyback dispatch over identical inputs
-            out_b = self._piggyback_fn(b, k)(
-                self.params, *decode_state(), jnp.asarray(keys), ad,
-                self._init_caches(1, self.max_total), ctoks,
-                jnp.int32(0), jnp.int32(b - 1), cad,
-            )
-            return bool(
-                self._states_equal(out_a, out_b[:6])
-                and self._states_equal(tmp_a, out_b[6])
-                and np.array_equal(np.asarray(lg_a),
-                                   np.asarray(out_b[7]))
-            )
-        finally:
-            self.prefill_dispatches = _disp
-            self._uncounted -= 1
-
-    def _probe_masked_parity(self) -> bool:
-        """One-time probe gating the sampling surface: does the MASKED
-        step program — grammar mask, logit bias, per-slot temperature/
-        top-k/top-p, logprob gathers all folded behind jnp.where at
-        their neutral values — reproduce, bitwise, the production step
-        program over identical inputs? Every decode-state leaf and the
-        token matrix must match, and the FSM state vector must hold at
-        the unconstrained sentinel. When piggyback is armed the masked
-        piggyback variant is held to the same bar against the plain
-        one. Failure leaves the surface off: base traffic keeps its
-        exact bytes and surface requests 400 at submit (never wrong,
-        just absent)."""
-        k = self.decode_horizon
-        n = self.n_slots
-        vs = self.cfg.vocab_size
-        _disp = self.prefill_dispatches  # probes don't count
-        self._uncounted += 1  # nor toward program_dispatches
-        try:
-            def caches0():
-                if self._paged:
-                    return {
-                        "blocks": jax.tree.map(
-                            jnp.zeros_like, self.pool.caches
-                        ),
-                        "tables": jnp.zeros(
-                            (n, self.pool.blocks_per_slot), jnp.int32
-                        ),
-                    }
-                return self._init_caches(n, self.max_total)
-
-            def decode_state():
-                # donation safety: each side gets fresh buffers
-                lg = (
-                    jnp.arange(n * vs, dtype=jnp.float32)
-                    .reshape(n, vs) % 7.0
-                )
-                return (
-                    caches0(), lg,
-                    jnp.arange(n, dtype=jnp.int32) % 3,
-                    jnp.ones((n,), bool),
-                    jnp.full((n,), 5, jnp.int32),
-                    jnp.full((n,), _NO_EOS, jnp.int32),
-                )
-
-            keys = np.arange(
-                self._slot_keys.size, dtype=self._slot_keys.dtype
-            ).reshape(self._slot_keys.shape)
-            ad = jnp.zeros((n,), jnp.int32)
-            # neutral surface vectors: the exact values _seat_surface
-            # writes for a request that sets nothing
-            temps = jnp.full((n,), self.temperature, jnp.float32)
-            topks = jnp.full((n,), int(self.top_k or 0), jnp.int32)
-            topps = jnp.ones((n,), jnp.float32)
-            bidx = jnp.full((n, MAX_LOGIT_BIAS), -1, jnp.int32)
-            bval = jnp.zeros((n, MAX_LOGIT_BIAS), jnp.float32)
-
-            def gstate():
-                # donated by the masked programs: fresh per call
-                return jnp.zeros((n,), jnp.int32)
-
-            mask_tab, trans_tab = self._grammar_device_tables()
-            out_a = self._step_fn_for(k)(
-                self.params, *decode_state(), jnp.asarray(keys), ad
-            )
-            out_b = self._masked_step_fn_for(k)(
-                self.params, *decode_state(), gstate(),
-                jnp.asarray(keys), ad, temps, topks, topps, bidx,
-                bval, mask_tab, trans_tab,
-            )
-            ok = bool(
-                self._states_equal(out_a[:5], out_b[:5])
-                and np.array_equal(np.asarray(out_a[5]),
-                                   np.asarray(out_b[6][:, :, 0]))
-                and np.array_equal(np.asarray(out_b[5]),
-                                   np.zeros((n,), np.int32))
-            )
-            if ok and self._piggyback:
-                b = self._max_bucket
-                ctoks = jnp.asarray(
-                    ((1 + np.arange(b)) % vs).astype(np.int32)[None, :]
-                )
-                cad = jnp.zeros((1,), jnp.int32)
-                out_c = self._piggyback_fn(b, k)(
-                    self.params, *decode_state(), jnp.asarray(keys),
-                    ad, self._init_caches(1, self.max_total), ctoks,
-                    jnp.int32(0), jnp.int32(b - 1), cad,
-                )
-                out_d = self._masked_piggyback_fn(b, k)(
-                    self.params, *decode_state(), gstate(),
-                    jnp.asarray(keys), ad, temps, topks, topps, bidx,
-                    bval, mask_tab, trans_tab,
-                    self._init_caches(1, self.max_total), ctoks,
-                    jnp.int32(0), jnp.int32(b - 1), cad,
-                )
-                ok = bool(
-                    self._states_equal(out_c[:5], out_d[:5])
-                    and np.array_equal(np.asarray(out_c[5]),
-                                       np.asarray(out_d[6][:, :, 0]))
-                    and self._states_equal(out_c[6], out_d[7])
-                    and np.array_equal(np.asarray(out_c[7]),
-                                       np.asarray(out_d[8]))
-                )
-            return ok
-        finally:
-            self.prefill_dispatches = _disp
-            self._uncounted -= 1
-
-    def _probe_batch_parity(self) -> bool:
-        """One-time probe gating batched admission: do the batched
-        same-bucket prefill program (vector last_idx) and — when the
-        prefix cache reuses — the batched partial-hit program
-        reproduce, bitwise, the full device state the serial
-        per-request paths produce?"""
-        if self.n_slots < 2:
-            return False
-        n0 = min(self._min_bucket, self.max_total)
-        if n0 < 2:
-            return False
-        n1 = n0 - 1
-        b = self._bucket_for(n0)
-        _disp = self.prefill_dispatches  # probes don't count
-        self._uncounted += 1  # nor toward program_dispatches
-        try:
-            vs = self.cfg.vocab_size
-            seq0 = ((1 + np.arange(n0)) % vs).astype(np.int32)
-            seq1 = ((2 + np.arange(n1)) % vs).astype(np.int32)
-            sa = self._prefill_into_state(
-                self._scratch_state(), seq0, 0, 3, _NO_EOS
-            )
-            sa = self._prefill_into_state(sa, seq1, 1, 2, _NO_EOS)
-            prompts = np.zeros((2, b), np.int32)
-            prompts[0, :n0] = seq0
-            prompts[1, :n1] = seq1
-            sb = self._batch_prefill_fn(b, 2)(
-                *self._scratch_state(), self.params,
-                jnp.asarray(prompts),
-                jnp.asarray([n0 - 1, n1 - 1], np.int32),
-                jnp.asarray([0, 1], np.int32),
-                jnp.asarray([n0, n1], np.int32),
-                jnp.asarray([3, 2], np.int32),
-                jnp.asarray([_NO_EOS, _NO_EOS], np.int32),
-                jnp.zeros((2,), jnp.int32),
-            )
-            if not self._states_equal(sa, sb):
-                return False
-            if self.prefix_cache is None or not self._prefix_reuse_ok():
-                return True
-            # batched partial hits: two suffixes behind one cached
-            # prefix, serial fetch+chunk+insert vs one batched program
-            L = self._min_bucket
-            lns = (2, 1)
-            bs = self._bucket_for(max(lns))
-            if (L + max(lns) > self.max_total
-                    or L + bs > self.pool.tpad):
-                return True  # geometry can't form hit groups anyway
-            prefix = ((3 + np.arange(L)) % vs).astype(np.int32)
-            sfx = [((5 + r + np.arange(ln)) % vs).astype(np.int32)
-                   for r, ln in enumerate(lns)]
-            sp = self._prefill_into_state(
-                self._scratch_state(), prefix, 0, 1, _NO_EOS
-            )
-            region = self.pool.alloc_region(1)
-            region = self._seg_store()(
-                region, sp[0], jnp.int32(0), jnp.int32(0)
-            )
-            sh = self._scratch_state()
-            for r, ln in enumerate(lns):
-                tmp = self._seg_fetch()(region, jnp.int32(0))
-                pad = np.zeros((1, bs), np.int32)
-                pad[0, :ln] = sfx[r]
-                tmp, lg = self._chunk_fn(bs)(
-                    self.params, tmp, jnp.asarray(pad), jnp.int32(L),
-                    jnp.int32(ln - 1), jnp.zeros((1,), jnp.int32),
-                )
-                sh = self._insert()(
-                    *sh, tmp, lg, jnp.int32(r), jnp.int32(L + ln),
-                    jnp.int32(2), jnp.int32(_NO_EOS),
-                )
-            toks = np.zeros((2, bs), np.int32)
-            for r, ln in enumerate(lns):
-                toks[r, :ln] = sfx[r]
-            sbh = self._batch_hit_fn(bs, 2)(
-                *self._scratch_state(), self.params, region,
-                jnp.asarray([0, 0], np.int32), jnp.asarray(toks),
-                jnp.int32(L),
-                jnp.asarray([ln - 1 for ln in lns], np.int32),
-                jnp.asarray([0, 1], np.int32),
-                jnp.asarray([L + ln for ln in lns], np.int32),
-                jnp.asarray([2, 2], np.int32),
-                jnp.asarray([_NO_EOS, _NO_EOS], np.int32),
-                jnp.zeros((2,), jnp.int32),
-            )
-            return self._states_equal(sh, sbh)
-        finally:
-            self.prefill_dispatches = _disp
-            self._uncounted -= 1
-
-    def _probe_verdict(self, name: str, compute, cfg=None,
-                       **geometry) -> bool:
-        """Gate one parity probe through the on-disk verdict cache
-        (when configured): a persisted verdict for the same (probe,
-        config, backend, geometry) skips the probe's device dispatches
-        entirely — verdicts are pure functions of those inputs, so a
-        second engine instance constructs probe-free. A fresh verdict
-        is computed and persisted. ``probes_run`` /
-        ``probes_from_cache`` record which path each probe took."""
-        cfg_json = (cfg if cfg is not None else self.cfg).to_json()
-        key = None
-        if self._probe_cache is not None:
-            key = probe_key(name, cfg_json, **geometry)
-            v = self._probe_cache.get(key)
-            if v is not None:
-                self.probes_from_cache.append(name)
-                log_event(_log, "parity_probe_cached", probe=name, ok=v)
-                return v
-        v = bool(compute())
-        self.probes_run.append(name)
-        if self._probe_cache is not None:
-            self._probe_cache.put(key, v)
-        return v
-
-    def _probe_tp_parity(self, cfg, params, mesh) -> bool:
-        """One-time probe gating tensor-parallel serving — the
-        construction-time mirror of ``chunked_replay="auto"``: do the
-        SHARDED prefill and decode programs reproduce, bitwise, the
-        single-chip logits on scratch state? The exact-TP layout
-        preserves every reduction's flop order by construction (see
-        ``serving_tp_shardings``), so this should pass on any backend —
-        the probe is the standing bar that proves it on THIS one.
-        Bitwise-equal logits at every step make greedy AND sampled
-        streams identical (sampling is a replicated pure function of
-        logits, slot key and position)."""
-        total = int(min(self.max_total, 32))
-        n = min(8, total - 4)
-        if n < 1:
-            return False
-
-        seq = ((1 + np.arange(n)) % cfg.vocab_size).astype(np.int32)
-        prompt = jnp.asarray(seq[None])
-
-        def stream(tp_mesh):
-            fwd1, init_caches, do_prefill, cast_params = _decode_builder(
-                cfg, tp_mesh=tp_mesh
-            )
-            p = params if tp_mesh is None else place_serving_tp_params(
-                tp_mesh, params, cfg
-            )
-            p = jax.jit(cast_params)(p)  # lint: retrace-ok one-shot parity probe
-            caches, logits = jax.jit(do_prefill)(  # lint: retrace-ok one-shot probe
-                p, init_caches(1, total), prompt
-            )
-            out = [np.asarray(logits)]
-            pos = jnp.full((1,), n, jnp.int32)
-            step = jax.jit(
-                lambda pp, c, lg, po: fwd1(
-                    pp, c, jnp.argmax(lg, axis=-1).astype(jnp.int32), po
-                )
-            )
-            for _ in range(3):
-                logits, caches = step(p, caches, logits, pos)
-                pos = pos + 1
-                out.append(np.asarray(logits))
-            return out
-
-        # compile and runtime errors propagate: only an honest
-        # inequality is a verdict
-        ref = stream(None)
-        tpo = stream(mesh)
-        return all(np.array_equal(a, b) for a, b in zip(ref, tpo))
-
-    def _probe_lora_zero(self) -> bool:
-        """One-time probe gating batched LoRA — the bank-attach mirror
-        of ``tp_parity``: with the bank riding in params, does adapter
-        index 0 reproduce, bitwise, the bank-free base model through
-        prefill + greedy decode? The forward SELECTS the base
-        activations for adapter-0 rows (``jnp.where``, never ``+ 0.0``
-        — adding a zero delta could flip ``-0.0`` sign bits), so this
-        should pass on any backend; the probe is the standing bar that
-        proves it on THIS one. Bitwise-equal logits make greedy AND
-        sampled adapter-0 streams identical to base (sampling is a pure
-        function of logits, slot key and position)."""
-        total = int(min(self.max_total, 32))
-        n = min(8, total - 4)
-        if n < 1:
-            return False
-        seq = ((1 + np.arange(n)) % self.cfg.vocab_size).astype(np.int32)
-        prompt = jnp.asarray(seq[None])
-        base = {k: v for k, v in self.params.items() if k != "lora"}
-        ad = jnp.zeros((1,), jnp.int32)
-
-        def stream(p):
-            caches, logits = jax.jit(self._do_prefill)(  # lint: retrace-ok one-shot parity probe
-                p, self._init_caches(1, total), prompt, adapter=ad
-            )
-            out = [np.asarray(logits)]
-            pos = jnp.full((1,), n, jnp.int32)
-            step = jax.jit(  # lint: retrace-ok one-shot parity probe
-                lambda pp, c, lg, po: self._fwd1(
-                    pp, c, jnp.argmax(lg, axis=-1).astype(jnp.int32),
-                    po, adapter=ad,
-                )
-            )
-            for _ in range(3):
-                logits, caches = step(p, caches, logits, pos)
-                pos = pos + 1
-                out.append(np.asarray(logits))
-            return out
-
-        ref = stream(base)
-        lz = stream(self.params)
-        return all(np.array_equal(a, b) for a, b in zip(ref, lz))
-
-    def _probe_paged_parity(self, block_size: int) -> bool:
-        """One-time probe gating the paged KV layout — the block-table
-        mirror of ``tp_parity``: does the paged step (block gather,
-        IDENTICAL fwd1 compute, block scatter) reproduce, bitwise, the
-        slab step's logits on scratch state? Both legs run batch-2 over
-        the same prefilled rows, with the paged tables SHUFFLED (blocks
-        land scattered through the pool, as after churn) and one block
-        ALIASED between the rows (the shared-prefix shape — both rows
-        write identical bytes into it, since their inputs are
-        identical). Bitwise-equal logits at every step make greedy AND
-        sampled streams identical (sampling is a pure function of
-        logits, slot key and position). Runs before the pool exists, on
-        self-built scratch blocks."""
-        total = int(min(self.max_total, 32))
-        n = min(8, total - 4)
-        if n < 1:
-            return False
-        seq = ((1 + np.arange(n)) % self.cfg.vocab_size).astype(np.int32)
-        prompt = jnp.asarray(seq[None])
-        shapes = jax.eval_shape(
-            lambda: self._init_caches(1, total)
-        )
-        tpad = full_cache_leaf(shapes).shape[3]
-        if tpad % block_size:
-            return False
-        bps = tpad // block_size
-        tmp, lg = jax.jit(self._do_prefill)(  # lint: retrace-ok one-shot parity probe
-            self.params, self._init_caches(1, total), prompt
-        )
-        # slab leg: the prefilled slab landed in both rows of a
-        # 2-slot pool
-        slab = self._init_caches(2, total)
-        place = jax.jit(  # lint: retrace-ok one-shot parity probe
-            lambda c, t, s: jax.tree.map(
-                lambda cc, tt: lax.dynamic_update_slice(
-                    cc, tt, (0, 0, s, 0, 0)
-                ),
-                c, t,
-            )
-        )
-        for s in (0, 1):
-            slab = place(slab, tmp, jnp.int32(s))
-        # paged leg: the same rows scattered through shuffled
-        # tables, rows 0 and 1 aliasing one shared block
-        perm = np.random.default_rng(0).permutation(2 * bps) + 1
-        tables = perm.reshape(2, bps).astype(np.int32)
-        tables[1, 0] = tables[0, 0]
-        blocks = jax.tree.map(
-            lambda sh: jnp.zeros(
-                (sh.shape[0], sh.shape[1], 2 * bps + 1,
-                 block_size, sh.shape[4]),
-                sh.dtype,
-            ),
-            shapes,
-        )
-        dtab = jnp.asarray(tables)
-        scatter = jax.jit(paged_slot_scatter)  # lint: retrace-ok one-shot parity probe
-        for s in (0, 1):
-            blocks = scatter(blocks, dtab[s], tmp)
-        pcaches = {"blocks": blocks, "tables": dtab}
-
-        sstep = jax.jit(  # lint: retrace-ok one-shot parity probe
-            lambda c, l, p: self._fwd1(
-                self.params, c,
-                jnp.argmax(l, axis=-1).astype(jnp.int32), p,
-            )
-        )
-        pfwd1 = make_paged_fwd1(self._fwd1)
-        pstep = jax.jit(  # lint: retrace-ok one-shot parity probe
-            lambda c, l, p: pfwd1(
-                self.params, c,
-                jnp.argmax(l, axis=-1).astype(jnp.int32), p,
-            )
-        )
-        lg2 = jnp.concatenate([lg, lg], axis=0)
-        slg, plg = lg2, lg2
-        pos = jnp.full((2,), n, jnp.int32)
-        for _ in range(3):
-            slg, slab = sstep(slab, slg, pos)
-            plg, pcaches = pstep(pcaches, plg, pos)
-            pos = pos + 1
-            if not np.array_equal(np.asarray(slg), np.asarray(plg)):
-                return False
-        return True
-
-    def _prefix_reuse_ok(self) -> bool:
-        if self.prefix_cache is None:
-            return False
-        if self._prefix_ok_memo is None:
-            self._prefix_ok_memo = self._probe_verdict(
-                "prefix_reuse", self._probe_prefix_parity,
-                n_slots=self.n_slots, max_total=self.max_total,
-                min_bucket=self._min_bucket, tpad=self.pool.tpad,
-                tp=self.tp,
-            )
-            log_event(_log, "prefix_parity_probe",
-                      ok=self._prefix_ok_memo)
-            self.tracer.instant(ENGINE_TRACK, "prefix_parity_probe",
-                                ok=self._prefix_ok_memo)
-        return self._prefix_ok_memo
-
-    def _probe_disagg_parity(self) -> bool:
-        """One-time probe gating the disaggregated wire path: does a
-        segment moved prefill -> seg_store -> host wire frame (a real
-        ``encode_segment``/``decode_segment`` byte round-trip) ->
-        device import -> zero-prefill hit insert reproduce, bitwise,
-        the KV rows AND logits of the direct prefill? Paged engines
-        additionally push the slab through the block scatter/gather
-        pair ingest uses. On refusal both export and ingest decline
-        and the fleet falls back to local prefill everywhere."""
-        n = min(self._min_bucket + 3, self.max_total - 1,
-                self.pool.tpad)
-        if n < 1:
-            return False
-        _disp = self.prefill_dispatches  # probes don't count
-        self._uncounted += 1  # nor toward program_dispatches
-        try:
-            seq = ((1 + np.arange(n)) % self.cfg.vocab_size).astype(
-                np.int32
-            )
-            sa = self._prefill_into_state(
-                self._scratch_state(), seq, 0, 1, _NO_EOS
-            )
-            rows_a = self._slot_rows(sa[0], 0, n)
-            lg_a = np.asarray(sa[1][0])
-            # export side: slab snapshot + pending logits row, to host
-            region = self._seg_store()(
-                self.pool.alloc_region(1), sa[0],
-                jnp.int32(0), jnp.int32(0),
-            )
-            leaves = [
-                np.asarray(leaf)  # lint: sync-ok probe round-trips through host bytes by design
-                for leaf in jax.tree.leaves(region)
-            ]
-            lg = np.asarray(  # lint: sync-ok probe round-trips through host bytes by design
-                self._logit_row()(sa[1], jnp.int32(0))
-            )
-            # the actual wire: frame the bytes and re-decode them
-            if self._paged:
-                wire_leaves = slab_to_blocks(leaves, self._block_size)
-                layout, bs = "paged", self._block_size
-            else:
-                wire_leaves, layout, bs = leaves, "slab", 0
-            frame = encode_segment(
-                config_hash=self.config_hash, tokens=seq,
-                leaves=wire_leaves, logits=lg,
-                layout=layout, block_size=bs,
-            )
-            try:
-                dec = decode_segment(frame, expect_hash=self.config_hash)
-                slab = self._wire_slab(dec)
-            except WireError:
-                return False
-            if self._paged:
-                # land and re-fetch through a scratch block store, as
-                # ingest will (rows past n scatter to the sentinel)
-                bps = self.pool.tpad // self._block_size
-                blocks = jax.tree.map(
-                    lambda sh: jnp.zeros(
-                        (sh.shape[0], sh.shape[1], bps + 1,
-                         self._block_size, sh.shape[4]),
-                        sh.dtype,
-                    ),
-                    jax.eval_shape(
-                        lambda: self._init_caches(1, self.max_total)
-                    ),
-                )
-                row = jnp.asarray(np.arange(1, bps + 1, dtype=np.int32))
-                blocks = self._paged_seg_import()(blocks, row, slab)
-                slab = self._paged_seg_fetch()(blocks, row)
-            region2 = self._seg_import()(
-                self.pool.alloc_region(1), slab, jnp.int32(0)
-            )
-            # decode-side seat: the ordinary zero-prefill full hit
-            sc = self._hit_insert()(
-                *self._scratch_state(), region2,
-                jnp.asarray(dec["logits"]), jnp.int32(0), jnp.int32(0),
-                jnp.int32(n), jnp.int32(1), jnp.int32(_NO_EOS),
-            )
-            rows_c = self._slot_rows(sc[0], 0, n)
-            lg_c = np.asarray(sc[1][0])
-            return bool(
-                np.array_equal(lg_a, lg_c)
-                and all(np.array_equal(a, c)
-                        for a, c in zip(rows_a, rows_c))
-            )
-        finally:
-            self.prefill_dispatches = _disp
-            self._uncounted -= 1
-
-    def _disagg_ok(self) -> bool:
-        if self._disagg_ok_memo is None:
-            self._disagg_ok_memo = self._probe_verdict(
-                "disagg_wire", self._probe_disagg_parity,
-                n_slots=self.n_slots, max_total=self.max_total,
-                min_bucket=self._min_bucket, tpad=self.pool.tpad,
-                paged=self._paged, block_size=self._block_size,
-                tp=self.tp,
-            )
-            log_event(_log, "disagg_parity_probe",
-                      ok=self._disagg_ok_memo)
-            self.tracer.instant(ENGINE_TRACK, "disagg_parity_probe",
-                                ok=self._disagg_ok_memo)
-        return self._disagg_ok_memo
-
-    def _batch_admission_ok(self) -> bool:
-        if self._paged:
-            # the batched admission programs are slab-landing (whole
-            # groups dynamic-update into pool slabs); paged admissions
-            # go serial through the paged prefill/insert programs
-            return False
-        if self.batch_admission is True:
-            return True
-        if self.batch_admission is False:
-            return False
-        if self._batch_ok_memo is None:
-            self._batch_ok_memo = self._probe_verdict(
-                "batch_admission", self._probe_batch_parity,
-                n_slots=self.n_slots, max_total=self.max_total,
-                min_bucket=self._min_bucket, tpad=self.pool.tpad,
-                prefix=self.prefix_cache is not None, tp=self.tp,
-            )
-            log_event(_log, "batch_parity_probe",
-                      ok=self._batch_ok_memo)
-            self.tracer.instant(ENGINE_TRACK, "batch_parity_probe",
-                                ok=self._batch_ok_memo)
-        return self._batch_ok_memo
-
     def _classify_plan(self, pl: _AdmitPlan) -> None:
         """Prefix-cache lookup for one planned admission. A FULL hit
         (whole prompt cached, stored logits present) admits by pure
@@ -3984,8 +3136,7 @@ class ServingEngine:
         # delta makes every later layer's KV rows adapter-dependent, so
         # segments are base-model-only and nonzero adapters always take
         # the full prefill path
-        if (cache is None or n == 0 or pl.req.adapter != 0
-                or not self._prefix_reuse_ok()):
+        if cache is None or n == 0 or pl.req.adapter != 0:
             return
         seg, m = cache.lookup(pl.req.prompt)
         if seg is None:
@@ -4339,7 +3490,7 @@ class ServingEngine:
         cache = self.prefix_cache
         n = len(pl.req.prompt)
         if (cache is None or pl.kind == "full" or pl.req.adapter != 0
-                or n < self._min_bucket or not self._prefix_reuse_ok()):
+                or n < self._min_bucket):
             return
         for seg in cache.insert(pl.req.prompt):
             if self._paged:
@@ -4553,7 +3704,11 @@ class ServingEngine:
         occupied = any(st is not None for st in self._slots)
         t_exec = time.perf_counter()
         # group what can share a dispatch
-        batch_ok = len(live) > 1 and self._batch_admission_ok()
+        # the batched admission programs are slab-landing (whole
+        # groups dynamic-update into pool slabs); paged admissions go
+        # serial through the paged prefill/insert programs
+        batch_ok = (len(live) > 1 and self.batch_admission
+                    and not self._paged)
         miss_groups: dict[int, list[_AdmitPlan]] = {}
         hit_groups: dict[tuple[int, int], list[_AdmitPlan]] = {}
         if batch_ok:
@@ -5227,77 +4382,6 @@ class ServingEngine:
         self._deos = jnp.full((self.n_slots,), _NO_EOS, jnp.int32)
         self._dgstate = jnp.zeros((self.n_slots,), jnp.int32)
 
-    def _probe_chunked_parity(self) -> bool:
-        """One-time probe for ``chunked_replay="auto"``: does a
-        full-sequence bucketed prefill reproduce, bitwise, the logits
-        of a shorter prefill + teacher-forced decode? (They are
-        differently-scheduled XLA programs; on some backends they agree
-        only to float-reassociation level, in which case chunked replay
-        would break greedy byte-parity and stepwise replay is used.)
-        Runs on abandoned pre-recovery state and leaves state zeroed."""
-        length = int(min(self._max_bucket + 1, self.max_total))
-        k = length - 2
-        if k < 1:
-            return False
-        _disp = self.prefill_dispatches  # probes don't count
-        self._uncounted += 1  # nor toward program_dispatches
-        try:
-            return self._probe_chunked_parity_inner(length, k)
-        finally:
-            self.prefill_dispatches = _disp
-            self._uncounted -= 1
-
-    def _probe_chunked_parity_inner(self, length: int, k: int) -> bool:
-        seq = ((1 + np.arange(length)) % self.cfg.vocab_size).astype(
-            np.int32
-        )
-        self.pool.reinit()
-        self._reset_device_state()
-        self._prefill_seq_into_slot(seq, 0, budget=1, eos_tok=_NO_EOS)
-        la = np.asarray(self._logits[0])
-        self.pool.reinit()
-        self._reset_device_state()
-        # budget length-k, not 1: in paged mode the prefill's block
-        # coverage is len(seq)+budget, and the teacher-forced rows
-        # [k, length) must land in allocated blocks (rows past coverage
-        # scatter to the sentinel and vanish). Budget never feeds the
-        # compared logits, so the slab verdict is unchanged.
-        self._prefill_seq_into_slot(
-            seq[:k], 0, budget=max(1, length - k), eos_tok=_NO_EOS
-        )
-        pos = np.zeros((self.n_slots,), np.int32)
-        replaying = np.zeros((self.n_slots,), bool)
-        replaying[0] = True
-        for j in range(k, length):
-            toks = np.zeros((self.n_slots,), np.int32)
-            toks[0] = seq[j]
-            pos[0] = j
-            caches, self._logits = self._replay_fn(
-                self.params, self._caches_in(), self._logits,
-                jnp.asarray(toks), jnp.asarray(pos.copy()),
-                jnp.asarray(replaying),
-                jnp.zeros((self.n_slots,), jnp.int32),
-            )
-            self._caches_out(caches)
-        lb = np.asarray(self._logits[0])
-        self.pool.reinit()
-        self._reset_device_state()
-        return bool(np.array_equal(la, lb))
-
-    def _use_chunked_replay(self) -> bool:
-        if self.chunked_replay is True:
-            return True
-        if self.chunked_replay is False:
-            return False
-        if self._chunked_ok is None:
-            self._chunked_ok = self._probe_verdict(
-                "chunked_replay", self._probe_chunked_parity,
-                n_slots=self.n_slots, max_total=self.max_total,
-                max_bucket=self._max_bucket, tp=self.tp,
-                paged=self._paged,
-            )
-        return self._chunked_ok
-
     def recover(self) -> int:
         """Rebuild engine/device state by deterministic replay after an
         engine-loop crash. The device buffers are abandoned (assumed
@@ -5309,9 +4393,8 @@ class ServingEngine:
         ``prompt + tokens_so_far``, O(len/bucket) device calls — or by
         STEPWISE replay — re-prefill the original prompt, then
         teacher-force the recorded tokens one fused step at a time —
-        per ``chunked_replay`` (see class docstring; "auto" probes for
-        bitwise parity and falls back to stepwise). Queued requests are
-        untouched. Returns the number of live requests replayed."""
+        per ``chunked_replay`` (see module docstring). Queued requests
+        are untouched. Returns the number of live requests replayed."""
         t_rec = time.perf_counter()
         self.metrics.record_restart()
         self.tracer.instant(ENGINE_TRACK, "crash", ts=t_rec)
@@ -5348,7 +4431,7 @@ class ServingEngine:
             self._pending_prefills.clear()
         live = [(s, st) for s, st in enumerate(self._slots)
                 if st is not None]
-        chunked = bool(live) and self._use_chunked_replay()
+        chunked = bool(live) and self.chunked_replay
         self.pool.reinit()
         self._reset_device_state()
         if self.prefix_cache is not None:

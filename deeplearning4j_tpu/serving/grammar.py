@@ -33,8 +33,7 @@ fixed-capacity combined table (:class:`GrammarTable`), so one compiled
 program serves any mix of constrained and unconstrained slots.
 
 Compiles are cached by ``sha256(kind, spec, tokenizer id, eos, V)`` in
-an in-process LRU plus an optional on-disk store next to the probe
-cache (:mod:`~deeplearning4j_tpu.serving.probe_cache`), and a state
+an in-process LRU plus an optional on-disk store, and a state
 budget turns pathological regexes into a 400 at submit instead of an
 unbounded device table.
 """
@@ -778,8 +777,8 @@ def grammar_key(kind: str, spec, tokenizer_id: str, eos_token: int,
 
 class GrammarCache:
     """LRU of compiled grammars keyed by :func:`grammar_key`, with an
-    optional on-disk store (one ``.npz`` per key in a directory next
-    to the probe-verdict cache). ``get_or_compile`` reports how the
+    optional on-disk store (one ``.npz`` per key in a directory).
+    ``get_or_compile`` reports how the
     grammar was obtained — ``"hit"`` (memory or disk) or ``"miss"``
     (freshly compiled) — for the
     ``serve_grammar_compiles_total{result}`` metrics."""

@@ -792,7 +792,7 @@ class ServingServer:
         (see :mod:`..serving.disagg`) and seat it in the prefix cache
         through the engine's admission loop. 400/409 come straight from
         ``WireError.status``; otherwise 200 with ``{"stored": bool,
-        "reason"}`` — a decline (cache full, parity probe failed) is
+        "reason"}`` — a decline (cache full, no prefix cache) is
         not an error, the sender just forfeits the transfer win. A
         repeated ``X-Idempotency-Key`` (a hedged retransmit of a frame
         already being seated) is declined with 409 so the frame is

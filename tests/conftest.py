@@ -12,25 +12,12 @@ time in conftest.
 """
 
 import os
-import tempfile
 
 os.environ["JAX_PLATFORMS"] = "cpu"
 # the suite and the children it starts run uncached (reason below), also
 # through entry points that place a compile cache (cli train/serve)
 os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "false"
 
-# One parity-probe verdict file for the whole session: engine
-# construction probes (chunked_replay, prefix_reuse, batch_admission,
-# lora_zero, tp_parity, paged_parity) are deterministic per
-# (cfg, backend, geometry), and the serving suites construct hundreds
-# of engines — without this every one re-dispatches its probes.
-# Tests that assert probe behaviour pass an explicit probe_cache=,
-# which always wins over this default.
-os.environ.setdefault(
-    "DL4J_TPU_PROBE_CACHE",
-    os.path.join(tempfile.mkdtemp(prefix="dl4j-test-probes-"),
-                 "probes.json"),
-)
 flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in flags:
     os.environ["XLA_FLAGS"] = (

@@ -46,6 +46,34 @@ def test_rehearsal_runs_the_kernels_leg():
     assert not _has_result_line(out.stdout)
 
 
+def test_rehearsal_runs_the_features_leg():
+    """Every optional serving feature on against off, at toy size: each
+    is really on (its own check says so), is compared on the logits its
+    tokens were drawn from, and the figures are left for the record."""
+    out = _run("--rehearse", "--legs", "features")
+    assert out.returncode == 0, out.stdout + out.stderr
+    for feature in (
+        "prefix_cache full hit", "prefix_cache partial hit",
+        "batch_admission", "chunked_replay", "piggyback", "paged",
+        "sampling_surface", "lora adapter 0", "KV wire",
+    ):
+        assert f"ok: {feature}: on against off over" in out.stdout, feature
+    assert "the cache served one full and one partial hit" in out.stdout
+    assert "four same-bucket prompts were admitted in one program" \
+        in out.stdout
+    figures = json.loads(
+        (ROOT / "chiprun_out" / "features_rehearsal.json").read_text()
+    )
+    assert len(figures) == 9
+    # the five that only reschedule are bitwise on XLA:CPU
+    assert {f["feature"] for f in figures
+            if f["rows_bitwise"] == f["rows"]} >= {
+        "piggyback", "paged", "sampling_surface", "lora adapter 0",
+        "KV wire",
+    }
+    assert not _has_result_line(out.stdout)
+
+
 def test_a_raising_leg_fails_the_run():
     out = _run(code=(
         "import sys, chip_smoke\n"

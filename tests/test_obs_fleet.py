@@ -8,7 +8,7 @@ admission span — verified structurally (the replica span's
 ``parent_span_id`` resolves to the router span's ``span_id`` on a
 different process track, and a flow arrow links the two). Plus the
 satellite contracts: traffic dispatches are counted per program family
-and probes and replays are not, flight-recorder dumps never contain
+and replays are not, flight-recorder dumps never contain
 prompt text, and the disabled paths cost nothing.
 """
 
@@ -267,10 +267,9 @@ def test_program_dispatches_counted_per_family():
                 in text)
 
 
-def test_construction_probes_are_not_counted():
-    """Construction and its parity probes dispatch programs that are
-    not traffic: a fresh engine has counted none, and counts from its
-    first request on."""
+def test_construction_counts_no_program():
+    """Construction dispatches no traffic: a fresh engine has counted
+    no program, and counts from its first request on."""
     engine = ServingEngine(CFG, _params(), n_slots=2, temperature=0.0,
                            decode_horizon=2)
     assert engine._uncounted == 0

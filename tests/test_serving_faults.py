@@ -314,8 +314,8 @@ def test_chunked_replay_recovery():
     teacher-forcing. On this backend/model the prefill-path caches
     reproduce the decode trajectory's argmax choices, so the streams
     still match the clean run (the general guarantee is completion;
-    byte-parity under forced chunked replay is what the "auto" probe
-    exists to verify before relying on it)."""
+    ``tests/test_serving_schedules.py`` holds the two replays' logits
+    to a tolerance)."""
     reqs = _requests(4, seed=31)
     clean = _run_clean(reqs)
 
@@ -332,23 +332,21 @@ def test_chunked_replay_recovery():
     _assert_parity(reqs, clean, reqs2, faulted)
 
 
-def test_auto_replay_probes_and_preserves_parity():
-    """Default ("auto") replay runs the one-time bitwise parity probe
-    at first recovery and picks a mode; whichever it picks, the
-    recovered streams are byte-identical to a clean run (stepwise by
-    construction; chunked only when the probe proved it)."""
+def test_default_replay_is_stepwise_and_preserves_parity():
+    """Unless asked for chunked replay, recovery teacher-forces the
+    recorded tokens step by step: the exact one, so the recovered
+    streams are byte-identical to a clean run by construction."""
     reqs = _requests(5, seed=37)
     clean = _run_clean(reqs)
 
     reqs2 = _clone(reqs)
     inj = FaultInjector().plan("step", at=3, kind="crash")
-    engine = _fast_engine(inj)  # chunked_replay defaults to "auto"
+    engine = _fast_engine(inj)  # chunked_replay defaults to False
     for r in reqs2:
         engine.submit(r)
     faulted = engine.run()
 
-    assert engine._chunked_ok is not None  # probe actually ran
-    assert engine.last_recover_mode in ("stepwise", "chunked")
+    assert engine.last_recover_mode == "stepwise"
     _assert_parity(reqs, clean, reqs2, faulted)
 
 

@@ -9,8 +9,8 @@ the plain engine, greedy AND sampled, across K∈{1,4}, paged block
 tables, chunked-prefill piggyback, fault-injected crash recovery, and
 TP=2. That holds because every surface feature folds out to the exact
 plain computation at its neutral value (state 0, bias-free rows,
-engine-default temp/top_k, top_p=1), and is enforced at construction
-by a bitwise parity probe persisted through ``ProbeCache``.
+engine-default temp/top_k, top_p=1); ``tests/test_serving_schedules.py``
+compares the masked and the plain step program's whole state bitwise.
 
 (2) Validity: a request with a JSON-schema/regex ``response_format``
 only ever emits DFA-permitted tokens — the mask lands BEFORE the draw
@@ -21,7 +21,6 @@ re-derived from ``gstate0`` + the emitted prefix at re-seat).
 """
 
 import json
-import os
 
 import numpy as np
 import pytest
@@ -272,12 +271,13 @@ def test_constrained_requires_eos_token():
         ))
 
 
-def test_approx_top_k_disables_surface():
-    """lax.approx_max_k reorders ties, so the surface refuses to arm
-    over it instead of silently breaking byte parity."""
-    eng = _engine(surface=True, temperature=0.9, top_k=8,
-                  approx_top_k=True)
-    assert not eng._surface
+def test_approx_top_k_with_surface_raises():
+    """lax.approx_max_k reorders ties and has no traced-k variant, so
+    asking for both is an error at construction, not a surface that is
+    silently off."""
+    with pytest.raises(ValueError, match="approx_top_k"):
+        _engine(surface=True, temperature=0.9, top_k=8,
+                approx_top_k=True)
 
 
 def test_compile_budget_overflow_rejected():
@@ -364,7 +364,7 @@ def test_tp2_parity_and_constrained(temperature):
     reqs = _requests()
     ref = _run(_engine(temperature=temperature), _clone(reqs))
     eng = _surface(temperature=temperature, tp=2)
-    assert eng.tp == 2, "TP parity probe fell back to tp=1"
+    assert eng.tp == 2
     got = _run(eng, _clone(reqs))
     _assert_same(ref, got)
     r = Request(prompt=np.arange(4, dtype=np.int32), max_new=12,
@@ -598,7 +598,7 @@ def test_crash_recovery_constrained_byte_parity(temperature, crash_at):
     assert validate_json_value(value, schema)
 
 
-# -- compile surface + probe cache ---------------------------------------
+# -- compile surface ------------------------------------------------------
 
 
 def test_masked_compile_surface_bounded():
@@ -627,16 +627,3 @@ def test_masked_compile_surface_bounded():
     assert live["masked_piggyback_step"] <= exp["masked_piggyback_step"]
     assert live["paged_masked_step"] == set()
     assert "gstate_set" in exp["singletons"]
-
-
-def test_masked_parity_probe_cached_across_engines(tmp_path):
-    """The construction-time masked-parity verdict persists through
-    ProbeCache: a second engine with the same geometry constructs with
-    zero probe dispatches."""
-    path = str(tmp_path / "probes.json")
-    e1 = _surface(probe_cache=path)
-    assert "masked_parity" in e1.probes_run
-    assert os.path.exists(path)
-    e2 = _surface(probe_cache=path)
-    assert "masked_parity" in e2.probes_from_cache
-    assert e2.probes_run == []

@@ -7,10 +7,9 @@ greedy AND sampled, through prefix-cache hits, refcounted eviction
 under block pressure, fault-injected crash-recovery replay, and TP=2.
 That holds by construction (the paged step gathers a slot's blocks
 into the exact slab view the fused program already computes on, and
-scatters the result back) and is enforced at engine construction by a
-bitwise parity probe over an aliased, shuffled block table — the same
-probe-gating contract the TP and prefix paths use, persisted through
-``ProbeCache`` so a warm process never re-dispatches it.
+scatters the result back); ``tests/test_serving_schedules.py`` compares
+the paged and the slab step's logits bitwise over an aliased, shuffled
+block table.
 
 The second contract is allocation hygiene: block ids come off a heap
 (deterministic tables), a cached prefix is byte-shared by aliasing
@@ -19,7 +18,6 @@ and dropping every reference returns the pool to empty — no leaks, no
 stale bytes surviving block reuse.
 """
 
-import os
 
 import numpy as np
 import pytest
@@ -57,12 +55,6 @@ def _params(cfg=CFG, seed=0):
     return _PARAMS[key]
 
 
-# Construction-time parity probes are shared session-wide through the
-# DL4J_TPU_PROBE_CACHE default that conftest sets (deterministic per
-# cfg x geometry); the probe-behaviour tests below pass their own
-# probe_cache= explicitly, which wins over the env default.
-
-
 def _engine(n_slots=3, cfg=CFG, **kw):
     kw.setdefault("temperature", 0.0)
     return ServingEngine(
@@ -74,7 +66,7 @@ def _engine(n_slots=3, cfg=CFG, **kw):
 def _paged(n_slots=3, cfg=CFG, **kw):
     kw.setdefault("block_size", 8)
     eng = _engine(n_slots=n_slots, cfg=cfg, paged=True, **kw)
-    assert eng._paged, "paged engine silently fell back to slab"
+    assert isinstance(eng.pool, PagedKVPool)
     return eng
 
 
@@ -297,50 +289,18 @@ def test_paged_tp2_parity():
     reqs = _requests(6, seed=9)
     ref = _run(_engine(cfg=cfg), _clone(reqs))
     eng = _paged(cfg=cfg, tp=2)
-    assert eng.tp == 2, "TP parity probe fell back to tp=1"
+    assert eng.tp == 2
     got = _run(eng, _clone(reqs))
     _assert_same(ref, got)
 
 
-# -- probe caching (satellite): zero re-probe on a warm process ---------
+# -- construction says what is missing -----------------------------------
 
 
-def test_paged_parity_probe_cached_across_engines(tmp_path):
-    """The construction-time paged-parity verdict persists through
-    ProbeCache: a second engine with the same geometry constructs with
-    ZERO probe dispatches (the tp_parity / prefix_reuse contract)."""
-    path = str(tmp_path / "probes.json")
-    e1 = _paged(probe_cache=path)
-    assert "paged_parity" in e1.probes_run
-    assert os.path.exists(path)
-    e2 = _paged(probe_cache=path)
-    assert e2._paged
-    assert "paged_parity" in e2.probes_from_cache
-    assert e2.probes_run == []
-
-
-@pytest.mark.slow
-def test_paged_parity_probe_key_separates_block_size(tmp_path):
-    """The cached verdict is keyed on the paging geometry: a different
-    block size is a different probe, not a cache hit."""
-    path = str(tmp_path / "probes.json")
-    e1 = _paged(probe_cache=path, block_size=8)
-    assert "paged_parity" in e1.probes_run
-    e2 = _paged(probe_cache=path, block_size=16)
-    assert "paged_parity" in e2.probes_run  # re-probed, not reused
-
-
-@pytest.mark.slow
-def test_paged_disabled_on_indivisible_block_size():
-    """A block size that does not divide Tpad disables paging (the
-    engine logs and falls back to the slab pool) instead of crashing."""
-    eng = _engine(paged=True, block_size=32)  # Tpad=32 -> ok
-    assert eng._paged
-    eng = _engine(paged=True, block_size=64)  # 64 > Tpad=32 -> fallback
-    assert not eng._paged
-    assert not isinstance(eng.pool, PagedKVPool)
-    # the fallback engine still serves correctly
-    reqs = _requests(3, seed=11)
-    ref = _run(_engine(), _clone(reqs))
-    got = _run(eng, _clone(reqs))
-    _assert_same(ref, got)
+def test_paged_bad_block_size_raises():
+    """A block size that does not divide Tpad is an error at
+    construction, not a slab pool in silence."""
+    assert isinstance(_engine(paged=True, block_size=32).pool,
+                      PagedKVPool)  # Tpad=32
+    with pytest.raises(ValueError, match="block_size=64"):
+        _engine(paged=True, block_size=64)

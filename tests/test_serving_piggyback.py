@@ -10,9 +10,9 @@ suffix is piggybacked), paged block tables, fault-injected crash
 recovery mid-prefill, and TP=2. That holds by construction (the fused
 ``piggyback_step`` program is the decode substep envelope followed by
 the exact chunk-prefill leg the blocking path runs, and the admission
-key chain is pre-split in blocking order) and is enforced at engine
-construction by a bitwise parity probe persisted through
-``ProbeCache``.
+key chain is pre-split in blocking order);
+``tests/test_serving_schedules.py`` compares the fused program with
+step + chunk run separately, every state leaf, bitwise.
 
 The second contract is accounting: piggybacked chunk tokens are
 charged to the owning tenant's DRR deficit at execution time (the
@@ -20,7 +20,6 @@ pop-time charge is credited back at deferral), so a tenant cannot
 smuggle free prefill past the fair scheduler by sending long prompts.
 """
 
-import os
 
 import numpy as np
 import pytest
@@ -189,7 +188,7 @@ def test_piggyback_tp2_parity(temperature):
     reqs = _requests()
     ref = _run(_engine(temperature=temperature), _clone(reqs))
     eng = _piggy(temperature=temperature, tp=2)
-    assert eng.tp == 2, "TP parity probe fell back to tp=1"
+    assert eng.tp == 2
     got = _run(eng, _clone(reqs))
     _assert_same(ref, got)
     assert eng.metrics.n_prefill_chunks > 0
@@ -276,20 +275,3 @@ def test_piggyback_charges_owner_tenant():
         charges[pb] = drr["deficit"].get("long", 0.0) + \
             drr["carry"].get("long", 0.0)
     assert charges[True] == pytest.approx(charges[False])
-
-
-# -- probe caching -------------------------------------------------------
-
-
-def test_piggyback_parity_probe_cached_across_engines(tmp_path):
-    """The construction-time piggyback-parity verdict persists through
-    ProbeCache: a second engine with the same geometry constructs with
-    ZERO probe dispatches."""
-    path = str(tmp_path / "probes.json")
-    e1 = _piggy(probe_cache=path)
-    assert "piggyback_parity" in e1.probes_run
-    assert os.path.exists(path)
-    e2 = _piggy(probe_cache=path)
-    assert e2._piggyback
-    assert "piggyback_parity" in e2.probes_from_cache
-    assert e2.probes_run == []

@@ -347,7 +347,7 @@ def _lora_engine(cfg=CFG, bank=None, tp=None, **kw):
     return ServingEngine(
         cfg, _params(cfg), n_slots=4,
         lora_bank=_bank(cfg) if bank is None else bank,
-        lora_parity=True, retry_backoff_s=0.001, max_backoff_s=0.004,
+        retry_backoff_s=0.001, max_backoff_s=0.004,
         **extra, **kw,
     )
 
@@ -387,10 +387,10 @@ def test_lora_mixed_batch_matches_single_adapter_engines_sampled():
 
 def test_lora_adapter0_is_bitwise_base_model():
     """Adapter row 0 is the zero adapter: with the bank ATTACHED, every
-    adapter-0 stream is bitwise the no-bank engine's — the probe that
-    gates the whole subsystem, asserted end to end."""
+    adapter-0 stream is bitwise the no-bank engine's, end to end
+    (``tests/test_serving_schedules.py`` compares the logits)."""
     eng = _lora_engine()
-    assert eng.n_adapters == 4  # parity probe passed, bank live
+    assert eng.n_adapters == 4
     reqs = _requests(5, seed=7, adapter=0, max_new=6)
     with_bank = _run(eng, reqs)
     clones = [Request(prompt=r.prompt.copy(), max_new=r.max_new)
@@ -557,7 +557,7 @@ def test_chaos_flood_with_tenancy_and_lora():
         return ServingEngine(
             CFG, _params(), n_slots=2, temperature=0.0,
             scheduler=RequestScheduler(max_queue_depth=64, tenancy=tenancy),
-            tenancy=tenancy, lora_bank=_bank(), lora_parity=True,
+            tenancy=tenancy, lora_bank=_bank(),
             faults=faults, retry_backoff_s=0.001, max_backoff_s=0.004,
         )
 

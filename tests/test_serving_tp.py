@@ -8,8 +8,9 @@ crash-recovery replay — are asserted identical to the single-chip
 engine's. That holds by construction of the exact-TP layout (column
 projections shard; row projections stay replicated behind a forced
 all-gather, so every floating-point reduction keeps single-chip flop
-order) and is enforced at engine construction by a bitwise parity
-probe that falls back to tp=1 on any mismatch.
+order); the sharded reductions' logits and cache rows are also held
+to a tolerance in ``tests/test_serving_schedules.py``. ``tp=N`` shards
+over N devices or raises at construction.
 
 The router suite pins the fleet-level contracts: prefix-affinity
 dispatch (shared-prefix prompts pin to one replica's cache),
@@ -42,7 +43,6 @@ from deeplearning4j_tpu.serving import (
     ServingEngine,
     ServingServer,
 )
-from deeplearning4j_tpu.serving.probe_cache import ProbeCache, probe_key
 from deeplearning4j_tpu.serving.router import PrefixShadow, ReplicaRouter
 
 pytestmark = pytest.mark.tp_serve
@@ -53,7 +53,7 @@ needs_2_devices = pytest.mark.skipif(
 
 # the Pallas decode kernel cannot GSPMD-partition, so TP forces the
 # dense decode path; parity runs compare dense-vs-dense at BOTH widths
-# (kernel-vs-dense equality is a different, unprobed claim)
+# (kernel-vs-dense equality is a different claim)
 CFG = TransformerConfig(
     vocab_size=64, d_model=32, n_heads=4, n_layers=2, d_ff=64,
     max_len=32, decode_kernel=False,
@@ -118,7 +118,7 @@ def test_tp2_streams_byte_identical_to_tp1(temperature):
 
     reqs2 = _clone(reqs)
     eng = _engine(tp=2, temperature=temperature)
-    assert eng.tp == 2, "construction-time parity probe fell back"
+    assert eng.tp == 2
     assert eng.tp_mesh is not None
     got = _run(eng, reqs2)
     for r in reqs:
@@ -163,8 +163,7 @@ def test_tp_crash_recovery_replay_parity():
 
 
 def test_tp_requires_dividing_heads():
-    """tp=3 cannot shard 4 heads: construction says so. (The parity
-    probe used to swallow this error and serve on one chip.)"""
+    """tp=3 cannot shard 4 heads: construction says so."""
     with pytest.raises(ValueError, match="dividing n_heads"):
         _engine(tp=3)
 
@@ -174,62 +173,14 @@ def test_tp1_is_the_unsharded_engine():
     assert eng.tp == 1 and eng.tp_mesh is None
 
 
-# -- satellite: probe-verdict persistence --------------------------------
+# -- construction says what is missing ------------------------------------
 
 
-@needs_2_devices
-def test_probe_cache_skips_reprobe_on_second_engine(tmp_path):
-    """First engine pays the probe dispatches and persists verdicts;
-    a second engine with the same (config, backend, geometry)
-    constructs WITHOUT dispatching a single probe."""
-    path = tmp_path / "probes.json"
-    e1 = _engine(tp=2, probe_cache=str(path))
-    assert e1.tp == 2
-    assert "tp_parity" in e1.probes_run
-    assert path.exists()
-    # real traffic also runs (and persists) the lazy probes — batched
-    # admission fires at the first multi-request admission wave
-    reqs = _requests(4, seed=5)
-    base = _run(e1, _clone(reqs))
-    assert "batch_admission" in e1.probes_run
-
-    e2 = _engine(tp=2, probe_cache=str(path))
-    assert e2.tp == 2
-    assert e2.probes_run == []
-    assert "tp_parity" in e2.probes_from_cache
-
-    # the same traffic through the cached-verdict engine: every
-    # verdict comes from disk, zero probe dispatches end to end
-    got = _run(e2, reqs)
-    assert e2.probes_run == []
-    assert "batch_admission" in e2.probes_from_cache
-    for rid, toks in base.items():
-        assert np.array_equal(toks, got[rid])
-
-
-def test_probe_cache_key_separates_geometry(tmp_path):
-    """Verdicts are keyed by config AND geometry: a different slot
-    count or TP width must never reuse another geometry's verdict."""
-    k1 = probe_key("tp_parity", CFG.to_json(), tp=2, max_total=32)
-    k2 = probe_key("tp_parity", CFG.to_json(), tp=4, max_total=32)
-    k3 = probe_key("tp_parity", CFG.to_json(), tp=2, max_total=64)
-    assert len({k1, k2, k3}) == 3
-
-    pc = ProbeCache(str(tmp_path / "p.json"))
-    pc.put(k1, True)
-    pc.put(k2, False)
-    re = ProbeCache(str(tmp_path / "p.json"))
-    assert re.get(k1) is True and re.get(k2) is False
-    assert re.get(k3) is None
-
-
-def test_probe_cache_tolerates_corrupt_file(tmp_path):
-    path = tmp_path / "p.json"
-    path.write_text("{not json")
-    pc = ProbeCache(str(path))
-    assert pc.get("anything") is None
-    pc.put("k", True)
-    assert ProbeCache(str(path)).get("k") is True
+def test_tp_requires_enough_devices():
+    """A process with fewer devices than ``tp`` cannot shard: the
+    constructor raises instead of serving on one chip."""
+    with pytest.raises(ValueError, match="need 16 devices"):
+        _engine(tp=16)
 
 
 # -- satellite: hit-weighted prefix eviction -----------------------------
