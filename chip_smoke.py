@@ -261,7 +261,10 @@ def kernels_leg(size: Size, log) -> None:
     import jax.numpy as jnp
     import numpy as np
 
-    from deeplearning4j_tpu.ops.pallas_kernels import flash_decode_attention
+    from deeplearning4j_tpu.ops.pallas_kernels import (
+        flash_decode_attention,
+        flash_decode_attention_write,
+    )
 
     n_kv, groups, hk = decode_dims(size)
     rng = np.random.default_rng(0)
@@ -309,6 +312,32 @@ def kernels_leg(size: Size, log) -> None:
                 check(err < bound,
                       f"kernel vs f32 reference: max abs err {err:.3e} "
                       f"< {bound:.3e} (scale {scale:.3e})")
+                if int8:
+                    continue
+                # the walk that places the step's new rows itself
+                # against XLA's scatter and then the read-only walk
+                new = jnp.asarray(
+                    rng.standard_normal((batch, 2, hk)), jnp.bfloat16
+                )
+                placed = cache
+                for plane in range(2):
+                    placed = placed.at[
+                        0, plane, jnp.arange(batch), pos
+                    ].set(new[:, plane])
+                out_r = jax.jit(
+                    lambda q, c, p: flash_decode_attention(q, c, p, n_kv)
+                )(q, placed, pos)
+                out_w, cache_w = jax.jit(
+                    lambda q, c, n, p: flash_decode_attention_write(
+                        q, c, n, p, n_kv
+                    )
+                )(q, cache, new, pos)
+                check(bool(jnp.array_equal(cache_w, placed)),
+                      "the writing kernel's cache equals the scatter's, "
+                      "bit for bit")
+                check(bool(jnp.array_equal(out_w, out_r)),
+                      "the writing kernel's output equals scatter-then-"
+                      "read's, bit for bit")
 
 
 # -- serve --------------------------------------------------------------------

@@ -647,6 +647,31 @@ def _decode_tpad(total: int) -> int:
     return -(-total // 512) * 512
 
 
+def kv_row_write(cfg: "TransformerConfig") -> str:
+    """Who places a decode substep's new K and V rows in the cache:
+    ``"kernel"`` where the bf16 / f32 walk kernel runs (it writes the
+    row it is about to read, ``flash_decode_attention_write``),
+    ``"xla"`` for the int8 slab (a scatter, or a
+    ``dynamic_update_slice``, before the grid kernel) and on the dense
+    path (``decode_kernel=False``: tp, LoRA banks). A fact of the
+    configuration the step programs are traced with; the engine
+    reports it."""
+    return "kernel" if cfg.decode_kernel and not cfg.decode_int8 else "xla"
+
+
+def _decode_write_at(pos, ring: bool, leaf):
+    """The cache row of ``leaf`` (layers, 2, B, rows, Hkv*K) that
+    position ``pos`` is written to: ``pos % rows`` on a ring, ``pos`` on
+    a slab. A scalar ``pos`` past a slab's end writes the last row, as
+    the ``dynamic_update_slice`` it replaces clamped (speculative
+    decoding's scratch positions); a per-row ``pos`` there writes
+    nothing, as the scatter it replaces dropped it."""
+    rows = leaf.shape[3]
+    if ring:
+        return pos % rows
+    return jnp.minimum(pos, rows - 1) if jnp.ndim(pos) == 0 else pos
+
+
 def _flash_seq_ok(t: int) -> bool:
     """Sequence lengths the training flash kernel accepts: sublane-
     aligned (%8 — Mosaic rejects e.g. a 100-row block shape on real
@@ -1495,12 +1520,14 @@ def _gated_builder(cfg: TransformerConfig):
 
     def kernel_attend(caches, l, pos, active):
         """``attend`` of layer ``l`` for one row a slot through the
-        decode kernel: write the row (a ring's at ``pos % rows``), then
-        walk the leaf. The kernel caps ``pos`` at the leaf's last row, so
-        it reads ``min(pos + 1, rows)`` rows of a ring, rounded up to
-        its block, and nothing for a row that is not active."""
+        decode kernel, which places the row (a ring's at ``pos % rows``)
+        in the block of its walk that holds it and attends to it from
+        there. The kernel caps ``pos`` at the leaf's last row, so it
+        reads ``min(pos + 1, rows)`` rows of a ring, rounded up to its
+        block, and neither reads nor writes for a row that is not
+        active."""
         from deeplearning4j_tpu.ops.pallas_kernels import (
-            flash_decode_attention,
+            flash_decode_attention_write,
         )
 
         kind, idx = place[l]
@@ -1508,26 +1535,15 @@ def _gated_builder(cfg: TransformerConfig):
         def attend(q, k, v):
             leaf = caches[kind]
             b, _, h, _ = q.shape
-            at = pos if kind == "full" else pos % leaf.shape[3]
-            if jnp.ndim(pos) == 0:
-                leaf = lax.dynamic_update_slice(
-                    leaf,
-                    jnp.stack([packed(k), packed(v)])[None].astype(leaf.dtype),
-                    (idx, 0, 0, at, 0),
-                )
-            else:
-                bidx = jnp.arange(b)
-                for plane, x in enumerate((k, v)):
-                    leaf = leaf.at[idx, plane, bidx, at].set(
-                        packed(x)[:, 0].astype(leaf.dtype)
-                    )
-            caches[kind] = leaf
             grp = h // hkv
             # query head j = kv * G + g: (B, G, Hkv*K), packed head-major
             qp = q[:, 0].reshape(b, hkv, grp, kd).transpose(
                 0, 2, 1, 3).reshape(b, grp, hk)
-            o = flash_decode_attention(
-                qp, leaf, pos, n_kv_heads=hkv, layer=idx, active=active,
+            o, caches[kind] = flash_decode_attention_write(
+                qp, leaf, jnp.concatenate([packed(k), packed(v)], axis=1),
+                pos, n_kv_heads=hkv, layer=idx,
+                write_at=_decode_write_at(pos, kind == "window", leaf),
+                active=active,
             )
             return o.reshape(b, grp, hkv, kd).transpose(
                 0, 2, 1, 3).reshape(b, 1, h, kd)
@@ -1680,47 +1696,39 @@ def _decode_builder(cfg: TransformerConfig, tp_mesh=None):
         vs bf16 cache is ~0.3% on random models."""
         return _quantize_int8(rows.astype(jnp.float32), (-1,))
 
-    def write_kv_rows(kv_all, i, pos, kv_row):
-        """Write one decode step's K/V rows into the stacked cache at
-        layer ``i``. ``kv_row``: (1, 2, B, 1, Hkv*K). Scalar ``pos``
-        writes every batch row at the same position with a single fused
-        ``dynamic_update_slice`` (the generate/beam path — XLA aliases
-        it in place); an (B,) vector scatters each row at its own
-        position (the serving engine's per-slot decode depths)."""
+    def write_kv_rows_int8(kv_all, i, pos, rows):
+        """Write one decode step's K/V ``rows`` (2, B, Hkv*K) into the
+        stacked int8 cache and its scale planes at layer ``i`` (the
+        bf16 / f32 cache's rows are placed by the decode kernel
+        itself). Scalar ``pos`` writes every batch row at the same
+        position with a single fused ``dynamic_update_slice`` (the
+        generate/beam path — XLA aliases it in place); an (B,) vector
+        scatters each row at its own position (the serving engine's
+        per-slot decode depths)."""
+        kv_buf, sc_buf = kv_all["kv"], kv_all["scale"]
+        q_rows, s_rows = quantize_kv_rows(rows)
         if jnp.ndim(pos) == 0:
-            if cfg.decode_int8:
-                kv_buf, sc_buf = kv_all["kv"], kv_all["scale"]
-                q_row, s_row = quantize_kv_rows(kv_row)
-                kv_buf = lax.dynamic_update_slice(
-                    kv_buf, q_row, (i, 0, 0, pos, 0)
-                )
-                sc_buf = lax.dynamic_update_slice(
-                    sc_buf, s_row, (i, 0, 0, pos, 0)
-                )
-                return {"kv": kv_buf, "scale": sc_buf}
-            return lax.dynamic_update_slice(
-                kv_all, kv_row.astype(kv_all.dtype), (i, 0, 0, pos, 0)
+            kv_buf = lax.dynamic_update_slice(
+                kv_buf, q_rows[None, :, :, None, :], (i, 0, 0, pos, 0)
             )
-        rows = kv_row[0, :, :, 0, :]  # (2, B, Hkv*K)
-        bidx = jnp.arange(rows.shape[1])
-        if cfg.decode_int8:
-            kv_buf, sc_buf = kv_all["kv"], kv_all["scale"]
-            q_rows, s_rows = quantize_kv_rows(rows)
-            for plane in range(2):
-                kv_buf = kv_buf.at[i, plane, bidx, pos].set(q_rows[plane])
-                sc_buf = sc_buf.at[i, plane, bidx, pos].set(s_rows[plane])
+            sc_buf = lax.dynamic_update_slice(
+                sc_buf, s_rows[None, :, :, None, :], (i, 0, 0, pos, 0)
+            )
             return {"kv": kv_buf, "scale": sc_buf}
-        rows = rows.astype(kv_all.dtype)
+        bidx = jnp.arange(rows.shape[1])
         for plane in range(2):
-            kv_all = kv_all.at[i, plane, bidx, pos].set(rows[plane])
-        return kv_all
+            kv_buf = kv_buf.at[i, plane, bidx, pos].set(q_rows[plane])
+            sc_buf = sc_buf.at[i, plane, bidx, pos].set(s_rows[plane])
+        return {"kv": kv_buf, "scale": sc_buf}
 
     def block_decode(x, p, kv_all, i, pos, lora=None, adapter=None,
                      active=None):
         # x: (B, D) one position; kv_all: the ONE stacked packed cache
-        # (nl, 2, B, Tpad, Hkv*K) (axis 1: K then V) — this layer writes
-        # its new K and V rows with a single dynamic_update_slice and
-        # XLA aliases the update in place. (The round-1 per-layer scan
+        # (nl, 2, B, Tpad, Hkv*K) (axis 1: K then V) — this layer's new
+        # K and V rows are placed by the decode kernel itself, in the
+        # block of its walk that holds them, the cache aliased in place
+        # through the call (int8: by XLA before the kernel, a
+        # dynamic_update_slice or a scatter). (The round-1 per-layer scan
         # carried the whole cache stack and restacked it every layer:
         # ~126ms/call of dynamic-update-slice + squeeze bookkeeping at
         # GPT-2-small B=16, measured.) The packed minor dim is the perf
@@ -1762,16 +1770,9 @@ def _decode_builder(cfg: TransformerConfig, tp_mesh=None):
                 cos, sin = cos[None, None], sin[None, None]  # (hd/2,)
             q = _apply_rope(q, cos, sin)
             k = _apply_rope(k, cos, sin)
-        kv_row = jnp.stack(
-            [k.reshape(b, -1), v.reshape(b, -1)]
-        )[None, :, :, None, :]  # (1, 2, B, 1, Hkv*K)
-        kv_all = write_kv_rows(kv_all, i, pos, kv_row)
-        if cfg.decode_int8:
-            kv_buf, sc_buf = kv_all["kv"], kv_all["scale"]
-        else:
-            kv_buf, sc_buf = kv_all, None
         from deeplearning4j_tpu.ops.pallas_kernels import (
             flash_decode_attention,
+            flash_decode_attention_write,
         )
 
         # query head h = kv*G + g (the _expand_kv repeat order):
@@ -1781,13 +1782,23 @@ def _decode_builder(cfg: TransformerConfig, tp_mesh=None):
             .transpose(0, 2, 1, 3)
             .reshape(b, grp, cfg.kv_heads * kd)
         )
-        # the kernel takes the STACKED cache and selects the (static)
-        # layer in its index map — slicing here would materialize a
-        # full-cache copy per layer (custom calls need dense operands)
-        o = flash_decode_attention(
-            qp, kv_buf, pos, n_kv_heads=cfg.kv_heads, layer=i,
-            kv_scales=sc_buf, active=active,
-        )
+        # the kernel takes the STACKED cache and selects the layer
+        # inside — slicing here would materialize a full-cache copy per
+        # layer (custom calls need dense operands)
+        kv_rows = [k.reshape(b, -1), v.reshape(b, -1)]  # (B, Hkv*K) each
+        if cfg.decode_int8:
+            kv_all = write_kv_rows_int8(kv_all, i, pos, jnp.stack(kv_rows))
+            o = flash_decode_attention(
+                qp, kv_all["kv"], pos, n_kv_heads=cfg.kv_heads, layer=i,
+                kv_scales=kv_all["scale"], active=active,
+            )
+        else:
+            o, kv_all = flash_decode_attention_write(
+                qp, kv_all, jnp.stack(kv_rows, axis=1), pos,
+                n_kv_heads=cfg.kv_heads, layer=i,
+                write_at=_decode_write_at(pos, False, kv_all),
+                active=active,
+            )
         o_flat = (
             o.reshape(b, grp, cfg.kv_heads, kd)
             .transpose(0, 2, 1, 3)
@@ -1823,12 +1834,13 @@ def _decode_builder(cfg: TransformerConfig, tp_mesh=None):
         at its own depth).
 
         ``active`` (B,) bool, optional: rows whose result somebody
-        reads. The decode kernel reads no cache row for a row that is
-        not active (its attention output is zeros, its logits are
-        whatever the residual stream then gives, and nobody samples
-        from them); the row it WRITES at ``pos`` stays. The dense path
-        reads every row whatever the mask says. Default: every row
-        active.
+        reads. The decode kernel neither reads nor writes a cache row
+        for a row that is not active (its attention output is zeros,
+        its logits are whatever the residual stream then gives, and
+        nobody samples from them). Where XLA places the new rows (int8
+        cache, dense path) such a row's K and V are still WRITTEN at
+        ``pos``, and the dense path reads every row whatever the mask
+        says. Default: every row active.
 
         ``adapter`` (B,) int rows (with a ``params["lora"]`` bank
         present) applies batched-LoRA deltas per row — dense path only;

@@ -702,6 +702,11 @@ _DECODE_VMEM_BYTES = 14 * 1024 * 1024
 # latency (about 0.5 us) was exposed once a block: 165 us a call against
 # 120 at the benchmark's geometry; a fourth buffer gained nothing.
 _WALK_BUFFERS = 3
+# Rows of the aligned tile the writing walk copies back to HBM around
+# a slot's new row. Mosaic refuses a slice of 1, 2 or 4 rows of a tiled
+# memref ("must be aligned to tiling (8)", bf16 and f32 alike), and 8
+# divides every cache T (``decode_block_rows`` asserts it).
+_WRITE_ROWS = 8
 
 
 @functools.lru_cache(maxsize=None)
@@ -799,9 +804,9 @@ def _bounded_grid_kernel(layer_ref, last_ref, src_ref, hi_ref, q_ref, k_ref,
 
 
 def _walk_decode_kernel(
-    layer_ref, last_ref, base_ref, nxt_ref, q_ref, kv_hbm, o_ref, buf, sem,
-    *, block_t: int, n_kv_heads: int, head_dim: int, groups: int,
-    scale: float,
+    layer_ref, last_ref, base_ref, nxt_ref, *rest,
+    block_t: int, n_kv_heads: int, head_dim: int, groups: int,
+    scale: float, write_rows: int = 0,
 ):
     """One batch row of the bounded walk: the stacked cache stays in
     HBM and the row's blocks 0..``last // block_t`` come in by explicit
@@ -816,7 +821,25 @@ def _walk_decode_kernel(
     the pipeline never drains between rows. ``base[i]`` is row i's
     first index in that sequence (modulo the buffers it picks one),
     ``nxt[k]`` the first row >= k with anything to read (B when
-    none)."""
+    none).
+
+    ``write_rows`` > 0: the kernel also PLACES the row's fresh K and V
+    (``new_ref``, (1, 2, hk)) at cache row ``at_ref[i]``: in the block
+    that holds that row it patches the aligned ``write_rows``-row tile
+    in VMEM before the arithmetic reads it, and copies the patched tile
+    (both planes) back to HBM from a scratch of its own, so the walk's
+    buffers are never held up. The cache is the same HBM buffer in and
+    out (``input_output_aliases``). ``pend`` says a write-back is in
+    flight: it is waited before the scratch is reused and before the
+    last grid step ends. A row that is not active, or whose ``at`` lies
+    in no block it walks, writes nothing.
+    rest = ([at_ref,] q_ref, [new_ref,] kv_hbm, o_ref, [kv_out,] buf,
+    sem[, wbuf, wsem, pend])."""
+    if write_rows:
+        (at_ref, q_ref, new_ref, _, o_ref, kv_hbm, buf, sem, wbuf, wsem,
+         pend) = rest
+    else:
+        q_ref, kv_hbm, o_ref, buf, sem = rest
     b = last_ref.shape[0]
     i = pl.program_id(0)
     last = last_ref[i]
@@ -833,6 +856,24 @@ def _walk_decode_kernel(
             buf.at[slot], sem.at[slot],
         )
 
+    def write_back(row, t0):
+        return pltpu.make_async_copy(
+            wbuf,
+            kv_hbm.at[
+                layer_ref[0], pl.ds(0, 2), row,
+                pl.ds(pl.multiple_of(t0, write_rows), write_rows),
+            ],
+            wsem.at[0],
+        )
+
+    def settle():
+        # the write-back in flight, if any, has landed (every one moves
+        # the same bytes, so any descriptor of that shape waits for it)
+        @pl.when(pend[0] == 1)
+        def _():
+            write_back(0, 0).wait()
+            pend[0] = 0
+
     def after(row, j):
         # the block that follows (row, j) in the sequence; row == B: none
         r = jnp.minimum(row, b - 1)
@@ -846,6 +887,8 @@ def _walk_decode_kernel(
 
     @pl.when(i == 0)
     def _prime():
+        if write_rows:
+            pend[0] = 0
         row, j = nxt_ref[0], 0
         for slot in range(_WALK_BUFFERS - 1):
             start(row, j, slot)
@@ -860,6 +903,24 @@ def _walk_decode_kernel(
         e_tile, s_g = _decode_structure(groups, n_kv_heads, head_dim)
         m_t, _ = _decode_fold_query(q_ref[0], e_tile, s_g, buf.dtype, False)
 
+        def place(j, slot):
+            at = at_ref[i]
+
+            @pl.when((at >= j * block_t) & (at < (j + 1) * block_t))
+            def _():
+                r = at - j * block_t
+                r0 = pl.multiple_of(r // write_rows * write_rows, write_rows)
+                tile = buf[slot, :, pl.ds(r0, write_rows), :]
+                rows = jax.lax.broadcasted_iota(jnp.int32, tile.shape, 1)
+                tile = jnp.where(
+                    rows == r - r0, new_ref[0][:, None, :], tile
+                )
+                buf[slot, :, pl.ds(r0, write_rows), :] = tile
+                settle()
+                wbuf[...] = tile
+                write_back(i, j * block_t + r0).start()
+                pend[0] = 1
+
         def block(j, state):
             g = base_ref[i] + j
             slot = g % _WALK_BUFFERS
@@ -868,6 +929,8 @@ def _walk_decode_kernel(
             for _ in range(_WALK_BUFFERS - 1):
                 ahead = after(*ahead)
             start(*ahead, (g + _WALK_BUFFERS - 1) % _WALK_BUFFERS)
+            if write_rows:
+                place(j, slot)
             return _decode_block(
                 state, buf[slot, 0], buf[slot, 1], None, m_t, None, e_tile,
                 s_g, j * block_t, last, scale,
@@ -883,6 +946,11 @@ def _walk_decode_kernel(
             ),
         )
         o_ref[0] = _decode_output(l, acc, e_tile, s_g).astype(o_ref.dtype)
+
+    if write_rows:
+        @pl.when(i == b - 1)
+        def _drain():
+            settle()
 
 
 def flash_decode_attention(
@@ -911,7 +979,10 @@ def flash_decode_attention(
     decodes at its own depth) — rows > pos are invisible. ``active``:
     optional (B,) bool; a row that is not active attends to nothing and
     returns zeros (default: every row active). Returns (B, G, Hkv*K)
-    attention output in q's dtype.
+    attention output in q's dtype. READ-ONLY: row ``pos`` must already
+    be in ``kvcache`` (the caller wrote it: the int8 slab, whose scale
+    planes Mosaic will not copy by rows); a bf16 / f32 cache takes
+    :func:`flash_decode_attention_write`, which places the row itself.
 
     What is read: a row's slab is walked in T blocks of ``block_t`` rows
     (default :func:`decode_block_rows`; T must be a multiple of it,
@@ -935,15 +1006,58 @@ def flash_decode_attention(
     and substep (144 times at 36 layers x K=4) traces and lowers the
     kernel once, not once a call.
     """
+    return _decode_attention(
+        q, kvcache, jnp.asarray(pos, jnp.int32), active,
+        jnp.asarray(layer, jnp.int32), kv_scales, None, None,
+        n_kv_heads=n_kv_heads,
+        block_t=_decode_block_t(q, kvcache, block_t),
+        interpret=_default_interpret() if interpret is None else interpret,
+    )
+
+
+def _decode_block_t(q, kvcache, block_t: int | None) -> int:
     t, hk = kvcache.shape[3], q.shape[2]
     if block_t is None:
         block_t = decode_block_rows(t, hk, kvcache.dtype.itemsize)
     block_t = min(block_t, t)
     assert t % block_t == 0, (t, block_t)
+    return block_t
+
+
+def flash_decode_attention_write(
+    q: jax.Array,
+    kvcache: jax.Array,
+    kv_new: jax.Array,
+    pos: jax.Array,
+    n_kv_heads: int,
+    layer: int = 0,
+    write_at: jax.Array | None = None,
+    active: jax.Array | None = None,
+    block_t: int | None = None,
+    interpret: bool | None = None,
+) -> tuple[jax.Array, jax.Array]:
+    """:func:`flash_decode_attention` over a bf16 / f32 cache that also
+    PLACES the step's fresh rows: ``kv_new`` (B, 2, Hkv*K) (K then V;
+    cast to the cache's dtype) goes to cache row ``write_at`` (scalar
+    or (B,); default ``pos``; a ring passes ``pos % rows``) of
+    ``kvcache[layer, :, b]`` before the row's walk reads it, so the
+    attention sees exactly the values a write followed by a read would.
+    Returns ``(o, kvcache)``: the cache is the same buffer in and out
+    (``input_output_aliases``), so a caller that donates it never
+    copies it.
+
+    What is written: in the block that holds ``write_at`` the kernel
+    patches the aligned ``_WRITE_ROWS``-row tile in VMEM and copies
+    both planes of it back (the other rows as they were read); one row
+    alone is no copy Mosaic accepts. A row that is not ``active``
+    writes nothing, nor does one whose ``write_at`` lies in no block
+    its walk reads (past ``pos``'s block, or outside the slab)."""
     return _decode_attention(
         q, kvcache, jnp.asarray(pos, jnp.int32), active,
-        jnp.asarray(layer, jnp.int32), kv_scales, n_kv_heads=n_kv_heads,
-        block_t=block_t,
+        jnp.asarray(layer, jnp.int32), None, kv_new,
+        jnp.asarray(pos if write_at is None else write_at, jnp.int32),
+        n_kv_heads=n_kv_heads,
+        block_t=_decode_block_t(q, kvcache, block_t),
         interpret=_default_interpret() if interpret is None else interpret,
     )
 
@@ -951,8 +1065,9 @@ def flash_decode_attention(
 @functools.partial(
     jax.jit, static_argnames=("n_kv_heads", "block_t", "interpret")
 )
-def _decode_attention(q, kvcache, pos, active, layer, kv_scales, *,
-                      n_kv_heads: int, block_t: int, interpret: bool):
+def _decode_attention(q, kvcache, pos, active, layer, kv_scales, kv_new,
+                      write_at, *, n_kv_heads: int, block_t: int,
+                      interpret: bool):
     b, g, hk = q.shape
     t = kvcache.shape[3]
     head_dim = hk // n_kv_heads
@@ -974,29 +1089,58 @@ def _decode_attention(q, kvcache, pos, active, layer, kv_scales, *,
             jax.lax.cummin(jnp.where(n_blocks > 0, rows, b), reverse=True),
             jnp.full((1,), b, jnp.int32),
         ])
+        prefetch = [layer, last, jnp.cumsum(n_blocks) - n_blocks, nxt]
+        in_specs = [
+            pl.BlockSpec((1, g, hk), row),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ]
+        operands = [q, kvcache]
+        out_specs = pl.BlockSpec((1, g, hk), row)
+        scratch = [
+            pltpu.VMEM((_WALK_BUFFERS, 2, block_t, hk), kvcache.dtype),
+            pltpu.SemaphoreType.DMA((_WALK_BUFFERS,)),
+        ]
+        write_rows, aliases = 0, {}
+        if kv_new is not None:
+            write_rows = _WRITE_ROWS
+            assert kv_new.shape == (b, 2, hk), (kv_new.shape, q.shape)
+            prefetch.append(_decode_positions(write_at, b))
+            in_specs.insert(1, pl.BlockSpec((1, 2, hk), row))
+            operands.insert(1, kv_new.astype(kvcache.dtype))
+            out_shape = (
+                out_shape,
+                jax.ShapeDtypeStruct(kvcache.shape, kvcache.dtype),
+            )
+            out_specs = (out_specs, pl.BlockSpec(memory_space=pl.ANY))
+            scratch += [
+                pltpu.VMEM((2, write_rows, hk), kvcache.dtype),
+                pltpu.SemaphoreType.DMA((1,)),
+                pltpu.SMEM((1,), jnp.int32),
+            ]
+            # the cache is updated in place: the last operand is the
+            # second result
+            aliases = {len(prefetch) + len(operands) - 1: 1}
         grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=len(prefetch),
             grid=(b,),
-            in_specs=[
-                pl.BlockSpec((1, g, hk), row),
-                pl.BlockSpec(memory_space=pl.ANY),
-            ],
-            out_specs=pl.BlockSpec((1, g, hk), row),
-            scratch_shapes=[
-                pltpu.VMEM((_WALK_BUFFERS, 2, block_t, hk), kvcache.dtype),
-                pltpu.SemaphoreType.DMA((_WALK_BUFFERS,)),
-            ],
+            in_specs=in_specs,
+            out_specs=out_specs,
+            scratch_shapes=scratch,
         )
         return pl.pallas_call(
-            functools.partial(_walk_decode_kernel, **statics),
+            functools.partial(
+                _walk_decode_kernel, write_rows=write_rows, **statics
+            ),
             out_shape=out_shape,
             grid_spec=grid_spec,
+            input_output_aliases=aliases,
             # rows in order: each starts the next one's first copies
             compiler_params=_dim_semantics(interpret, ("arbitrary",)),
             interpret=interpret,
             name="decode_attn",
-        )(layer, last, jnp.cumsum(n_blocks) - n_blocks, nxt, q, kvcache)
+        )(*prefetch, *operands)
 
+    assert kv_new is None, "the int8 slab's rows are written by XLA"
     assert kvcache.dtype == jnp.int8, kvcache.dtype
     assert kv_scales.shape == (kvcache.shape[0], 2, b, t, 1), kv_scales.shape
 

@@ -133,6 +133,7 @@ from deeplearning4j_tpu.models.transformer import (
     decode_rows_live,
     decode_rows_streamed,
     full_cache_leaf,
+    kv_row_write,
     make_paged_fwd1,
     paged_block_copy,
     paged_slot_gather,
@@ -326,9 +327,11 @@ def build_step_program(fwd1, horizon: int, temperature: float,
                     lambda kk, lg: jax.random.categorical(kk, lg)
                 )(tok_keys, filt / temperature).astype(jnp.int32)
             # inactive slots decode token 0 at their frozen
-            # position — shape stability; the garbage row they
-            # write stays inside their own slab and is wiped by the
-            # next admission's prefill insert
+            # position — shape stability. Where the decode kernel
+            # places the rows (kv_row_write "kernel") they write
+            # nothing; where XLA does (int8 cache, dense path) the
+            # garbage row they write stays inside their own slab and
+            # is wiped by the next admission's prefill insert
             toks = jnp.where(active, toks, 0)
             new_logits, caches = fwd(
                 params, caches, toks, pos, adapter=adapters,
@@ -1364,6 +1367,7 @@ class ServingEngine:
         self.metrics.topk_select = topk_select(
             cfg.vocab_size, self.top_k, self.approx_top_k
         )
+        self.metrics.kv_row_write = kv_row_write(cfg)
         self.metrics.compile_log = self._compile_log
         # the one place a phase of step() is named: profiler
         # annotation, metrics.loop_seconds, ring span, sanitizer phase
@@ -1694,6 +1698,14 @@ class ServingEngine:
             "none.",
             labelnames=("how",),
         ).set(1, how=self.metrics.topk_select)
+        reg.gauge(
+            "serve_kv_row_write",
+            "Who places a decode substep's new K and V rows in the "
+            "cache, decided when the step programs are traced: kernel "
+            "(the decode kernel writes the row it reads) or xla (a "
+            "scatter before it: int8 cache, dense path).",
+            labelnames=("how",),
+        ).set(1, how=self.metrics.kv_row_write)
         reg.gauge(
             "serve_decode_horizon_current",
             "Decode substeps fused into the next horizon dispatch "

@@ -173,6 +173,9 @@ class ServingMetrics:
         # how the step programs' top-k filter finds its threshold at
         # the engine's vocabulary and k (transformer.topk_select)
         self.topk_select = "none"
+        # who places a substep's new K and V rows in the cache at the
+        # engine's configuration (transformer.kv_row_write)
+        self.kv_row_write = "xla"
         self.n_finished = 0
         self.n_generated = 0
         # fault-tolerance counters (see serving.faults / engine docs):
@@ -880,6 +883,7 @@ class ServingMetrics:
             "steps": self._step,
             "decode_horizon": self.decode_horizon,
             "topk_select": self.topk_select,
+            "kv_row_write": self.kv_row_write,
         }
         lookups = (self.n_prefix_hits_full + self.n_prefix_hits_partial
                    + self.n_prefix_misses)

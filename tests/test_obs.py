@@ -18,6 +18,7 @@ import json
 import logging
 import math
 import re
+import time
 from io import StringIO
 
 import jax
@@ -457,12 +458,26 @@ def test_server_metrics_sidecar_and_profile_endpoint(tmp_path):
         code, _, text = get(side, "/healthz")
         assert code == 200
 
-        code, body = post(main, "/profile?s=2")
-        assert code == 200 and body["armed"] == 2
-        code, body = post(main, "/profile?s=1")
-        assert code == 409  # already armed
+        # an idle loop steps every few milliseconds, so a two-step
+        # capture can be over before a second POST arrives: hold its
+        # end until "already armed" has been seen
+        trigger = engine.profile
+        trigger.step_end = lambda: None
+        try:
+            code, body = post(main, "/profile?s=2")
+            assert code == 200 and body["armed"] == 2
+            code, body = post(main, "/profile?s=1")
+            assert code == 409  # already armed
+        finally:
+            del trigger.step_end
         code, body = post(main, "/profile?s=0")
         assert code == 400
+        # and let the capture end before the loop does: the XLA
+        # profiler is one a process
+        deadline = time.monotonic() + 30
+        while trigger.armed and time.monotonic() < deadline:
+            time.sleep(0.01)
+        assert not trigger.armed
     finally:
         srv.stop()
 

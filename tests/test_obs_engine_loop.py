@@ -427,3 +427,30 @@ def test_metrics_carry_the_loop_and_compile_series_and_no_utilization():
     assert s["compile"]["requests"] >= 2
     assert set(s["compile"]["stage_seconds"]) == {"trace", "lower", "backend"}
     assert "program_seconds" not in s
+
+
+def _lora_bank(cfg):
+    from deeplearning4j_tpu.models.transformer import init_lora_bank
+
+    return init_lora_bank(jax.random.key(1), cfg, n_adapters=2, rank=2)
+
+
+@pytest.mark.parametrize("how,cfg,kw", [
+    ("kernel", CFG, {}),
+    ("kernel", CFG, {"paged": True, "block_size": 8}),
+    ("xla", dataclasses.replace(CFG, decode_int8=True), {}),
+    ("xla", dataclasses.replace(CFG, decode_kernel=False), {}),
+    ("xla", CFG, {"lora_bank": _lora_bank}),
+], ids=["walk", "walk-paged", "int8", "dense", "lora-bank"])
+def test_engine_reports_who_places_the_new_cache_rows(how, cfg, kw):
+    """A fact fixed when the step programs are traced: the walk kernel
+    writes the row it reads; the int8 slab, the dense path and a LoRA
+    bank (which forces the dense path) leave the write to XLA."""
+    kw = {k: v(cfg) if callable(v) else v for k, v in kw.items()}
+    engine = ServingEngine(cfg, _params(), n_slots=2, temperature=0.0,
+                           batch_admission=False, **kw)
+    assert engine.metrics.summary()["kv_row_write"] == how
+    text = engine.metrics.render_prometheus()
+    assert f'serve_kv_row_write{{how="{how}"}} 1' in text
+    other = "xla" if how == "kernel" else "kernel"
+    assert f'serve_kv_row_write{{how="{other}"}}' not in text

@@ -332,6 +332,170 @@ def test_flash_decode_scalar_pos_broadcasts_through_the_walk():
     np.testing.assert_allclose(np.asarray(scalar), ref, atol=2e-5)
 
 
+# -- the writing walk: the kernel places the row it is about to read (PR 30)
+
+
+def _scatter_then_read(q, cache, new, pos, n_kv, layer, at, active, block_t):
+    """What the writing kernel replaces: XLA's scatter of the live rows'
+    K and V at ``at``, then the read-only kernel."""
+    from deeplearning4j_tpu.ops.pallas_kernels import flash_decode_attention
+
+    b = q.shape[0]
+    at = jnp.broadcast_to(jnp.asarray(at, jnp.int32), (b,))
+    if active is not None:  # a row that is not active: out of bounds
+        at = jnp.where(jnp.asarray(active), at, cache.shape[3])
+    for plane in range(2):
+        cache = cache.at[layer, plane, jnp.arange(b), at].set(
+            new[:, plane].astype(cache.dtype), mode="drop"
+        )
+    out = flash_decode_attention(
+        q, cache, pos, n_kv, layer=layer, block_t=block_t, interpret=True,
+        active=active,
+    )
+    return out, cache
+
+
+def _write_case(seed, b, g, dtype=jnp.float32, t=_WALK_T):
+    q, cache, n_kv = _walk_case(seed, b, g=g, t=t)
+    new = jnp.asarray(
+        np.random.default_rng(seed + 1).normal(size=(b, 2, q.shape[-1])),
+        dtype,
+    )
+    return q.astype(dtype), cache.astype(dtype), new, n_kv
+
+
+# one slot a case, so that each position counts as a test of its own:
+# the slab's first row, both sides of a block edge, the slab's last row
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("groups", [1, 6, 9])
+@pytest.mark.parametrize("pos", [0, _WALK_BLOCK - 1, _WALK_BLOCK, _WALK_T - 1])
+def test_writing_walk_equals_scatter_then_read_bitwise(pos, groups, dtype):
+    from deeplearning4j_tpu.ops.pallas_kernels import (
+        flash_decode_attention_write,
+    )
+
+    # the case's slot beside two at other depths
+    where = jnp.asarray([pos, 13, _WALK_T - 2], jnp.int32)
+    q, cache, new, n_kv = _write_case(40 + pos, 3, groups, dtype)
+    out, written = flash_decode_attention_write(
+        q, cache, new, where, n_kv, layer=1, block_t=_WALK_BLOCK,
+        interpret=True,
+    )
+    ref_out, ref_cache = _scatter_then_read(
+        q, cache, new, where, n_kv, 1, where, None, _WALK_BLOCK
+    )
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref_out))
+    np.testing.assert_array_equal(np.asarray(written), np.asarray(ref_cache))
+    # and the fresh row is where it belongs, in the cache's dtype
+    np.testing.assert_array_equal(
+        np.asarray(written[1, :, 0, pos]), np.asarray(new[0])
+    )
+
+
+@pytest.mark.parametrize("pos", [_WALK_T + 3, 2 * _WALK_T + _WALK_BLOCK, 3 * _WALK_T + 9])
+def test_writing_walk_on_a_ring_writes_a_block_that_is_not_the_last_read(pos):
+    """A ring that has wrapped reads every row (``pos`` is capped at the
+    last one) and writes row ``pos % rows``, in a block before the last
+    of its walk; a ring that has not yet wrapped writes its last row."""
+    from deeplearning4j_tpu.ops.pallas_kernels import (
+        flash_decode_attention_write,
+    )
+
+    where = jnp.asarray([pos, 5, pos + 11], jnp.int32)  # row 1: not wrapped
+    at = where % _WALK_T
+    assert int(at[0]) // _WALK_BLOCK < _WALK_T // _WALK_BLOCK - 1
+    q, cache, new, n_kv = _write_case(60, 3, 9)
+    out, written = flash_decode_attention_write(
+        q, cache, new, where, n_kv, layer=0, write_at=at,
+        block_t=_WALK_BLOCK, interpret=True,
+    )
+    ref_out, ref_cache = _scatter_then_read(
+        q, cache, new, where, n_kv, 0, at, None, _WALK_BLOCK
+    )
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref_out))
+    np.testing.assert_array_equal(np.asarray(written), np.asarray(ref_cache))
+
+
+@pytest.mark.parametrize("pos", [0, 13, _WALK_T - 1, _WALK_T + 4])
+def test_writing_walk_scalar_pos_writes_every_row_at_one_depth(pos):
+    """Scalar ``pos`` (generate, beam, speculative decoding) broadcasts
+    for the write as it does for the read; past the slab the caller
+    names the last row, as ``dynamic_update_slice`` clamped."""
+    from deeplearning4j_tpu.ops.pallas_kernels import (
+        flash_decode_attention_write,
+    )
+
+    q, cache, new, n_kv = _write_case(70 + pos, 3, 1)
+    at = min(pos, _WALK_T - 1)
+    out, written = flash_decode_attention_write(
+        q, cache, new, jnp.int32(pos), n_kv, layer=1,
+        write_at=jnp.int32(at), block_t=_WALK_BLOCK, interpret=True,
+    )
+    ref_cache = jax.lax.dynamic_update_slice(
+        cache, new.transpose(1, 0, 2)[None, :, :, None, :], (1, 0, 0, pos, 0)
+    )
+    np.testing.assert_array_equal(np.asarray(written), np.asarray(ref_cache))
+    ref_out, _ = _scatter_then_read(
+        q, cache, new, pos, n_kv, 1, at, None, _WALK_BLOCK
+    )
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref_out))
+
+
+@pytest.mark.parametrize("mask", [
+    [True, False, True, True],    # a free slot between live ones
+    [False, False, True, True],   # the first rows free
+    [True, True, False, False],   # the last rows free
+    [False, False, False, False],  # nobody home
+])
+def test_writing_walk_inactive_row_leaves_its_slab_and_moves_no_other(mask):
+    from deeplearning4j_tpu.ops.pallas_kernels import (
+        flash_decode_attention_write,
+    )
+
+    pos = jnp.asarray([5, 17, _WALK_T - 1, _WALK_BLOCK], jnp.int32)
+    q, cache, new, n_kv = _write_case(80, 4, 2)
+    mask = np.asarray(mask)
+    kw = dict(layer=0, block_t=_WALK_BLOCK, interpret=True)
+    full_out, full_cache = flash_decode_attention_write(
+        q, cache, new, pos, n_kv, **kw)
+    out, written = flash_decode_attention_write(
+        q, cache, new, pos, n_kv, active=jnp.asarray(mask), **kw)
+    out, written = np.asarray(out), np.asarray(written)
+    assert np.all(out[~mask] == 0.0)
+    np.testing.assert_array_equal(out[mask], np.asarray(full_out)[mask])
+    # a free slot's slab is as it was, a live one's as with every row live
+    np.testing.assert_array_equal(
+        written[:, :, ~mask], np.asarray(cache)[:, :, ~mask])
+    np.testing.assert_array_equal(
+        written[:, :, mask], np.asarray(full_cache)[:, :, mask])
+    # the layer beside it is untouched
+    np.testing.assert_array_equal(written[1], np.asarray(cache)[1])
+
+
+def test_writing_walk_drops_a_row_outside_the_slab():
+    """A per-row ``write_at`` past the slab writes nothing, as the
+    scatter it replaces dropped it; the row still attends to its
+    slab."""
+    from deeplearning4j_tpu.ops.pallas_kernels import (
+        flash_decode_attention,
+        flash_decode_attention_write,
+    )
+
+    pos = jnp.asarray([_WALK_T + 2, 9], jnp.int32)
+    q, cache, new, n_kv = _write_case(90, 2, 1)
+    kw = dict(layer=1, block_t=_WALK_BLOCK, interpret=True)
+    out, written = flash_decode_attention_write(q, cache, new, pos, n_kv, **kw)
+    np.testing.assert_array_equal(
+        np.asarray(written[:, :, 0]), np.asarray(cache[:, :, 0]))
+    np.testing.assert_array_equal(
+        np.asarray(written[1, :, 1, 9]), np.asarray(new[1]))
+    np.testing.assert_array_equal(
+        np.asarray(out[0]),
+        np.asarray(flash_decode_attention(q, cache, pos, n_kv, **kw)[0]),
+    )
+
+
 def test_flash_decode_default_block_at_one_block_is_the_whole_slab():
     """A slab no longer than one block of the rule is walked as one
     block: bit-equal to ``block_t=T``, the kernel every toy geometry

@@ -10,6 +10,9 @@ which is what ``chip_smoke.py`` is for.
 """
 
 import dataclasses
+import math
+import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -270,3 +273,147 @@ def test_gated_step_program_lowers_with_both_leaves_and_the_counters():
     assert "ragged_dot" in text
     out = jax.eval_shape(step, *avals)
     assert out[5].shape == (slots + MOE_COUNT_ROWS, k)
+
+
+# -- the writing walk (PR 30): the cache goes through the kernel in place ------
+
+# layers, slots, rows, KV heads, packed width, query rows a KV head: the
+# gpt2-large serve slab at full depth, then the gated cell's two leaves
+WRITE_GEOMETRIES = {
+    "gpt2-large": (36, 48, 1024, 20, 1280, 1),
+    "laguna-full": (2, 64, 4096, 8, 1024, 6),
+    "laguna-ring": (3, 64, 512, 8, 1024, 9),
+}
+_ALIAS = ("output_operand_alias<output_tuple_indices = [1], "
+          "operand_index = 7, operand_tuple_indices = []>")
+
+
+def _writing_avals(name, sharding=None):
+    layers, batch, t, n_kv, hk, groups = WRITE_GEOMETRIES[name]
+
+    def s(shape, dtype):
+        return S(shape, dtype, sharding=sharding)
+
+    def fn(q, c, new, pos, act):
+        return pk.flash_decode_attention_write(
+            q, c, new, pos, n_kv, layer=layers - 1,
+            write_at=pos % t, active=act)
+
+    return fn, (
+        s((batch, groups, hk), jnp.bfloat16),
+        s((layers, 2, batch, t, hk), jnp.bfloat16),
+        s((batch, 2, hk), jnp.bfloat16),
+        s((batch,), jnp.int32), s((batch,), jnp.bool_),
+    )
+
+
+@pytest.mark.parametrize("name", sorted(WRITE_GEOMETRIES))
+def test_writing_decode_lowers_with_the_cache_aliased(name):
+    """The kernel's custom call names the stacked cache, its eighth
+    operand after the five prefetched vectors, ``q`` and the new rows,
+    as its second result."""
+    fn, avals = _writing_avals(name)
+    assert _ALIAS in lower_tpu(fn, *avals)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """One described (not attached) v5e chip to compile for: XLA:TPU and
+    Mosaic run, nothing executes."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu here, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("name", sorted(WRITE_GEOMETRIES))
+def test_writing_decode_compiles_in_place_for_a_v5e(name, one_chip):
+    """Mosaic accepts the 8-row tile's copy at the real widths, and a
+    donated cache goes through the call without a copy: the program's
+    temporaries stay far under one slot's slab."""
+    fn, avals = _writing_avals(name, one_chip)
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(*avals).compile()
+    cache = avals[1]
+    mem = compiled.memory_analysis()
+    cache_bytes = 2 * math.prod(cache.shape)
+    assert mem.alias_size_in_bytes == cache_bytes
+    assert mem.temp_size_in_bytes < cache_bytes // (cache.shape[0] * 2 * 48)
+
+
+def _cache_shaped_ops(hlo: str, shape: str) -> set[str]:
+    """Opcodes of the compiled instructions whose result is ``shape``."""
+    return set(re.findall(
+        r"= " + re.escape(shape) + r"\{[^}]*\} ([a-z-]+)\(", hlo))
+
+
+def test_serve_step_places_rows_in_the_kernel_and_copies_no_cache(one_chip):
+    """The gpt2-large serve step (two layers, K=2, every width real)
+    compiled for a v5e: the donated cache is aliased to the result, the
+    kernel runs once a layer and substep, and no scatter, fusion or copy
+    produces anything of the cache's shape — only the parameter and the
+    kernel calls' second results do."""
+    from deeplearning4j_tpu.models.transformer import _decode_builder
+    from deeplearning4j_tpu.serving.engine import build_step_program
+
+    cfg = TransformerConfig(
+        vocab_size=50257, d_model=1280, n_heads=20, n_layers=2, d_ff=5120,
+        max_len=1024, compute_dtype=jnp.bfloat16, use_flash=True,
+        decode_kernel=True,
+    )
+    fwd1, init_caches, _, cast = _decode_builder(cfg)
+    slots, k = 48, 2
+
+    def placed(tree):
+        return jax.tree.map(
+            lambda a: S(a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = placed(jax.eval_shape(
+        lambda key: cast(init_transformer(key, cfg)), jax.random.key(0)))
+    caches = placed(jax.eval_shape(lambda: init_caches(slots, 1024)))
+    avals = (
+        params, caches, S((slots, cfg.vocab_size), jnp.float32),
+        S((slots,), jnp.int32), S((slots,), jnp.bool_),
+        S((slots,), jnp.int32), S((slots,), jnp.int32),
+        S((slots, 2), jnp.uint32), S((slots,), jnp.int32),
+    )
+    avals = avals[:2] + placed(avals[2:])
+    compiled = jax.jit(
+        build_step_program(fwd1, k, 1.0, 40, False),
+        donate_argnums=(1, 2, 3, 4, 5),
+    ).lower(*avals).compile()
+    hlo = compiled.as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') == 2 * k
+    assert _cache_shaped_ops(hlo, "bf16[2,2,48,1024,1280]") <= {
+        "parameter", "get-tuple-element"}
+    mem = compiled.memory_analysis()
+    cache_bytes = 2 * 2 * 2 * 48 * 1024 * 1280
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.temp_size_in_bytes < cache_bytes // 48
+
+
+def test_lowered_serve_steps_alias_the_cache_and_scatter_no_row():
+    """The slab and the paged step at the serve geometry: the kernel's
+    call carries the alias, and no scatter or update-slice of a slab's
+    shape is left for XLA beside it (the paged step's own gather and
+    scatter work on the pool's blocks)."""
+    slab = "12x2x16x584x256xbf16"
+    for name in ("step[K=4]", "paged_step[K=4]"):
+        spec = next(
+            s for s in enumerate_programs(SERVE_CFG, SERVE_GEOM)
+            if s.name == name
+        )
+        text = _lower_spec(spec)
+        assert _ALIAS in text
+        writes = [
+            line for line in text.splitlines()
+            if ("stablehlo.scatter" in line
+                or "stablehlo.dynamic_update_slice" in line)
+            and line.rstrip().endswith(f"tensor<{slab}>")
+        ]
+        assert not writes, writes[:2]
