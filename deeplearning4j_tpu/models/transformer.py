@@ -21,7 +21,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any
+from typing import Any, Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -38,7 +38,10 @@ from deeplearning4j_tpu.parallel.sequence_parallel import ring_attention
 
 
 #: a layer's published type -> the cache leaf its kind shares
-_KINDS = {"full_attention": "full", "sliding_attention": "window"}
+_KINDS = {
+    "full_attention": "full", "sliding_attention": "window",
+    "latent_attention": "latent",
+}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -137,6 +140,25 @@ class TransformerConfig:
     moe_scale: float = 1.0
     d_expert: int = 0  # width of one routed expert
     d_shared: int = 0  # width of the shared expert (0: none)
+    # the router's score over all published experts: "softmax", or
+    # "sigmoid" (each expert scored alone; the same selected set)
+    moe_score: str = "softmax"
+    # -- latent attention ("latent_attention" layers; every layer or none)
+    # Low-rank query and key-value projections, each with an RMSNorm of
+    # its own; a head's query and key are ``qk_nope_head_dim`` channels
+    # from the latent plus ``qk_rope_head_dim`` rotary channels whose key
+    # part is ONE vector shared by all heads; values are ``v_head_dim``
+    # wide. The cache row of a position is the normed latent followed by
+    # the rotated shared key: ``kv_lora_rank + qk_rope_head_dim`` values,
+    # one plane. ``rope_theta`` is the base over the rotary channels.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # four RMSNorms a layer: the attention's and the MLP's outputs are
+    # normed before they join the residual stream
+    sandwich_norm: bool = False
 
     @property
     def head_dim(self) -> int:
@@ -154,8 +176,20 @@ class TransformerConfig:
     def heads_of(self, layer: int) -> int:
         return self.layer_heads[layer] if self.layer_heads else self.n_heads
 
+    @property
+    def latent(self) -> bool:
+        """A gated stack whose layers attend over a latent cache row."""
+        return self.gated and "latent_attention" in self.layer_types
+
+    @property
+    def latent_row(self) -> int:
+        """Values of one latent cache row: the latent, then the rotated
+        key part all heads share."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
     def layers_of(self, kind: str) -> tuple:
-        """Indices of the layers of one kind ("full" / "window"), in
+        """Indices of the layers of one kind ("full" / "window" /
+        "latent"), in
         order: a layer's place in this tuple is its place in the
         cache leaf of that kind."""
         return tuple(
@@ -234,6 +268,21 @@ class TransformerConfig:
                     f"n_kv_heads ({self.kv_heads}) must divide layer {l}'s "
                     f"{self.heads_of(l)} query heads"
                 )
+        if self.moe_score not in ("softmax", "sigmoid"):
+            raise ValueError(
+                f"moe_score is 'softmax' or 'sigmoid', got {self.moe_score!r}"
+            )
+        if self.latent:
+            self._check_latent()
+        elif self.sandwich_norm or any((
+            self.q_lora_rank, self.kv_lora_rank, self.qk_nope_head_dim,
+            self.qk_rope_head_dim, self.v_head_dim,
+        )):
+            raise ValueError(
+                "sandwich_norm, q_lora_rank, kv_lora_rank, qk_nope_head_dim, "
+                "qk_rope_head_dim and v_head_dim belong to latent_attention "
+                "layers: layer_types names none"
+            )
         if "sliding_attention" in self.layer_types and (
             not self.sliding_window or self.sliding_window % 8
         ):
@@ -245,6 +294,41 @@ class TransformerConfig:
             raise ValueError(
                 "the gated block has no learned positions: set rope=True"
             )
+        self._check_routed()
+
+    def _check_latent(self):
+        if set(self.layer_types) != {"latent_attention"}:
+            raise ValueError(
+                "latent_attention layers share no stack with full_attention "
+                "or sliding_attention ones: the cache is one latent leaf, "
+                f"got {self.layer_types}"
+            )
+        for name in ("q_lora_rank", "kv_lora_rank", "qk_nope_head_dim",
+                     "qk_rope_head_dim", "v_head_dim"):
+            if getattr(self, name) <= 0:
+                raise ValueError(f"latent_attention layers need {name} > 0")
+        if self.qk_rope_head_dim % 2:
+            raise ValueError(
+                f"qk_rope_head_dim ({self.qk_rope_head_dim}) rotates in "
+                "pairs: it must be even"
+            )
+        for name, asked in (
+            ("n_kv_heads", self.n_kv_heads not in (None, self.n_heads)),
+            ("head_size", self.head_size is not None),
+            ("layer_heads", bool(self.layer_heads)),
+            ("sliding_window", bool(self.sliding_window)),
+            ("attn_gate", self.attn_gate),
+            ("rope_full", self.rope_full is not None),
+        ):
+            if asked:
+                raise ValueError(
+                    f"{name} does not apply to latent_attention layers: "
+                    "every head reads the one latent row, head sizes are "
+                    "qk_nope_head_dim / qk_rope_head_dim / v_head_dim, and "
+                    "the rotary is plain rope_theta"
+                )
+
+    def _check_routed(self):
         routed = [l for l in range(self.n_layers) if l not in self.dense_layers]
         if routed and not (
             0 < self.n_experts <= self.n_experts_total - self.expert_first
@@ -350,12 +434,14 @@ def quantize_decode_params(params, cfg: TransformerConfig):
     already 3x smaller and the weight stream dominates (PERF.md r5
     crossover analysis).
     """
-    if cfg.n_experts:
+    if cfg.n_experts or cfg.latent:
         raise NotImplementedError(
-            "int8 decode quantization does not cover MoE experts yet: "
-            "neither MoEParams (moe_ffn) nor the held experts' "
-            "we_gate / we_up / we_down of a gated layer (moe_held_ffn) "
-            "have scale leaves or a dequantising grouped product"
+            "int8 decode quantization does not cover MoE experts or "
+            "latent attention yet: neither MoEParams (moe_ffn) nor the "
+            "held experts' we_gate / we_up / we_down of a gated layer "
+            "(moe_held_ffn) have scale leaves or a dequantising grouped "
+            "product, nor do a latent layer's wq_a / wq_b / wkv_a / w_uk "
+            "/ w_uv"
         )
     blocks = dict(params["blocks"])
     for name, axes in _INT8_BLOCK_AXES.items():
@@ -648,9 +734,10 @@ def _decode_tpad(total: int) -> int:
 
 
 def kv_row_write(cfg: "TransformerConfig") -> str:
-    """Who places a decode substep's new K and V rows in the cache:
-    ``"kernel"`` where the bf16 / f32 walk kernel runs (it writes the
-    row it is about to read, ``flash_decode_attention_write``),
+    """Who places a decode substep's new K and V rows (a latent stack's
+    one latent row) in the cache: ``"kernel"`` where the bf16 / f32 walk
+    kernel runs (it writes the row it is about to read,
+    ``flash_decode_attention_write`` / ``latent_decode_attention_write``),
     ``"xla"`` for the int8 slab (a scatter, or a
     ``dynamic_update_slice``, before the grid kernel) and on the dense
     path (``decode_kernel=False``: tp, LoRA banks). A fact of the
@@ -659,8 +746,20 @@ def kv_row_write(cfg: "TransformerConfig") -> str:
     return "kernel" if cfg.decode_kernel and not cfg.decode_int8 else "xla"
 
 
+def kv_cache_rows(cfg: "TransformerConfig") -> str:
+    """What a position's cache row is: ``"kv"`` a K and a V plane of
+    ``Hkv x K`` values a layer, ``"kv+ring"`` the same in two leaves (a
+    slab and a ring of ``sliding_window`` rows), ``"latent"`` one plane
+    of ``kv_lora_rank + qk_rope_head_dim`` values that is key and value
+    of every head. A fact of the configuration, like
+    :func:`kv_row_write`; the engine reports it."""
+    if cfg.latent:
+        return "latent"
+    return "kv+ring" if cfg.gated and cfg.layers_of("window") else "kv"
+
+
 def _decode_write_at(pos, ring: bool, leaf):
-    """The cache row of ``leaf`` (layers, 2, B, rows, Hkv*K) that
+    """The cache row of ``leaf`` (layers, planes, B, rows, width) that
     position ``pos`` is written to: ``pos % rows`` on a ring, ``pos`` on
     a slab. A scalar ``pos`` past a slab's end writes the last row, as
     the ``dynamic_update_slice`` it replaces clamped (speculative
@@ -897,8 +996,8 @@ def transformer_apply(
     """
     _gated_refuses(
         cfg, "training (transformer_apply / transformer_train_step)",
-        "_gated_block has no backward-ready attention for a window, "
-        "moe_held_ffn no auxiliary load-balancing loss, and "
+        "_gated_block has no backward-ready attention for a window or a "
+        "latent row, moe_held_ffn no auxiliary load-balancing loss, and "
         "transformer_shardings no layout for per-layer parameter dicts",
     )
     if (cfg.n_experts or cfg.sequence_parallel) and mesh is None:
@@ -1129,13 +1228,17 @@ def transformer_loss(cfg: TransformerConfig, mesh: Mesh | None = None):
 # B, rows, Hkv*K): "full" at the decode length, "window" a ring of
 # ``sliding_window`` rows in which position t lives at t % window. Keys are
 # cached rotated, so a ring's order does not matter to the softmax.
+# A stack of latent layers has ONE leaf, "latent": (layers, 1, B, rows,
+# latent_row_width): a position's normed latent and rotated shared key in
+# one plane, which is the key and the value of every head.
 
 def full_cache_leaf(caches):
     """The leaf of a cache pytree (arrays or shapes) whose rows run to
     the decode length: the array itself, ``kv`` of an int8 cache,
-    ``full`` of a cache grouped by layer kind."""
+    ``full`` of a cache grouped by layer kind, ``latent`` of a stack of
+    latent layers."""
     if isinstance(caches, dict):
-        return caches["kv"] if "kv" in caches else caches["full"]
+        return next(caches[k] for k in ("kv", "full", "latent") if k in caches)
     return caches
 
 
@@ -1174,10 +1277,12 @@ def _gated_rope(cfg: TransformerConfig, kind: str, positions, dtype):
     tables in ``dtype``; rot = 2 x the last axis is how many leading
     channels of a head rotate. Window layers: plain, whole head. Full
     layers: ``cfg.rope_full`` (YaRN over part of the head, tables
-    scaled by its attention_factor), or as window layers without it."""
+    scaled by its attention_factor), or as window layers without it.
+    Latent layers: plain over the ``qk_rope_head_dim`` rotary channels."""
     full = cfg.rope_full_settings if kind == "full" else None
     if full is None:
-        half = cfg.head_dim // 2
+        half = (cfg.qk_rope_head_dim if kind == "latent"
+                else cfg.head_dim) // 2
         inv = cfg.rope_theta ** (-np.arange(half, dtype=np.float64) / half)
         mscale = 1.0
     else:
@@ -1213,10 +1318,11 @@ _DENSE_SCORES_AT_ONCE = 32 * 1024 * 1024
 
 
 def _attend_dense(q, k, v, mask):
-    """Masked attention without a kernel. ``q`` (B, C, H, K); ``k``,
-    ``v`` (B, T, Hkv, K), query head j reading KV head j // (H / Hkv);
-    ``mask`` (B or 1, C, T) bool, True where the key is visible (every
-    query sees at least one). Softmax in float32. Returns (B, C, H, K)."""
+    """Masked attention without a kernel. ``q`` (B, C, H, K); ``k``
+    (B, T, Hkv, K) and ``v`` (B, T, Hkv, Kv), query head j reading KV
+    head j // (H / Hkv); ``mask`` (B or 1, C, T) bool, True where the
+    key is visible (every query sees at least one). Softmax in float32.
+    Returns (B, C, H, Kv)."""
     b, c, h, kd = q.shape
     t, hkv = k.shape[1], k.shape[2]
     qg = q.reshape(b, c, hkv, h // hkv, kd)
@@ -1239,7 +1345,7 @@ def _attend_dense(q, k, v, mask):
         o = lax.map(one_kv_head, heads_first)
     else:
         o = jax.vmap(one_kv_head)(heads_first)
-    return o.transpose(1, 2, 0, 3, 4).reshape(b, c, h, kd)
+    return o.transpose(1, 2, 0, 3, 4).reshape(b, c, h, v.shape[-1])
 
 
 def _attend_window(q, k, v, w: int):
@@ -1272,6 +1378,99 @@ def _attend_window(q, k, v, w: int):
     return o.reshape(b, t, h, kd)
 
 
+def latent_row_width(cfg: TransformerConfig) -> int:
+    """Stored width of a latent cache row: ``cfg.latent_row`` values
+    rounded up to whole 128-lane tiles (576 -> 640), the padding zero.
+    The TPU tiles the minor dimension by 128 lanes whatever is asked, so
+    the bytes are the same; stating them makes every slice of the decode
+    kernel lane-aligned."""
+    return -(-cfg.latent_row // 128) * 128
+
+
+def _latent_scale(cfg: TransformerConfig) -> float:
+    """The softmax scale of latent attention, ``1 / sqrt(n + p)``."""
+    return 1.0 / float(np.sqrt(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim))
+
+
+def _latent_dense(q_lat, rows, mask, r_kv: int):
+    """Latent attention in its absorbed form, without a kernel.
+    ``q_lat`` (B, C, H, R): a head's query folded onto the latent, then
+    its rotated part, the softmax scale included, then zeros; ``rows``
+    (B, T, R): cache rows, the normed latent (``r_kv`` values), the
+    rotated shared key, then the same lane padding; ``mask`` (B or 1, C, T) bool. One row is key (all R values)
+    and value (its first ``r_kv``) of every head. Softmax in float32;
+    heads go in groups that keep the scores under
+    ``_DENSE_SCORES_AT_ONCE``. Returns (B, C, H, r_kv)."""
+    b, c, h, r = q_lat.shape
+    t = rows.shape[1]
+    keys, vals = rows, rows[..., :r_kv]
+    group = max(
+        g for g in range(1, h + 1)
+        if h % g == 0 and (g == 1 or b * g * c * t <= _DENSE_SCORES_AT_ONCE)
+    )
+
+    def some_heads(qh):  # (B, C, G, R)
+        att = jnp.einsum(
+            "bcgr,btr->bgct", qh, keys, preferred_element_type=jnp.float32
+        )
+        att = jnp.where(mask[:, None], att, -jnp.inf)
+        w = jax.nn.softmax(att, axis=-1).astype(vals.dtype)
+        return jnp.einsum("bgct,btr->bcgr", w, vals)
+
+    qg = q_lat.reshape(b, c, h // group, group, r).transpose(2, 0, 1, 3, 4)
+    o = lax.map(some_heads, qg) if group < h else some_heads(qg[0])[None]
+    return o.transpose(1, 2, 0, 3, 4).reshape(b, c, h, r_kv)
+
+
+class LatentAttend(NamedTuple):
+    """A caller's way to reach a latent layer's keys, and to cache them
+    (:func:`_gated_block`'s ``attend`` for such a layer). ``absorbed``
+    False: ``fn(q, k, v, row)`` over keys and values expanded a head
+    (:func:`_latent_expanded`); True: ``fn(q_lat, row)`` over the cached
+    rows themselves (:func:`_latent_absorbed`). ``row`` (B, T, r_kv + p)
+    is what the position caches; either returns a head's part of the
+    output, (B, T, H, v) or (B, T, H, r_kv)."""
+
+    absorbed: bool
+    fn: Callable
+
+
+def _latent_expanded(cfg: TransformerConfig, p, qn, qp, row, attend_qkv):
+    """Latent attention as published (prefill's form): the latent rows
+    are expanded to a key and a value a head, ``k = [c W_uk, k_p]`` (the
+    rotated part shared by all heads) and ``v = c W_uv``, and
+    ``attend_qkv(q, k, v)`` (q, k (B, T, H, n + p), v (B, T, H, v) ->
+    (B, T, H, v)) attends over them."""
+    dt = qn.dtype
+    r = cfg.kv_lora_rank
+    c, kp = row[..., :r], row[..., r:]
+    kn = jnp.einsum("btc,chn->bthn", c, _w(p, "w_uk", dt))
+    v = jnp.einsum("btc,chv->bthv", c, _w(p, "w_uv", dt))
+    kp = jnp.broadcast_to(kp[:, :, None, :], kn.shape[:3] + kp.shape[-1:])
+    return attend_qkv(
+        jnp.concatenate([qn, qp], axis=-1),
+        jnp.concatenate([kn, kp], axis=-1), v,
+    )
+
+
+def _latent_absorbed(cfg: TransformerConfig, p, qn, qp, attend_rows):
+    """The same attention with the up-projections absorbed (decode's and
+    a chunk's form): ``q_c = q_n W_uk^T`` folds a head's query onto the
+    latent, ``attend_rows(q_lat)`` (q_lat (B, T, H, latent_row_width):
+    the folded query, its rotated part, zeros in the lane padding -> (B,
+    T, H, r_kv)) attends over the cached rows themselves, and ``W_uv``
+    takes the attended latent to the head's value. ``qn`` and ``qp``
+    carry the softmax scale ``1 / sqrt(n + p)`` already
+    (:func:`_gated_block` puts it into the query norm's gain for this
+    form), so ``attend_rows`` takes plain dot products."""
+    dt = qn.dtype
+    qc = jnp.einsum("bthn,chn->bthc", qn, _w(p, "w_uk", dt))
+    pad = latent_row_width(cfg) - cfg.latent_row
+    oc = attend_rows(jnp.concatenate(
+        [qc, qp, jnp.zeros(qc.shape[:-1] + (pad,), dt)], axis=-1))
+    return jnp.einsum("bthc,chv->bthv", oc, _w(p, "w_uv", dt))
+
+
 def _ring_write(leaf, idx: int, k, v, positions, last):
     """Write the rows of ``k``, ``v`` (B, C, Hkv*K) that a ring of
     ``leaf.shape[3]`` rows must hold once position ``last`` is its
@@ -1300,7 +1499,13 @@ def _gated_block(cfg: TransformerConfig, l: int, p, x, positions, attend,
     ``attend(q, k, v)`` (q (B, T, H_l, K), k / v (B, T, Hkv, K) rotated
     -> (B, T, H_l, K); the caller's way to reach keys, and to cache
     them), the per-head gate, the output projection, RMSNorm, then the
-    dense SwiGLU or the held experts' part plus the shared expert.
+    dense SwiGLU or the held experts' part plus the shared expert. A
+    latent layer's ``attend`` is a :class:`LatentAttend`: the caller
+    picks the form (expanded keys and values, or the cached rows
+    themselves) and is handed the position's cache row ([normed latent,
+    rotated shared key], (B, T, r_kv + p)) with the queries.
+    With ``sandwich_norm`` the attention's and the MLP's outputs are
+    normed before they join the residual stream.
     ``live`` (B,) bool: rows somebody reads (others route nowhere).
     Returns (x, counts): ``moe_held_ffn``'s int32 (3,), zeros in a dense
     layer."""
@@ -1312,36 +1517,69 @@ def _gated_block(cfg: TransformerConfig, l: int, p, x, positions, attend,
     dt = x.dtype
     kind = _KINDS[cfg.layer_types[l]]
     h = _rms_norm(x, p["ln1_scale"], cfg.norm_eps)
-    q = jnp.einsum("btd,dhk->bthk", h, _w(p, "wq", dt))
-    kv = jnp.einsum("btd,dshk->sbthk", h, _w(p, "wkv", dt))
-    cos, sin = _gated_rope(cfg, kind, positions, dt)
-    cos, sin = cos[..., None, :], sin[..., None, :]  # over the head axis
-    q = _rotate(q, cos, sin)
-    k = _rotate(kv[0], cos, sin)
-    o = attend(q, k, kv[1])
+    if kind == "latent":
+        n, r = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+        gain = p["q_norm_scale"]
+        if attend.absorbed:
+            # the absorbed form takes plain dot products of queries with
+            # the softmax scale in them; both query parts are linear in
+            # the query norm's gain, so it goes there, in float32, free
+            gain = gain.astype(jnp.float32) * _latent_scale(cfg)
+        cq = _rms_norm(h @ _w(p, "wq_a", dt), gain, cfg.norm_eps)
+        q = jnp.einsum("btr,rhk->bthk", cq, _w(p, "wq_b", dt))
+        ckp = h @ _w(p, "wkv_a", dt)  # (B, T, r_kv + p)
+        cos, sin = _gated_rope(cfg, kind, positions, dt)
+        row = jnp.concatenate([
+            _rms_norm(ckp[..., :r], p["kv_norm_scale"], cfg.norm_eps),
+            _rotate(ckp[..., r:], cos, sin),
+        ], axis=-1)
+        qn = q[..., :n]
+        qp = _rotate(q[..., n:], cos[..., None, :], sin[..., None, :])
+        if attend.absorbed:
+            o = _latent_absorbed(
+                cfg, p, qn, qp, lambda q_lat: attend.fn(q_lat, row))
+        else:
+            o = _latent_expanded(
+                cfg, p, qn, qp, row,
+                lambda q, k, v: attend.fn(q, k, v, row))
+    else:
+        q = jnp.einsum("btd,dhk->bthk", h, _w(p, "wq", dt))
+        kv = jnp.einsum("btd,dshk->sbthk", h, _w(p, "wkv", dt))
+        cos, sin = _gated_rope(cfg, kind, positions, dt)
+        cos, sin = cos[..., None, :], sin[..., None, :]  # over the head axis
+        q = _rotate(q, cos, sin)
+        k = _rotate(kv[0], cos, sin)
+        o = attend(q, k, kv[1])
     if cfg.attn_gate:
         gate = jax.nn.sigmoid(
             jnp.einsum("btd,dh->bth", h, _w(p, "wg", dt)).astype(jnp.float32)
         )
         o = o * gate[..., None].astype(dt)
-    x = x + jnp.einsum("bthk,hkd->btd", o, _w(p, "wo", dt))
+    a = jnp.einsum("bthk,hkd->btd", o, _w(p, "wo", dt))
+    if cfg.sandwich_norm:
+        a = _rms_norm(a, p["ln1_post_scale"], cfg.norm_eps)
+    x = x + a
     h2 = _rms_norm(x, p["ln2_scale"], cfg.norm_eps)
+    counts = None
     if l in cfg.dense_layers:
         y = swiglu(h2, _w(p, "w_gate", dt), _w(p, "w_up", dt),
                    _w(p, "w_down", dt))
-        return x + y, jnp.zeros((3,), jnp.int32)
-    b, t, d = h2.shape
-    y, counts = moe_held_ffn(
-        h2.reshape(b * t, d), p["router"], _w(p, "we_gate", dt),
-        _w(p, "we_up", dt), _w(p, "we_down", dt), first=cfg.expert_first,
-        k=cfg.moe_k, scale=cfg.moe_scale,
-        live=None if live is None else jnp.repeat(live, t),
-    )
-    y = y.reshape(b, t, d)
-    if cfg.d_shared:
-        y = y + swiglu(h2, _w(p, "ws_gate", dt), _w(p, "ws_up", dt),
-                       _w(p, "ws_down", dt))
-    return x + y, counts
+    else:
+        b, t, d = h2.shape
+        y, counts = moe_held_ffn(
+            h2.reshape(b * t, d), p["router"], _w(p, "we_gate", dt),
+            _w(p, "we_up", dt), _w(p, "we_down", dt), first=cfg.expert_first,
+            k=cfg.moe_k, scale=cfg.moe_scale, score=cfg.moe_score,
+            live=None if live is None else jnp.repeat(live, t),
+        )
+        y = y.reshape(b, t, d)
+        if cfg.d_shared:
+            y = y + swiglu(h2, _w(p, "ws_gate", dt), _w(p, "ws_up", dt),
+                           _w(p, "ws_down", dt))
+    if cfg.sandwich_norm:
+        y = _rms_norm(y, p["ln2_post_scale"], cfg.norm_eps)
+    x = x + y
+    return x, jnp.zeros((3,), jnp.int32) if counts is None else counts
 
 
 def _init_gated(key, cfg: TransformerConfig):
@@ -1363,12 +1601,33 @@ def _init_gated(key, cfg: TransformerConfig):
     for l, kl in enumerate(jax.random.split(k_layers, cfg.n_layers)):
         ks = jax.random.split(kl, 12)
         h = cfg.heads_of(l)
-        p = {
-            "ln1_scale": jnp.ones((d,)), "ln2_scale": jnp.ones((d,)),
-            "wq": norm(ks[0], (d, h, kd), d),
-            "wkv": norm(ks[1], (d, 2, hkv, kd), d),
-            "wo": norm(ks[2], (h, kd, d), h * kd),
-        }
+        p = {"ln1_scale": jnp.ones((d,)), "ln2_scale": jnp.ones((d,))}
+        if cfg.sandwich_norm:
+            p["ln1_post_scale"] = jnp.ones((d,))
+            p["ln2_post_scale"] = jnp.ones((d,))
+        if cfg.latent:
+            # W_kvb is held as its two halves: the key part W_uk and the
+            # value part W_uv are separate operands of the absorbed form
+            rq, rkv = cfg.q_lora_rank, cfg.kv_lora_rank
+            n, rp, vd = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                         cfg.v_head_dim)
+            ka = jax.random.split(ks[11], 3)
+            p.update({
+                "wq_a": norm(ks[0], (d, rq), d),
+                "q_norm_scale": jnp.ones((rq,)),
+                "wq_b": norm(ka[0], (rq, h, n + rp), rq),
+                "wkv_a": norm(ks[1], (d, rkv + rp), d),
+                "kv_norm_scale": jnp.ones((rkv,)),
+                "w_uk": norm(ka[1], (rkv, h, n), rkv),
+                "w_uv": norm(ka[2], (rkv, h, vd), rkv),
+                "wo": norm(ks[2], (h, vd, d), h * vd),
+            })
+        else:
+            p.update({
+                "wq": norm(ks[0], (d, h, kd), d),
+                "wkv": norm(ks[1], (d, 2, hkv, kd), d),
+                "wo": norm(ks[2], (h, kd, d), h * kd),
+            })
         if cfg.attn_gate:
             p["wg"] = norm(ks[3], (d, h), d)
         if l in cfg.dense_layers:
@@ -1403,18 +1662,22 @@ def _gated_builder(cfg: TransformerConfig):
     if cfg.decode_int8:
         _gated_refuses(
             cfg, "decode_int8",
-            "its ring leaf has no int8 rows or scale planes, and "
-            "quantize_decode_params does not cover its experts",
+            "neither its ring leaf nor its latent leaf has int8 rows or "
+            "scale planes, and quantize_decode_params covers neither its "
+            "experts nor its latent projections",
         )
     d, kd, hkv = cfg.d_model, cfg.head_dim, cfg.kv_heads
     hk = hkv * kd
     place = {  # layer -> (kind, index in that kind's leaf)
-        l: (kind, i) for kind in ("full", "window")
+        l: (kind, i) for kind in ("full", "window", "latent")
         for i, l in enumerate(cfg.layers_of(kind))
     }
-
     def init_caches(batch: int, total: int):
         tpad = _decode_tpad(total)
+        if cfg.latent:  # one plane: a row is key and value of every head
+            return {"latent": jnp.zeros(
+                (cfg.n_layers, 1, batch, tpad, latent_row_width(cfg)),
+                cfg.compute_dtype)}
         out = {"full": jnp.zeros(
             (len(cfg.layers_of("full")), 2, batch, tpad, hk),
             cfg.compute_dtype)}
@@ -1466,12 +1729,34 @@ def _gated_builder(cfg: TransformerConfig):
         x = leaf[idx, plane]
         return x.reshape(x.shape[0], x.shape[1], hkv, kd)
 
+    def lane_padded(x):  # (..., latent_row) -> (..., latent_row_width)
+        pad = latent_row_width(cfg) - x.shape[-1]
+        return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+
+    def latent_chunk_attend(caches, idx, positions):
+        """A latent layer's ``attend`` for C rows at ``positions``: the
+        rows go to the leaf, then the absorbed form over the slab."""
+        def attend(q_lat, row):
+            leaf = caches["latent"]
+            b, c = row.shape[:2]
+            leaf = leaf.at[
+                idx, 0, jnp.arange(b)[:, None],
+                jnp.broadcast_to(positions, (b, c)),
+            ].set(lane_padded(row).astype(leaf.dtype), mode="drop")
+            caches["latent"] = leaf
+            mask = jnp.arange(leaf.shape[3]) <= positions[..., None]
+            return _latent_dense(q_lat, leaf[idx, 0], mask, cfg.kv_lora_rank)
+
+        return LatentAttend(True, attend)
+
     def chunk_attend(caches, l, positions, last):
         """``attend`` of layer ``l`` for C rows at ``positions`` (B or 1,
         C) against the cache, rows up to ``last`` (B or 1, 1) real. A
         full layer writes its rows and reads the slab; a window layer
         reads the ring as it stood plus its own rows, then writes."""
         kind, idx = place[l]
+        if kind == "latent":
+            return latent_chunk_attend(caches, idx, positions)
 
         def attend(q, k, v):
             leaf = caches[kind]
@@ -1528,9 +1813,22 @@ def _gated_builder(cfg: TransformerConfig):
         active."""
         from deeplearning4j_tpu.ops.pallas_kernels import (
             flash_decode_attention_write,
+            latent_decode_attention_write,
         )
 
         kind, idx = place[l]
+
+        def attend_latent(q_lat, row):  # (B, 1, H, width), (B, 1, r_kv + p)
+            o, caches[kind] = latent_decode_attention_write(
+                q_lat[:, 0], caches[kind], lane_padded(row),
+                pos, value_width=cfg.kv_lora_rank, layer=idx,
+                write_at=_decode_write_at(pos, False, caches[kind]),
+                active=active,
+            )
+            return o[:, None]
+
+        if kind == "latent":
+            return LatentAttend(True, attend_latent)
 
         def attend(q, k, v):
             leaf = caches[kind]
@@ -1593,8 +1891,48 @@ def _gated_builder(cfg: TransformerConfig):
         w = cfg.sliding_window
         flash = cfg.use_flash and _flash_seq_ok(tp)
 
+        def flash_attend(q, k, v, grp: int):
+            """Causal flash attention over (B, T, H, K) heads, ``k`` and
+            ``v`` repeated ``grp`` times; the kernel has one head size,
+            so a narrower ``v`` is zero-padded to K and the result cut."""
+            from deeplearning4j_tpu.ops.pallas_kernels import (
+                flash_attention_trainable,
+            )
+
+            kd_, vd = q.shape[-1], v.shape[-1]
+            if vd < kd_:
+                v = jnp.pad(v, [(0, 0)] * 3 + [(0, kd_ - vd)])
+            bq, bk = _flash_blocks(tp)
+            o = flash_attention_trainable(
+                q.transpose(0, 2, 1, 3),
+                jnp.repeat(k.transpose(0, 2, 1, 3), grp, axis=1),
+                jnp.repeat(v.transpose(0, 2, 1, 3), grp, axis=1),
+                causal=True, block_q=bq, block_k=bk, layout="bhtd",
+            )
+            o = o.transpose(0, 2, 1, 3)
+            return o[..., :vd] if vd < kd_ else o
+
+        def whole_sequence(q, k, v, grp: int):
+            if flash:
+                return flash_attend(q, k, v, grp)
+            mask = positions[None, :] <= positions[:, None]
+            return _attend_dense(q, k, v, mask[None])
+
         def attend_of(l):
             kind, idx = place[l]
+
+            def attend_latent(q, k, v, row):
+                # the expanded form over the whole bucket; what is
+                # cached is the latent row, not K and V
+                caches[kind] = lax.dynamic_update_slice(
+                    caches[kind],
+                    lane_padded(row)[None, None].astype(caches[kind].dtype),
+                    (idx, 0, 0, 0, 0),
+                )
+                return whole_sequence(q, k, v, 1)
+
+            if kind == "latent":
+                return LatentAttend(False, attend_latent)
 
             def attend(q, k, v):
                 leaf = caches[kind]
@@ -1612,22 +1950,7 @@ def _gated_builder(cfg: TransformerConfig):
                     )
                 if kind == "window" and tp > w:
                     return _attend_window(q, k, v, w)
-                if not flash:
-                    mask = positions[None, :] <= positions[:, None]
-                    return _attend_dense(q, k, v, mask[None])
-                from deeplearning4j_tpu.ops.pallas_kernels import (
-                    flash_attention_trainable,
-                )
-
-                grp = q.shape[2] // hkv
-                bq, bk = _flash_blocks(tp)
-                o = flash_attention_trainable(
-                    q.transpose(0, 2, 1, 3),
-                    jnp.repeat(k.transpose(0, 2, 1, 3), grp, axis=1),
-                    jnp.repeat(v.transpose(0, 2, 1, 3), grp, axis=1),
-                    causal=True, block_q=bq, block_k=bk, layout="bhtd",
-                )
-                return o.transpose(0, 2, 1, 3)
+                return whole_sequence(q, k, v, q.shape[2] // hkv)
 
             return attend
 
@@ -1678,7 +2001,7 @@ def _decode_builder(cfg: TransformerConfig, tp_mesh=None):
         _gated_refuses(
             cfg, "tensor-parallel serving (tp > 1)",
             "serving_tp_shardings has no layout for per-layer head "
-            "counts, held experts or a ring leaf",
+            "counts, held experts, a ring leaf or latent projections",
         )
     if cfg.gated:
         return _gated_builder(cfg)[:4]
@@ -2195,7 +2518,8 @@ def paged_block_copy(blocks, src, dst):
 
 def decode_rows_streamed(cfg: TransformerConfig, batch: int, tpad: int,
                          held, paged: bool = False) -> int:
-    """Cache rows (of one layer's K or V plane) that one ``forward_one``
+    """Cache rows (of one layer's K or V plane, or of its one latent
+    plane) that one ``forward_one``
     call over ``batch`` slots of ``tpad`` rows reads. ``held``: for each
     ACTIVE batch row, the rows it attends to (its position + 1); a row
     that is not active has no entry.
@@ -2212,14 +2536,23 @@ def decode_rows_streamed(cfg: TransformerConfig, batch: int, tpad: int,
     def leaf(rows: int) -> int:
         if paged or not cfg.decode_kernel:
             return batch * rows
-        from deeplearning4j_tpu.ops.pallas_kernels import decode_block_rows
+        from deeplearning4j_tpu.ops.pallas_kernels import (
+            decode_block_rows,
+            latent_block_rows,
+        )
 
         itemsize = (1 if cfg.decode_int8
                     else jnp.dtype(cfg.compute_dtype).itemsize)
-        block = decode_block_rows(rows, cfg.kv_heads * cfg.head_dim, itemsize)
+        # the block rows are the kernel's own rule over the leaf's own
+        # row width: K and V planes of Hkv x K, or the one latent plane
+        if cfg.latent:
+            block = latent_block_rows(rows, latent_row_width(cfg), itemsize)
+        else:
+            block = decode_block_rows(
+                rows, cfg.kv_heads * cfg.head_dim, itemsize)
         return sum(min(-(-h // block) * block, rows) for h in held)
 
-    if not cfg.gated:
+    if not cfg.gated or cfg.latent:
         return leaf(tpad)
     # two leaves: a layer-weighted mean, so that the sum over the layers
     # is what the kernels of both leaves read (a ring never more than
@@ -2234,7 +2567,7 @@ def decode_rows_live(cfg: TransformerConfig, held) -> int:
     ``forward_one`` call NEED: ``held`` summed; in a gated stack a
     window layer needs at most ``sliding_window`` of them, and the count
     is the same layer-weighted mean as :func:`decode_rows_streamed`'s."""
-    if not cfg.gated:
+    if not cfg.gated or cfg.latent:
         return sum(held)
     n_full, n_win = len(cfg.layers_of("full")), len(cfg.layers_of("window"))
     return sum(
@@ -2332,7 +2665,8 @@ def transformer_beam_search(cfg: TransformerConfig):
     _gated_refuses(
         cfg, "beam search",
         "the beam reorder gathers one stacked cache along its batch axis "
-        "and has not been tried on two leaves",
+        "and has not been tried on leaves grouped by layer kind (a slab "
+        "and a ring, or latent rows)",
     )
     forward_one, init_caches, do_prefill, cast_params = _decode_builder(cfg)
 
@@ -2405,21 +2739,33 @@ def transformer_beam_search(cfg: TransformerConfig):
 _SELECT_CHUNKS = (128, 8)
 
 
+def select_chunks(vocab: int, top_k: int) -> tuple:
+    """The chunk widths ``_kth_largest`` narrows a row of ``vocab``
+    logits by: first 128 where ``top_k`` chunks of 128 hold at most a
+    quarter of the row (``top_k * 128 * 4 <= vocab``), else 64 under
+    the same condition (a 19,200-id slice of a vocabulary at k = 40,
+    where the sort of the row cost 4 ms a substep at 224 slots:
+    PERF.md, PR 31), then 8. Empty: the row is too short for a level
+    to pay."""
+    for chunk in (_SELECT_CHUNKS[0], 64):
+        if top_k * chunk * 4 <= vocab:
+            return (chunk, _SELECT_CHUNKS[1])
+    return ()
+
+
 def topk_select(vocab: int, top_k: int | None,
                 approx_top_k: bool = False) -> str:
     """Which branch of ``_top_k_filter`` these static sizes take:
     ``"none"`` (no filter), ``"approx"`` (``approx_max_k``), and for
     the exact threshold ``"chunked"`` (the selection by chunks) where
-    the first level's candidates are at most a quarter of the row,
-    ``"sort"`` (``lax.top_k`` over the row) otherwise. The engine
-    reports it."""
+    :func:`select_chunks` finds a first level whose candidates are at
+    most a quarter of the row, ``"sort"`` (``lax.top_k`` over the row)
+    otherwise. The engine reports it."""
     if top_k is None:
         return "none"
     if approx_top_k:
         return "approx"
-    if top_k * _SELECT_CHUNKS[0] * 4 <= vocab:
-        return "chunked"
-    return "sort"
+    return "chunked" if select_chunks(vocab, top_k) else "sort"
 
 
 def _chunk_candidates(x, k: int, chunk: int):
@@ -2473,7 +2819,7 @@ def _top_k_filter(logits, top_k: int | None, approx_top_k: bool):
         kth = lax.approx_max_k(logits, top_k)[0][..., -1:]
     else:
         kth = _kth_largest(
-            logits, top_k, _SELECT_CHUNKS if how == "chunked" else ()
+            logits, top_k, select_chunks(logits.shape[-1], top_k)
         )
     return jnp.where(logits < kth, -jnp.inf, logits)
 
@@ -2750,8 +3096,9 @@ def transformer_speculative_generate(
     for c in (cfg, draft_cfg):
         _gated_refuses(
             c, "speculative decoding",
-            "a rejected draft rewinds the position, and a ring leaf has "
-            "already overwritten the rows the rewound window needs",
+            "a rejected draft rewinds the position: a ring leaf has "
+            "already overwritten the rows the rewound window needs, and "
+            "the verify chunk has not been tried on a latent leaf",
         )
     _, t_init, t_prefill, t_cast = _decode_builder(cfg)
     t_chunk = _chunk_builder(cfg)

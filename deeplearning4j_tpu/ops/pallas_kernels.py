@@ -803,17 +803,100 @@ def _bounded_grid_kernel(layer_ref, last_ref, src_ref, hi_ref, q_ref, k_ref,
     _flash_decode_kernel(q_ref, k_ref, v_ref, last_ref, *rest, **kw)
 
 
+def _kv_walk_math(n_kv_heads: int, head_dim: int, groups: int,
+                  scale: float, block_t: int):
+    """The walk's arithmetic over K and V planes: ``math(q_ref, dtype)``
+    for a row's (1, G, hk) query block gives ``(state0, fold, finish)``,
+    the online softmax of :func:`_decode_block` over blocks of two
+    planes (``state0`` is a function: the state is made where the loop
+    starts)."""
+    def math(q_ref, dtype):
+        e_tile, s_g = _decode_structure(groups, n_kv_heads, head_dim)
+        m_t, _ = _decode_fold_query(q_ref[0], e_tile, s_g, dtype, False)
+        gh = groups * n_kv_heads
+
+        def state0():
+            return (
+                jnp.full((1, gh), -jnp.inf, jnp.float32),
+                jnp.zeros((1, gh), jnp.float32),
+                jnp.zeros(q_ref.shape[1:], jnp.float32),
+            )
+
+        def fold(state, buf, slot, j, last):
+            return _decode_block(
+                state, buf[slot, 0], buf[slot, 1], None, m_t, None, e_tile,
+                s_g, j * block_t, last, scale,
+            )
+
+        def finish(state):
+            _, l, acc = state
+            return _decode_output(l, acc, e_tile, s_g)
+
+        return state0, fold, finish
+
+    return math
+
+
+def _latent_walk_math(value_width: int):
+    """The walk's arithmetic over ONE plane of latent rows: a row's
+    ``q`` (H, W) holds every head's query folded onto the latent (scale
+    included), a block (block_t, W) is the key of all of them and, in
+    its first ``value_width`` lanes, their value, so it is read once for
+    both products. Heads lie on sublanes and cache rows on lanes, as in
+    the flash forward kernel: scores (H, block_t), softmax state (H, 1),
+    accumulator (H, value_width), no segment masks."""
+    def math(q_ref, dtype):
+        q = q_ref[0]
+        h = q.shape[0]
+
+        def state0():
+            return (
+                jnp.full((h, 1), -jnp.inf, jnp.float32),
+                jnp.zeros((h, 1), jnp.float32),
+                jnp.zeros((h, value_width), jnp.float32),
+            )
+
+        def fold(state, buf, slot, j, last):
+            m_prev, l_prev, acc = state
+            kb = buf[slot, 0]  # (block_t, W): block j of the row's walk
+            s = jax.lax.dot_general(
+                q, kb, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # (H, block_t)
+            cols = j * kb.shape[0] + jax.lax.broadcasted_iota(
+                jnp.int32, (1, kb.shape[0]), 1
+            )
+            s = jnp.where(cols > last, -jnp.inf, s)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_new = corr * l_prev + jnp.sum(p, axis=1, keepdims=True)
+            pv = jnp.dot(
+                p.astype(kb.dtype), kb[:, :value_width],
+                preferred_element_type=jnp.float32,
+            )
+            return m_new, l_new, acc * corr + pv
+
+        def finish(state):
+            _, l, acc = state
+            return acc / jnp.maximum(l, 1e-30)
+
+        return state0, fold, finish
+
+    return math
+
+
 def _walk_decode_kernel(
     layer_ref, last_ref, base_ref, nxt_ref, *rest,
-    block_t: int, n_kv_heads: int, head_dim: int, groups: int,
-    scale: float, write_rows: int = 0,
+    block_t: int, math, planes: int = 2, write_rows: int = 0,
 ):
     """One batch row of the bounded walk: the stacked cache stays in
     HBM and the row's blocks 0..``last // block_t`` come in by explicit
-    copies into ``_WALK_BUFFERS`` buffers (K and V planes in one strided
-    copy), so no grid step and no copy is spent on a block nobody
-    reads; a row that is not active (``last`` = -1) copies nothing and
-    writes zeros.
+    copies into ``_WALK_BUFFERS`` buffers (all ``planes`` of a block, K
+    and V, in one strided copy), so no grid step and no copy is spent on
+    a block nobody reads; a row that is not active (``last`` = -1)
+    copies nothing and writes zeros. ``math`` is the arithmetic over the
+    blocks (:func:`_kv_walk_math`, :func:`_latent_walk_math`).
 
     The blocks of ALL rows form one sequence, and the copy of the block
     ``_WALK_BUFFERS - 1`` places ahead in that sequence — the next rows'
@@ -824,7 +907,7 @@ def _walk_decode_kernel(
     none).
 
     ``write_rows`` > 0: the kernel also PLACES the row's fresh K and V
-    (``new_ref``, (1, 2, hk)) at cache row ``at_ref[i]``: in the block
+    (``new_ref``, (1, planes, width)) at cache row ``at_ref[i]``: in the block
     that holds that row it patches the aligned ``write_rows``-row tile
     in VMEM before the arithmetic reads it, and copies the patched tile
     (both planes) back to HBM from a scratch of its own, so the walk's
@@ -850,7 +933,7 @@ def _walk_decode_kernel(
     def copy(row, j, slot):
         return pltpu.make_async_copy(
             kv_hbm.at[
-                layer_ref[0], pl.ds(0, 2), row,
+                layer_ref[0], pl.ds(0, planes), row,
                 pl.ds(pl.multiple_of(j * block_t, block_t), block_t),
             ],
             buf.at[slot], sem.at[slot],
@@ -860,7 +943,7 @@ def _walk_decode_kernel(
         return pltpu.make_async_copy(
             wbuf,
             kv_hbm.at[
-                layer_ref[0], pl.ds(0, 2), row,
+                layer_ref[0], pl.ds(0, planes), row,
                 pl.ds(pl.multiple_of(t0, write_rows), write_rows),
             ],
             wsem.at[0],
@@ -900,8 +983,7 @@ def _walk_decode_kernel(
 
     @pl.when(last >= 0)
     def _walk():
-        e_tile, s_g = _decode_structure(groups, n_kv_heads, head_dim)
-        m_t, _ = _decode_fold_query(q_ref[0], e_tile, s_g, buf.dtype, False)
+        state0, fold, finish = math(q_ref, buf.dtype)
 
         def place(j, slot):
             at = at_ref[i]
@@ -931,21 +1013,10 @@ def _walk_decode_kernel(
             start(*ahead, (g + _WALK_BUFFERS - 1) % _WALK_BUFFERS)
             if write_rows:
                 place(j, slot)
-            return _decode_block(
-                state, buf[slot, 0], buf[slot, 1], None, m_t, None, e_tile,
-                s_g, j * block_t, last, scale,
-            )
+            return fold(state, buf, slot, j, last)
 
-        gh = groups * n_kv_heads
-        _, l, acc = jax.lax.fori_loop(
-            0, n_blocks(i), block,
-            (
-                jnp.full((1, gh), -jnp.inf, jnp.float32),
-                jnp.zeros((1, gh), jnp.float32),
-                jnp.zeros(q_ref.shape[1:], jnp.float32),
-            ),
-        )
-        o_ref[0] = _decode_output(l, acc, e_tile, s_g).astype(o_ref.dtype)
+        state = jax.lax.fori_loop(0, n_blocks(i), block, state0())
+        o_ref[0] = finish(state).astype(o_ref.dtype)
 
     if write_rows:
         @pl.when(i == b - 1)
@@ -1062,6 +1133,153 @@ def flash_decode_attention_write(
     )
 
 
+def latent_block_rows(t: int, width: int, itemsize: int) -> int:
+    """Rows of one T block of :func:`latent_decode_attention_write` over
+    a ``t``-row slab of one plane of ``width`` values: the rule of
+    :func:`decode_block_rows` for the same bytes a row (one plane of
+    ``width`` moves what K and V planes of ``width / 2`` do). The kernel
+    and the engine's row counter both read it."""
+    return decode_block_rows(t, width // 2, itemsize)
+
+
+def latent_decode_attention_write(
+    q: jax.Array,
+    cache: jax.Array,
+    row_new: jax.Array | None,
+    pos: jax.Array,
+    value_width: int,
+    layer: int = 0,
+    write_at: jax.Array | None = None,
+    active: jax.Array | None = None,
+    block_t: int | None = None,
+    interpret: bool | None = None,
+) -> tuple[jax.Array, jax.Array]:
+    """One decode step of latent attention in its absorbed form, over a
+    cache of ONE plane, that also places the step's fresh row.
+
+    ``q`` (B, H, W): every head's query folded onto the latent, then its
+    rotated part, the softmax scale included, zero in the lane padding.
+    ``cache``: the stacked (layers, 1, B, T, W) leaf; a row is the
+    normed latent (``value_width`` values), the rotated shared key, and
+    zeros up to W (a multiple of 128 lanes). ``row_new`` (B, 1, W) goes
+    to cache row ``write_at`` (default ``pos``) of ``cache[layer, 0,
+    b]`` before the walk reads it (``None``: nothing is written and the
+    cache comes back as it was; row ``pos`` is already there). Scores
+    are ``q . row`` over all W lanes; the value of every head is the
+    row's first ``value_width`` lanes, so a block is copied in once and
+    used in both products.
+    Returns ``(o, cache)``: ``o`` (B, H, value_width) and the cache, the
+    same buffer in and out.
+
+    The walk is :func:`flash_decode_attention_write`'s: blocks up to the
+    row's position, nothing read or written for a row that is not
+    ``active``, copies in flight across rows, the new row patched into
+    an aligned ``_WRITE_ROWS``-row tile."""
+    b, _, width = q.shape
+    t = cache.shape[3]
+    assert cache.shape[1] == 1 and cache.shape[4] == width, (
+        cache.shape, q.shape)
+    if block_t is None:
+        block_t = latent_block_rows(t, width, cache.dtype.itemsize)
+    block_t = min(block_t, t)
+    assert t % block_t == 0, (t, block_t)
+    return _latent_decode_attention(
+        q, cache, row_new, jnp.asarray(pos, jnp.int32), active,
+        jnp.asarray(layer, jnp.int32),
+        jnp.asarray(pos if write_at is None else write_at, jnp.int32),
+        value_width=value_width, block_t=block_t,
+        interpret=_default_interpret() if interpret is None else interpret,
+    )
+
+
+@functools.partial(
+    jax.jit, static_argnames=("value_width", "block_t", "interpret")
+)
+def _latent_decode_attention(q, cache, row_new, pos, active, layer, write_at,
+                             *, value_width: int, block_t: int,
+                             interpret: bool):
+    b = q.shape[0]
+    last = _decode_last_rows(pos, active, b, cache.shape[3])
+    out = _walk_call(
+        _latent_walk_math(value_width), q, cache, row_new, write_at, last,
+        jnp.reshape(layer, (1,)), out_width=value_width, block_t=block_t,
+        interpret=interpret, name="latent_decode_attn",
+    )
+    return (out, cache) if row_new is None else out
+
+
+def _walk_call(math, q, kvcache, kv_new, write_at, last, layer, *,
+               out_width: int, block_t: int, interpret: bool, name: str):
+    """The bounded walk (:func:`_walk_decode_kernel`) of ``q`` (B, G, W)
+    over the stacked cache (layers, planes, B, T, W) with ``math`` as
+    its arithmetic: (B, G, out_width), and with ``kv_new`` (B, planes,
+    W) also the cache, updated in place."""
+    b, g, width = q.shape
+    planes = kvcache.shape[1]
+    n_blocks = (last + block_t) // block_t
+    rows = jnp.arange(b, dtype=jnp.int32)
+    nxt = jnp.concatenate([
+        jax.lax.cummin(jnp.where(n_blocks > 0, rows, b), reverse=True),
+        jnp.full((1,), b, jnp.int32),
+    ])
+    prefetch = [layer, last, jnp.cumsum(n_blocks) - n_blocks, nxt]
+
+    def row(i, *_):
+        return (i, 0, 0)
+
+    in_specs = [
+        pl.BlockSpec((1, g, width), row),
+        pl.BlockSpec(memory_space=pl.ANY),
+    ]
+    operands = [q, kvcache]
+    out_shape = jax.ShapeDtypeStruct((b, g, out_width), q.dtype)
+    out_specs = pl.BlockSpec((1, g, out_width), row)
+    scratch = [
+        pltpu.VMEM((_WALK_BUFFERS, planes, block_t, width), kvcache.dtype),
+        pltpu.SemaphoreType.DMA((_WALK_BUFFERS,)),
+    ]
+    write_rows, aliases = 0, {}
+    if kv_new is not None:
+        write_rows = _WRITE_ROWS
+        assert kv_new.shape == (b, planes, width), (kv_new.shape, q.shape)
+        prefetch.append(_decode_positions(write_at, b))
+        in_specs.insert(1, pl.BlockSpec((1, planes, width), row))
+        operands.insert(1, kv_new.astype(kvcache.dtype))
+        out_shape = (
+            out_shape,
+            jax.ShapeDtypeStruct(kvcache.shape, kvcache.dtype),
+        )
+        out_specs = (out_specs, pl.BlockSpec(memory_space=pl.ANY))
+        scratch += [
+            pltpu.VMEM((planes, write_rows, width), kvcache.dtype),
+            pltpu.SemaphoreType.DMA((1,)),
+            pltpu.SMEM((1,), jnp.int32),
+        ]
+        # the cache is updated in place: the last operand is the
+        # second result
+        aliases = {len(prefetch) + len(operands) - 1: 1}
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch),
+        grid=(b,),
+        in_specs=in_specs,
+        out_specs=out_specs,
+        scratch_shapes=scratch,
+    )
+    return pl.pallas_call(
+        functools.partial(
+            _walk_decode_kernel, block_t=block_t, math=math, planes=planes,
+            write_rows=write_rows,
+        ),
+        out_shape=out_shape,
+        grid_spec=grid_spec,
+        input_output_aliases=aliases,
+        # rows in order: each starts the next one's first copies
+        compiler_params=_dim_semantics(interpret, ("arbitrary",)),
+        interpret=interpret,
+        name=name,
+    )(*prefetch, *operands)
+
+
 @functools.partial(
     jax.jit, static_argnames=("n_kv_heads", "block_t", "interpret")
 )
@@ -1083,62 +1301,11 @@ def _decode_attention(q, kvcache, pos, active, layer, kv_scales, kv_new,
         return (i, 0, 0)
 
     if kv_scales is None:
-        n_blocks = (last + block_t) // block_t
-        rows = jnp.arange(b, dtype=jnp.int32)
-        nxt = jnp.concatenate([
-            jax.lax.cummin(jnp.where(n_blocks > 0, rows, b), reverse=True),
-            jnp.full((1,), b, jnp.int32),
-        ])
-        prefetch = [layer, last, jnp.cumsum(n_blocks) - n_blocks, nxt]
-        in_specs = [
-            pl.BlockSpec((1, g, hk), row),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ]
-        operands = [q, kvcache]
-        out_specs = pl.BlockSpec((1, g, hk), row)
-        scratch = [
-            pltpu.VMEM((_WALK_BUFFERS, 2, block_t, hk), kvcache.dtype),
-            pltpu.SemaphoreType.DMA((_WALK_BUFFERS,)),
-        ]
-        write_rows, aliases = 0, {}
-        if kv_new is not None:
-            write_rows = _WRITE_ROWS
-            assert kv_new.shape == (b, 2, hk), (kv_new.shape, q.shape)
-            prefetch.append(_decode_positions(write_at, b))
-            in_specs.insert(1, pl.BlockSpec((1, 2, hk), row))
-            operands.insert(1, kv_new.astype(kvcache.dtype))
-            out_shape = (
-                out_shape,
-                jax.ShapeDtypeStruct(kvcache.shape, kvcache.dtype),
-            )
-            out_specs = (out_specs, pl.BlockSpec(memory_space=pl.ANY))
-            scratch += [
-                pltpu.VMEM((2, write_rows, hk), kvcache.dtype),
-                pltpu.SemaphoreType.DMA((1,)),
-                pltpu.SMEM((1,), jnp.int32),
-            ]
-            # the cache is updated in place: the last operand is the
-            # second result
-            aliases = {len(prefetch) + len(operands) - 1: 1}
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=len(prefetch),
-            grid=(b,),
-            in_specs=in_specs,
-            out_specs=out_specs,
-            scratch_shapes=scratch,
+        return _walk_call(
+            _kv_walk_math(n_kv_heads, head_dim, g, statics["scale"], block_t),
+            q, kvcache, kv_new, write_at, last, layer, out_width=hk,
+            block_t=block_t, interpret=interpret, name="decode_attn",
         )
-        return pl.pallas_call(
-            functools.partial(
-                _walk_decode_kernel, write_rows=write_rows, **statics
-            ),
-            out_shape=out_shape,
-            grid_spec=grid_spec,
-            input_output_aliases=aliases,
-            # rows in order: each starts the next one's first copies
-            compiler_params=_dim_semantics(interpret, ("arbitrary",)),
-            interpret=interpret,
-            name="decode_attn",
-        )(*prefetch, *operands)
 
     assert kv_new is None, "the int8 slab's rows are written by XLA"
     assert kvcache.dtype == jnp.int8, kvcache.dtype

@@ -291,22 +291,30 @@ def moe_reference(params: MoEParams, x, *, k: int = 2,
 # code serves a 64-row decode step and a 1,024-row prefill chunk.
 
 
-def route_top_k(h, router, *, k: int, scale: float):
-    """Softmax-then-top-k routing over every published expert.
+def route_top_k(h, router, *, k: int, scale: float, score: str = "softmax"):
+    """Score-then-top-k routing over every published expert: ``score``
+    ``"softmax"`` (over all experts) or ``"sigmoid"`` (each expert
+    alone). Both grow with the logit, so they select the same set and
+    differ in the weights.
 
-    ``h`` (N, D), ``router`` (D, E_total). The product, the softmax and
+    ``h`` (N, D), ``router`` (D, E_total). The product, the scores and
     the weights are float32 whatever the compute dtype: a bf16 product
     moves a token's k-th and (k+1)-th expert past each other far more
     often than the rounding of the activations does. Returns ``(ids,
-    weights)``, both (N, k): the k largest probabilities' experts and
-    ``scale * p_e / sum_{e' in top k} p_e'``."""
+    weights)``, both (N, k): the k largest scores' experts and ``scale
+    * s_e / sum_{e' in top k} s_e'`` (a sigmoid's sum + 1e-20)."""
     logits = jnp.dot(
         h.astype(jnp.float32), router.astype(jnp.float32),
         precision=lax.Precision.HIGHEST,
     )
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_i = lax.top_k(probs, k)
-    return top_i, scale * top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    if score == "softmax":
+        top_s, top_i = lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+        return top_i, scale * top_s / jnp.sum(top_s, axis=-1, keepdims=True)
+    if score != "sigmoid":
+        raise ValueError(f"score is 'softmax' or 'sigmoid', got {score!r}")
+    top_s, top_i = lax.top_k(jax.nn.sigmoid(logits), k)
+    return top_i, scale * top_s / (
+        jnp.sum(top_s, axis=-1, keepdims=True) + 1e-20)
 
 
 def swiglu(h, w_gate, w_up, w_down):
@@ -316,8 +324,9 @@ def swiglu(h, w_gate, w_up, w_down):
 
 
 def moe_held_ffn(h, router, w_gate, w_up, w_down, *, first: int, k: int,
-                 scale: float, live=None):
-    """The held experts' part of a routed SwiGLU layer.
+                 scale: float, live=None, score: str = "softmax"):
+    """The held experts' part of a routed SwiGLU layer, routed by
+    :func:`route_top_k` (``score``: softmax or sigmoid, then top k).
 
     ``h`` (N, D) rows; ``router`` (D, E_total); ``w_gate`` / ``w_up``
     (E_held, D, F) and ``w_down`` (E_held, F, D): experts ``first`` ..
@@ -329,7 +338,7 @@ def moe_held_ffn(h, router, w_gate, w_up, w_down, *, first: int, k: int,
     in all (k x live rows), held experts with at least one row."""
     n, d = h.shape
     e_held = w_gate.shape[0]
-    ids, weights = route_top_k(h, router, k=k, scale=scale)
+    ids, weights = route_top_k(h, router, k=k, scale=scale, score=score)
     here = (ids >= first) & (ids < first + e_held)
     if live is not None:
         here = here & live[:, None]

@@ -133,6 +133,7 @@ from deeplearning4j_tpu.models.transformer import (
     decode_rows_live,
     decode_rows_streamed,
     full_cache_leaf,
+    kv_cache_rows,
     kv_row_write,
     make_paged_fwd1,
     paged_block_copy,
@@ -1093,6 +1094,11 @@ class ServingEngine:
     K=1 reproduces the unpipelined per-step cadence except that token
     readback still lags dispatch by one step (the double buffer).
 
+    ``max_queue_depth`` sizes the engine's own scheduler (default: the
+    scheduler's 128; a closed loop that keeps more than slots + 128
+    requests outstanding needs more, or the surplus is refused). It is
+    ``RequestScheduler(max_queue_depth=)`` for a caller that hands the
+    engine its arguments as data and builds no scheduler.
     ``prefill_max_bucket`` caps the power-of-two prompt padding bucket;
     longer prompts are chunked through the same buckets.
     ``chunked_replay`` picks the crash-replay mode (see module doc).
@@ -1142,6 +1148,7 @@ class ServingEngine:
         prefix_cache_tokens: int | None = None,
         prefix_affinity_tokens: int = 0,
         scheduler: RequestScheduler | None = None,
+        max_queue_depth: int | None = None,
         metrics: ServingMetrics | None = None,
         rng_seed: int = 0,
         faults: FaultInjector | None = None,
@@ -1167,21 +1174,25 @@ class ServingEngine:
         self.n_slots = n_slots
         self.max_total = int(min(max_total or cfg.max_len, cfg.max_len))
         if cfg.gated:
-            # what a stack with caches of two lengths cannot do yet
+            # what a stack whose cache is grouped by layer kind (a slab
+            # and a ring, or one plane of latent rows) cannot do yet
             # raises here, by name: none of it falls back in silence
             for asked, what, lacks in (
                 (paged, "the paged pool (paged=True)",
-                 "PagedKVPool carves one slab length into blocks and "
-                 "has no block table for a ring leaf"),
+                 "PagedKVPool carves one slab of K and V planes into "
+                 "blocks and has no block table for a ring leaf or a "
+                 "one-plane latent leaf"),
                 (prefix_cache, "the prefix cache (prefix_cache=True)",
-                 "a cached segment holds rows 0..n of every layer, and "
-                 "a ring has already dropped all but the last window"),
+                 "a cached segment holds K and V rows 0..n of every "
+                 "layer: a ring has already dropped all but the last "
+                 "window, and a latent leaf has one plane"),
                 (int(tp) > 1, "tensor-parallel serving (tp > 1)",
                  "serving_tp_shardings has no layout for per-layer head "
-                 "counts, held experts or a ring leaf"),
+                 "counts, held experts, a ring leaf or latent projections"),
                 (lora_bank is not None, "a LoRA bank (lora_bank=...)",
                  "init_lora_bank stacks q and MLP factors of one shape a "
-                 "layer, and _gated_block has no delta attach point"),
+                 "layer, and _gated_block has no delta attach point (a "
+                 "latent layer's query is two low-rank products)"),
             ):
                 if asked:
                     raise NotImplementedError(
@@ -1353,8 +1364,15 @@ class ServingEngine:
         # NOT `scheduler or ...`: RequestScheduler defines __len__, so
         # a caller's (normally empty) scheduler would be falsy and
         # silently swapped for a default one, dropping its knobs
+        if scheduler is not None and max_queue_depth is not None:
+            raise ValueError(
+                "max_queue_depth sizes the engine's own scheduler: give it "
+                "to the RequestScheduler that is passed in instead"
+            )
         self.scheduler = scheduler if scheduler is not None else (
             RequestScheduler(
+                **({} if max_queue_depth is None
+                   else {"max_queue_depth": int(max_queue_depth)}),
                 max_total_tokens=self.max_total,
                 prefix_affinity_tokens=prefix_affinity_tokens,
                 tenancy=tenancy,
@@ -1368,6 +1386,7 @@ class ServingEngine:
             cfg.vocab_size, self.top_k, self.approx_top_k
         )
         self.metrics.kv_row_write = kv_row_write(cfg)
+        self.metrics.kv_cache_rows = kv_cache_rows(cfg)
         self.metrics.compile_log = self._compile_log
         # the one place a phase of step() is named: profiler
         # annotation, metrics.loop_seconds, ring span, sanitizer phase
@@ -1706,6 +1725,14 @@ class ServingEngine:
             "scatter before it: int8 cache, dense path).",
             labelnames=("how",),
         ).set(1, how=self.metrics.kv_row_write)
+        reg.gauge(
+            "serve_kv_cache_rows",
+            "What a position's cache row is, decided when the step "
+            "programs are traced: kv (a K and a V plane), kv+ring (the "
+            "same in a slab and a ring leaf) or latent (one plane that "
+            "is key and value of every head).",
+            labelnames=("how",),
+        ).set(1, how=self.metrics.kv_cache_rows)
         reg.gauge(
             "serve_decode_horizon_current",
             "Decode substeps fused into the next horizon dispatch "
@@ -2159,8 +2186,9 @@ class ServingEngine:
             raise NotImplementedError(
                 f"request {req.id} ({req.kind}): disaggregated KVSG "
                 "frames are not built for a stack of gated layers "
-                "(layer_types set): a frame carries rows 0..n of one "
-                "slab, and a ring leaf holds the last window only"
+                "(layer_types set): a frame carries K and V rows 0..n of "
+                "one slab; a ring leaf holds the last window only, and a "
+                "latent leaf has one plane"
             )
         if getattr(req, "uses_sampling_surface", False):
             if not self._surface:
@@ -2296,9 +2324,9 @@ class ServingEngine:
         if self.cfg.gated:
             raise NotImplementedError(
                 "session export (KVSG frames) is not built for a stack "
-                "of gated layers (layer_types set): a frame carries rows "
-                "0..n of one slab, and a ring leaf holds the last window "
-                "only"
+                "of gated layers (layer_types set): a frame carries K and V "
+                "rows 0..n of one slab; a ring leaf holds the last window "
+                "only, and a latent leaf has one plane"
             )
         if self._inflight is not None:
             # sync the pipelined horizon first so tokens-so-far and the

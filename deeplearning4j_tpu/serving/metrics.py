@@ -176,6 +176,8 @@ class ServingMetrics:
         # who places a substep's new K and V rows in the cache at the
         # engine's configuration (transformer.kv_row_write)
         self.kv_row_write = "xla"
+        # what a position's cache row is (transformer.kv_cache_rows)
+        self.kv_cache_rows = "kv"
         self.n_finished = 0
         self.n_generated = 0
         # fault-tolerance counters (see serving.faults / engine docs):
@@ -884,6 +886,7 @@ class ServingMetrics:
             "decode_horizon": self.decode_horizon,
             "topk_select": self.topk_select,
             "kv_row_write": self.kv_row_write,
+            "kv_cache_rows": self.kv_cache_rows,
         }
         lookups = (self.n_prefix_hits_full + self.n_prefix_hits_partial
                    + self.n_prefix_misses)

@@ -261,6 +261,9 @@ class ReplicaRouter:
         self._route_lock = wrap_lock(
             threading.Lock(), "router._route_lock"
         )
+        # one health poll at a time: a background poll that asked a
+        # replica before it died must not land after a later poll's verdict
+        self._poll_lock = wrap_lock(threading.Lock(), "router._poll_lock")
         self._rr = 0  # round-robin tie-break cursor
 
         reg = self.registry = MetricsRegistry()
@@ -680,8 +683,9 @@ class ReplicaRouter:
     def poll_health(self) -> None:
         """One synchronous poll of every replica (tests use this to
         avoid sleeping for the background interval)."""
-        for r in self.replicas:
-            self._poll_one(r)
+        with self._poll_lock:
+            for r in self.replicas:
+                self._poll_one(r)
 
     def _health_loop(self) -> None:
         while not self._stop.is_set():

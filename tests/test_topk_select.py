@@ -82,14 +82,29 @@ def test_chunked_threshold_equals_top_k(shape, k, kind):
 
 @pytest.mark.parametrize("vocab,k,how", [
     (50257, 40, "chunked"), (50176, 40, "chunked"), (EDGE, 40, "chunked"),
-    (EDGE - 1, 40, "sort"), (64, 40, "sort"), (50257, 64, "chunked"),
-    (50257, 128, "sort"), (512, 1, "chunked"), (511, 1, "sort"),
-    (50257, None, "none"),
+    (EDGE - 1, 40, "chunked"), (19200, 40, "chunked"), (10240, 40, "chunked"),
+    (10239, 40, "sort"), (64, 40, "sort"), (50257, 64, "chunked"),
+    (50257, 128, "chunked"), (50257, 256, "sort"), (512, 1, "chunked"),
+    (511, 1, "chunked"), (255, 1, "sort"), (50257, None, "none"),
 ])
 def test_topk_select_rule(vocab, k, how):
     assert topk_select(vocab, k) == how
     if k is not None:
         assert topk_select(vocab, k, approx_top_k=True) == "approx"
+
+
+@pytest.mark.parametrize("vocab,k,chunks", [
+    (50257, 40, (128, 8)), (EDGE, 40, (128, 8)), (EDGE - 1, 40, (64, 8)),
+    (19200, 40, (64, 8)), (10240, 40, (64, 8)), (10239, 40, ()),
+])
+def test_first_chunk_level_narrows_with_the_row(vocab, k, chunks):
+    """128, else 64, where k chunks of that width are a quarter of the
+    row at most: the rows the benchmark serves (50,257 and 50,176 ids)
+    keep chunks of 128, a 19,200-id slice of a vocabulary takes 64."""
+    from deeplearning4j_tpu.models.transformer import select_chunks
+
+    assert select_chunks(vocab, k) == chunks
+    assert _SELECT_CHUNKS == (128, 8)
 
 
 @pytest.mark.parametrize("kind", ["normal", "ties", "few_finite", "bf16"])

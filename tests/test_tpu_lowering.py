@@ -346,6 +346,52 @@ def test_writing_decode_compiles_in_place_for_a_v5e(name, one_chip):
     assert mem.temp_size_in_bytes < cache_bytes // (cache.shape[0] * 2 * 48)
 
 
+# the latent leaf of the openpangu cell: layers, slots, rows, stored row
+# width, heads, latent values
+LATENT_GEOMETRY = (5, 224, 4096, 640, 128, 512)
+
+
+def _latent_avals(sharding=None):
+    layers, batch, t, width, heads, r_kv = LATENT_GEOMETRY
+
+    def s(shape, dtype):
+        return S(shape, dtype, sharding=sharding)
+
+    def fn(q, c, new, pos, act):
+        return pk.latent_decode_attention_write(
+            q, c, new, pos, r_kv, layer=layers - 1, active=act)
+
+    return fn, (
+        s((batch, heads, width), jnp.bfloat16),
+        s((layers, 1, batch, t, width), jnp.bfloat16),
+        s((batch, 1, width), jnp.bfloat16),
+        s((batch,), jnp.int32), s((batch,), jnp.bool_),
+    )
+
+
+def test_latent_decode_lowers_with_the_cache_aliased():
+    """The one-plane walk is the same call as the K/V walk: five
+    prefetched vectors, ``q``, the new row, then the cache, which is its
+    second result."""
+    fn, avals = _latent_avals()
+    text = lower_tpu(fn, *avals)
+    assert _ALIAS in text
+    assert "latent_decode_attn" in text
+
+
+def test_latent_decode_compiles_in_place_for_a_v5e(one_chip):
+    """Mosaic accepts the kernel at the cell's widths (128 query rows
+    against 512-row blocks of 640 lanes, the row's 8-row tile copied
+    back), and the donated 5.9 GB leaf goes through without a copy."""
+    fn, avals = _latent_avals(one_chip)
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(*avals).compile()
+    cache = avals[1]
+    mem = compiled.memory_analysis()
+    cache_bytes = 2 * math.prod(cache.shape)
+    assert mem.alias_size_in_bytes == cache_bytes
+    assert mem.temp_size_in_bytes < cache_bytes // (cache.shape[0] * 224)
+
+
 def _cache_shaped_ops(hlo: str, shape: str) -> set[str]:
     """Opcodes of the compiled instructions whose result is ``shape``."""
     return set(re.findall(
