@@ -758,6 +758,32 @@ def kv_cache_rows(cfg: "TransformerConfig") -> str:
     return "kv+ring" if cfg.gated and cfg.layers_of("window") else "kv"
 
 
+def flash_layout(cfg: "TransformerConfig", mesh: Mesh | None = None) -> str:
+    """The layout in which the training block (``transformer_apply`` with
+    ``use_flash``) hands q, k and v to the flash kernels: ``"packed"``,
+    (B, T, H*K) as the projections write them, a kernel block being one
+    128-lane group of whole heads (``flash_attention_packed``; no
+    transpose between the products and the kernels); or ``"bhtd"``,
+    (B, H, T, K) (``flash_attention_trainable``). Packed wherever the
+    block can see that it holds: plain multi-head attention, no rotary
+    (which turns pairs of lanes inside a head), a head size that divides
+    128, and whole 128-lane groups on every device of the mesh's model
+    axis. A fact of the configuration and the mesh, and the one the
+    block itself branches on; a trace shows it as the kernels' names
+    (``flash_fwd_packed`` / ``flash_bwd_packed``)."""
+    shards = 1
+    if mesh is not None and mesh_lib.MODEL_AXIS in mesh.axis_names:
+        shards = mesh.shape[mesh_lib.MODEL_AXIS]
+    packed = (
+        cfg.kv_heads == cfg.n_heads
+        and not cfg.rope
+        and not cfg.sequence_parallel
+        and 128 % cfg.head_dim == 0
+        and (cfg.n_heads * cfg.head_dim) % (128 * shards) == 0
+    )
+    return "packed" if packed else "bhtd"
+
+
 def _decode_write_at(pos, ring: bool, leaf):
     """The cache row of ``leaf`` (layers, planes, B, rows, width) that
     position ``pos`` is written to: ``pos % rows`` on a ring, ``pos`` on
@@ -787,7 +813,11 @@ def _flash_blocks(t: int) -> tuple[int, int]:
     it), falling back to the largest candidate that divides t — callers
     only guarantee t <= 128 or t % 128 == 0. ONE implementation shared
     by the training block and bulk prefill so kernel selection cannot
-    drift."""
+    drift. The packed kernels (``flash_layout``) were measured on their
+    own at 8 x 16 x 1,024 x 64 and prefer the same
+    (``scripts/flash_train_bench.py``, forward + backward: 1,024/1,024
+    1,663 us a call, 512/1,024 1,868, 512/512 1,849 though it skips a
+    tile of four, 1,024/512 2,173; PERF.md, PR 32)."""
 
     def pick(pref: int) -> int:
         if t <= pref:
@@ -831,6 +861,23 @@ def _project_qkv(cfg: TransformerConfig, p, h_in):
         "btd,dshk->sbhtk", h_in, _w(p, "wqkv", h_in.dtype)
     )
     return qkv[0], qkv[1], qkv[2]
+
+
+def _project_qkv_packed(p, h_in):
+    """The training block's projection under ``flash_layout`` "packed":
+    h_in (B, T, D) -> q, k, v, each (B, T, H*K), heads side by side on
+    the minor dimension, which is what a ``[B*T, D] x [D, H*K]`` product
+    writes. Three products over the planes of the ``wqkv`` leaf viewed
+    ``[D, 3, H*K]`` (a free reshape: the leaf keeps its shape, its
+    sharding and its checkpoints), so that neither the kernels' operands
+    nor their cotangents are slices of a wider array. (Sliced as
+    ``[D, 3, H, K]``, XLA:TPU folded the reshape into dq's
+    weight-gradient product and transposed dq for it.)"""
+    w = _w(p, "wqkv", h_in.dtype)  # (D, 3, H, K)
+    w = w.reshape(w.shape[0], 3, -1)
+    return tuple(
+        jnp.einsum("btd,df->btf", h_in, w[:, s]) for s in range(3)
+    )
 
 
 def _expand_kv(cfg: TransformerConfig, k_r, v_r):
@@ -1036,12 +1083,59 @@ def transformer_apply(
             mesh, causal=True, head_axis=mesh_lib.MODEL_AXIS
         )
 
-    def block(x, p):
+    packed = cfg.use_flash and flash_layout(cfg, mesh) == "packed"
+
+    def flash_attend(t: int):
+        """The flash entry of this configuration's layout at sequence
+        length ``t``: (q, k, v) -> o, all in that layout."""
+        from deeplearning4j_tpu.ops.pallas_kernels import (
+            flash_attention_packed,
+            flash_attention_trainable,
+        )
+
+        if not _flash_seq_ok(t):
+            raise ValueError(
+                f"use_flash needs a seq len that is a multiple of 8 "
+                f"and either <= 128 or a multiple of 128, got {t}"
+            )
+        bq, bk = _flash_blocks(t)
+        bbq, bbk = _flash_bwd_blocks(t)
+        blocks = dict(
+            causal=True, block_q=bq, block_k=bk,
+            bwd_block_q=bbq, bwd_block_k=bbk,
+        )
+        if packed:
+            flash = functools.partial(
+                flash_attention_packed, head_dim=cfg.head_dim, **blocks
+            )
+        else:
+            flash = functools.partial(
+                flash_attention_trainable, layout="bhtd", **blocks
+            )
+        if mesh is not None:
+            # compiled, the kernel is a custom call that GSPMD
+            # cannot partition (the TPU lowering refuses it outside
+            # a fully manual region): each device runs it on its
+            # own (batch, heads) shard — attention mixes neither.
+            # Packed, the heads are the minor dimension, sharded in
+            # whole 128-lane groups (flash_layout)
+            axes = mesh.axis_names
+            data = mesh_lib.DATA_AXIS if mesh_lib.DATA_AXIS in axes else None
+            model = (
+                mesh_lib.MODEL_AXIS if mesh_lib.MODEL_AXIS in axes else None
+            )
+            spec = P(data, None, model) if packed else P(data, model)
+            flash = jax.shard_map(
+                flash, mesh=mesh, in_specs=(spec, spec, spec),
+                out_specs=spec, check_vma=False,
+            )
+        return flash
+
+    def attend_bhtd(p, h_in):
         # attention sublayer — internally (B, H, T, K) layout so the
         # flash kernel's (B*H, T, K) view is a free reshape; the bthd
         # layout cost ~3ms/step of physical transposes at GPT-2-small
         # scale (B=16, T=1024)
-        h_in = _layer_norm(x, p["ln1_scale"], p["ln1_bias"])
         q_h, k_r, v_r = _project_qkv(cfg, p, h_in)
         if cfg.rope:
             t = q_h.shape[2]
@@ -1059,7 +1153,7 @@ def transformer_apply(
             # next to the ring collectives. Named so remat saves the
             # ring output instead of re-running its collectives in the
             # backward pass.
-            o = checkpoint_name(
+            return checkpoint_name(
                 ring(
                     q_h.transpose(0, 2, 1, 3),
                     k_h.transpose(0, 2, 1, 3),
@@ -1067,50 +1161,35 @@ def transformer_apply(
                 ).transpose(0, 2, 1, 3),
                 "attn_out",
             )
-        elif cfg.use_flash:
-            from deeplearning4j_tpu.ops.pallas_kernels import (
-                flash_attention_trainable,
-            )
-
-            t = q_h.shape[2]
-            if not _flash_seq_ok(t):
-                raise ValueError(
-                    f"use_flash needs a seq len that is a multiple of 8 "
-                    f"and either <= 128 or a multiple of 128, got {t}"
-                )
+        if cfg.use_flash:
             # no attn_out naming here: the kernel's own flash_out
             # residual is the saveable (naming both would store the
             # same tensor twice and cost ~450MB at GPT-2-small scale)
-            bq, bk = _flash_blocks(t)
-            bbq, bbk = _flash_bwd_blocks(t)
-            flash = functools.partial(
-                flash_attention_trainable, causal=True,
-                block_q=bq, block_k=bk, layout="bhtd",
-                bwd_block_q=bbq, bwd_block_k=bbk,
+            return flash_attend(q_h.shape[2])(q_h, k_h, v_h)
+        return checkpoint_name(
+            attention(q_h, k_h, v_h, causal=True, layout="bhtd"),
+            "attn_out",
+        )
+
+    def block(x, p):
+        h_in = _layer_norm(x, p["ln1_scale"], p["ln1_bias"])
+        if packed:
+            # (B, T, H*K) from the projections through the kernels to
+            # wo: no layout op on an activation, forward or backward
+            # (the (B, H, T, K) path's layout copies were 8% of the
+            # gpt2-medium step: PERF.md, PR 32)
+            q, k, v = _project_qkv_packed(p, h_in)
+            o = flash_attend(x.shape[1])(q, k, v)
+            wo = _w(p, "wo", x.dtype)
+            attn = jnp.einsum(
+                "btf,fd->btd", o, wo.reshape(-1, wo.shape[-1])
             )
-            if mesh is not None:
-                # compiled, the kernel is a custom call that GSPMD
-                # cannot partition (the TPU lowering refuses it outside
-                # a fully manual region): each device runs it on its
-                # own (batch, heads) shard — attention mixes neither
-                axes = mesh.axis_names
-                spec = P(
-                    mesh_lib.DATA_AXIS if mesh_lib.DATA_AXIS in axes
-                    else None,
-                    mesh_lib.MODEL_AXIS if mesh_lib.MODEL_AXIS in axes
-                    else None,
-                )
-                flash = jax.shard_map(
-                    flash, mesh=mesh, in_specs=(spec, spec, spec),
-                    out_specs=spec, check_vma=False,
-                )
-            o = flash(q_h, k_h, v_h)
         else:
-            o = checkpoint_name(
-                attention(q_h, k_h, v_h, causal=True, layout="bhtd"),
-                "attn_out",
+            attn = jnp.einsum(
+                "bhtk,hkd->btd", attend_bhtd(p, h_in),
+                _w(p, "wo", x.dtype),
             )
-        x = x + jnp.einsum("bhtk,hkd->btd", o, _w(p, "wo", x.dtype))
+        x = x + attn
         # ffn sublayer: dense MLP or routed MoE
         h_in = _layer_norm(x, p["ln2_scale"], p["ln2_bias"])
         if cfg.n_experts:

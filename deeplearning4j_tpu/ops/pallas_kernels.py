@@ -116,6 +116,21 @@ def _dim_semantics(interpret, semantics=("parallel", "parallel", "arbitrary")):
     return pltpu.CompilerParams(dimension_semantics=semantics)
 
 
+def _softmax_fold(s, m_s, l_s, at):
+    """Fold one f32 score tile into the running row maximum ``m_s[at]``
+    and row sum ``l_s[at]`` of the online softmax. Returns the tile's
+    unnormalised probabilities, the factor that rescales what was
+    accumulated under the old maximum, and the new maximum, which the
+    caller stores once it has rescaled its accumulator. ONE update shared
+    by the forward kernels of both layouts."""
+    m_prev = m_s[at]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+    p = jnp.exp(s - m_new[:, None])
+    corr = jnp.exp(m_prev - m_new)
+    l_s[at] = corr * l_s[at] + jnp.sum(p, axis=-1)
+    return p, corr, m_new
+
+
 def _flash_fwd_stream_kernel(
     q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s,
     *, block_q: int, block_k: int, n_k: int, scale: float, causal: bool,
@@ -139,11 +154,7 @@ def _flash_fwd_stream_kernel(
         s = jnp.dot(q, k_ref[0].T, preferred_element_type=jnp.float32)
         if masked:
             s = s + _causal_bias(q_start, k_start, block_q, block_k)
-        m_prev = m_s[:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-        p = jnp.exp(s - m_new[:, None])
-        corr = jnp.exp(m_prev - m_new)
-        l_s[:, 0] = corr * l_s[:, 0] + jnp.sum(p, axis=-1)
+        p, corr, m_new = _softmax_fold(s, m_s, l_s, (slice(None), 0))
         # PV dot with p cast to the value dtype (bf16 on TPU): operands
         # must stay low-precision to hit the MXU at full rate — an f32
         # matmul runs at a fraction of peak on v5e. The accumulator is
@@ -205,6 +216,63 @@ def _flash_fwd_call(qf, kf, vf, block_q, block_k, interpret, causal):
     )(qf, kf, vf)
 
 
+def _flash_bwd_tile(q, k_blk, v_blk, do, lse, delta, dk_s, dv_s, scale,
+                    bias=None):
+    """One (q block, kv block) tile of the fused backward, shared by the
+    kernels of both layouts: ``q`` arrives scaled, ``bias`` (if the tile
+    crosses the diagonal) builds the causal bias. Adds the tile's share
+    to the ``dk_s`` / ``dv_s`` accumulators and returns its f32 dq
+    contribution."""
+    s = jnp.dot(q, k_blk.T, preferred_element_type=jnp.float32)
+    if bias is not None:
+        s = s + bias()
+    p = jnp.exp(s - lse[:, None])
+    dv_s[:] = dv_s[:] + jnp.dot(
+        p.astype(do.dtype).T, do, preferred_element_type=jnp.float32
+    )
+    dp = jnp.dot(do, v_blk.T, preferred_element_type=jnp.float32)
+    # ds in the storage dtype: cast p and (dp - delta) BEFORE the
+    # multiply instead of multiplying f32 and casting the product —
+    # one fewer full-tile f32 pass; measured part of a -4% bench win
+    # at T=8192 (r4), grad error covered by the on-device parity
+    # gate (bench._verify_flash_grads). (An exp2/log2e fold was
+    # also tried and measured neutral-to-negative in situ — exp
+    # stays.)
+    ds = p.astype(q.dtype) * (dp - delta[:, None]).astype(q.dtype)
+    dk_s[:] = dk_s[:] + jnp.dot(
+        ds.T, q, preferred_element_type=jnp.float32
+    )
+    return jnp.dot(
+        ds, k_blk, preferred_element_type=jnp.float32
+    ) * scale
+
+
+def _store_dq(dq_ref, dq_c, kk, dq_partials: bool):
+    """Place a tile's dq contribution (see ``_DQ_PARTIALS``)."""
+    if dq_partials:
+        # one clean write per (kv, q) cell into this kv block's
+        # partial plane; the caller sums planes in f32. No HBM
+        # read-modify-write at all — the non-consecutive-revisit
+        # accumulation pattern (ADVICE r3 medium) is gone.
+        dq_ref[0, 0] = dq_c.astype(dq_ref.dtype)
+    else:
+        @pl.when(kk == 0)
+        def _dq_init():
+            dq_ref[0] = dq_c
+
+        @pl.when(kk != 0)
+        def _dq_acc():
+            dq_ref[0] = dq_ref[0] + dq_c
+
+
+def _zero_hidden_dq(dq_ref, q_start, k_start, block_q: int):
+    """In partials mode a tile the causal dispatch skipped still owns a
+    block of its kv block's dq plane: zero it."""
+    @pl.when(jnp.logical_not(_kv_block_visible(q_start, k_start, block_q)))
+    def _dq_zero():
+        dq_ref[0, 0] = jnp.zeros_like(dq_ref[0, 0])
+
+
 def _flash_bwd_fused_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     dq_ref, dk_ref, dv_ref, dk_s, dv_s,
@@ -245,53 +313,20 @@ def _flash_bwd_fused_kernel(
         do = do_ref[0]
         lse = lse_ref[0, :, 0]
         delta = delta_ref[0, :, 0]
-        s = jnp.dot(q, k_blk.T, preferred_element_type=jnp.float32)
-        if masked:
-            s = s + _causal_bias(q_start, k_start, block_q, block_k)
-        p = jnp.exp(s - lse[:, None])
-        dv_s[:] = dv_s[:] + jnp.dot(
-            p.astype(do.dtype).T, do, preferred_element_type=jnp.float32
+        dq_c = _flash_bwd_tile(
+            q, k_blk, v_blk, do, lse, delta, dk_s, dv_s, scale,
+            functools.partial(
+                _causal_bias, q_start, k_start, block_q, block_k
+            ) if masked else None,
         )
-        dp = jnp.dot(do, v_blk.T, preferred_element_type=jnp.float32)
-        # ds in the storage dtype: cast p and (dp - delta) BEFORE the
-        # multiply instead of multiplying f32 and casting the product —
-        # one fewer full-tile f32 pass; measured part of a -4% bench win
-        # at T=8192 (r4), grad error covered by the on-device parity
-        # gate (bench._verify_flash_grads). (An exp2/log2e fold was
-        # also tried and measured neutral-to-negative in situ — exp
-        # stays.)
-        ds = p.astype(q.dtype) * (dp - delta[:, None]).astype(q.dtype)
-        dk_s[:] = dk_s[:] + jnp.dot(
-            ds.T, q, preferred_element_type=jnp.float32
-        )
-        dq_c = jnp.dot(
-            ds, k_blk, preferred_element_type=jnp.float32
-        ) * scale
-        if dq_partials:
-            # one clean write per (kv, q) cell into this kv block's
-            # partial plane; the caller sums planes in f32. No HBM
-            # read-modify-write at all — the non-consecutive-revisit
-            # accumulation pattern (ADVICE r3 medium) is gone.
-            dq_ref[0, 0] = dq_c.astype(dq_ref.dtype)
-        else:
-            @pl.when(kk == 0)
-            def _dq_init():
-                dq_ref[0] = dq_c
-
-            @pl.when(kk != 0)
-            def _dq_acc():
-                dq_ref[0] = dq_ref[0] + dq_c
+        _store_dq(dq_ref, dq_c, kk, dq_partials)
 
     # invisible tiles are skipped wholesale (in rmw mode their dq tile
     # is left untouched — kv block 0, always visible, initialized it;
     # in partials mode their plane block is zeroed below)
     _causal_dispatch(compute, causal, q_start, k_start, block_q, block_k)
     if dq_partials and causal:
-        @pl.when(
-            jnp.logical_not(_kv_block_visible(q_start, k_start, block_q))
-        )
-        def _dq_zero():
-            dq_ref[0, 0] = jnp.zeros_like(dq_ref[0, 0])
+        _zero_hidden_dq(dq_ref, q_start, k_start, block_q)
 
     @pl.when(qq == n_q - 1)
     def _finalize():
@@ -431,6 +466,15 @@ def _flash_bwd_rule(
 _flash_bhtd.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
+def _blocks_within(t: int, *blocks):
+    """Each block size clamped to the sequence length ``t``, which it
+    has to divide; ``None`` (a backward block that inherits the
+    forward's) stays ``None``."""
+    clamped = tuple(b if b is None else min(b, t) for b in blocks)
+    assert all(b is None or t % b == 0 for b in clamped), (t, blocks)
+    return clamped
+
+
 def flash_attention_trainable(
     q: jax.Array,
     k: jax.Array,
@@ -456,9 +500,9 @@ def flash_attention_trainable(
         b, h, t, d = q.shape
     else:
         b, t, h, d = q.shape
-    block_q = min(block_q, t)
-    block_k = min(block_k, t)
-    assert t % block_q == 0 and t % block_k == 0
+    block_q, block_k, bwd_block_q, bwd_block_k = _blocks_within(
+        t, block_q, block_k, bwd_block_q, bwd_block_k
+    )
     interpret = _default_interpret() if interpret is None else interpret
     if layout == "bhtd":
         qf, kf, vf = (a.reshape(b * h, t, d) for a in (q, k, v))
@@ -466,18 +510,329 @@ def flash_attention_trainable(
         qf, kf, vf = (
             a.transpose(0, 2, 1, 3).reshape(b * h, t, d) for a in (q, k, v)
         )
-    if bwd_block_q is not None:
-        bwd_block_q = min(bwd_block_q, t)
-        assert t % bwd_block_q == 0
-    if bwd_block_k is not None:
-        bwd_block_k = min(bwd_block_k, t)
-        assert t % bwd_block_k == 0
     out = _flash_bhtd(
         qf, kf, vf, block_q, block_k, interpret, causal,
         bwd_block_q, bwd_block_k,
     )
     out = out.reshape(b, h, t, d)
     return out if layout == "bhtd" else out.transpose(0, 2, 1, 3)
+
+
+# -- flash attention, packed layout --------------------------------------------
+#
+# The training block's second path: q, k, v, o and their cotangents are
+# all (B, T, H*K), the layout a ``[B*T, D] x [D, H*K]`` projection writes
+# and ``wo`` reads, so no transpose stands between the products and the
+# kernels. A block is one 128-lane group of WHOLE heads (two at K = 64)
+# of one row block: lane-dense, where a (B*H, T, 64) block half-fills
+# every 128-lane tile it moves. Inside a grid step each head of the group
+# is computed from the 128-lane tiles with the other heads' lanes zeroed
+# in one operand of every product (a contraction of 128 with 64 exact
+# zeros: the same sum, and on a 128 x 128 MXU the same passes) and its
+# lanes of the result kept by a lane select. The tile math, the causal
+# dispatch and the dq handling are the (B*H, T, K) kernels' own.
+
+_PACK_LANES = 128
+
+
+def _head_lanes(shape, head_dim: int, a: int):
+    """Bool mask of ``shape`` (.., 128): the lanes of head ``a`` of a
+    128-lane group."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, len(shape) - 1)
+    return lane // head_dim == a
+
+
+def _flash_fwd_packed_kernel(
+    q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc_s,
+    *, block_q: int, block_k: int, n_k: int, head_dim: int, scale: float,
+    causal: bool,
+):
+    """One (batch, lane group, q block, kv block) grid step of the
+    online-softmax forward over a group of ``128 // head_dim`` heads."""
+    kk = pl.program_id(3)
+    q_start = pl.program_id(2) * block_q
+    k_start = kk * block_k
+    heads = _PACK_LANES // head_dim
+
+    @pl.when(kk == 0)
+    def _init():
+        m_s[:] = jnp.full_like(m_s, -jnp.inf)
+        l_s[:] = jnp.zeros_like(l_s)
+        acc_s[:] = jnp.zeros_like(acc_s)
+
+    def compute(masked: bool):
+        q = q_ref[0] * jnp.asarray(scale, q_ref.dtype)
+        k_blk = k_ref[0]
+        v_blk = v_ref[0]
+        if masked:  # one bias for every head of the group
+            bias = _causal_bias(q_start, k_start, block_q, block_k)
+        acc = acc_s[:]
+        for a in range(heads):
+            mine = _head_lanes(q.shape, head_dim, a)
+            s = jnp.dot(
+                jnp.where(mine, q, jnp.zeros_like(q)), k_blk.T,
+                preferred_element_type=jnp.float32,
+            )
+            if masked:
+                s = s + bias
+            at = (slice(None), a)
+            p, corr, m_new = _softmax_fold(s, m_s, l_s, at)
+            pv = jnp.dot(
+                p.astype(v_blk.dtype), v_blk,
+                preferred_element_type=jnp.float32,
+            )
+            acc = jnp.where(mine, corr[:, None] * acc + pv, acc)
+            m_s[at] = m_new
+        acc_s[:] = acc
+
+    _causal_dispatch(compute, causal, q_start, k_start, block_q, block_k)
+
+    @pl.when(kk == n_k - 1)
+    def _finalize():
+        l_lanes = jnp.ones_like(acc_s)
+        for a in range(heads):
+            l = jnp.maximum(l_s[:, a], 1e-30)
+            l_lanes = jnp.where(
+                _head_lanes(l_lanes.shape, head_dim, a), l[:, None], l_lanes
+            )
+            lse_ref[0, 0, :, a] = (m_s[:, a] + jnp.log(l)).astype(
+                jnp.float32
+            )
+        o_ref[0] = (acc_s[:] / l_lanes).astype(o_ref.dtype)
+
+
+def _flash_fwd_packed_call(q, k, v, head_dim, block_q, block_k, interpret,
+                           causal):
+    b, t, hk = q.shape
+    heads = _PACK_LANES // head_dim
+    n_k = t // block_k
+    return pl.pallas_call(
+        functools.partial(
+            _flash_fwd_packed_kernel, block_q=block_q, block_k=block_k,
+            n_k=n_k, head_dim=head_dim, scale=1.0 / (head_dim**0.5),
+            causal=causal,
+        ),
+        out_shape=(
+            jax.ShapeDtypeStruct((b, t, hk), q.dtype),
+            # (.., T, heads of a group): a row's heads side by side, where
+            # (.., T, 1) a head pads every row to 128 lanes once a head,
+            # in HBM and in the kernel's VMEM alike
+            jax.ShapeDtypeStruct(
+                (b, hk // _PACK_LANES, t, heads), jnp.float32),
+        ),
+        grid=(b, hk // _PACK_LANES, t // block_q, n_k),
+        in_specs=[
+            pl.BlockSpec(
+                (1, block_q, _PACK_LANES), lambda i, g, j, kk: (i, j, g)),
+            pl.BlockSpec(
+                (1, block_k, _PACK_LANES), lambda i, g, j, kk: (i, kk, g)),
+            pl.BlockSpec(
+                (1, block_k, _PACK_LANES), lambda i, g, j, kk: (i, kk, g)),
+        ],
+        out_specs=(
+            pl.BlockSpec(
+                (1, block_q, _PACK_LANES), lambda i, g, j, kk: (i, j, g)),
+            pl.BlockSpec(
+                (1, 1, block_q, heads), lambda i, g, j, kk: (i, g, j, 0)),
+        ),
+        scratch_shapes=[
+            pltpu.VMEM((block_q, heads), jnp.float32),
+            pltpu.VMEM((block_q, heads), jnp.float32),
+            pltpu.VMEM((block_q, _PACK_LANES), jnp.float32),
+        ],
+        compiler_params=_dim_semantics(
+            interpret, ("parallel", "parallel", "parallel", "arbitrary")
+        ),
+        interpret=interpret,
+        name="flash_fwd_packed",
+    )(q, k, v)
+
+
+def _flash_bwd_packed_kernel(
+    q_ref, k_ref, v_ref, o_ref, do_ref, lse_ref,
+    dq_ref, dk_ref, dv_ref, dk_s, dv_s,
+    *, block_q: int, block_k: int, n_q: int, head_dim: int, scale: float,
+    causal: bool, dq_partials: bool,
+):
+    """One (batch, lane group, kv block, q block) step of the fused
+    backward over a group of heads. ``delta = <dO, O>`` per head is taken
+    here from the O block (f32, as the (B*H, T, K) path takes it outside
+    its kernel): a pass over (block_q, 128) beside (block_q, block_k)
+    tiles, where outside it is a reduction over a 64-wide minor
+    dimension and a transpose."""
+    kk = pl.program_id(2)
+    qq = pl.program_id(3)
+    k_start = kk * block_k
+    q_start = qq * block_q
+    heads = _PACK_LANES // head_dim
+
+    @pl.when(qq == 0)
+    def _init():
+        dk_s[:] = jnp.zeros_like(dk_s)
+        dv_s[:] = jnp.zeros_like(dv_s)
+
+    def compute(masked: bool):
+        k_blk = k_ref[0]
+        v_blk = v_ref[0]
+        q = q_ref[0] * jnp.asarray(scale, q_ref.dtype)
+        do = do_ref[0]
+        do_o = do.astype(jnp.float32) * o_ref[0].astype(jnp.float32)
+        bias = None
+        if masked:  # one bias for every head of the group
+            tile = _causal_bias(q_start, k_start, block_q, block_k)
+            bias = lambda: tile  # noqa: E731
+        dq_c = jnp.zeros(q.shape, jnp.float32)
+        for a in range(heads):
+            mine = _head_lanes(q.shape, head_dim, a)
+            # q and dO carry head a's lanes alone, so s, dp, dk and dv
+            # are head a's; k's and v's other lanes meet zeros
+            dq_a = _flash_bwd_tile(
+                jnp.where(mine, q, jnp.zeros_like(q)), k_blk, v_blk,
+                jnp.where(mine, do, jnp.zeros_like(do)),
+                lse_ref[0, 0, :, a],
+                jnp.sum(jnp.where(mine, do_o, 0.0), axis=-1),
+                dk_s, dv_s, scale, bias,
+            )
+            dq_c = jnp.where(mine, dq_a, dq_c)
+        _store_dq(dq_ref, dq_c, kk, dq_partials)
+
+    _causal_dispatch(compute, causal, q_start, k_start, block_q, block_k)
+    if dq_partials and causal:
+        _zero_hidden_dq(dq_ref, q_start, k_start, block_q)
+
+    @pl.when(qq == n_q - 1)
+    def _finalize():
+        dk_ref[0] = dk_s[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_s[:].astype(dv_ref.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
+def _flash_packed(
+    q, k, v, head_dim, block_q, block_k, interpret, causal,
+    bwd_block_q, bwd_block_k,
+):
+    out, _ = _flash_fwd_packed_call(
+        q, k, v, head_dim, block_q, block_k, interpret, causal
+    )
+    return out
+
+
+def _flash_packed_fwd_rule(
+    q, k, v, head_dim, block_q, block_k, interpret, causal,
+    bwd_block_q, bwd_block_k,
+):
+    from jax.ad_checkpoint import checkpoint_name
+
+    out, lse = _flash_fwd_packed_call(
+        q, k, v, head_dim, block_q, block_k, interpret, causal
+    )
+    # the names a surrounding jax.checkpoint policy saves (see
+    # _flash_fwd_rule)
+    out = checkpoint_name(out, "flash_out")
+    lse = checkpoint_name(lse, "flash_lse")
+    return out, (q, k, v, out, lse)
+
+
+def _flash_packed_bwd_rule(
+    head_dim, block_q, block_k, interpret, causal, bwd_block_q,
+    bwd_block_k, res, do,
+):
+    q, k, v, out, lse = res
+    b, t, hk = q.shape
+    heads = _PACK_LANES // head_dim
+    block_q = bwd_block_q or block_q
+    block_k = bwd_block_k or block_k
+    n_q, n_k = t // block_q, t // block_k
+    # dq as in _flash_bwd_rule: a plane a KV block, or f32 revisits
+    dq_partials = _DQ_PARTIALS and n_k <= 8
+    if dq_partials:
+        plane_dtype = jnp.float32 if _DQ_PARTIALS_F32 else q.dtype
+        dq_shape = jax.ShapeDtypeStruct((n_k, b, t, hk), plane_dtype)
+        dq_spec = pl.BlockSpec(
+            (1, 1, block_q, _PACK_LANES), lambda i, g, j, qq: (j, i, qq, g)
+        )
+    else:
+        dq_shape = jax.ShapeDtypeStruct((b, t, hk), jnp.float32)
+        dq_spec = pl.BlockSpec(
+            (1, block_q, _PACK_LANES), lambda i, g, j, qq: (i, qq, g)
+        )
+    q_spec = pl.BlockSpec(
+        (1, block_q, _PACK_LANES), lambda i, g, j, qq: (i, qq, g))
+    kv_spec = pl.BlockSpec(
+        (1, block_k, _PACK_LANES), lambda i, g, j, qq: (i, j, g))
+    dq_raw, dk, dv = pl.pallas_call(
+        functools.partial(
+            _flash_bwd_packed_kernel, block_q=block_q, block_k=block_k,
+            n_q=n_q, head_dim=head_dim, scale=1.0 / (head_dim**0.5),
+            causal=causal, dq_partials=dq_partials,
+        ),
+        out_shape=(
+            dq_shape,
+            jax.ShapeDtypeStruct((b, t, hk), k.dtype),
+            jax.ShapeDtypeStruct((b, t, hk), v.dtype),
+        ),
+        grid=(b, hk // _PACK_LANES, n_k, n_q),
+        in_specs=[
+            q_spec, kv_spec, kv_spec, q_spec, q_spec,
+            pl.BlockSpec(
+                (1, 1, block_q, heads), lambda i, g, j, qq: (i, g, qq, 0)),
+        ],
+        out_specs=(dq_spec, kv_spec, kv_spec),
+        scratch_shapes=[
+            pltpu.VMEM((block_k, _PACK_LANES), jnp.float32),
+            pltpu.VMEM((block_k, _PACK_LANES), jnp.float32),
+        ],
+        # the kv dim sequential, as in _flash_bwd_rule
+        compiler_params=_dim_semantics(
+            interpret, ("parallel", "parallel", "arbitrary", "arbitrary")
+        ),
+        interpret=interpret,
+        name="flash_bwd_packed",
+    )(q, k, v, out, do, lse)
+    if not dq_partials:
+        return dq_raw.astype(q.dtype), dk, dv
+    if n_k == 1 and dq_raw.dtype == q.dtype:
+        return dq_raw[0], dk, dv  # the one plane is dq
+    return jnp.sum(dq_raw.astype(jnp.float32), axis=0).astype(q.dtype), dk, dv
+
+
+_flash_packed.defvjp(_flash_packed_fwd_rule, _flash_packed_bwd_rule)
+
+
+def flash_attention_packed(
+    q: jax.Array,
+    k: jax.Array,
+    v: jax.Array,
+    head_dim: int,
+    block_q: int = 128,
+    block_k: int = 128,
+    interpret: bool | None = None,
+    causal: bool = False,
+    bwd_block_q: int | None = None,
+    bwd_block_k: int | None = None,
+) -> jax.Array:
+    """Differentiable flash attention over (B, T, H*K) in and out: the
+    layout the projections write and ``wo`` reads, heads side by side on
+    the lane dimension. ``head_dim`` divides 128 and ``H*K`` is a
+    multiple of 128 (a block is a 128-lane group of whole heads). The
+    same mathematics as :func:`flash_attention_trainable`, at the same
+    precision; the kernels show in a trace as ``flash_fwd_packed`` and
+    ``flash_bwd_packed``."""
+    b, t, hk = q.shape
+    if _PACK_LANES % head_dim or hk % _PACK_LANES:
+        raise ValueError(
+            f"the packed flash kernels take whole heads in groups of "
+            f"{_PACK_LANES} lanes: head_dim {head_dim} must divide "
+            f"{_PACK_LANES} and H*K = {hk} be a multiple of it"
+        )
+    block_q, block_k, bwd_block_q, bwd_block_k = _blocks_within(
+        t, block_q, block_k, bwd_block_q, bwd_block_k
+    )
+    interpret = _default_interpret() if interpret is None else interpret
+    return _flash_packed(
+        q, k, v, head_dim, block_q, block_k, interpret, causal,
+        bwd_block_q, bwd_block_k,
+    )
 
 
 # -- flash decode attention (single-position KV-cache read) -------------------

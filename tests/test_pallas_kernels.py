@@ -660,3 +660,120 @@ def test_flash_decode_paged_sentinel_blocks_are_invisible():
     )
     ref = _dense_decode_ref(q, jnp.asarray(slab), pos, n_kv, 0)
     np.testing.assert_allclose(np.asarray(out), ref, atol=2e-5)
+
+
+# -- the packed training entry: (B, T, H*K), a block is a 128-lane group ------
+
+
+def _packed_case(seed, b, t, heads, head_dim):
+    rng = np.random.default_rng(seed)
+    return tuple(
+        jnp.asarray(rng.normal(size=(b, t, heads * head_dim)), jnp.float32)
+        for _ in range(3)
+    )
+
+
+def _heads_first(x, head_dim):  # (B, T, H*K) -> (B, H, T, K)
+    b, t, hk = x.shape
+    return x.reshape(b, t, hk // head_dim, head_dim).transpose(0, 2, 1, 3)
+
+
+def _rows_first(x):  # (B, H, T, K) -> (B, T, H*K)
+    b, h, t, k = x.shape
+    return x.transpose(0, 2, 1, 3).reshape(b, t, h * k)
+
+
+# T of one block, and of several with forward and backward blocks that
+# differ from each other: (t, block_q, block_k, bwd_block_q, bwd_block_k)
+_PACKED_BLOCKS = {
+    "one-block": (64, 64, 64, None, None),
+    "several-blocks": (128, 32, 64, 64, 32),
+}
+
+
+@pytest.mark.parametrize("blocks", sorted(_PACKED_BLOCKS))
+@pytest.mark.parametrize("hk", [128, 384])
+@pytest.mark.parametrize("head_dim", [64, 32])
+def test_flash_packed_matches_dense_and_the_bhtd_entry(head_dim, hk, blocks):
+    """Two (K = 64) and four (K = 32) heads to a 128-lane block, one and
+    three lane groups: output and all three gradients of the packed
+    entry against dense causal attention and against
+    ``flash_attention_trainable(layout="bhtd")`` at the same blocks."""
+    from deeplearning4j_tpu.ops.pallas_kernels import (
+        flash_attention_packed,
+        flash_attention_trainable,
+    )
+
+    t, bq, bk, bbq, bbk = _PACKED_BLOCKS[blocks]
+    q, k, v = _packed_case(11, 2, t, hk // head_dim, head_dim)
+    kw = dict(block_q=bq, block_k=bk, bwd_block_q=bbq, bwd_block_k=bbk,
+              causal=True, interpret=True)
+
+    def packed(q, k, v):
+        return flash_attention_packed(q, k, v, head_dim, **kw)
+
+    def bhtd(q, k, v):
+        return _rows_first(flash_attention_trainable(
+            *(_heads_first(a, head_dim) for a in (q, k, v)),
+            layout="bhtd", **kw))
+
+    def dense(q, k, v):
+        return _rows_first(attention(
+            *(_heads_first(a, head_dim) for a in (q, k, v)),
+            causal=True, layout="bhtd"))
+
+    def loss(fn):
+        def f(q, k, v):
+            o = fn(q, k, v)
+            return jnp.sum(o * jnp.cos(o)), o
+        return jax.value_and_grad(f, argnums=(0, 1, 2), has_aux=True)
+
+    (_, o_p), g_p = loss(packed)(q, k, v)
+    for other in (bhtd, dense):
+        (_, o), g = loss(other)(q, k, v)
+        np.testing.assert_allclose(np.asarray(o_p), np.asarray(o), atol=2e-5)
+        for a, b in zip(g_p, g):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=5e-5)
+
+
+def test_flash_packed_keeps_a_head_to_its_own_lanes():
+    """A ``v`` that is zero in one head gives an ``o`` that is zero in
+    that head's lanes and nowhere else; and dq, dk of that head are zero
+    too (its output does not depend on its scores), while the other head
+    of the same 128-lane block is what it is without its neighbour."""
+    from deeplearning4j_tpu.ops.pallas_kernels import flash_attention_packed
+
+    head_dim, heads, t = 64, 4, 64
+    q, k, v = _packed_case(12, 1, t, heads, head_dim)
+    a = 1  # the second head of the first lane group
+    lanes = slice(a * head_dim, (a + 1) * head_dim)
+    v0 = v.at[:, :, lanes].set(0.0)
+
+    def f(q, k, v):
+        return flash_attention_packed(
+            q, k, v, head_dim, block_q=32, block_k=32, causal=True,
+            interpret=True)
+
+    o, pull = jax.vjp(f, q, k, v0)
+    o_full = f(q, k, v)
+    assert not np.asarray(o[:, :, lanes]).any()
+    others = np.ones(heads * head_dim, bool)
+    others[lanes] = False
+    assert np.asarray(o[:, :, others] != 0).all()
+    np.testing.assert_array_equal(
+        np.asarray(o[:, :, others]), np.asarray(o_full[:, :, others]))
+    dq, dk, dv = pull(jnp.ones_like(o))
+    assert not np.asarray(dq[:, :, lanes]).any()
+    assert not np.asarray(dk[:, :, lanes]).any()
+    assert np.asarray(dv[:, :, lanes]).any()  # dv = P^T dO does not see v
+
+
+def test_flash_packed_refuses_heads_that_do_not_fill_lane_groups():
+    from deeplearning4j_tpu.ops.pallas_kernels import flash_attention_packed
+
+    x = jnp.zeros((1, 16, 192), jnp.float32)
+    with pytest.raises(ValueError, match="128"):
+        flash_attention_packed(x, x, x, 64)  # 3 heads of 64: 1.5 groups
+    y = jnp.zeros((1, 16, 384), jnp.float32)
+    with pytest.raises(ValueError, match="128"):
+        flash_attention_packed(y, y, y, 48)  # 48 does not divide 128

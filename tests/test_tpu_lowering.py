@@ -463,3 +463,43 @@ def test_lowered_serve_steps_alias_the_cache_and_scatter_no_row():
             and line.rstrip().endswith(f"tensor<{slab}>")
         ]
         assert not writes, writes[:2]
+
+
+def test_packed_train_step_compiles_for_a_v5e_and_copies_no_activation(
+        one_chip):
+    """Two layers of the gpt2-medium train step (8 x 1,024 tokens, 16
+    heads x 64, bf16, ``remat`` with ``dots_no_batch``) compiled for a
+    v5e, XLA:TPU and Mosaic: the packed kernels take 1,024 x 1,024
+    blocks with two heads in one body inside the scoped VMEM, run once a
+    layer forward and once backward (the policy saves ``flash_out`` and
+    ``flash_lse``: no second forward), and no copy or transpose of the
+    compiled step moves an activation the size of q (the (B, H, T, K)
+    path had nine copies a layer: PERF.md, PR 32)."""
+    from deeplearning4j_tpu.models.transformer import flash_layout
+
+    cfg = TransformerConfig(
+        vocab_size=2048, d_model=1024, n_heads=16, n_layers=2, d_ff=4096,
+        max_len=1024, compute_dtype=jnp.bfloat16, use_flash=True,
+        remat=True, scan_layers=False,
+    )
+    assert flash_layout(cfg) == "packed"
+    params = jax.tree.map(
+        lambda a: S(a.shape, a.dtype, sharding=one_chip),
+        jax.eval_shape(lambda: init_transformer(jax.random.key(0), cfg)),
+    )
+    hlo = jax.jit(jax.value_and_grad(transformer_loss(cfg))).lower(
+        params, S((8, 1025), jnp.int32, sharding=one_chip)
+    ).compile().as_text()
+    kernels = re.findall(r"%(\w+?)_?[.\d]* = .*tpu_custom_call", hlo)
+    assert sorted(k.removeprefix("jvp_") for k in kernels) == (
+        ["flash_bwd_packed"] * cfg.n_layers
+        + ["flash_fwd_packed"] * cfg.n_layers), kernels
+    q_size = 8 * 1024 * 16 * 64
+    moved = [
+        (op, dims)
+        for dims, op in re.findall(
+            r"= bf16\[([\d,]+)\]\{[^}]*\} (copy|transpose)\(",
+            hlo[hlo.index("ENTRY "):])
+        if math.prod(int(d) for d in dims.split(",")) % q_size == 0
+    ]
+    assert not moved, moved

@@ -903,3 +903,167 @@ def test_config_json_roundtrip():
     again = TransformerConfig.from_json(cfg.to_json())
     assert again == cfg
     assert again.compute_dtype == jnp.bfloat16
+
+
+# -- the training block's flash layout (PR 32) -------------------------------
+
+# MHA, 2 heads x 64: one whole 128-lane group
+PACKED = TransformerConfig(
+    vocab_size=64, d_model=128, n_heads=2, n_layers=2, d_ff=128,
+    max_len=65, use_flash=True, scan_layers=False,
+)
+
+
+def _replace(cfg, **over):
+    import dataclasses
+
+    return dataclasses.replace(cfg, **over)
+
+
+@pytest.mark.parametrize("over,want", [
+    ({}, "packed"),
+    ({"n_heads": 4, "n_kv_heads": 2}, "bhtd"),                 # GQA
+    ({"rope": True}, "bhtd"),                                  # rotary
+    ({"use_flash": False, "sequence_parallel": True}, "bhtd"),
+    ({"d_model": 64, "n_heads": 4}, "bhtd"),  # heads of 16: 64 lanes in all
+    ({"d_model": 192, "n_heads": 3}, "bhtd"),  # 1.5 lane groups
+    ({"d_model": 256, "n_heads": 8}, "packed"),  # four heads of 32 a group
+], ids=["mha-2x64", "gqa", "rope", "sequence-parallel", "64-wide",
+        "3x64", "8x32"])
+def test_flash_layout_is_packed_only_where_whole_lane_groups_of_heads(
+        over, want):
+    from deeplearning4j_tpu.models.transformer import flash_layout
+
+    assert flash_layout(_replace(PACKED, **over)) == want
+
+
+def test_flash_layout_counts_each_device_s_lanes(devices):
+    from deeplearning4j_tpu.models.transformer import flash_layout
+
+    assert flash_layout(PACKED, mesh_lib.dp_mp_mesh(2, 1)) == "packed"
+    # 2 heads x 64 over a model axis of 2: 64 lanes a device
+    assert flash_layout(PACKED, mesh_lib.dp_mp_mesh(1, 2)) == "bhtd"
+    wide = _replace(PACKED, d_model=256, n_heads=4)
+    assert flash_layout(wide, mesh_lib.dp_mp_mesh(1, 2)) == "packed"
+
+
+def _as_bhtd(monkeypatch):
+    """Send the same configuration through the (B, H, T, K) entry."""
+    from deeplearning4j_tpu.models import transformer
+
+    monkeypatch.setattr(
+        transformer, "flash_layout", lambda cfg, mesh=None: "bhtd")
+
+
+@pytest.mark.parametrize("with_mesh", [False, True], ids=["bare", "mesh-1x1"])
+@pytest.mark.parametrize("remat", [False, True], ids=["plain", "remat"])
+def test_packed_block_matches_the_bhtd_block(remat, with_mesh, monkeypatch):
+    """Loss and every gradient leaf of the packed block against the same
+    configuration through the ``bhtd`` block (and the loss against dense
+    attention), with and without ``remat``, bare and under the trainer's
+    ``shard_map`` on ``dp_mp_mesh(1, 1)``."""
+    cfg = _replace(PACKED, remat=remat)
+    mesh = mesh_lib.dp_mp_mesh(1, 1) if with_mesh else None
+    params = init_transformer(jax.random.key(32), cfg)
+    toks = _tokens(2, 65, seed=32)
+    l_p, g_p = jax.value_and_grad(transformer_loss(cfg, mesh))(params, toks)
+    l_d = transformer_loss(_replace(cfg, use_flash=False), mesh)(params, toks)
+    with monkeypatch.context() as m:
+        _as_bhtd(m)
+        l_b, g_b = jax.value_and_grad(transformer_loss(cfg, mesh))(
+            params, toks)
+    np.testing.assert_allclose(float(l_p), float(l_b), atol=2e-4)
+    np.testing.assert_allclose(float(l_p), float(l_d), atol=2e-4)
+    for (path, a), b in zip(
+            jax.tree_util.tree_leaves_with_path(g_p), jax.tree.leaves(g_b)):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), atol=2e-4, err_msg=str(path))
+
+
+def _activation_transposes(cfg, monkeypatch, t: int) -> list[str]:
+    """``stablehlo.transpose`` lines of the train step lowered for the
+    TPU (kernels compiled: Mosaic calls, not interpreted ops) whose
+    result has four or more dimensions, one of them the sequence: the
+    layout moves of q, k, v, o and their cotangents."""
+    from deeplearning4j_tpu.ops import pallas_kernels as pk
+
+    monkeypatch.setattr(pk, "_default_interpret", lambda: False)
+    params = jax.eval_shape(
+        lambda: init_transformer(jax.random.key(0), cfg))
+    text = jax.jit(jax.value_and_grad(transformer_loss(cfg))).trace(
+        params, jax.ShapeDtypeStruct((4, t + 1), jnp.int32)
+    ).lower(lowering_platforms=("tpu",)).as_text()
+    assert "tpu_custom_call" in text
+    moves = []
+    for line in text.splitlines():
+        if "stablehlo.transpose" not in line:
+            continue
+        dims = line.rsplit("tensor<", 1)[1].split(">")[0].split("x")[:-1]
+        if len(dims) >= 4 and str(t) in dims:
+            moves.append(line.strip())
+    return moves
+
+
+def test_packed_train_step_transposes_no_activation(monkeypatch):
+    """Between the QKV products and the kernels, and between the kernels
+    and ``wo``, forward and backward, the packed step moves no
+    activation; the ``bhtd`` step of the same configuration does (the
+    test can fail)."""
+    t = 256  # unlike every width of the configuration
+    cfg = _replace(PACKED, max_len=t + 1, remat=True)
+    assert _activation_transposes(cfg, monkeypatch, t) == []
+    _as_bhtd(monkeypatch)
+    assert len(_activation_transposes(cfg, monkeypatch, t)) >= 6
+
+
+def test_packed_block_loads_a_tree_saved_before_it(tmp_path):
+    """The leaves keep the shapes ``init_transformer`` gave them before
+    PR 32 (``wqkv`` (layers, d, 3, heads, head size), ``wo`` (layers,
+    heads, head size, d)): a tree written then goes through the packed
+    block as it is."""
+    nl, d, h, k, f, v = 2, 128, 2, 64, 128, 64
+    before = {
+        "embed": (v, d), "pos": (65, d), "lnf_scale": (d,), "lnf_bias": (d,),
+        "head": (d, v),
+        "blocks/ln1_scale": (nl, d), "blocks/ln1_bias": (nl, d),
+        "blocks/wqkv": (nl, d, 3, h, k), "blocks/wo": (nl, h, k, d),
+        "blocks/ln2_scale": (nl, d), "blocks/ln2_bias": (nl, d),
+        "blocks/w1": (nl, d, f), "blocks/b1": (nl, f),
+        "blocks/w2": (nl, f, d), "blocks/b2": (nl, d),
+    }
+    rng = np.random.default_rng(5)
+    np.savez(tmp_path / "saved.npz", **{
+        name: rng.normal(size=shape).astype(np.float32) * 0.05
+        for name, shape in before.items()
+    })
+    params = {"blocks": {}}
+    with np.load(tmp_path / "saved.npz") as saved:
+        for name in saved.files:
+            where, _, leaf = name.rpartition("/")
+            (params[where] if where else params)[leaf] = jnp.asarray(
+                saved[name])
+    fresh = init_transformer(jax.random.key(0), PACKED)
+    assert jax.tree.map(jnp.shape, params) == jax.tree.map(jnp.shape, fresh)
+    loss = transformer_loss(PACKED)(params, _tokens(2, 65, seed=6))
+    assert np.isfinite(float(loss))
+
+
+def test_packed_block_on_a_dp_tp_mesh_matches_one_device(devices):
+    """Four heads of 64 over a model axis of 2: each device's kernel
+    takes its own 128-lane group of the (B, T, H*K) activations, and the
+    loss and gradients are the unsharded block's."""
+    from deeplearning4j_tpu.models.transformer import flash_layout
+
+    cfg = _replace(PACKED, d_model=256, n_heads=4)
+    mesh = mesh_lib.dp_mp_mesh(2, 2)
+    assert flash_layout(cfg, mesh) == "packed"
+    params = init_transformer(jax.random.key(33), cfg)
+    toks = _tokens(4, 65, seed=33)
+    l_1, g_1 = jax.value_and_grad(transformer_loss(cfg))(params, toks)
+    l_m, g_m = jax.jit(jax.value_and_grad(transformer_loss(cfg, mesh)))(
+        place_transformer_params(mesh, params, cfg), toks)
+    np.testing.assert_allclose(float(l_m), float(l_1), atol=2e-4)
+    for (path, a), b in zip(
+            jax.tree_util.tree_leaves_with_path(g_m), jax.tree.leaves(g_1)):
+        np.testing.assert_allclose(
+            np.asarray(a), np.asarray(b), atol=2e-4, err_msg=str(path))
