@@ -817,7 +817,13 @@ def _flash_blocks(t: int) -> tuple[int, int]:
     own at 8 x 16 x 1,024 x 64 and prefer the same
     (``scripts/flash_train_bench.py``, forward + backward: 1,024/1,024
     1,663 us a call, 512/1,024 1,868, 512/512 1,849 though it skips a
-    tile of four, 1,024/512 2,173; PERF.md, PR 32)."""
+    tile of four, 1,024/512 2,173; PERF.md, PR 32). Since PR 35 a
+    1,024/1,024 tile that crosses the diagonal is walked in four causal
+    bands of 256 rows inside the kernel body (``pallas_kernels.
+    _band_rows``), and the forward of a tile that is alone in its rows
+    holds its scores transposed: 1,154-1,160 us a call where the whole
+    square read 1,654-1,665; bands of 512 read 1,243, of 128 1,429
+    (PERF.md, PR 35)."""
 
     def pick(pref: int) -> int:
         if t <= pref:

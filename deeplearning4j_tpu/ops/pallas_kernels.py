@@ -535,11 +535,137 @@ def flash_attention_trainable(
 _PACK_LANES = 128
 
 
+def _band_rows(block: int) -> int:
+    """Rows of one causal band of a ``block`` x ``block`` tile that
+    crosses the diagonal: a quarter of the tile in whole 128-lane tiles
+    (every band's visible prefix of the key columns is then a whole
+    number of lane tiles), the largest such that divides the tile, and
+    128 at least: 1,024 -> 4 bands of 256, 512 -> 4 of 128, 256 -> 2 of
+    128. A tile of 128 rows or fewer, or one that is no multiple of 128,
+    is one band, which is the unbanded body. Measured at 8 x 16 x 1,024
+    x 64 on a v5e (``scripts/flash_train_bench.py``; PERF.md, PR 35)."""
+    if block % _PACK_LANES:
+        return block
+    rows = max(_PACK_LANES, block // 4 // _PACK_LANES * _PACK_LANES)
+    while block % rows:
+        rows -= _PACK_LANES
+    return rows
+
+
+def _diagonal_bands(block_q: int, block_k: int, masked: bool):
+    """The packed kernels' walk over one tile, ``(first row, rows,
+    visible key columns)`` a band. A causal tile that crosses the
+    diagonal with ``block_q == block_k`` (its first row is then its first
+    column) is cut into bands of ``_band_rows`` query rows, band ``r``
+    seeing the key columns ``[0, (r + 1) rows)``: what lies above the
+    diagonal is never multiplied, exponentiated or summed, down to a
+    band. Every other tile is one band over all its columns."""
+    rows = _band_rows(block_q) if masked and block_q == block_k else block_q
+    if rows == block_q:
+        return [(0, block_q, block_k)]
+    return [(r, rows, r + rows) for r in range(0, block_q, rows)]
+
+
+def flash_computed_share(
+    t: int, block_q: int, block_k: int, causal: bool
+) -> float:
+    """The share of the ``t`` x ``t`` score square that a packed flash
+    kernel multiplies at these blocks: 1.0 without ``causal``; with it,
+    nothing of a tile above the diagonal, all of a tile below it, and of
+    a tile that crosses it the bands of ``_diagonal_bands`` (0.5 is the
+    triangle; one causal tile of 1,024 rows reads 0.625)."""
+    if not causal:
+        return 1.0
+    area = 0
+    for q_start in range(0, t, block_q):
+        for k_start in range(0, t, block_k):
+            if k_start > q_start + block_q - 1:
+                continue
+            crosses = k_start + block_k - 1 > q_start
+            area += sum(
+                rows * cols
+                for _, rows, cols in _diagonal_bands(block_q, block_k, crosses)
+            )
+    return area / (t * t)
+
+
+def _band_bias(bands, masked: bool, q_start, k_start, block_q: int,
+               block_k: int):
+    """The causal bias of each band of a tile: none unless it crosses
+    the diagonal (``masked``). The bands of a cut tile share one ``rows``
+    x ``rows`` triangle, which lies over the last ``rows`` of a band's
+    visible columns."""
+    if not masked:
+        return [None] * len(bands)
+    if len(bands) == 1:
+        return [_causal_bias(q_start, k_start, block_q, block_k)]
+    rows = bands[0][1]
+    corner = _causal_bias(0, 0, rows, rows)
+    return [
+        corner if cols == rows else jnp.concatenate(
+            [jnp.zeros((rows, cols - rows), corner.dtype), corner], axis=1)
+        for _, _, cols in bands
+    ]
+
+
 def _head_lanes(shape, head_dim: int, a: int):
     """Bool mask of ``shape`` (.., 128): the lanes of head ``a`` of a
     128-lane group."""
     lane = jax.lax.broadcasted_iota(jnp.int32, shape, len(shape) - 1)
     return lane // head_dim == a
+
+
+def _flash_fwd_packed_lone_tile(
+    q_ref, k_ref, v_ref, o_ref, lse_ref, bands, head_dim: int, scale: float
+):
+    """The forward of a causal tile that is the only one of its rows
+    (``n_k == 1``) and is cut into ``bands``: there is no running state
+    to fold into, so each band is finished where it is computed, and its
+    score tile is held TRANSPOSED, keys on sublanes and queries on lanes.
+    The row maximum and the row sum then reduce over sublanes on the VPU
+    and stay lane-major, where a (queries, keys) tile pays two lane
+    reductions a query row whatever the band sees, which was three
+    fifths of the forward (PERF.md, PR 35). The same products at the same
+    precision; the row sum adds in another order, so the output can
+    differ from the (queries, keys) body's in its last bf16 bit."""
+    heads = _PACK_LANES // head_dim
+    q = q_ref[0] * jnp.asarray(scale, q_ref.dtype)
+    k_blk = k_ref[0]
+    v_t = v_ref[0].T  # (128, block): one transpose a body
+    rows = bands[0][1]
+    key = jax.lax.broadcasted_iota(jnp.int32, (rows, rows), 0)
+    query = jax.lax.broadcasted_iota(jnp.int32, (rows, rows), 1)
+    corner = jnp.where(key <= query, 0.0, -jnp.inf).astype(jnp.float32)
+    # a head's lanes of o are its sublanes of o^T
+    sublane = jax.lax.broadcasted_iota(jnp.int32, (_PACK_LANES, rows), 0)
+    for r0, _, cols in bands:
+        band = slice(r0, r0 + rows)
+        q_r, k_r = q[band], k_blk[:cols]
+        bias = corner if cols == rows else jnp.concatenate(
+            [jnp.zeros((cols - rows, rows), corner.dtype), corner])
+        o_t = jnp.zeros((_PACK_LANES, rows), jnp.float32)
+        l_t = jnp.ones((_PACK_LANES, rows), jnp.float32)
+        lse_t = jnp.zeros((_PACK_LANES, rows), jnp.float32)
+        for a in range(heads):
+            mine = _head_lanes(q_r.shape, head_dim, a)
+            s_t = jax.lax.dot_general(  # k q^T, (cols, rows)
+                k_r, jnp.where(mine, q_r, jnp.zeros_like(q_r)),
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            ) + bias
+            m = jnp.max(s_t, axis=0, keepdims=True)
+            p_t = jnp.exp(s_t - m)
+            l = jnp.maximum(jnp.sum(p_t, axis=0, keepdims=True), 1e-30)
+            pv_t = jnp.dot(  # v^T p^T, (128, rows)
+                v_t[:, :cols], p_t.astype(v_t.dtype),
+                preferred_element_type=jnp.float32,
+            )
+            mine_t = sublane // head_dim == a
+            o_t = jnp.where(mine_t, pv_t, o_t)
+            l_t = jnp.where(mine_t, l, l_t)
+            lse_t = jnp.where(sublane == a, m + jnp.log(l), lse_t)
+        o_ref[0, band, :] = (o_t / l_t).T.astype(o_ref.dtype)
+        lse_ref[0, 0, band, :] = lse_t.T[:, :heads]
 
 
 def _flash_fwd_packed_kernel(
@@ -549,6 +675,12 @@ def _flash_fwd_packed_kernel(
 ):
     """One (batch, lane group, q block, kv block) grid step of the
     online-softmax forward over a group of ``128 // head_dim`` heads."""
+    if causal and n_k == 1:
+        bands = _diagonal_bands(block_q, block_k, True)
+        if len(bands) > 1:  # block_q == block_k: the rows' only tile
+            _flash_fwd_packed_lone_tile(
+                q_ref, k_ref, v_ref, o_ref, lse_ref, bands, head_dim, scale)
+            return
     kk = pl.program_id(3)
     q_start = pl.program_id(2) * block_q
     k_start = kk * block_k
@@ -564,26 +696,30 @@ def _flash_fwd_packed_kernel(
         q = q_ref[0] * jnp.asarray(scale, q_ref.dtype)
         k_blk = k_ref[0]
         v_blk = v_ref[0]
-        if masked:  # one bias for every head of the group
-            bias = _causal_bias(q_start, k_start, block_q, block_k)
-        acc = acc_s[:]
-        for a in range(heads):
-            mine = _head_lanes(q.shape, head_dim, a)
-            s = jnp.dot(
-                jnp.where(mine, q, jnp.zeros_like(q)), k_blk.T,
-                preferred_element_type=jnp.float32,
-            )
-            if masked:
-                s = s + bias
-            at = (slice(None), a)
-            p, corr, m_new = _softmax_fold(s, m_s, l_s, at)
-            pv = jnp.dot(
-                p.astype(v_blk.dtype), v_blk,
-                preferred_element_type=jnp.float32,
-            )
-            acc = jnp.where(mine, corr[:, None] * acc + pv, acc)
-            m_s[at] = m_new
-        acc_s[:] = acc
+        bands = _diagonal_bands(block_q, block_k, masked)
+        # one bias for every head of the group
+        biases = _band_bias(bands, masked, q_start, k_start, block_q, block_k)
+        for (r0, rows, cols), bias in zip(bands, biases):
+            band = slice(r0, r0 + rows)
+            q_r, k_r, v_r = q[band], k_blk[:cols], v_blk[:cols]
+            acc = acc_s[band]
+            for a in range(heads):
+                mine = _head_lanes(q_r.shape, head_dim, a)
+                s = jnp.dot(
+                    jnp.where(mine, q_r, jnp.zeros_like(q_r)), k_r.T,
+                    preferred_element_type=jnp.float32,
+                )
+                if masked:
+                    s = s + bias
+                at = (band, a)
+                p, corr, m_new = _softmax_fold(s, m_s, l_s, at)
+                pv = jnp.dot(
+                    p.astype(v_r.dtype), v_r,
+                    preferred_element_type=jnp.float32,
+                )
+                acc = jnp.where(mine, corr[:, None] * acc + pv, acc)
+                m_s[at] = m_new
+            acc_s[band] = acc
 
     _causal_dispatch(compute, causal, q_start, k_start, block_q, block_k)
 
@@ -677,23 +813,35 @@ def _flash_bwd_packed_kernel(
         q = q_ref[0] * jnp.asarray(scale, q_ref.dtype)
         do = do_ref[0]
         do_o = do.astype(jnp.float32) * o_ref[0].astype(jnp.float32)
-        bias = None
-        if masked:  # one bias for every head of the group
-            tile = _causal_bias(q_start, k_start, block_q, block_k)
-            bias = lambda: tile  # noqa: E731
-        dq_c = jnp.zeros(q.shape, jnp.float32)
-        for a in range(heads):
-            mine = _head_lanes(q.shape, head_dim, a)
-            # q and dO carry head a's lanes alone, so s, dp, dk and dv
-            # are head a's; k's and v's other lanes meet zeros
-            dq_a = _flash_bwd_tile(
-                jnp.where(mine, q, jnp.zeros_like(q)), k_blk, v_blk,
-                jnp.where(mine, do, jnp.zeros_like(do)),
-                lse_ref[0, 0, :, a],
-                jnp.sum(jnp.where(mine, do_o, 0.0), axis=-1),
-                dk_s, dv_s, scale, bias,
+        bands = _diagonal_bands(block_q, block_k, masked)
+        # one bias for every head of the group
+        biases = _band_bias(bands, masked, q_start, k_start, block_q, block_k)
+        dq_bands = []
+        for (r0, rows, cols), tile in zip(bands, biases):
+            band = slice(r0, r0 + rows)
+            q_r, do_r, do_o_r = q[band], do[band], do_o[band]
+            # a band adds to the rows of dk and dv that it sees
+            dk_r, dv_r = (
+                acc if cols == block_k else acc.at[:cols]
+                for acc in (dk_s, dv_s)
             )
-            dq_c = jnp.where(mine, dq_a, dq_c)
+            bias = (lambda tile=tile: tile) if masked else None
+            dq_c = jnp.zeros(q_r.shape, jnp.float32)
+            for a in range(heads):
+                mine = _head_lanes(q_r.shape, head_dim, a)
+                # q and dO carry head a's lanes alone, so s, dp, dk and
+                # dv are head a's; k's and v's other lanes meet zeros
+                dq_a = _flash_bwd_tile(
+                    jnp.where(mine, q_r, jnp.zeros_like(q_r)),
+                    k_blk[:cols], v_blk[:cols],
+                    jnp.where(mine, do_r, jnp.zeros_like(do_r)),
+                    lse_ref[0, 0, band, a],
+                    jnp.sum(jnp.where(mine, do_o_r, 0.0), axis=-1),
+                    dk_r, dv_r, scale, bias,
+                )
+                dq_c = jnp.where(mine, dq_a, dq_c)
+            dq_bands.append(dq_c)
+        dq_c = dq_bands[0] if len(bands) == 1 else jnp.concatenate(dq_bands)
         _store_dq(dq_ref, dq_c, kk, dq_partials)
 
     _causal_dispatch(compute, causal, q_start, k_start, block_q, block_k)

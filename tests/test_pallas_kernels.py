@@ -1,6 +1,8 @@
 """Pallas kernels validated in interpret mode against the XLA references
 (the lowered TPU path runs the identical kernel code on real chips)."""
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -688,6 +690,12 @@ def _rows_first(x):  # (B, H, T, K) -> (B, T, H*K)
 _PACKED_BLOCKS = {
     "one-block": (64, 64, 64, None, None),
     "several-blocks": (128, 32, 64, 64, 32),
+    # tiles that cross the diagonal in causal bands (``_band_rows``): one
+    # tile of two bands, one of four, and a banded tile beside a fully
+    # visible and a skipped one, so that the online state folds by rows
+    "two-bands": (256, 256, 256, None, None),
+    "four-bands": (512, 512, 512, None, None),
+    "banded-among-tiles": (512, 256, 256, None, None),
 }
 
 
@@ -777,3 +785,96 @@ def test_flash_packed_refuses_heads_that_do_not_fill_lane_groups():
     y = jnp.zeros((1, 16, 384), jnp.float32)
     with pytest.raises(ValueError, match="128"):
         flash_attention_packed(y, y, y, 48)  # 48 does not divide 128
+
+
+def _kernel_dot_flops(fn, name, *args):
+    """The ``dot_general``s of the Pallas kernel called ``name`` in the
+    trace of ``fn`` as (products, FLOPs): those of the body itself, if
+    it multiplies outside any ``pl.when``, then a causal class each (a
+    ``pl.when`` body that multiplies, in program order: fully visible,
+    then diagonal-crossing)."""
+    def dots(jaxpr, nested=True):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "dot_general":
+                (lhs_c, _), _ = eqn.params["dimension_numbers"]
+                depth = math.prod(eqn.invars[0].aval.shape[d] for d in lhs_c)
+                yield 2 * math.prod(eqn.outvars[0].aval.shape) * depth
+            for sub in jax.core.jaxprs_in_params(eqn.params) if nested else ():
+                yield from dots(sub)
+
+    def kernels(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                if eqn.params["name"] == name:
+                    yield eqn.params["jaxpr"]
+            else:
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    yield from kernels(sub)
+
+    (body,) = kernels(jax.make_jaxpr(fn)(*args).jaxpr)
+    classes = [list(dots(body, nested=False))] + [
+        [f for sub in jax.core.jaxprs_in_params(eqn.params)
+         for f in dots(sub)]
+        for eqn in body.eqns
+    ]
+    return [(len(flops), sum(flops)) for flops in classes if flops]
+
+
+@pytest.mark.parametrize("block,share,bands", [
+    (64, 1.0, 1), (128, 1.0, 1), (256, 0.75, 2), (512, 0.625, 4),
+    (1024, 0.625, 4),
+])
+def test_flash_packed_multiplies_the_bands_of_a_diagonal_tile_and_no_more(
+        block, share, bands):
+    """The rule and the work it leaves. ``flash_computed_share`` of one
+    causal tile is 1.0 / 0.75 / 0.625 / 0.625 at 128 / 256 / 512 / 1,024
+    rows and 1.0 without ``causal``; the products of the traced forward
+    and backward bodies multiply exactly that share of the square a
+    head in the diagonal-crossing class and the whole square in the
+    fully visible one (a forward tile in bands is the only tile of its
+    rows here, and its body holds the bands' products and no class); a
+    tile of 128 rows or fewer holds the products it held before the
+    bands: 2 a head forward, 5 a head backward."""
+    from deeplearning4j_tpu.ops.pallas_kernels import (
+        flash_attention_packed,
+        flash_computed_share,
+    )
+
+    assert flash_computed_share(block, block, block, True) == share
+    assert flash_computed_share(block, block, block, False) == 1.0
+    head_dim, heads = 64, 2
+    x = jax.ShapeDtypeStruct((1, block, heads * head_dim), jnp.float32)
+
+    def forward(q, k, v):
+        return flash_attention_packed(
+            q, k, v, head_dim, block_q=block, block_k=block, causal=True,
+            interpret=True)
+
+    def backward(q, k, v):
+        o, pull = jax.vjp(forward, q, k, v)
+        return pull(o)
+
+    # a product a head runs 128 lanes wide: the other head's are zeros
+    square = 2 * block * block * 128 * heads
+    for fn, name, products in ((forward, "flash_fwd_packed", 2),
+                               (backward, "flash_bwd_packed", 5)):
+        whole = (products * heads, products * square)
+        banded = (products * heads * bands, products * square * share)
+        lone = products == 2 and bands > 1
+        assert _kernel_dot_flops(fn, name, x, x, x) == (
+            [banded] if lone else [whole, banded]), name
+
+
+def test_flash_computed_share_counts_every_tile_of_a_longer_sequence():
+    """At ``T = 8192`` with the forward's 1,024 / 1,024 blocks the eight
+    diagonal tiles are banded and the 28 below them whole; the
+    backward's 512 / 2,048 blocks differ from each other, so their
+    diagonal tiles keep the masked body over the whole tile."""
+    from deeplearning4j_tpu.ops.pallas_kernels import flash_computed_share
+
+    assert flash_computed_share(8192, 1024, 1024, True) == (
+        (28 + 8 * 0.625) / 64)
+    # key block j of four is seen by the 16 - 4 j query blocks from its
+    # first column down: 16 + 12 + 8 + 4 = 40 whole tiles of 64
+    assert flash_computed_share(8192, 512, 2048, True) == 40 / 64
+    assert flash_computed_share(512, 256, 256, True) == (1 + 2 * 0.75) / 4

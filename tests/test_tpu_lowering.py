@@ -474,8 +474,14 @@ def test_packed_train_step_compiles_for_a_v5e_and_copies_no_activation(
     layer forward and once backward (the policy saves ``flash_out`` and
     ``flash_lse``: no second forward), and no copy or transpose of the
     compiled step moves an activation the size of q (the (B, H, T, K)
-    path had nine copies a layer: PERF.md, PR 32)."""
-    from deeplearning4j_tpu.models.transformer import flash_layout
+    path had nine copies a layer: PERF.md, PR 32). Since PR 35 each of
+    those tiles is walked in four causal bands inside the body."""
+    from deeplearning4j_tpu.models.transformer import (
+        _flash_blocks,
+        flash_layout,
+    )
+
+    assert pk.flash_computed_share(1024, *_flash_blocks(1024), True) == 0.625
 
     cfg = TransformerConfig(
         vocab_size=2048, d_model=1024, n_heads=16, n_layers=2, d_ff=4096,
@@ -503,3 +509,35 @@ def test_packed_train_step_compiles_for_a_v5e_and_copies_no_activation(
         if math.prod(int(d) for d in dims.split(",")) % q_size == 0
     ]
     assert not moved, moved
+
+
+def test_packed_flash_compiles_banded_and_unbanded_tiles_for_a_v5e(one_chip):
+    """Forward and backward at 1 x 8,192 x 16 heads x 64 compiled for a
+    v5e: 1,024 / 1,024 forward blocks (the eight diagonal tiles in
+    bands, the rest whole) and 512 / 2,048 backward blocks (no bands:
+    the blocks differ) in one call, inside the scoped VMEM."""
+    from deeplearning4j_tpu.models.transformer import (
+        _flash_blocks,
+        _flash_bwd_blocks,
+    )
+
+    t = 8192
+    (bq, bk), (bbq, bbk) = _flash_blocks(t), _flash_bwd_blocks(t)
+    assert (bq, bk, bbq, bbk) == (1024, 1024, 512, 2048)
+    assert pk.flash_computed_share(t, bq, bk, True) < (
+        pk.flash_computed_share(t, bbq, bbk, True))
+
+    def both(q, k, v):
+        o, pull = jax.vjp(
+            lambda q, k, v: pk.flash_attention_packed(
+                q, k, v, 64, block_q=bq, block_k=bk, bwd_block_q=bbq,
+                bwd_block_k=bbk, causal=True),
+            q, k, v)
+        return o, pull(o)
+
+    x = S((1, t, 16 * 64), jnp.bfloat16, sharding=one_chip)
+    hlo = jax.jit(both).lower(x, x, x).compile().as_text()
+    kernels = re.findall(r"%([\w.]+) = .*tpu_custom_call", hlo)
+    assert sorted(
+        re.search(r"flash_(fwd|bwd)_packed", k).group() for k in kernels
+    ) == ["flash_bwd_packed", "flash_fwd_packed"], kernels
