@@ -61,6 +61,9 @@ from pathlib import Path
 #: canonical track names the serving engine uses (slots are "slot-N")
 ENGINE_TRACK = "engine"
 SCHEDULER_TRACK = "scheduler"
+#: prefix of the engine loop's :class:`PhaseRegions` (``engine.admit``
+#: ...), which :mod:`~deeplearning4j_tpu.obs.capture` finds them by
+ENGINE_REGIONS = "engine"
 
 _TRACEPARENT_RE = re.compile(
     r"^[0-9a-f]{2}-([0-9a-f]{32})-([0-9a-f]{16})-[0-9a-f]{2}$"

@@ -149,6 +149,7 @@ from deeplearning4j_tpu.obs.flight import FlightRecorder
 from deeplearning4j_tpu.obs.logs import log_event
 from deeplearning4j_tpu.obs.profiler import ProfileTrigger
 from deeplearning4j_tpu.obs.trace import (
+    ENGINE_REGIONS,
     ENGINE_TRACK,
     SCHEDULER_TRACK,
     PhaseRegions,
@@ -1391,7 +1392,7 @@ class ServingEngine:
         # the one place a phase of step() is named: profiler
         # annotation, metrics.loop_seconds, ring span, sanitizer phase
         self._regions = PhaseRegions(
-            "engine", self.metrics.loop_seconds, self.tracer,
+            ENGINE_REGIONS, self.metrics.loop_seconds, self.tracer,
             ENGINE_TRACK, on_phase=self._set_phase,
         )
         # power-of-two prompt buckets: the largest must respect the

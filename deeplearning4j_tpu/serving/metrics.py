@@ -24,14 +24,17 @@ The series:
   effective decode batch; > 1 means batching actually interleaved
   requests), with the fraction as ``serve/occupancy_frac``;
 - ``serve/queue_depth`` — queued (not yet admitted) requests, sampled
-  per engine step;
+  per engine step (JSONL only: the live value is the ``serve_queue_depth``
+  gauge, the trace's the ``queue_depth`` counter);
 - ``serve/queue_delay_seconds`` — submit-to-admission wait per request
   (the scheduling component of TTFT, separated out so horizon-induced
   admission latency is visible on its own);
 - ``serve/sync_wait_seconds`` / ``serve/overlap_seconds`` — per
   readback, how long the host blocked on the device token sync vs how
   long it spent doing useful work (bookkeeping + next dispatch) while
-  the horizon computed. ``dispatch_overlap_frac`` in ``summary()`` is
+  the horizon computed (JSONL only; their exact sums are
+  ``phase_seconds["sync"]`` and ``phase_seconds["decode"]`` less it).
+  ``dispatch_overlap_frac`` in ``summary()`` is
   overlap / (overlap + sync wait): ~0 means the host serializes with
   the device (the pre-pipelining behavior), near 1 means readback is
   fully hidden.
@@ -143,10 +146,7 @@ class ServingMetrics:
         self.ttft = Reservoir(reservoir_cap)
         self.tpot = Reservoir(reservoir_cap)
         self.occupancy = Reservoir(reservoir_cap)
-        self.queue_depth = Reservoir(reservoir_cap)
         self.queue_delay = Reservoir(reservoir_cap)
-        self.sync_wait = Reservoir(reservoir_cap)
-        self.overlap = Reservoir(reservoir_cap)
         # exact per-phase wall-second totals (see module docstring)
         self.phase_seconds = {p: 0.0 for p in PHASES}
         # exact engine-loop totals, written by the engine thread only
@@ -545,7 +545,6 @@ class ServingMetrics:
         """Per-engine-step utilization sample (``n_active`` slots
         decoding this step, of ``n_slots``)."""
         self.occupancy.add(float(n_active))
-        self.queue_depth.add(int(queue_depth))
         self._c_steps.inc()
         self._emit("occupancy", n_active, self._step)
         self._emit("occupancy_frac", n_active / n_slots, self._step)
@@ -574,8 +573,6 @@ class ServingMetrics:
         token sync after ``overlap_s`` of overlapped host work. The
         horizon's decode interval (dispatch → block arrival) is their
         sum."""
-        self.sync_wait.add(float(sync_wait_s))
-        self.overlap.add(float(overlap_s))
         self.record_phase("decode", float(sync_wait_s) + float(overlap_s))
         self.record_phase("sync", float(sync_wait_s))
         self._emit("sync_wait_seconds", sync_wait_s)
@@ -975,17 +972,16 @@ class ServingMetrics:
             if xs:
                 out[f"{name}_p50_s"] = _pct(xs, 50)
                 out[f"{name}_p99_s"] = _pct(xs, 99)
-        if self.sync_wait:
-            sync = self.sync_wait.total
-            over = self.overlap.total
-            out["sync_wait_mean_s"] = sync / len(self.sync_wait)
-            if sync + over > 0:
-                out["dispatch_overlap_frac"] = over / (sync + over)
+        if self.phase_seconds["decode"] > 0:
+            # a horizon's decode interval is overlapped host work plus
+            # the blocking sync (record_readback)
+            out["dispatch_overlap_frac"] = 1.0 - (
+                self.phase_seconds["sync"] / self.phase_seconds["decode"]
+            )
         if self.occupancy:
             # mean slots actually decoding per step — the "effective
             # batch" a continuous batcher is supposed to keep > 1
             out["occupancy_mean"] = self.occupancy.mean
-            out["queue_depth_max"] = int(self.queue_depth.max)
         if self.program_dispatches:
             out["program_dispatches"] = dict(
                 sorted(self.program_dispatches.items())

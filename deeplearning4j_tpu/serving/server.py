@@ -74,6 +74,11 @@ Endpoints:
   engine steps (requires the engine to be wired with a
   ``ProfileTrigger``; 409 while a capture is already armed). Returns
   the directory the capture will land in.
+- ``GET /profile/report`` — the last finished capture reduced to
+  numbers (:func:`..obs.capture.loop_report`): the device's idle
+  seconds by the loop phase they lie under, the seconds between decode
+  steps by the program that ran in them. 404 before the first capture,
+  409 while one is armed or running.
 - ``GET /healthz`` — liveness: 200 while the engine thread is alive
   (or recovering), 503 once it is dead OR HUNG; payload carries
   ``engine_alive``, ``last_error``, the restart count, and the
@@ -116,6 +121,7 @@ from http.server import ThreadingHTTPServer
 from pathlib import Path
 from urllib.parse import parse_qs, urlparse
 
+from deeplearning4j_tpu.obs import capture
 from deeplearning4j_tpu.obs.logs import log_event
 from deeplearning4j_tpu.obs.trace import (
     format_traceparent,
@@ -355,6 +361,8 @@ class ServingServer:
             send_json(handler, 200, self._metrics_payload())
         elif path == "/debug/dump":
             send_json(handler, 200, self.flight_bundle("debug_dump"))
+        elif path == "/profile/report":
+            self._handle_profile_report(handler)
         else:
             return False
         return True
@@ -419,6 +427,26 @@ class ServingServer:
             return
         log_event(_log, "profile_armed", steps=n, dir=str(capture_dir))
         send_json(handler, 200, {"armed": n, "dir": str(capture_dir)})
+
+    def _handle_profile_report(self, handler) -> None:
+        """``GET /profile/report``: the last finished capture reduced
+        by :func:`~deeplearning4j_tpu.obs.capture.loop_report`, on this
+        handler's thread and never on the loop's."""
+        trigger = self.engine.profile
+        try:
+            done = trigger.finished_capture() if trigger else None
+        except RuntimeError as e:  # armed or running
+            send_json(handler, 409, {"error": str(e)})
+            return
+        path = capture.find_xplane(done) if done is not None else None
+        if path is None:
+            send_json(handler, 404, {
+                "error": "no finished capture (POST /profile?s=N first)",
+            })
+            return
+        send_json(handler, 200, dict(
+            capture.loop_report(path), dir=str(done),
+        ))
 
     def _byte_vocab(self) -> bool:
         return self.engine.cfg.vocab_size <= 256
