@@ -1340,17 +1340,33 @@ def _kv_walk_math(n_kv_heads: int, head_dim: int, groups: int,
     return math
 
 
+# Parts a block's rows are cut in by ``_latent_walk_math``.
+_LATENT_PARTS = 2
+
+
 def _latent_walk_math(value_width: int):
     """The walk's arithmetic over ONE plane of latent rows: a row's
     ``q`` (H, W) holds every head's query folded onto the latent (scale
     included), a block (block_t, W) is the key of all of them and, in
     its first ``value_width`` lanes, their value, so it is read once for
     both products. Heads lie on sublanes and cache rows on lanes, as in
-    the flash forward kernel: scores (H, block_t), softmax state (H, 1),
-    accumulator (H, value_width), no segment masks."""
+    the flash forward kernel: scores (H, rows), softmax state (H, 1),
+    accumulator (H, value_width), no segment masks.
+
+    The block's rows are cut in ``_LATENT_PARTS`` parts. A part's
+    weights are taken against the part's OWN maximum (with the running
+    one), so its softmax waits for no other part's scores, and all the
+    softmaxes are written before the first values product: one part's
+    ``exp`` then runs under another part's products and the MXU is fed
+    all through the block, where one softmax over the whole block stood
+    between the two products with the MXU idle (PERF.md, PR 37). The
+    parts meet once a block, in one rescale of the accumulator; it is
+    the online softmax of a walk with blocks a part long, summed in
+    another order."""
+    nt = (((1,), (1,)), ((), ()))
+
     def math(q_ref, dtype):
-        q = q_ref[0]
-        h = q.shape[0]
+        h = q_ref.shape[1]
 
         def state0():
             return (
@@ -1361,24 +1377,48 @@ def _latent_walk_math(value_width: int):
 
         def fold(state, buf, slot, j, last):
             m_prev, l_prev, acc = state
-            kb = buf[slot, 0]  # (block_t, W): block j of the row's walk
-            s = jax.lax.dot_general(
-                q, kb, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )  # (H, block_t)
-            cols = j * kb.shape[0] + jax.lax.broadcasted_iota(
-                jnp.int32, (1, kb.shape[0]), 1
-            )
-            s = jnp.where(cols > last, -jnp.inf, s)
-            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-            p = jnp.exp(s - m_new)
+            block_t = buf.shape[2]
+            r = block_t // _LATENT_PARTS
+            # block j of the row's walk, a part at a time; q is read
+            # from its block every time (held across the walk it is
+            # spilled and filled again)
+            q = q_ref[0]
+            kbs = [buf[slot, 0, k * r:(k + 1) * r, :]
+                   for k in range(_LATENT_PARTS)]
+            scores = [
+                jax.lax.dot_general(
+                    q, kb, nt, preferred_element_type=jnp.float32)
+                for kb in kbs
+            ]  # (H, r) each
+            weights = []
+            for k, s in enumerate(scores):
+                cols = j * block_t + k * r + jax.lax.broadcasted_iota(
+                    jnp.int32, (1, r), 1
+                )
+                # a select, not a bias a column: what a slot's last tenant
+                # left past the position may score inf
+                s = jnp.where(cols > last, -jnp.inf, s)
+                m_k = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+                # a part past the row's position in its first block has
+                # seen nothing yet (m_k = -inf): exp(-inf - 0) = 0
+                p = jnp.exp(s - jnp.where(m_k == -jnp.inf, 0.0, m_k))
+                weights.append((
+                    m_k, jnp.sum(p, axis=1, keepdims=True),
+                    p.astype(kbs[k].dtype),
+                ))
+            # part 0 holds the block's first row: m_new is finite, and a
+            # part that saw nothing is scaled by exp(-inf) = 0
+            m_new = functools.reduce(jnp.maximum, [w[0] for w in weights])
             corr = jnp.exp(m_prev - m_new)
-            l_new = corr * l_prev + jnp.sum(p, axis=1, keepdims=True)
-            pv = jnp.dot(
-                p.astype(kb.dtype), kb[:, :value_width],
-                preferred_element_type=jnp.float32,
-            )
-            return m_new, l_new, acc * corr + pv
+            l_new, acc = corr * l_prev, acc * corr
+            for (m_k, l_k, p), kb in zip(weights, kbs):
+                scale = jnp.exp(m_k - m_new)
+                pv = jnp.dot(
+                    p, kb[:, :value_width],
+                    preferred_element_type=jnp.float32,
+                )
+                l_new, acc = l_new + l_k * scale, acc + pv * scale
+            return m_new, l_new, acc
 
         def finish(state):
             _, l, acc = state

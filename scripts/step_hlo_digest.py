@@ -10,8 +10,10 @@ configuration and Laguna's gated stack (its configuration file's
 sampler takes the branch it takes there: the step (K = 4, temperature 1.0,
 top-k 40), prefill and chunk programs are lowered from abstract values on
 the CPU (nothing is compiled or run, about 20 s) and the SHA-256 of each
-StableHLO text is printed. Run it on two checkouts and compare the lines:
-equal digests are the same program, byte for byte.
+StableHLO text is printed; then (PR 37) the loss and gradients of a GPT-2
+whose heads fill whole lane groups, so that training takes the packed
+flash kernels as ``gpt2-medium.train-1k`` does. Run it on two checkouts
+and compare the lines: equal digests are the same program, byte for byte.
 """
 
 from __future__ import annotations
@@ -34,6 +36,7 @@ from deeplearning4j_tpu.models.transformer import (  # noqa: E402
     _chunk_builder,
     _decode_builder,
     init_transformer,
+    transformer_loss,
 )
 from deeplearning4j_tpu.serving.engine import (  # noqa: E402
     build_chunk_program,
@@ -84,6 +87,16 @@ def programs(cfg) -> dict:
     }
 
 
+def train_program():
+    cfg = TransformerConfig(
+        vocab_size=50257, d_model=256, n_heads=4, n_layers=2, d_ff=512,
+        max_len=ROWS, use_flash=True, compute_dtype=jnp.bfloat16)
+    params = jax.eval_shape(
+        lambda key: init_transformer(key, cfg), jax.random.key(0))
+    return jax.jit(jax.value_and_grad(transformer_loss(cfg))).lower(
+        params, S((2, ROWS + 1), jnp.int32))
+
+
 def main() -> int:
     import deeplearning4j_tpu
 
@@ -94,6 +107,9 @@ def main() -> int:
             text = lowered.as_text()
             print(name, kind, len(text),
                   hashlib.sha256(text.encode()).hexdigest()[:16], flush=True)
+    text = train_program().as_text()
+    print("gpt2-packed train", len(text),
+          hashlib.sha256(text.encode()).hexdigest()[:16], flush=True)
     return 0
 
 

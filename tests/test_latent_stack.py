@@ -254,14 +254,14 @@ def test_the_absorbed_form_equals_the_expanded_form(cfg, params, layer):
 _T, _BLOCK, _H, _R, _W = 64, 16, 4, 16, 128
 
 
-def _kernel_case(seed, batch, dtype=jnp.float32):
+def _kernel_case(seed, batch, dtype=jnp.float32, heads=_H):
     rng = np.random.default_rng(seed)
     lanes = np.arange(_W) < 24  # 16 latent + 8 rotary values, then padding
 
     def draw(*shape):
         return jnp.asarray(rng.normal(size=shape) * lanes, dtype)
 
-    return (draw(batch, _H, _W), draw(2, 1, batch, _T, _W),
+    return (draw(batch, heads, _W), draw(2, 1, batch, _T, _W),
             draw(batch, 1, _W))
 
 
@@ -332,6 +332,122 @@ def test_latent_kernel_in_bf16_stays_near_the_dense_product():
         pos)
     np.testing.assert_allclose(
         np.asarray(out, np.float64), want, atol=0.03)
+
+
+# what the body must get right, with a block's rows cut in two parts each
+# under a maximum of its own (PR 37): (pos, active, write_at) of three
+# slots; ``None``: every slot live, the row at ``pos``
+WALKS = {
+    "ends_on_a_blocks_last_row": ([2 * _BLOCK - 1, 13, _T - 2], None, None),
+    "ends_on_a_blocks_first_row": ([2 * _BLOCK, 13, _T - 2], None, None),
+    "one_block_exactly": ([_BLOCK - 1] * 3, None, None),
+    "ends_on_a_parts_last_and_first_row": (
+        [_BLOCK // 2 - 1, _BLOCK // 2, 2 * _BLOCK + _BLOCK // 2], None, None),
+    "a_free_slot_between_two_live_ones": (
+        [5, 17, _T - 1], [True, False, True], None),
+    "the_row_lands_in_the_first_walked_block": (
+        [3 * _BLOCK + 2, 2 * _BLOCK, _T - 1], None, [3, 0, _BLOCK - 1]),
+    "the_row_lands_in_the_last_walked_block": (
+        [3 * _BLOCK + 5, 2 * _BLOCK, _T - 1], None,
+        [3 * _BLOCK, 2 * _BLOCK, _T - 1]),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bf16"])
+@pytest.mark.parametrize("heads", [4, 128])
+@pytest.mark.parametrize("walk", sorted(WALKS))
+def test_latent_kernel_walks_are_the_dense_product(walk, heads, dtype):
+    """Contexts that end on a part's or a block's edge, free slots and
+    rows placed in the first and the last walked block give the dense
+    absorbed product at the cell's 128 heads and at a few, in both
+    cache dtypes, and the cache is the scatter's, bit for bit."""
+    pos, active, write_at = WALKS[walk]
+    pos = jnp.asarray(pos, jnp.int32)
+    live = np.ones(3, bool) if active is None else np.asarray(active)
+    at = pos if write_at is None else jnp.asarray(write_at, jnp.int32)
+    q, cache, new = _kernel_case(sorted(WALKS).index(walk), 3, dtype, heads)
+    q = q * jnp.asarray(0.25, dtype)
+    out, written = latent_decode_attention_write(
+        q, cache, new, pos, _R, layer=1, write_at=at,
+        active=jnp.asarray(live), block_t=_BLOCK, interpret=True)
+    assert out.dtype == dtype and out.shape == (3, heads, _R)
+    want = cache.at[1, 0, jnp.arange(3)[live], at[live]].set(new[live, 0])
+    np.testing.assert_array_equal(
+        np.asarray(written, np.float32), np.asarray(want, np.float32))
+    dense = _dense_absorbed(
+        q.astype(jnp.float32), want[1, 0].astype(jnp.float32), pos)
+    out = np.asarray(out, np.float64)
+    assert np.all(out[~live] == 0.0)
+    np.testing.assert_allclose(
+        out[live], dense[live], atol=1e-5 if dtype == jnp.float32 else 0.03)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bf16"])
+@pytest.mark.parametrize("row", [5, _BLOCK - 3, 2 * _BLOCK + 3, _T - 1])
+def test_latent_kernel_holds_a_score_that_leaps_over_the_rest(row, dtype):
+    """One row whose score lies 200 over every other, more than
+    float32's exp holds, in the first part of a block, in the second, in
+    a later block and at the context's end: each part's weights are
+    taken against a maximum that holds the part's own scores, so the
+    answer is the dense product's, no inf, no nan."""
+    pos = jnp.asarray([_T - 1, _T - 1, row], jnp.int32)
+    q, cache, new = _kernel_case(200 + row, 3, dtype)
+    q = q * jnp.asarray(0.25, dtype)
+    q0 = np.asarray(q[:, 0], np.float64)
+    leap = 200.0 * q0 / np.sum(q0 * q0, axis=-1, keepdims=True)
+    cache = cache.at[0, 0, :, row].set(jnp.asarray(leap, dtype))
+    out, written = latent_decode_attention_write(
+        q, cache, None, pos, _R, layer=0, block_t=_BLOCK, interpret=True)
+    out = np.asarray(out, np.float64)
+    assert np.all(np.isfinite(out))
+    dense = _dense_absorbed(
+        q.astype(jnp.float32), written[0, 0].astype(jnp.float32), pos)
+    # head 0 sees the leaping row alone
+    tol = (dict(atol=1e-4) if dtype == jnp.float32
+           else dict(atol=0.06, rtol=2.0 ** -7))  # bf16 rounds the output
+    np.testing.assert_allclose(
+        out[:, 0], np.asarray(written[0, 0, :, row, :_R], np.float64), **tol)
+    np.testing.assert_allclose(out, dense, **tol)
+
+
+@pytest.mark.parametrize("pos", [3, _BLOCK // 2, _BLOCK + 2])
+def test_latent_kernel_short_context_with_scores_far_below_zero(pos):
+    """A part of a slot's first block that lies past the slot's position
+    sees no row. Head 0 scores every row -200: its weights are uniform,
+    whatever a part that saw nothing took for its reference."""
+    where = jnp.asarray([pos, pos, pos], jnp.int32)
+    q, cache, new = _kernel_case(300 + pos, 3)
+    q0 = np.asarray(q[:, 0], np.float64)
+    low = -200.0 * q0 / np.sum(q0 * q0, axis=-1, keepdims=True)
+    rows = cache[0, 0] * jnp.asarray(np.arange(_W) >= 8, jnp.float32)
+    q = q.at[:, 0, 8:].set(0.0)  # head 0 reads lanes 0-7 alone
+    cache = cache.at[0, 0].set(
+        rows + jnp.asarray(low * (np.arange(_W) < 8), jnp.float32)[:, None])
+    out, written = latent_decode_attention_write(
+        q, cache, None, where, _R, layer=0, block_t=_BLOCK, interpret=True)
+    got = np.asarray(out, np.float64)
+    want = _dense_absorbed(q, written[0, 0], where)
+    uniform = np.asarray(written[0, 0, :, :pos + 1, :_R], np.float64).mean(1)
+    np.testing.assert_allclose(want[:, 0], uniform, atol=1e-6)
+    np.testing.assert_allclose(got, want, atol=1e-5)
+
+
+@pytest.mark.parametrize("pos", [0, 5, _BLOCK // 2 - 1, _BLOCK + 3])
+def test_latent_kernel_never_reads_what_lies_past_the_position(pos):
+    """Rows past a slot's position are whatever its last tenant left:
+    values there so large that their scores overflow, in the part of the
+    block that is walked and in the part after it, reach no output (the
+    mask is a select on the scores, not a bias added to them)."""
+    where = jnp.asarray([pos, pos + 1, pos], jnp.int32)
+    q, cache, new = _kernel_case(400 + pos, 3)
+    stale = jnp.arange(_T)[None, :, None] > where[:, None, None]
+    cache = cache.at[1, 0].set(jnp.where(stale, 3e38, cache[1, 0]))
+    out, written = latent_decode_attention_write(
+        q, cache, None, where, _R, layer=1, block_t=_BLOCK, interpret=True)
+    np.testing.assert_allclose(
+        np.asarray(out), _dense_absorbed(q, written[1, 0], where), atol=1e-5)
 
 
 def test_latent_block_rows_is_the_walk_rule_for_the_same_bytes():

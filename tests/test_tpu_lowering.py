@@ -381,10 +381,17 @@ def test_latent_decode_lowers_with_the_cache_aliased():
 
 def test_latent_decode_compiles_in_place_for_a_v5e(one_chip):
     """Mosaic accepts the kernel at the cell's widths (128 query rows
-    against 512-row blocks of 640 lanes, the row's 8-row tile copied
-    back), and the donated 5.9 GB leaf goes through without a copy."""
+    against 512-row blocks of 640 lanes cut in two parts, the row's
+    8-row tile copied back), and the donated 5.9 GB leaf goes through
+    without a copy. It is compiled with the scoped VMEM limit set to
+    HALF a v5e's 16 MiB (Mosaic refuses a kernel whose stack asks for
+    more: "Scoped allocation with size ... exceeded scoped vmem limit";
+    PR 32 met that wall), so the three block buffers, both parts' score
+    tiles and weights and the accumulator together ask for under 8
+    MiB."""
     fn, avals = _latent_avals(one_chip)
-    compiled = jax.jit(fn, donate_argnums=(1,)).lower(*avals).compile()
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(*avals).compile(
+        compiler_options={"xla_tpu_scoped_vmem_limit_kib": "8192"})
     cache = avals[1]
     mem = compiled.memory_analysis()
     cache_bytes = 2 * math.prod(cache.shape)
