@@ -976,6 +976,45 @@ def build_block_copy_program():
     return copy
 
 
+_M32 = 0xFFFFFFFF
+_THREEFRY_ROTATIONS = ((13, 15, 26, 6), (17, 29, 16, 24))
+
+
+def _threefry2x32(k0: int, k1: int, x0: int, x1: int) -> tuple[int, int]:
+    """jax's threefry2x32 hash of one counter pair under key (k0, k1),
+    in Python integers: five groups of four rounds, the key schedule
+    injected after each group (``jax._src.prng._threefry2x32_lowering``
+    unrolled)."""
+    ks = (k0, k1, k0 ^ k1 ^ 0x1BD11BDA)
+    x0 = (x0 + k0) & _M32
+    x1 = (x1 + k1) & _M32
+    for i in range(5):
+        for r in _THREEFRY_ROTATIONS[i & 1]:
+            x0 = (x0 + x1) & _M32
+            x1 = (((x1 << r) | (x1 >> (32 - r))) & _M32) ^ x0
+        x0 = (x0 + ks[(i + 1) % 3]) & _M32
+        x1 = (x1 + ks[(i + 2) % 3] + i + 1) & _M32
+    return x0, x1
+
+
+def threefry_split_host(key) -> tuple[np.ndarray, np.ndarray]:
+    """``jax.random.split(key)`` of a threefry2x32 key's raw
+    ``uint32[2]`` data, on the host: (first, second) bit for bit as the
+    device gives them under the ``jax_threefry_partitionable`` rule in
+    effect. Partitionable (jax's default): key i hashes the counter
+    pair (0, i). Otherwise the counters are ``iota(4)`` hashed as the
+    pairs (0, 2) and (1, 3), and key i takes the i-th word of each."""
+    k0, k1 = int(key[0]), int(key[1])
+    if jax.config.jax_threefry_partitionable:
+        first = _threefry2x32(k0, k1, 0, 0)
+        second = _threefry2x32(k0, k1, 0, 1)
+    else:
+        a0, b0 = _threefry2x32(k0, k1, 0, 2)
+        a1, b1 = _threefry2x32(k0, k1, 1, 3)
+        first, second = (a0, a1), (b0, b1)
+    return np.array(first, np.uint32), np.array(second, np.uint32)
+
+
 class _SlotState:
     """Host-side record for one occupied slot."""
 
@@ -1496,19 +1535,28 @@ class ServingEngine:
         # attached opt-in SyncSanitizer (None in production: the hot
         # path pays one attribute-is-None check per phase)
         self._san = None
-        self._key = jax.random.key(rng_seed)
-        # per-slot sampling keys, split from the master key at
-        # admission (deterministic by admission order). The step
-        # program derives each sampled token's key as
+        master = jax.random.key(rng_seed)
+        impl = str(jax.random.key_impl(master))
+        if impl != "threefry2x32":
+            raise ValueError(
+                f"the engine splits its sampling keys on the host as "
+                f"threefry2x32 does; the default key impl is {impl!r}"
+            )
+        # the master key's raw uint32[2] data, held on the host: each
+        # admission splits it there (threefry_split_host, the bits
+        # jax.random.split gives), so admitting queues no device
+        # program and reads nothing back. Per-slot sampling keys are
+        # split from it at admission (deterministic by admission
+        # order). The step program derives each sampled token's key as
         # fold_in(slot_key, position) — a pure function of slot key and
         # position, independent of batch composition or horizon K, so
         # crash-recovery replay (teacher-force recorded tokens, re-seat
         # positions and keys) resumes the EXACT key stream an
         # uninterrupted run would have used. _slot_keys is the raw
         # uint32 key data, host-side; each _SlotState keeps its row.
-        _kd0 = np.asarray(jax.random.key_data(self._key))
+        self._key = np.asarray(jax.random.key_data(master))
         self._slot_keys = np.zeros(
-            (n_slots,) + _kd0.shape, _kd0.dtype
+            (n_slots,) + self._key.shape, self._key.dtype
         )
         # per-slot LoRA adapter indices, host-side mirror of
         # _slot_keys: written at admission, snapshotted (copied) per
@@ -3404,14 +3452,14 @@ class ServingEngine:
 
     # lint: hot-path
     def _split_slot_key(self, req_id: int) -> np.ndarray:
-        """Advance the master key chain by one admission and read the
-        new slot's key back. The split is a tiny device program queued
-        behind the horizon in flight, so the readback waits for up to a
-        whole step: the loop waiting on the device, booked as
-        ``key_sync`` and not as the phase it interrupts."""
-        self._key, sub = jax.random.split(self._key)
+        """Advance the master key chain by one admission and return the
+        new slot's key. The split is integer arithmetic on the host and
+        waits for nothing: no device program is queued behind the
+        horizon in flight. Booked as ``key_sync``, one region an
+        admission, and not as the phase it interrupts."""
         with self._regions("key_sync", req_id=req_id):
-            return np.asarray(jax.random.key_data(sub))  # lint: sync-ok per-admission key snapshot (tiny, off the decode critical section)
+            self._key, sub = threefry_split_host(self._key)  # lint: sync-ok host integer arithmetic into np.array, no device buffer, waits for nothing
+            return sub
 
     # lint: hot-path
     def _seat_plan(self, pl: _AdmitPlan, now: float) -> None:

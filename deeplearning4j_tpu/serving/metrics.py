@@ -55,8 +55,8 @@ partition of wall time).
 The engine LOOP has its own exact books, next to the requests':
 ``loop_seconds`` holds the self time of each phase of
 ``ServingEngine.step`` (``sweep``, ``admit``, ``prefill``, ``key_sync``,
-``dispatch``, ``sync``, ``process`` — see :data:`LOOP_PHASES`; in the
-two ``sync`` phases the thread waits for the device), fed by the same
+``dispatch``, ``sync``, ``process`` — see :data:`LOOP_PHASES`; in
+``sync`` the thread waits for the device), fed by the same
 regions that name the phases in a profiler capture;
 ``kv_rows_live`` / ``kv_rows_streamed`` count, per decode substep, the
 cache rows the occupied slots hold against the rows the step program
@@ -121,10 +121,9 @@ RESERVOIR_CAP = 4096
 #: the phases of one turn of the engine loop, in the order they run
 #: (``prefill`` and ``key_sync`` inside ``admit`` or ``dispatch``,
 #: ``sync`` inside ``process``); each holds its self time, so they add
-#: up. In ``sync`` (the designated readback) and ``key_sync`` (the
-#: readback of a new slot's sampling key, a tiny program queued behind
-#: the horizon in flight) the loop's thread waits for the device; the
-#: rest is the host's own work.
+#: up. In ``sync`` (the designated readback) the loop's thread waits
+#: for the device; the rest is the host's own work, ``key_sync`` (a new
+#: slot's sampling key, split on the host) included.
 LOOP_PHASES = ("sweep", "admit", "prefill", "key_sync", "dispatch", "sync",
                "process")
 
@@ -433,9 +432,9 @@ class ServingMetrics:
             "serve_loop_seconds_total",
             "Self seconds of each phase of the engine loop "
             "(sweep|admit|prefill|key_sync|dispatch|sync|process). In "
-            "sync (the horizon's readback) and key_sync (a new slot's "
-            "sampling key, read back behind the horizon in flight) the "
-            "loop waits for the device; the others are host work.",
+            "sync (the horizon's readback) the loop waits for the "
+            "device; the others, key_sync (a new slot's sampling key, "
+            "split on the host) included, are host work.",
             ("phase",),
         )
         self._c_kv_rows_live = reg.counter(

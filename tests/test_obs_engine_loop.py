@@ -181,23 +181,23 @@ def test_horizon_number_links_decode_span_to_its_dispatch():
     assert all(e["args"]["n"] in dispatched for e in firsts)
 
 
-def test_slot_key_readback_is_a_wait_of_its_own_inside_admit():
-    """The readback of a new slot's sampling key waits for the device
-    (the split queues behind the horizon in flight): one ``key_sync``
+def test_slot_key_split_is_a_region_of_its_own_inside_admit(monkeypatch):
+    """The host split of a new slot's sampling key: one ``key_sync``
     region per admission, inside ``admit``, whose self seconds it does
     not count towards."""
+    from deeplearning4j_tpu.serving import engine as engine_mod
+
     tracer = Tracer(enabled=True)
     engine = _engine(tracer=tracer)
     reqs = [_request(5, 6, seed=s) for s in range(3)]
     wait = 0.02
-    read_key = engine._split_slot_key
+    split = engine_mod.threefry_split_host
 
-    def slow_key(req_id):  # the wait, made long enough to tell apart
-        with engine._regions("key_sync", req_id=req_id):
-            time.sleep(wait)
-        return read_key(req_id)
+    def slow_split(key):  # the split, made long enough to tell apart
+        time.sleep(wait)
+        return split(key)
 
-    engine._split_slot_key = slow_key
+    monkeypatch.setattr(engine_mod, "threefry_split_host", slow_split)
     _serve(engine, reqs)
     loop = engine.metrics.loop_seconds
     assert loop["key_sync"] >= wait * len(reqs)
