@@ -31,6 +31,7 @@ from deeplearning4j_tpu.models.transformer import (
     init_lora_bank,
     init_transformer,
     make_paged_fwd1,
+    min_bucket,
     tp_collective_contract,
 )
 from deeplearning4j_tpu.parallel.mesh import model_parallel_mesh
@@ -136,7 +137,7 @@ class ServingGeometry:
         mb = 1
         while mb * 2 <= limit:
             mb *= 2
-        lo = min(8, mb)
+        lo = min(min_bucket(cfg), mb)
         return [b for b in _pow2_up_to(mb) if b >= lo]
 
     def horizons(self) -> list[int]:

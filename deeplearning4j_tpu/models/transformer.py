@@ -37,10 +37,12 @@ from deeplearning4j_tpu.parallel.expert_parallel import MoEParams, moe_ffn
 from deeplearning4j_tpu.parallel.sequence_parallel import ring_attention
 
 
-#: a layer's published type -> the cache leaf its kind shares
+#: a layer's published type -> the cache leaf its kind shares (Granite's
+#: "attention" is a full layer; its "mamba" keeps a state like a linear one)
 _KINDS = {
     "full_attention": "full", "sliding_attention": "window",
     "latent_attention": "latent", "linear_attention": "linear",
+    "attention": "full", "mamba": "linear",
 }
 
 #: the fields of linear_attention layers, under their published names
@@ -48,6 +50,18 @@ _LINEAR_FIELDS = (
     "linear_num_key_heads", "linear_num_value_heads", "linear_key_head_dim",
     "linear_value_head_dim", "linear_conv_kernel_dim",
 )
+
+#: the fields of mamba layers, under their published names
+_MAMBA_FIELDS = (
+    "mamba_n_heads", "mamba_d_head", "mamba_d_state", "mamba_n_groups",
+    "mamba_d_conv", "mamba_expand",
+)
+
+#: the gated stack's multipliers and their neutral values
+_MULTIPLIERS = {
+    "embedding_multiplier": 1.0, "residual_multiplier": 1.0,
+    "attention_multiplier": None, "logits_scaling": 1.0,
+}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -188,6 +202,34 @@ class TransformerConfig:
     linear_value_head_dim: int = 0
     linear_conv_kernel_dim: int = 0
     linear_allow_neg_eigval: bool = False
+    # -- Mamba-2 ("mamba" layers, SSD). ``[z | xBC | dt] = h W_in``; xBC
+    # (heads x d_head channels of x, then B and C, ``mamba_n_groups`` x
+    # d_state each) goes through SiLU of a causal depthwise convolution of
+    # width ``mamba_d_conv`` (with a bias under ``mamba_conv_bias``);
+    # dt = softplus(dt + dt_bias), a = exp(-exp(A_log) dt) a head. A head
+    # h keeps a float32 state S_h of d_state x d_head in the linear
+    # layers' leaf: S_h <- a_h S_h + B (dt_h x_h)^T (the additive rule,
+    # the group's B and C shared by its heads), y_h = S_h^T C + D_h x_h;
+    # then RMSNorm(y * SiLU(z)) over each group's channels and W_out.
+    # heads x d_head is mamba_expand x d_model.
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 0
+    mamba_d_state: int = 0
+    mamba_n_groups: int = 0
+    mamba_d_conv: int = 0
+    mamba_expand: int = 0
+    mamba_conv_bias: bool = False
+    # -- muP multipliers of the gated stack (Granite's): the embedding is
+    # scaled by ``embedding_multiplier``, each branch by
+    # ``residual_multiplier`` before it joins the residual stream, the
+    # softmax by ``attention_multiplier`` (None: head_dim^-1/2), and the
+    # logits are divided by ``logits_scaling``
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: float | None = None
+    logits_scaling: float = 1.0
+    # the head is the embedding transposed (no ``head`` leaf)
+    tie_word_embeddings: bool = False
 
     @property
     def head_dim(self) -> int:
@@ -218,20 +260,55 @@ class TransformerConfig:
 
     @property
     def linear(self) -> bool:
-        """A gated stack with linear_attention layers: a recurrent state
-        leaf (and a convolution window leaf) beside the K/V slab."""
-        return self.gated and "linear_attention" in self.layer_types
+        """A gated stack with linear_attention or mamba layers: a
+        recurrent state leaf (and a convolution window leaf) beside the
+        K/V slab."""
+        return self.gated and any(
+            _KINDS[t] == "linear" for t in self.layer_types)
+
+    @property
+    def mamba(self) -> bool:
+        """A gated stack whose state layers are Mamba-2's (the additive
+        rule), not the gated delta rule's."""
+        return self.gated and "mamba" in self.layer_types
+
+    @property
+    def state_heads(self) -> int:
+        """Value heads of a state layer: each keeps its own state."""
+        return self.mamba_n_heads if self.mamba else self.linear_num_value_heads
+
+    @property
+    def state_head_dim(self) -> int:
+        """Value channels of one head: a state's lanes a head."""
+        return self.mamba_d_head if self.mamba else self.linear_value_head_dim
+
+    @property
+    def state_rows(self) -> int:
+        """Key channels: the rows of a slot's state (d_state, key_head_dim)."""
+        return self.mamba_d_state if self.mamba else self.linear_key_head_dim
+
+    @property
+    def state_key_groups(self) -> int:
+        """Keys a position gives a state layer: each is shared by
+        ``state_heads / state_key_groups`` heads."""
+        return self.mamba_n_groups if self.mamba else self.linear_num_key_heads
+
+    @property
+    def conv_kernel(self) -> int:
+        """Width of a state layer's causal convolution."""
+        return self.mamba_d_conv if self.mamba else self.linear_conv_kernel_dim
 
     @property
     def linear_state_width(self) -> int:
         """Lanes of one state row: the heads' value channels side by side
-        (heads x value_head_dim); a slot's state is key_head_dim rows."""
-        return self.linear_num_value_heads * self.linear_value_head_dim
+        (heads x value channels); a slot's state is ``state_rows`` rows."""
+        return self.state_heads * self.state_head_dim
 
     @property
     def linear_conv_width(self) -> int:
-        """Channels of the causal convolution: q, k and v side by side."""
-        return (2 * self.linear_num_key_heads * self.linear_key_head_dim
+        """Channels of the causal convolution: q, k and v side by side
+        (mamba: x, B and C)."""
+        return (2 * self.state_key_groups * self.state_rows
                 + self.linear_state_width)
 
     def layers_of(self, kind: str) -> tuple:
@@ -289,12 +366,16 @@ class TransformerConfig:
             )
         if self.gated:
             self._check_gated()
-        elif self.post_norm or self.qk_norm or any(
-            getattr(self, name) for name in _LINEAR_FIELDS
-        ):
+        elif (self.post_norm or self.qk_norm or self.tie_word_embeddings
+              or self.mamba_conv_bias
+              or any(getattr(self, name)
+                     for name in _LINEAR_FIELDS + _MAMBA_FIELDS)
+              or any(getattr(self, name) != neutral
+                     for name, neutral in _MULTIPLIERS.items())):
             raise ValueError(
-                "post_norm, qk_norm and the linear_* fields belong to the "
-                "gated stack: set layer_types"
+                "post_norm, qk_norm, the linear_* and mamba_* fields, the "
+                "multipliers and tie_word_embeddings belong to the gated "
+                "stack: set layer_types"
             )
         if self.n_heads % self.kv_heads:
             raise ValueError(
@@ -342,7 +423,7 @@ class TransformerConfig:
                 "qk_rope_head_dim and v_head_dim belong to latent_attention "
                 "layers: layer_types names none"
             )
-        if self.linear:
+        if "linear_attention" in self.layer_types:
             self._check_linear()
         elif self.linear_allow_neg_eigval or any(
             getattr(self, name) for name in _LINEAR_FIELDS
@@ -351,6 +432,17 @@ class TransformerConfig:
                 f"{', '.join(_LINEAR_FIELDS)} and linear_allow_neg_eigval "
                 "belong to linear_attention layers: layer_types names none"
             )
+        if self.mamba:
+            self._check_mamba()
+        elif self.mamba_conv_bias or any(
+            getattr(self, name) for name in _MAMBA_FIELDS
+        ):
+            raise ValueError(
+                f"{', '.join(_MAMBA_FIELDS)} and mamba_conv_bias belong to "
+                "mamba layers: layer_types names none"
+            )
+        if self.linear:
+            self._check_state_stack()
         if "sliding_attention" in self.layer_types and (
             not self.sliding_window or self.sliding_window % 8
         ):
@@ -358,9 +450,13 @@ class TransformerConfig:
                 "sliding_attention layers need a sliding_window that is a "
                 "multiple of 8 (the ring leaf's rows)"
             )
-        if not self.rope:
+        if not self.rope and not set(self.layer_types) <= {
+                "full_attention", "attention", "linear_attention", "mamba"}:
             raise ValueError(
-                "the gated block has no learned positions: set rope=True"
+                "the gated block has no learned positions: rope=False (no "
+                "positions at all, NoPE) is built for full layers only; "
+                "set rope=True for sliding_attention and latent_attention "
+                "layers"
             )
         self._check_routed()
 
@@ -373,16 +469,44 @@ class TransformerConfig:
                 "linear_attention layers with linear_num_key_heads "
                 f"({self.linear_num_key_heads}) != linear_num_value_heads "
                 f"({self.linear_num_value_heads}) are not built: a head's "
-                "state pairs one key head with one value head"
+                "state pairs one key head with one value head (grouped "
+                "keys are built for mamba layers' additive rule, not for "
+                "the delta rule)"
             )
-        if "full_attention" not in self.layer_types:
+
+    def _check_mamba(self):
+        for name in _MAMBA_FIELDS:
+            if getattr(self, name) <= 0:
+                raise ValueError(f"mamba layers need {name} > 0")
+        if self.mamba_n_heads % self.mamba_n_groups:
             raise ValueError(
-                "linear_attention layers need a full_attention layer beside "
+                f"mamba_n_groups ({self.mamba_n_groups}) must divide "
+                f"mamba_n_heads ({self.mamba_n_heads}): a group's B and C "
+                "are shared by whole heads"
+            )
+        if self.mamba_n_heads * self.mamba_d_head != (
+                self.mamba_expand * self.d_model):
+            raise ValueError(
+                f"mamba_n_heads x mamba_d_head ({self.mamba_n_heads} x "
+                f"{self.mamba_d_head}) must be mamba_expand x d_model "
+                f"({self.mamba_expand} x {self.d_model})"
+            )
+        if "linear_attention" in self.layer_types:
+            raise ValueError(
+                "mamba layers share no stack with linear_attention ones: "
+                "both kinds keep their state in one leaf of one layout"
+            )
+
+    def _check_state_stack(self):
+        kind = "mamba" if self.mamba else "linear_attention"
+        if not self.layers_of("full"):
+            raise ValueError(
+                f"{kind} layers need a full_attention layer beside "
                 "them: the slot pool's rows are the full leaf's"
             )
         if "sliding_attention" in self.layer_types:
             raise ValueError(
-                "linear_attention layers share no stack with "
+                f"{kind} layers share no stack with "
                 "sliding_attention ones: not built (no test holds a ring "
                 "and a state leaf in one pool, and kv_cache_rows names "
                 "one of them)"
@@ -845,10 +969,10 @@ def kv_cache_rows(cfg: "TransformerConfig") -> str:
     slab and a ring of ``sliding_window`` rows), ``"latent"`` one plane
     of ``kv_lora_rank + qk_rope_head_dim`` values that is key and value
     of every head, ``"kv+state"`` a K and a V plane in the full layers'
-    slab while the linear_attention layers keep no rows at all: a
-    float32 state of key_head_dim x (heads x value_head_dim) a slot and
-    layer, and a ring of the convolution's last inputs, each a leaf of
-    its own. A fact
+    slab while the linear_attention (or mamba) layers keep no rows at
+    all: a float32 state of key_head_dim (d_state) x (heads x
+    value_head_dim) a slot and layer, and a ring of the convolution's
+    last inputs, each a leaf of its own. A fact
     of the configuration, like :func:`kv_row_write`; the engine reports
     it."""
     if cfg.latent:
@@ -1688,9 +1812,11 @@ class LinearAttend(NamedTuple):
     ``window(x)``: x (B, T, C), the convolution's inputs -> (B, K - 1 + T,
     C), the inputs of the K - 1 positions before x (zeros before position
     0) followed by x; the caller keeps the rows the next call needs.
-    ``mix(q, k, v, g, beta)``: q, k (B, T, H, dk), v (B, T, H, dv), g (the
+    ``mix(q, k, v, g, beta)``: q, k (B, T, G, dk), v (B, T, H, dv), g (the
     log of the decay) and beta (B, T, H), all float32 -> o (B, T, H, dv)
-    float32, through the caller's recurrent state."""
+    float32, through the caller's recurrent state. ``beta`` None is the
+    additive rule (mamba layers), whose G key groups each serve H / G
+    heads; the delta rule has G = H."""
 
     window: Callable
     mix: Callable
@@ -1731,8 +1857,63 @@ def _linear_attention(cfg: TransformerConfig, p, h, attend: LinearAttend):
     return (o * gate).astype(dt)
 
 
-def delta_rule_chunked(q, k, v, g, beta, state, chunk: int = _LINEAR_CHUNK):
-    """The gated delta rule over T positions in its chunkwise (WY) form:
+def _ssd_mixer(cfg: TransformerConfig, p, h, attend: LinearAttend):
+    """The mixing part of a mamba layer (Mamba-2, SSD) over ``h`` (B, T,
+    D): ``[z | xBC | dt] = h W_in``, SiLU of the causal convolution (plus
+    its bias) over the window ``attend.window`` gives for xBC, dt =
+    softplus(dt + dt_bias) and the decay's log -exp(A_log) dt a head,
+    ``attend.mix`` (the additive rule through the caller's state: C the
+    queries, B the keys, dt x the values), the skip D x, then the gated
+    RMSNorm over each group's channels. Returns (B, T, H, P) in h's
+    dtype, before the output projection."""
+    dt_, f32 = h.dtype, jnp.float32
+    b, t, _ = h.shape
+    heads, hd, n, groups = (cfg.mamba_n_heads, cfg.mamba_d_head,
+                            cfg.mamba_d_state, cfg.mamba_n_groups)
+    inner = heads * hd
+    zxbcdt = h @ _w(p, "w_in", dt_)
+    z = zxbcdt[..., :inner]
+    win = attend.window(zxbcdt[..., inner:-heads]).astype(f32)
+    w = p["conv_w"].astype(f32)  # (K, C): w[K - 1] weighs the position
+    c = sum(win[:, i:i + t] * w[i] for i in range(w.shape[0]))
+    if cfg.mamba_conv_bias:
+        c = c + p["conv_b"].astype(f32)
+    c = jax.nn.silu(c)
+    x = c[..., :inner].reshape(b, t, heads, hd)
+    keys = c[..., inner:inner + groups * n].reshape(b, t, groups, n)
+    queries = c[..., inner + groups * n:].reshape(b, t, groups, n)
+    dt = jax.nn.softplus(
+        zxbcdt[..., -heads:].astype(f32) + p["dt_bias"].astype(f32))
+    g = -jnp.exp(p["A_log"].astype(f32)) * dt
+    y = attend.mix(queries, keys, x * dt[..., None], g, None)
+    y = y + x * p["D"].astype(f32)[:, None]
+    y = y.reshape(b, t, groups, inner // groups) * jax.nn.silu(
+        z.astype(f32)).reshape(b, t, groups, inner // groups)
+    y = y * lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True) + cfg.norm_eps)
+    y = y.reshape(b, t, inner) * p["ssm_norm_scale"].astype(f32)
+    return y.reshape(b, t, heads, hd).astype(dt_)
+
+
+#: positions a chunk of the additive rule's chunked form holds (mamba
+#: layers; Mamba-2's published chunk)
+_SSD_CHUNK = 256
+
+
+def min_bucket(cfg: TransformerConfig) -> int:
+    """The smallest prompt bucket a server of ``cfg`` pads to (before
+    the largest bucket clips it): 8 rows, a sublane tile; with state
+    layers, one chunk of their chunked form (the delta rule's 64,
+    Mamba-2's 256), the unit the scan works in. Every bucket is one more
+    prefill and one more chunk program of the whole stack to compile."""
+    if not cfg.linear:
+        return 8
+    return _SSD_CHUNK if cfg.mamba else _LINEAR_CHUNK
+
+
+def state_rule_chunked(q, k, v, g, beta, state, chunk: int = _LINEAR_CHUNK,
+                       rule: str = "delta"):
+    """A state layer's rule over T positions in its chunkwise form.
+    ``rule="delta"``, the gated delta rule (WY form):
     q, k (B, T, H, dk), v (B, T, H, dv), g and beta (B, T, H), state (B,
     H, dk, dv), all float32. Per position, ``S <- exp(g) S``, ``u = beta
     (v - S^T k)``, ``S <- S + k u^T``, ``o = S^T q``. A position with g
@@ -1743,24 +1924,57 @@ def delta_rule_chunked(q, k, v, g, beta, state, chunk: int = _LINEAR_CHUNK):
     ``A_ij = beta_i exp(G_i - G_j) k_i . k_j`` (j < i), one triangular
     solve; then ``O = exp(G) Q S0 + (exp(G_i - G_j) q_i . k_j)_{j<=i} U``
     and ``S = exp(G_L) S0 + K^T (exp(G_L - G) U)``. A ``lax.scan``
-    carries S over the chunks. Returns (o (B, T, H, dv), state)."""
-    b, t, h, dk = q.shape
-    dv = v.shape[-1]
+    carries S over the chunks. Returns (o (B, T, H, dv), state).
+
+    ``rule="additive"`` is Mamba-2's (SSD): ``S <- exp(g) S + k v^T``,
+    so ``U = V`` and there is no solve; ``beta`` is None, q and k may
+    have G key groups (B, T, G, dk), each shared by H / G heads, and a
+    position with g = 0 and v = 0 is padding. The same words select
+    :func:`linear_decode`'s kernel body."""
+    if rule not in ("delta", "additive") or (beta is None) != (
+            rule == "additive"):
+        raise ValueError(
+            f"rule {rule!r}: 'delta' takes a beta, 'additive' none")
+    delta = rule == "delta"
+    b, t, gk, dk = q.shape
+    h, dv = v.shape[2:]
     n = -(-t // chunk)
     pad = n * chunk - t
+    inputs = (q, k, v, g, beta) if delta else (q, k, v, g)
     if pad:
         def padded(x):
             return jnp.pad(x, [(0, 0), (0, pad)] + [(0, 0)] * (x.ndim - 2))
-        q, k, v, g, beta = map(padded, (q, k, v, g, beta))
+        inputs = tuple(map(padded, inputs))
 
-    def chunks(x):  # (B, n*L, H, ...) -> (n, B, H, L, ...)
-        x = x.reshape((b, n, chunk, h) + x.shape[3:])
+    def chunks(x):  # (B, n*L, heads, ...) -> (n, B, heads, L, ...)
+        x = x.reshape((b, n, chunk) + x.shape[2:])
         return jnp.moveaxis(jnp.moveaxis(x, 1, 0), 3, 2)
 
     hi = lax.Precision.HIGHEST
     i = jnp.arange(chunk)
     upto = i[:, None] >= i[None, :]
     before = i[:, None] > i[None, :]
+    r = h // gk  # heads a key group serves
+
+    def additive(s, xs):
+        qc, kc, vc, gc = xs  # (B, G, L, dk) x 2, (B, H, L, dv), (B, H, L)
+        gs = jnp.cumsum(gc, axis=-1)
+        diff = gs[..., :, None] - gs[..., None, :]
+        qk = jnp.einsum("bgid,bgjd->bgij", qc, kc, precision=hi)
+        decay = jnp.exp(jnp.where(upto, diff, -jnp.inf)).reshape(
+            b, gk, r, chunk, chunk)
+        vg = vc.reshape(b, gk, r, chunk, dv)
+        o = (jnp.exp(gs).reshape(b, gk, r, chunk)[..., None]
+             * jnp.einsum("bgld,bgrde->bgrle", qc,
+                          s.reshape(b, gk, r, dk, dv), precision=hi)
+             + jnp.einsum("bgrij,bgrje->bgrie", qk[:, :, None] * decay, vg,
+                          precision=hi))
+        last = gs[..., -1:]
+        s = jnp.exp(last)[..., None] * s + jnp.einsum(
+            "bgld,bgrle->bgrde", kc,
+            jnp.exp(last - gs).reshape(b, gk, r, chunk)[..., None] * vg,
+            precision=hi).reshape(b, h, dk, dv)
+        return s, o.reshape(b, h, chunk, dv)
 
     def one(s, xs):
         qc, kc, vc, gc, bc = xs  # (B, H, L, d) / (B, H, L)
@@ -1786,7 +2000,8 @@ def delta_rule_chunked(q, k, v, g, beta, state, chunk: int = _LINEAR_CHUNK):
             precision=hi)
         return s, o
 
-    state, o = lax.scan(one, state, tuple(map(chunks, (q, k, v, g, beta))))
+    state, o = lax.scan(one if delta else additive, state,
+                        tuple(map(chunks, inputs)))
     o = jnp.moveaxis(o, 0, 1).transpose(0, 1, 3, 2, 4)  # (B, n, L, H, dv)
     return o.reshape(b, n * chunk, h, dv)[:, :t], state
 
@@ -1794,8 +2009,8 @@ def delta_rule_chunked(q, k, v, g, beta, state, chunk: int = _LINEAR_CHUNK):
 def _state_heads(cfg: TransformerConfig, s):
     """(B, dk, H*dv), the leaf's lanes -> (B, H, dk, dv)."""
     b, dk, _ = s.shape
-    return s.reshape(b, dk, cfg.linear_num_value_heads,
-                     cfg.linear_value_head_dim).transpose(0, 2, 1, 3)
+    return s.reshape(b, dk, cfg.state_heads,
+                     cfg.state_head_dim).transpose(0, 2, 1, 3)
 
 
 def _state_lanes(s):
@@ -1831,7 +2046,9 @@ def _gated_block(cfg: TransformerConfig, l: int, p, x, positions, attend,
     dt = x.dtype
     kind = _KINDS[cfg.layer_types[l]]
     h = x if cfg.post_norm else _rms_norm(x, p["ln1_scale"], cfg.norm_eps)
-    if kind == "linear":
+    if kind == "linear" and cfg.mamba:
+        o = _ssd_mixer(cfg, p, h, attend)
+    elif kind == "linear":
         o = _linear_attention(cfg, p, h, attend)
     elif kind == "latent":
         n, r = cfg.qk_nope_head_dim, cfg.kv_lora_rank
@@ -1867,10 +2084,23 @@ def _gated_block(cfg: TransformerConfig, l: int, p, x, positions, attend,
 
         q = jnp.einsum("btd,dhk->bthk", h, _w(p, "wq", dt))
         kv = jnp.einsum("btd,dshk->sbthk", h, _w(p, "wkv", dt))
-        cos, sin = _gated_rope(cfg, kind, positions, dt)
-        cos, sin = cos[..., None, :], sin[..., None, :]  # over the head axis
-        q = _rotate(qk_normed(q, "q_norm_scale"), cos, sin)
-        k = _rotate(qk_normed(kv[0], "k_norm_scale"), cos, sin)
+        if cfg.rope:
+            cos, sin = _gated_rope(cfg, kind, positions, dt)
+            cos, sin = cos[..., None, :], sin[..., None, :]  # over heads
+
+            def rotated(z):
+                return _rotate(z, cos, sin)
+        else:  # NoPE: order comes from the causal mask alone
+            def rotated(z):
+                return z
+        q = rotated(qk_normed(q, "q_norm_scale"))
+        k = rotated(qk_normed(kv[0], "k_norm_scale"))
+        if cfg.attention_multiplier is not None:
+            # every attention path scales by head_dim^-1/2: the rest of
+            # the published scale goes into q (0.015625 x 8 = 2^-3 at
+            # Granite's, exact in any float type)
+            q = q * jnp.asarray(
+                cfg.attention_multiplier * np.sqrt(q.shape[-1]), dt)
         o = attend(q, k, kv[1])
     if cfg.attn_gate:
         gate = jax.nn.sigmoid(
@@ -1880,7 +2110,7 @@ def _gated_block(cfg: TransformerConfig, l: int, p, x, positions, attend,
     a = jnp.einsum("bthk,hkd->btd", o, _w(p, "wo", dt))
     if cfg.sandwich_norm or cfg.post_norm:
         a = _rms_norm(a, p["ln1_post_scale"], cfg.norm_eps)
-    x = x + a
+    x = x + _residual(cfg, a)
     h2 = x if cfg.post_norm else _rms_norm(x, p["ln2_scale"], cfg.norm_eps)
     counts = None
     if l in cfg.dense_layers:
@@ -1900,8 +2130,16 @@ def _gated_block(cfg: TransformerConfig, l: int, p, x, positions, attend,
                            _w(p, "ws_down", dt))
     if cfg.sandwich_norm or cfg.post_norm:
         y = _rms_norm(y, p["ln2_post_scale"], cfg.norm_eps)
-    x = x + y
+    x = x + _residual(cfg, y)
     return x, jnp.zeros((3,), jnp.int32) if counts is None else counts
+
+
+def _residual(cfg: TransformerConfig, branch):
+    """A branch as it joins the residual stream: times
+    ``residual_multiplier`` (no operation at 1)."""
+    if cfg.residual_multiplier == 1.0:
+        return branch
+    return branch * jnp.asarray(cfg.residual_multiplier, branch.dtype)
 
 
 def _init_gated(key, cfg: TransformerConfig):
@@ -1932,6 +2170,8 @@ def _init_gated(key, cfg: TransformerConfig):
             p["ln2_post_scale"] = jnp.ones((d,))
         if cfg.layer_types[l] == "linear_attention":
             p.update(_init_linear(ks[0:4], cfg))
+        elif cfg.layer_types[l] == "mamba":
+            p.update(_init_ssd(ks[0:4], cfg))
         elif cfg.latent:
             # W_kvb is held as its two halves: the key part W_uk and the
             # value part W_uv are separate operands of the absorbed form
@@ -1968,13 +2208,15 @@ def _init_gated(key, cfg: TransformerConfig):
             if cfg.d_shared:
                 p.update(mlp(ks[8:11], "ws", (), cfg.d_shared))
         layers.append(p)
-    return {
+    out = {
         "embed": jax.random.normal(
             k_embed, (cfg.vocab_size, d), jnp.float32) * 0.02,
         "layers": layers,
         "lnf_scale": jnp.ones((d,)),
-        "head": norm(k_head, (d, cfg.vocab_size), d),
     }
+    if not cfg.tie_word_embeddings:
+        out["head"] = norm(k_head, (d, cfg.vocab_size), d)
+    return out
 
 
 def _init_linear(keys, cfg: TransformerConfig) -> dict:
@@ -2008,6 +2250,40 @@ def _init_linear(keys, cfg: TransformerConfig) -> dict:
     }
 
 
+def _init_ssd(keys, cfg: TransformerConfig) -> dict:
+    """A mamba layer's mixing weights, as mamba_ssm initialises them: A
+    uniform in [1, 16] held as its log, dt log-uniform in [1e-3, 0.1]
+    held as softplus^-1(dt), D = 1; the convolution's taps normal /
+    sqrt(width) and its bias uniform in +-1/sqrt(width) (a depthwise
+    ``Conv1d``'s default); ``w_in`` is [z | xBC | dt] side by side and
+    ``wo`` is W_out by heads."""
+    d = cfg.d_model
+    heads, hd = cfg.mamba_n_heads, cfg.mamba_d_head
+    c, width = cfg.linear_conv_width, cfg.mamba_d_conv
+    ks = jax.random.split(keys[3], 4)
+
+    def norm(key, shape, fan_in):
+        return jax.random.normal(key, shape, jnp.float32) / np.sqrt(fan_in)
+
+    a = jax.random.uniform(ks[0], (heads,), jnp.float32, 1.0, 16.0)
+    dt = jnp.exp(jax.random.uniform(
+        ks[1], (heads,), jnp.float32, np.log(1e-3), np.log(0.1)))
+    p = {
+        "w_in": norm(keys[0], (d, heads * hd + c + heads), d),
+        "conv_w": norm(keys[1], (width, c), width),
+        "A_log": jnp.log(a),
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+        "D": jnp.ones((heads,)),
+        "ssm_norm_scale": jnp.ones((heads * hd,)),
+        "wo": norm(keys[2], (heads, hd, d), heads * hd),
+    }
+    if cfg.mamba_conv_bias:
+        bound = 1.0 / np.sqrt(width)
+        p["conv_b"] = jax.random.uniform(ks[2], (c,), jnp.float32,
+                                         -bound, bound)
+    return p
+
+
 def _gated_refuses(cfg: TransformerConfig, what: str, lacks: str):
     if cfg.gated:
         raise NotImplementedError(
@@ -2035,7 +2311,7 @@ def _gated_builder(cfg: TransformerConfig):
         for i, l in enumerate(cfg.layers_of(kind))
     }
     n_linear = len(cfg.layers_of("linear"))
-    conv_ring = max(8, -(-(cfg.linear_conv_kernel_dim - 1) // 8) * 8)
+    conv_ring = max(8, -(-(cfg.conv_kernel - 1) // 8) * 8)
 
     def init_caches(batch: int, total: int):
         tpad = _decode_tpad(total)
@@ -2057,7 +2333,7 @@ def _gated_builder(cfg: TransformerConfig):
             # (position p at row p % conv_ring; whole (8, 128) tiles, so
             # a step writes one row where a shift rewrote the window)
             out["state"] = jnp.zeros(
-                (n_linear, 1, batch, cfg.linear_key_head_dim,
+                (n_linear, 1, batch, cfg.state_rows,
                  cfg.linear_state_width), jnp.float32)
             out["conv"] = jnp.zeros(
                 (n_linear, 1, batch, conv_ring, cfg.linear_conv_width),
@@ -2067,15 +2343,21 @@ def _gated_builder(cfg: TransformerConfig):
     def cast_params(params):
         """Streamed weights to the compute dtype, once; the router
         stays float32 (its product is float32, see ``route_top_k``), and
-        so do a linear layer's decay constants ``A_log`` and
-        ``dt_bias``."""
+        so do a linear or mamba layer's decay constants ``A_log`` and
+        ``dt_bias`` and a mamba layer's skip ``D``."""
         def cast(path, a):
             name = getattr(path[-1], "key", None)
-            if name in ("router", "A_log", "dt_bias") or not jnp.issubdtype(
-                    a.dtype, jnp.floating):
+            if name in ("router", "A_log", "dt_bias", "D") or not (
+                    jnp.issubdtype(a.dtype, jnp.floating)):
                 return a
             return a.astype(cfg.compute_dtype)
         return jax.tree_util.tree_map_with_path(cast, params)
+
+    def embed_of(params, toks):
+        x = params["embed"][toks].astype(cfg.compute_dtype)
+        if cfg.embedding_multiplier == 1.0:
+            return x
+        return x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
 
     def run_layers(params, x, positions, attend_of, live=None):
         counts = jnp.zeros((3,), jnp.int32)
@@ -2089,10 +2371,18 @@ def _gated_builder(cfg: TransformerConfig):
 
     def logits_of(params, x_last):
         x_last = _rms_norm(x_last, params["lnf_scale"], cfg.norm_eps)
-        return jnp.einsum(
-            "...d,dv->...v", x_last, _w(params, "head", x_last.dtype),
-            preferred_element_type=jnp.float32,
-        )  # bf16 operands, f32 accumulation: see _decode_builder
+        if cfg.tie_word_embeddings:  # the embedding, read transposed
+            logits = jnp.einsum(
+                "...d,vd->...v", x_last, _w(params, "embed", x_last.dtype),
+                preferred_element_type=jnp.float32)
+        else:
+            logits = jnp.einsum(
+                "...d,dv->...v", x_last, _w(params, "head", x_last.dtype),
+                preferred_element_type=jnp.float32,
+            )  # bf16 operands, f32 accumulation: see _decode_builder
+        if cfg.logits_scaling != 1.0:
+            logits = logits / cfg.logits_scaling
+        return logits
 
     def row_at(x, last_idx):  # (B, T, D) -> (B, D): the named row
         if last_idx is None:
@@ -2119,15 +2409,28 @@ def _gated_builder(cfg: TransformerConfig):
         1)): the K - 1 inputs before ``first`` from the slot's ring
         (zeros before position 0), then x. The inputs at positions up to
         ``last`` (B or 1, 1; a bucket's padding lies past it) go to the
-        ring, in the rows of a ``live`` (B,) slot only."""
+        ring, in the rows of a ``live`` (B,) slot only.
+
+        Where the call's rows and the K - 1 before them fit the ring
+        together (decode), the rows are written first and the window read
+        from the written leaf: the read then depends on the write, so the
+        leaf is updated in place through every layer. Read first, XLA
+        copies the whole leaf to keep the read's rows (a copy of the
+        leaf every other layer in a step)."""
         leaf = caches["conv"]
         b, c = x.shape[:2]
         before = jnp.broadcast_to(
-            first + jnp.arange(1 - cfg.linear_conv_kernel_dim, 0),
-            (b, cfg.linear_conv_kernel_dim - 1))
-        prev = jnp.take_along_axis(
-            leaf[idx, 0], (before % conv_ring)[..., None], axis=1)
-        prev = jnp.where((before >= 0)[..., None], prev, 0.0)
+            first + jnp.arange(1 - cfg.conv_kernel, 0),
+            (b, cfg.conv_kernel - 1))
+
+        def rows_before(leaf):
+            prev = jnp.take_along_axis(
+                leaf[idx, 0], (before % conv_ring)[..., None], axis=1)
+            return jnp.where((before >= 0)[..., None], prev, 0.0)
+
+        write_first = c + cfg.conv_kernel - 1 <= conv_ring
+        if not write_first:
+            prev = rows_before(leaf)
         positions = jnp.broadcast_to(first + jnp.arange(c), (b, c))
         keep = (positions <= last) & (positions > last - conv_ring)
         if live is not None:
@@ -2136,25 +2439,36 @@ def _gated_builder(cfg: TransformerConfig):
             idx, 0, jnp.arange(b)[:, None],
             jnp.where(keep, positions % conv_ring, conv_ring),
         ].set(x.astype(leaf.dtype), mode="drop")  # conv_ring: dropped
+        if write_first:
+            prev = rows_before(caches["conv"])
         return jnp.concatenate([prev.astype(x.dtype), x], axis=1)
 
     def linear_chunk_attend(caches, idx, first, last):
         """A linear layer's ``attend`` for C consecutive rows from
         position ``first`` that carry on from the slot's state (a
         prefill's from zeros) in the chunked form. ``last`` (B or 1, 1):
-        the position of the call's last real row. Past it g and beta are
-        0, so the state left is the one at that row, and the ring keeps
-        the inputs up to it: a bucket's padding reaches neither."""
+        the position of the call's last real row. Past it g and beta (the
+        additive rule's: g and v) are 0, so the state left is the one at
+        that row, and the ring keeps the inputs up to it: a bucket's
+        padding reaches neither."""
 
         def mix(q, k, v, g, beta):
             real = (first + jnp.arange(q.shape[1]) <= last)[..., None]
             leaf = caches["state"]
-            with jax.named_scope("linear_chunk_scan"):
-                o, s = delta_rule_chunked(
-                    q, k, v, jnp.where(real, g, 0.0),
-                    jnp.where(real, beta, 0.0),
-                    _state_heads(cfg, leaf[idx, 0]),
-                )
+            if beta is None:  # mamba: the additive rule
+                with jax.named_scope("ssd_chunk_scan"):
+                    o, s = state_rule_chunked(
+                        q, k, jnp.where(real[..., None], v, 0.0),
+                        jnp.where(real, g, 0.0), None,
+                        _state_heads(cfg, leaf[idx, 0]),
+                        chunk=min(_SSD_CHUNK, q.shape[1]), rule="additive")
+            else:
+                with jax.named_scope("linear_chunk_scan"):
+                    o, s = state_rule_chunked(
+                        q, k, v, jnp.where(real, g, 0.0),
+                        jnp.where(real, beta, 0.0),
+                        _state_heads(cfg, leaf[idx, 0]),
+                    )
             caches["state"] = leaf.at[idx, 0].set(_state_lanes(s))
             return o
 
@@ -2164,9 +2478,9 @@ def _gated_builder(cfg: TransformerConfig):
     def linear_step_attend(caches, idx, pos, active):
         """A linear layer's ``attend`` for one row a slot at ``pos``: the
         input goes to the ring and the state advances one step, through
-        ``linear_decode`` (the kernel updates the leaf in place) or its
-        jnp twin; a row that is not ``active`` keeps both as they
-        were."""
+        ``linear_decode`` (the kernel updates the leaf in place; under
+        the additive rule it is ``ssd_decode``) or its jnp twin; a row
+        that is not ``active`` keeps both as they were."""
         from deeplearning4j_tpu.ops.pallas_kernels import (
             linear_decode,
             linear_decode_dense,
@@ -2179,7 +2493,9 @@ def _gated_builder(cfg: TransformerConfig):
             o, caches["state"] = step(
                 q[:, 0].transpose(0, 2, 1), k[:, 0].transpose(0, 2, 1),
                 v[:, 0].reshape(v.shape[0], -1), jnp.exp(g[:, 0]),
-                beta[:, 0], caches["state"], layer=idx, active=active,
+                None if beta is None else beta[:, 0], caches["state"],
+                layer=idx, active=active,
+                rule="additive" if beta is None else "delta",
             )
             return o.reshape(v.shape)
 
@@ -2313,7 +2629,7 @@ def _gated_builder(cfg: TransformerConfig):
         whose state advanced (int32 (1,))."""
         del adapter  # no LoRA bank: the engine refuses one
         caches = dict(caches)
-        x = params["embed"][token].astype(cfg.compute_dtype)[:, None, :]
+        x = embed_of(params, token)[:, None, :]
         positions = jnp.reshape(pos, (-1, 1))  # (B or 1, 1)
 
         def attend_of(l):
@@ -2428,7 +2744,7 @@ def _gated_builder(cfg: TransformerConfig):
 
             return attend
 
-        x = params["embed"][prompt].astype(cfg.compute_dtype)
+        x = embed_of(params, prompt)
         x, _ = run_layers(params, x, positions, attend_of)
         return caches, logits_of(params, row_at(x, last_idx))
 
@@ -2446,7 +2762,7 @@ def _gated_builder(cfg: TransformerConfig):
         last = positions[..., :1] + jnp.reshape(
             c - 1 if last_idx is None else last_idx, (-1, 1)
         )
-        x = params["embed"][toks].astype(cfg.compute_dtype)
+        x = embed_of(params, toks)
         x, _ = run_layers(
             params, x, positions,
             lambda l: chunk_attend(caches, l, positions, last),

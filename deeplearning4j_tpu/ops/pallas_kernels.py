@@ -1751,7 +1751,9 @@ def _latent_decode_attention(q, cache, row_new, pos, active, layer, write_at,
     return (out, cache) if row_new is None else out
 
 
-# -- the gated delta rule's decode step (linear_attention layers) -------------
+# -- the decode step of a state layer: the gated delta rule (linear_attention
+# layers, ``linear_decode``) and the additive rule (mamba layers,
+# ``ssd_decode``) ---------------------------------------------------------------
 #
 # A slot's state of one layer is key_head_dim rows (sublanes) of heads x
 # value_head_dim lanes, float32, in the stacked (layers, 1, slots, dk,
@@ -1759,7 +1761,9 @@ def _latent_decode_attention(q, cache, row_new, pos, active, layer, write_at,
 # block comes in, is advanced one position and goes back to the same HBM
 # buffer (input_output_aliases). Inside, heads go in the fewest that fill
 # whole 128-lane tiles (two of 192), and a head's key and query, which
-# run down the sublanes, are spread over its lanes by selects.
+# run down the sublanes, are spread over its lanes by selects. Under the
+# additive rule a key and a query serve a whole group of heads (Mamba-2's
+# B and C): with one group they are one column broadcast over every lane.
 
 
 def _lane_groups(heads: int, value_dim: int) -> list[int]:
@@ -1796,6 +1800,19 @@ def _spread(x, head_of):
     return out
 
 
+def _copy_or_skip(job, s_ref, o_ref, s_out):
+    """An idle slot's grid step (``job`` 0 or 2, see
+    :func:`_linear_decode_kernel`): a zero output, and the block copied
+    through where it is not a live slot's."""
+    @pl.when(job == 2)
+    def _():
+        s_out[...] = s_ref[...]
+
+    @pl.when(job != 1)
+    def _():
+        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+
+
 def _linear_decode_kernel(layer_ref, src_ref, job_ref, q_ref, k_ref, v_ref,
                           a_ref, b_ref, s_ref, o_ref, s_out, *,
                           value_dim: int, group: int):
@@ -1807,14 +1824,7 @@ def _linear_decode_kernel(layer_ref, src_ref, job_ref, q_ref, k_ref, v_ref,
     none is live), so copy it through unchanged."""
     del layer_ref, src_ref
     job = job_ref[pl.program_id(0)]
-
-    @pl.when(job == 2)
-    def _():
-        s_out[...] = s_ref[...]
-
-    @pl.when(job != 1)
-    def _():
-        o_ref[...] = jnp.zeros(o_ref.shape, o_ref.dtype)
+    _copy_or_skip(job, s_ref, o_ref, s_out)
 
     @pl.when(job == 1)
     def _():
@@ -1836,33 +1846,81 @@ def _linear_decode_kernel(layer_ref, src_ref, job_ref, q_ref, k_ref, v_ref,
                 s * _spread(q[:, cols], head_of), axis=0, keepdims=True)
 
 
+def _ssd_decode_kernel(layer_ref, src_ref, job_ref, q_ref, k_ref, v_ref,
+                       a_ref, s_ref, o_ref, s_out, *, value_dim: int,
+                       group: int, heads_per_step: int, heads_per_key: int):
+    """:func:`_linear_decode_kernel`'s grid step under the additive rule:
+    ``S <- a S + k v^T``, ``o = S^T q``, where ``q``, ``k`` (dk, G) are
+    the key groups' columns (all of them in every step) and a lane's
+    group is its head over ``heads_per_key``. About five operations a
+    state value: the decay, the outer product and its add, the product
+    with q and its sum."""
+    del layer_ref, src_ref
+    job = job_ref[pl.program_id(0)]
+    first_head = pl.program_id(1) * heads_per_step
+    _copy_or_skip(job, s_ref, o_ref, s_out)
+
+    @pl.when(job == 1)
+    def _():
+        dk = s_ref.shape[0]
+        lanes = group * value_dim
+        head_of = jax.lax.broadcasted_iota(jnp.int32, (dk, lanes), 1) // value_dim
+        q, k = q_ref[...], k_ref[...]  # (dk, key groups)
+        a = a_ref[...]  # (1, heads of the block)
+        for c in range(s_ref.shape[1] // lanes):
+            cols = slice(c * group, (c + 1) * group)
+            lane = slice(c * lanes, (c + 1) * lanes)
+            key_of = (first_head + c * group + head_of) // heads_per_key
+            s = (_spread(a[:, cols], head_of[:1]) * s_ref[:, lane]
+                 + _spread(k, key_of) * v_ref[:, lane])
+            s_out[:, lane] = s
+            o_ref[:, lane] = jnp.sum(s * _spread(q, key_of), axis=0,
+                                     keepdims=True)
+
+
+#: the Pallas call's name under each rule: what the device trace shows
+_STATE_KERNEL = {"delta": "linear_decode", "additive": "ssd_decode"}
+
+
 def linear_decode(
     q: jax.Array,
     k: jax.Array,
     v: jax.Array,
     alpha: jax.Array,
-    beta: jax.Array,
+    beta: jax.Array | None,
     state: jax.Array,
     layer: int = 0,
     active: jax.Array | None = None,
     heads_per_step: int | None = None,
     interpret: bool | None = None,
+    rule: str = "delta",
 ) -> tuple[jax.Array, jax.Array]:
-    """One decode step of the gated delta rule for every slot, in place.
+    """One decode step of a state layer for every slot, in place.
 
-    ``q``, ``k`` (B, dk, H): a head's query (scaled) and key, L2-normed,
-    down the sublanes; ``v`` (B, H*dv); ``alpha`` (the decay) and
-    ``beta`` (B, H); all float32. ``state``: the stacked (layers, 1, B,
-    dk, H*dv) float32 leaf. For each head j of slot b at layer
+    ``rule="delta"`` (the gated delta rule, ``%linear_decode`` in a
+    trace): ``q``, ``k`` (B, dk, H): a head's query (scaled) and key,
+    L2-normed, down the sublanes; ``v`` (B, H*dv); ``alpha`` (the decay)
+    and ``beta`` (B, H); all float32. ``state``: the stacked (layers, 1,
+    B, dk, H*dv) float32 leaf. For each head j of slot b at layer
     ``layer``: ``S~ = alpha_j S_j``, ``u = beta_j (v_j - S~^T k_j)``,
-    ``S_j <- S~ + k_j u^T``, ``o_j = S_j^T q_j``. A slot that is not
-    ``active`` is neither read nor written (its ``o`` is zeros), save
-    where no slot is: then slot 0's first block is copied through as it
-    was. Returns ``(o (B, H*dv), state)``, the leaf the same buffer in
-    and out."""
-    b, dk, h = q.shape
+    ``S_j <- S~ + k_j u^T``, ``o_j = S_j^T q_j``.
+
+    ``rule="additive"`` (Mamba-2's, ``%ssd_decode``): ``q``, ``k`` (B, dk,
+    G) are C and B of G key groups, each shared by H / G heads; ``v`` is
+    dt x; ``beta`` is None. ``S_j <- alpha_j S_j + k_g v_j^T``, ``o_j =
+    S_j^T q_g`` with g head j's group.
+
+    A slot that is not ``active`` is neither read nor written (its ``o``
+    is zeros), save where no slot is: then slot 0's first block is copied
+    through as it was. Returns ``(o (B, H*dv), state)``, the leaf the
+    same buffer in and out."""
+    b, dk, _ = q.shape
+    h = alpha.shape[-1]
     width = state.shape[-1]
     dv = width // h
+    if rule not in _STATE_KERNEL or (beta is None) != (rule == "additive"):
+        raise ValueError(
+            f"rule {rule!r}: 'delta' takes a beta, 'additive' none")
     if heads_per_step is None:
         heads_per_step = linear_heads_per_step(h, dv, dk)
     assert h % heads_per_step == 0, (h, heads_per_step)
@@ -1873,14 +1931,17 @@ def linear_decode(
         q, k, v, alpha, beta, state, jnp.asarray(layer, jnp.int32), live,
         heads_per_step=heads_per_step, group=group,
         interpret=_default_interpret() if interpret is None else interpret,
+        rule=rule,
     )
 
 
 @functools.partial(
-    jax.jit, static_argnames=("heads_per_step", "group", "interpret"))
+    jax.jit, static_argnames=("heads_per_step", "group", "interpret", "rule"))
 def _linear_decode(q, k, v, alpha, beta, state, layer, live, *,
-                   heads_per_step: int, group: int, interpret: bool):
-    b, dk, h = q.shape
+                   heads_per_step: int, group: int, interpret: bool,
+                   rule: str = "delta"):
+    b, dk, kh = q.shape
+    h = alpha.shape[-1]
     width = state.shape[-1]
     dv = width // h
     nj = h // heads_per_step
@@ -1908,9 +1969,31 @@ def _linear_decode(q, k, v, alpha, beta, state, layer, live, *,
     def lane_row(i, j, *_):
         return (i, 0, j)
 
+    def whole(i, j, *_):
+        return (i, 0, 0)
+
     state_spec = pl.BlockSpec((None, None, None, dk, lanes), state_at)
+    lane_spec = pl.BlockSpec((None, 1, lanes), lane_row)
+    head_spec = pl.BlockSpec((None, None, 1, heads_per_step), small)
+    scalars = (jnp.reshape(layer, (1,)), src, job)
+    if rule == "delta":
+        kernel = functools.partial(
+            _linear_decode_kernel, value_dim=dv, group=group)
+        key_spec = pl.BlockSpec((None, None, dk, heads_per_step), small)
+        keys = (blk(q.astype(jnp.float32)), blk(k.astype(jnp.float32)))
+    else:
+        kernel = functools.partial(
+            _ssd_decode_kernel, value_dim=dv, group=group,
+            heads_per_step=heads_per_step, heads_per_key=h // kh)
+        key_spec = pl.BlockSpec((None, dk, kh), whole)
+        keys = (q.astype(jnp.float32), k.astype(jnp.float32))
+    values = v.astype(jnp.float32).reshape(b, 1, width)
+    per_head = (blk(alpha.astype(jnp.float32)[:, None]),)
+    if rule == "delta":
+        per_head += (blk(beta.astype(jnp.float32)[:, None]),)
+    operands = (*keys, values, *per_head)
     o, state = pl.pallas_call(
-        functools.partial(_linear_decode_kernel, value_dim=dv, group=group),
+        kernel,
         out_shape=(
             jax.ShapeDtypeStruct((b, 1, width), jnp.float32),
             jax.ShapeDtypeStruct(state.shape, state.dtype),
@@ -1918,51 +2001,47 @@ def _linear_decode(q, k, v, alpha, beta, state, layer, live, *,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
             grid=(b, nj),
-            in_specs=[
-                pl.BlockSpec((None, None, dk, heads_per_step), small),
-                pl.BlockSpec((None, None, dk, heads_per_step), small),
-                pl.BlockSpec((None, 1, lanes), lane_row),
-                pl.BlockSpec((None, None, 1, heads_per_step), small),
-                pl.BlockSpec((None, None, 1, heads_per_step), small),
-                state_spec,
-            ],
-            out_specs=(pl.BlockSpec((None, 1, lanes), lane_row), state_spec),
+            in_specs=[key_spec, key_spec, lane_spec,
+                      *[head_spec] * len(per_head), state_spec],
+            out_specs=(lane_spec, state_spec),
         ),
-        # the state is updated in place: operand 8 (after the three
-        # scalars and five inputs) is the second result
-        input_output_aliases={8: 1},
+        # the state is updated in place: the operand after the three
+        # scalars and the other inputs is the second result
+        input_output_aliases={3 + len(operands): 1},
         # in order: an idle slot leans on the block the step before left
         compiler_params=_dim_semantics(interpret, ("arbitrary", "arbitrary")),
         interpret=interpret,
-        name="linear_decode",
-    )(
-        jnp.reshape(layer, (1,)), src, job,
-        blk(q.astype(jnp.float32)), blk(k.astype(jnp.float32)),
-        v.astype(jnp.float32).reshape(b, 1, width),
-        blk(alpha.astype(jnp.float32)[:, None]),
-        blk(beta.astype(jnp.float32)[:, None]), state,
-    )
+        name=_STATE_KERNEL[rule],
+    )(*scalars, *operands, state)
     return o.reshape(b, width), state
 
 
-def linear_decode_dense(q, k, v, alpha, beta, state, layer=0, active=None):
+def linear_decode_dense(q, k, v, alpha, beta, state, layer=0, active=None,
+                        rule: str = "delta"):
     """:func:`linear_decode` in ``jax.numpy``, on the same operands and
     layout, float32 elementwise (no matrix unit, so no rounding of the
     products on any backend): the path without the kernel, and its
-    yardstick."""
-    b, dk, h = q.shape
+    yardstick, under either rule."""
+    b, dk, kh = q.shape
+    h = alpha.shape[-1]
     dv = state.shape[-1] // h
     s_old = state[layer, 0]
 
-    def lanes(x):  # (B, r, H) -> (B, r, H*dv)
-        return jnp.repeat(x.astype(jnp.float32), dv, axis=-1)
+    def lanes(x, per=dv):  # (B, r, n) -> (B, r, n*per)
+        return jnp.repeat(x.astype(jnp.float32), per, axis=-1)
 
-    s = lanes(alpha[:, None]) * s_old
-    u = lanes(beta[:, None]) * (
-        v.astype(jnp.float32)[:, None]
-        - jnp.sum(s * lanes(k), axis=1, keepdims=True))
-    s = s + lanes(k) * u
-    o = jnp.sum(s * lanes(q), axis=1)
+    if rule == "delta":
+        s = lanes(alpha[:, None]) * s_old
+        u = lanes(beta[:, None]) * (
+            v.astype(jnp.float32)[:, None]
+            - jnp.sum(s * lanes(k), axis=1, keepdims=True))
+        s = s + lanes(k) * u
+        o = jnp.sum(s * lanes(q), axis=1)
+    else:  # additive: a key group's column over all its heads' lanes
+        per = (h // kh) * dv
+        s = (lanes(alpha[:, None]) * s_old
+             + lanes(k, per) * v.astype(jnp.float32)[:, None])
+        o = jnp.sum(s * lanes(q, per), axis=1)
     if active is not None:
         live = jnp.asarray(active, bool)
         s = jnp.where(live[:, None, None], s, s_old)

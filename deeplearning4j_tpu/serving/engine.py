@@ -136,6 +136,7 @@ from deeplearning4j_tpu.models.transformer import (
     kv_cache_rows,
     kv_row_write,
     make_paged_fwd1,
+    min_bucket,
     paged_block_copy,
     paged_slot_gather,
     paged_slot_scatter,
@@ -1448,10 +1449,10 @@ class ServingEngine:
         self.metrics.kv_row_write = kv_row_write(cfg)
         self.metrics.kv_cache_rows = kv_cache_rows(cfg)
         if cfg.linear:
-            # one (slot, layer) state block: key_head_dim x the heads'
-            # value lanes, float32
+            # one (slot, layer) state block: key channels (key_head_dim,
+            # a mamba layer's d_state) x the heads' value lanes, float32
             self.metrics.state_block_bytes = (
-                4 * cfg.linear_key_head_dim * cfg.linear_state_width)
+                4 * cfg.state_rows * cfg.linear_state_width)
         self.metrics.compile_log = self._compile_log
         # the one place a phase of step() is named: profiler
         # annotation, metrics.loop_seconds, ring span, sanitizer phase
@@ -1467,7 +1468,7 @@ class ServingEngine:
         while mb * 2 <= limit:
             mb *= 2
         self._max_bucket = mb
-        self._min_bucket = min(8, mb)
+        self._min_bucket = min(min_bucket(cfg), mb)
         # partial-hit rounding grain: block-aligned in paged mode so
         # every partial hit is pure block aliasing (no sub-block copy),
         # the bucket grain otherwise

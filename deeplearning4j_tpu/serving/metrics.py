@@ -71,8 +71,9 @@ still is that share); ``moe_assignments_local`` / ``moe_assignments_total``
 program and read back with its tokens: token-expert pairs computed by the
 experts held here, pairs routed in all, and held experts that saw a token
 (each streams its weights once); ``state_slot_updates`` is a stack
-with linear_attention layers' book, counted the same way: the (live slot,
-linear layer) pairs whose recurrent state the step programs advanced,
+with linear_attention or mamba layers' book, counted the same way: the
+(live slot, state layer) pairs whose recurrent state the step programs
+advanced,
 each reading and writing one state block (``state_block_bytes``) — the
 state leaf holds no rows, so ``kv_rows_live`` / ``kv_rows_streamed``
 count the full layers' slab alone there; ``ttft_segment_seconds`` splits every request's time
@@ -164,7 +165,8 @@ class ServingMetrics:
         self.moe_assignments_local = 0
         self.moe_assignments_total = 0
         self.moe_experts_hit = 0
-        # a stack with linear_attention layers: state blocks advanced,
+        # a stack with linear_attention or mamba layers: state blocks
+        # advanced,
         # and the bytes of one (0: no state leaf)
         self.state_slot_updates = 0
         self.state_block_bytes = 0
@@ -469,8 +471,8 @@ class ServingMetrics:
         self._c_state_bytes = reg.counter(
             "serve_state_bytes_total",
             "Bytes of recurrent state the step programs read and wrote "
-            "(linear_attention layers: state_slot_updates x 2 x one "
-            "slot's state block of a layer).",
+            "(linear_attention and mamba layers: state_slot_updates x 2 "
+            "x one slot's state block of a layer).",
         )
         self._c_compile_requests = reg.counter(
             "serve_compile_requests_total",

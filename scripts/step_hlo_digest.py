@@ -5,8 +5,9 @@ change left another configuration's programs as they were.
     python3 scripts/step_hlo_digest.py [CHECKOUT]     (default: this one)
 
 For GPT-2 (MHA, learned positions, flash prefill), a GQA + RoPE
-configuration and Laguna's gated stack (its configuration file's
-``rehearse`` widths), each at the vocabulary its cell serves so that the
+configuration, and Laguna's gated stack, openPangu's latent one and
+Olmo-Hybrid's linear one (each configuration file's ``rehearse``
+widths), each at the vocabulary its cell serves so that the
 sampler takes the branch it takes there: the step (K = 4, temperature 1.0,
 top-k 40), prefill and chunk programs are lowered from abstract values on
 the CPU (nothing is compiled or run, about 20 s) and the SHA-256 of each
@@ -48,19 +49,27 @@ S = jax.ShapeDtypeStruct
 SLOTS, ROWS = 4, 256
 
 
-def configurations() -> dict:
-    with open(os.path.join(ROOT, "benchmark/configs/laguna-s-2.1.json")) as f:
-        laguna = json.load(f)
-    gated = dict(laguna["model"], **laguna["rehearse"]["model"])
-    gated.update(vocab_size=laguna["model"]["vocab_size"],
+def rehearsed(name: str) -> TransformerConfig:
+    """A configuration file's ``rehearse`` widths at its served
+    vocabulary, bf16."""
+    with open(os.path.join(ROOT, f"benchmark/configs/{name}.json")) as f:
+        config = json.load(f)
+    model = dict(config["model"], **config["rehearse"]["model"])
+    model.update(vocab_size=config["model"]["vocab_size"],
                  compute_dtype=jnp.bfloat16)
+    return TransformerConfig(**model)
+
+
+def configurations() -> dict:
     toy = dict(d_model=64, n_heads=4, n_layers=2, d_ff=128, max_len=ROWS,
                compute_dtype=jnp.bfloat16)
     return {
         "gpt2": TransformerConfig(vocab_size=50257, use_flash=True, **toy),
         "gqa-rope": TransformerConfig(vocab_size=50257, n_kv_heads=2,
                                       rope=True, **toy),
-        "laguna": TransformerConfig(**gated),
+        "laguna": rehearsed("laguna-s-2.1"),
+        "pangu": rehearsed("openpangu-ultra-moe-718b"),
+        "olmo-hybrid": rehearsed("olmo-hybrid-7b"),
     }
 
 

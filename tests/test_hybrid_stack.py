@@ -31,9 +31,9 @@ from deeplearning4j_tpu.models.transformer import (  # noqa: E402
     _decode_builder,
     decode_rows_live,
     decode_rows_streamed,
-    delta_rule_chunked,
     init_transformer,
     kv_cache_rows,
+    state_rule_chunked,
     transformer_generate,
 )
 from deeplearning4j_tpu.ops.pallas_kernels import (  # noqa: E402
@@ -223,7 +223,7 @@ def _rule_inputs(seed, t, b=2, h=2, dk=16, dv=32):
 def test_the_chunked_form_is_the_token_recurrence(t):
     q, k, v, g, beta, s0 = _rule_inputs(t, t)
     want_o, want_s = _recurrence(q, k, v, g, beta, s0)
-    o, s = jax.jit(delta_rule_chunked)(q, k, v, g, beta, s0)
+    o, s = jax.jit(state_rule_chunked)(q, k, v, g, beta, s0)
     np.testing.assert_allclose(np.asarray(o), want_o, rtol=2e-4, atol=2e-4)
     np.testing.assert_allclose(np.asarray(s), want_s, rtol=2e-4, atol=2e-4)
 

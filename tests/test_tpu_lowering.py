@@ -632,3 +632,88 @@ def test_hybrid_step_program_lowers_with_the_state_and_its_counter():
     assert text.count("call @_linear_decode(") == 3 * k
     out = jax.eval_shape(step, *avals)
     assert out[5].shape == (slots + 1, k)
+
+
+# mamba layers, slots, d_state, heads, d_head: the state leaf of the
+# granite-4.0-h-micro cell (4.83 GB of float32), one key group
+SSD_GEOMETRY = (36, 64, 128, 64, 64)
+
+
+def _ssd_avals(sharding=None, heads_per_step=None, groups=1):
+    layers, b, dk, h, dv = SSD_GEOMETRY
+    f32 = jnp.float32
+
+    def s(shape, dtype=f32):
+        return S(shape, dtype, sharding=sharding)
+
+    def fn(q, k, v, a, state, act):
+        return pk.linear_decode(q, k, v, a, None, state, layer=layers - 1,
+                                active=act, heads_per_step=heads_per_step,
+                                rule="additive")
+
+    return fn, (s((b, dk, groups)), s((b, dk, groups)), s((b, h * dv)),
+                s((b, h)), s((layers, 1, b, dk, h * dv)), s((b,), jnp.bool_))
+
+
+@pytest.mark.parametrize("heads_per_step, groups", [
+    (None, 1), (16, 1), (64, 1), (32, 8)])
+def test_ssd_decode_lowers_with_the_state_aliased(heads_per_step, groups):
+    """Three prefetched vectors (layer, source slot, job) and four small
+    inputs (C, B, dt x, the decay), then the state leaf, which is the
+    second result; the call is named ``ssd_decode`` in the trace."""
+    fn, avals = _ssd_avals(heads_per_step=heads_per_step, groups=groups)
+    text = lower_tpu(fn, *avals)
+    assert ("output_operand_alias<output_tuple_indices = [1], "
+            "operand_index = 7, operand_tuple_indices = []>") in text
+    assert "ssd_decode" in text and "linear_decode\"" not in text
+
+
+def test_ssd_decode_compiles_in_place_for_a_v5e(one_chip):
+    """Mosaic accepts the kernel at the cell's widths, 32 heads' 1 MB
+    block a grid step, within half a v5e's default scoped VMEM, and the
+    donated 4.83 GB leaf goes through without a copy."""
+    fn, avals = _ssd_avals(one_chip)
+    compiled = jax.jit(fn, donate_argnums=(4,)).lower(*avals).compile(
+        compiler_options={"xla_tpu_scoped_vmem_limit_kib": "8192"})
+    mem = compiled.memory_analysis()
+    state_bytes = 4 * math.prod(avals[4].shape)
+    assert mem.alias_size_in_bytes == state_bytes
+    assert mem.temp_size_in_bytes < state_bytes // 128
+
+
+def test_mamba_step_program_lowers_with_the_state_and_its_counter():
+    """A step program of a stack with mamba layers at the cell's widths
+    and four layers: the additive kernel once a mamba layer and substep,
+    the walk once an attention layer and substep, and one counter row
+    (state updates) under the token block."""
+    from deeplearning4j_tpu.models.transformer import _decode_builder
+    from deeplearning4j_tpu.serving.engine import build_step_program
+
+    cfg = TransformerConfig(
+        vocab_size=2048, d_model=2048, n_heads=32, n_kv_heads=8,
+        n_layers=4, d_ff=1024, max_len=1024, rope=False, use_flash=True,
+        compute_dtype=jnp.bfloat16, decode_kernel=True,
+        layer_types=("mamba",) * 3 + ("attention",),
+        dense_layers=(0, 1, 2, 3), mamba_n_heads=64, mamba_d_head=64,
+        mamba_d_state=128, mamba_n_groups=1, mamba_d_conv=4, mamba_expand=2,
+        mamba_conv_bias=True, embedding_multiplier=12.0,
+        residual_multiplier=0.22, attention_multiplier=0.015625,
+        logits_scaling=8.0, tie_word_embeddings=True,
+    )
+    fwd1, init_caches, _, cast = _decode_builder(cfg)
+    slots, k = 16, 2
+    params = jax.eval_shape(
+        lambda key: cast(init_transformer(key, cfg)), jax.random.key(0))
+    caches = jax.eval_shape(lambda: init_caches(slots, 1024))
+    step = build_step_program(fwd1, k, 1.0, 40, False)
+    avals = (
+        params, caches, S((slots, cfg.vocab_size), jnp.float32),
+        S((slots,), jnp.int32), S((slots,), jnp.bool_),
+        S((slots,), jnp.int32), S((slots,), jnp.int32),
+        S((slots, 2), jnp.uint32), S((slots,), jnp.int32),
+    )
+    text = lower_tpu(step, *avals)
+    assert text.count("call @_linear_decode(") == 3 * k
+    assert "ssd_decode" in text
+    out = jax.eval_shape(step, *avals)
+    assert out[5].shape == (slots + 1, k)
