@@ -963,6 +963,21 @@ def kv_row_write(cfg: "TransformerConfig") -> str:
     return "kernel" if cfg.decode_kernel and not cfg.decode_int8 else "xla"
 
 
+def conv_row_write(cfg: "TransformerConfig") -> str | None:
+    """Who places a decode substep's fresh convolution input in the
+    ring of a stack with linear_attention or mamba layers: ``"kernel"``
+    where the state kernel runs (``linear_decode`` / ``ssd_decode``
+    copies the slot's row into the ring leaf it aliases), ``"xla"`` on
+    the dense path (a scatter in ``linear_decode_dense``); None for a
+    stack without state layers, which has no ring. One write a (live
+    slot, state layer) pair and substep, which is what
+    ``state_slot_updates`` counts. A fact of the configuration, like
+    :func:`kv_row_write`; the engine reports it."""
+    if not cfg.linear:
+        return None
+    return "kernel" if cfg.decode_kernel else "xla"
+
+
 def kv_cache_rows(cfg: "TransformerConfig") -> str:
     """What a position's cache row is: ``"kv"`` a K and a V plane of
     ``Hkv x K`` values a layer, ``"kv+ring"`` the same in two leaves (a
@@ -2311,7 +2326,12 @@ def _gated_builder(cfg: TransformerConfig):
         for i, l in enumerate(cfg.layers_of(kind))
     }
     n_linear = len(cfg.layers_of("linear"))
-    conv_ring = max(8, -(-(cfg.conv_kernel - 1) // 8) * 8)
+    # the convolution's ring: position p of a slot at p % conv_ring, the K
+    # - 1 inputs a step reads and the one it writes; a position is
+    # conv_rows rows of 128 lanes (the channels, zero-padded to whole (8,
+    # 128) tiles), so the decode kernel places it with one aligned copy
+    conv_ring = cfg.conv_kernel
+    conv_rows = -(-cfg.linear_conv_width // 1024) * 8
 
     def init_caches(batch: int, total: int):
         tpad = _decode_tpad(total)
@@ -2328,15 +2348,14 @@ def _gated_builder(cfg: TransformerConfig):
                  cfg.sliding_window, hk), cfg.compute_dtype)
         if n_linear:
             # a slot's recurrent state, float32: key_head_dim rows of
-            # heads x value_head_dim lanes, one plane; and a ring of the
-            # convolution's inputs at the last ``conv_ring`` positions
-            # (position p at row p % conv_ring; whole (8, 128) tiles, so
-            # a step writes one row where a shift rewrote the window)
+            # heads x value_head_dim lanes, one plane; and the ring of
+            # the convolution's inputs at the last ``conv_ring``
+            # positions, a position (conv_rows, 128) tiles of its own
             out["state"] = jnp.zeros(
                 (n_linear, 1, batch, cfg.state_rows,
                  cfg.linear_state_width), jnp.float32)
             out["conv"] = jnp.zeros(
-                (n_linear, 1, batch, conv_ring, cfg.linear_conv_width),
+                (n_linear, 1, batch, conv_ring, conv_rows, 128),
                 jnp.float32)
         return out
 
@@ -2403,44 +2422,40 @@ def _gated_builder(cfg: TransformerConfig):
         pad = latent_row_width(cfg) - x.shape[-1]
         return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
 
-    def conv_window(caches, idx, x, first, last, live=None):
-        """The convolution's window for the inputs ``x`` (B, C, channels)
-        at positions ``first`` .. ``first + C - 1`` (``first`` (B or 1,
-        1)): the K - 1 inputs before ``first`` from the slot's ring
-        (zeros before position 0), then x. The inputs at positions up to
-        ``last`` (B or 1, 1; a bucket's padding lies past it) go to the
-        ring, in the rows of a ``live`` (B,) slot only.
+    def ring_rows(x):  # (..., channels) -> (..., conv_rows, 128) float32
+        pad = conv_rows * 128 - x.shape[-1]
+        x = jnp.pad(x.astype(jnp.float32), [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+        return x.reshape(x.shape[:-1] + (conv_rows, 128))
 
-        Where the call's rows and the K - 1 before them fit the ring
-        together (decode), the rows are written first and the window read
-        from the written leaf: the read then depends on the write, so the
-        leaf is updated in place through every layer. Read first, XLA
-        copies the whole leaf to keep the read's rows (a copy of the
-        leaf every other layer in a step)."""
-        leaf = caches["conv"]
-        b, c = x.shape[:2]
+    def rows_before(leaf, idx, first, b):
+        """The convolution's K - 1 inputs before position ``first`` (B or
+        1, 1) from the slot's ring, zeros before position 0: (B, K - 1,
+        channels)."""
         before = jnp.broadcast_to(
             first + jnp.arange(1 - cfg.conv_kernel, 0),
             (b, cfg.conv_kernel - 1))
+        prev = jnp.take_along_axis(
+            leaf[idx, 0], (before % conv_ring)[..., None, None], axis=1)
+        prev = prev.reshape(prev.shape[:2] + (-1,))[
+            ..., :cfg.linear_conv_width]
+        return jnp.where((before >= 0)[..., None], prev, 0.0)
 
-        def rows_before(leaf):
-            prev = jnp.take_along_axis(
-                leaf[idx, 0], (before % conv_ring)[..., None], axis=1)
-            return jnp.where((before >= 0)[..., None], prev, 0.0)
-
-        write_first = c + cfg.conv_kernel - 1 <= conv_ring
-        if not write_first:
-            prev = rows_before(leaf)
+    def conv_window(caches, idx, x, first, last):
+        """The convolution's window for the inputs ``x`` (B, C, channels)
+        at positions ``first`` .. ``first + C - 1`` (``first`` (B or 1,
+        1)): the K - 1 inputs before ``first`` from the slot's ring, then
+        x. The ring is read first, then the inputs at positions up to
+        ``last`` (B or 1, 1; a bucket's padding lies past it) go to it,
+        the last ``conv_ring`` of them."""
+        leaf = caches["conv"]
+        b, c = x.shape[:2]
+        prev = rows_before(leaf, idx, first, b)
         positions = jnp.broadcast_to(first + jnp.arange(c), (b, c))
         keep = (positions <= last) & (positions > last - conv_ring)
-        if live is not None:
-            keep = keep & jnp.reshape(live, (-1, 1))
         caches["conv"] = leaf.at[
             idx, 0, jnp.arange(b)[:, None],
             jnp.where(keep, positions % conv_ring, conv_ring),
-        ].set(x.astype(leaf.dtype), mode="drop")  # conv_ring: dropped
-        if write_first:
-            prev = rows_before(caches["conv"])
+        ].set(ring_rows(x), mode="drop")  # conv_ring: dropped
         return jnp.concatenate([prev.astype(x.dtype), x], axis=1)
 
     def linear_chunk_attend(caches, idx, first, last):
@@ -2477,30 +2492,39 @@ def _gated_builder(cfg: TransformerConfig):
 
     def linear_step_attend(caches, idx, pos, active):
         """A linear layer's ``attend`` for one row a slot at ``pos``: the
-        input goes to the ring and the state advances one step, through
-        ``linear_decode`` (the kernel updates the leaf in place; under
-        the additive rule it is ``ssd_decode``) or its jnp twin; a row
-        that is not ``active`` keeps both as they were."""
+        window reads the K - 1 inputs before ``pos`` from the ring, and
+        the state advances one step through ``linear_decode`` (under the
+        additive rule ``ssd_decode``), which also places the fresh input
+        at ``pos`` in the ring, both leaves updated in place; or through
+        its jnp twin. The read feeds the kernel's operands, so it comes
+        before the write. A row that is not ``active`` keeps both as
+        they were."""
         from deeplearning4j_tpu.ops.pallas_kernels import (
             linear_decode,
             linear_decode_dense,
         )
 
-        at = jnp.reshape(pos, (-1, 1))
+        fresh = {}
+
+        def window(x):  # (B, 1, channels)
+            fresh["row"] = ring_rows(x[:, 0])
+            prev = rows_before(caches["conv"], idx, jnp.reshape(pos, (-1, 1)),
+                               x.shape[0])
+            return jnp.concatenate([prev.astype(x.dtype), x], axis=1)
 
         def mix(q, k, v, g, beta):
             step = linear_decode if cfg.decode_kernel else linear_decode_dense
-            o, caches["state"] = step(
+            o, caches["state"], caches["conv"] = step(
                 q[:, 0].transpose(0, 2, 1), k[:, 0].transpose(0, 2, 1),
                 v[:, 0].reshape(v.shape[0], -1), jnp.exp(g[:, 0]),
                 None if beta is None else beta[:, 0], caches["state"],
                 layer=idx, active=active,
                 rule="additive" if beta is None else "delta",
+                ring=caches["conv"], row=fresh["row"], pos=pos,
             )
             return o.reshape(v.shape)
 
-        return LinearAttend(
-            lambda x: conv_window(caches, idx, x, at, at, active), mix)
+        return LinearAttend(window, mix)
 
     def latent_chunk_attend(caches, idx, positions):
         """A latent layer's ``attend`` for C rows at ``positions``: the

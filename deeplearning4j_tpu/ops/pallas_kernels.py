@@ -1878,6 +1878,38 @@ def _ssd_decode_kernel(layer_ref, src_ref, job_ref, q_ref, k_ref, v_ref,
                                      keepdims=True)
 
 
+def _ring_writing(kernel, n_inputs: int):
+    """``kernel`` (a rule's grid step) that also places each live slot's
+    fresh convolution input in the ring leaf: on the slot's first head
+    block one aligned copy of its (1, R, 128) block (``x_ref``, in VMEM) to
+    ``ring[layer, 0, slot, at[slot]]`` starts, and it is waited before
+    the slot's last head block ends, so it runs under the slot's state
+    arithmetic. An idle slot copies nothing. The ring is the same HBM
+    buffer in and out, and a position of a slot is whole (8, 128) tiles:
+    no other row of the leaf is read or written."""
+
+    def body(layer_ref, src_ref, job_ref, at_ref, *refs):
+        ins = refs[:n_inputs]
+        x_ref, s_ref, _, o_ref, s_out, ring_out, sem = refs[n_inputs:]
+        i, j = pl.program_id(0), pl.program_id(1)
+        live = job_ref[i] == 1
+        place = pltpu.make_async_copy(
+            x_ref, ring_out.at[layer_ref[0], 0, pl.ds(i, 1), at_ref[i]],
+            sem.at[0])
+
+        @pl.when(live & (j == 0))
+        def _():
+            place.start()
+
+        kernel(layer_ref, src_ref, job_ref, *ins, s_ref, o_ref, s_out)
+
+        @pl.when(live & (j == pl.num_programs(1) - 1))
+        def _():
+            place.wait()
+
+    return body
+
+
 #: the Pallas call's name under each rule: what the device trace shows
 _STATE_KERNEL = {"delta": "linear_decode", "additive": "ssd_decode"}
 
@@ -1894,7 +1926,10 @@ def linear_decode(
     heads_per_step: int | None = None,
     interpret: bool | None = None,
     rule: str = "delta",
-) -> tuple[jax.Array, jax.Array]:
+    ring: jax.Array | None = None,
+    row: jax.Array | None = None,
+    pos: jax.Array | None = None,
+) -> tuple[jax.Array, ...]:
     """One decode step of a state layer for every slot, in place.
 
     ``rule="delta"`` (the gated delta rule, ``%linear_decode`` in a
@@ -1913,7 +1948,14 @@ def linear_decode(
     A slot that is not ``active`` is neither read nor written (its ``o``
     is zeros), save where no slot is: then slot 0's first block is copied
     through as it was. Returns ``(o (B, H*dv), state)``, the leaf the
-    same buffer in and out."""
+    same buffer in and out.
+
+    ``ring``: the layer's convolution ring, the stacked (layers, 1, B, P,
+    R, 128) float32 leaf (``P`` positions a slot, position p at p % P,
+    each R rows of 128 lanes). The kernel then also places ``row`` (B,
+    R, 128), each slot's fresh convolution input, at position ``pos``
+    (B,) of a live slot (:func:`_ring_writing`), and returns ``(o, state,
+    ring)``, the ring too the same buffer in and out."""
     b, dk, _ = q.shape
     h = alpha.shape[-1]
     width = state.shape[-1]
@@ -1927,8 +1969,15 @@ def linear_decode(
     group = min(_lane_groups(heads_per_step, dv) or [heads_per_step])
     live = (jnp.ones((b,), bool) if active is None
             else jnp.asarray(active, bool))
+    at = None
+    if ring is not None:
+        assert row.shape == (b,) + ring.shape[4:], (row.shape, ring.shape)
+        at = jnp.broadcast_to(
+            jnp.reshape(jnp.asarray(pos, jnp.int32), (-1,)) % ring.shape[3],
+            (b,))
     return _linear_decode(
         q, k, v, alpha, beta, state, jnp.asarray(layer, jnp.int32), live,
+        ring, row, at,
         heads_per_step=heads_per_step, group=group,
         interpret=_default_interpret() if interpret is None else interpret,
         rule=rule,
@@ -1937,9 +1986,9 @@ def linear_decode(
 
 @functools.partial(
     jax.jit, static_argnames=("heads_per_step", "group", "interpret", "rule"))
-def _linear_decode(q, k, v, alpha, beta, state, layer, live, *,
-                   heads_per_step: int, group: int, interpret: bool,
-                   rule: str = "delta"):
+def _linear_decode(q, k, v, alpha, beta, state, layer, live, ring=None,
+                   row=None, at=None, *, heads_per_step: int, group: int,
+                   interpret: bool, rule: str = "delta"):
     b, dk, kh = q.shape
     h = alpha.shape[-1]
     width = state.shape[-1]
@@ -1959,9 +2008,12 @@ def _linear_decode(q, k, v, alpha, beta, state, layer, live, *,
     def blk(x):  # (B, dk or 1, H) -> (B, nj, dk or 1, heads a step)
         return x.reshape(b, x.shape[1], nj, heads_per_step).transpose(0, 2, 1, 3)
 
-    def state_at(i, j, layer_ref, src_ref, job_ref):
+    def state_at(i, j, layer_ref, src_ref, job_ref, *_):
         jj = jnp.where(job_ref[i] == 1, j, jnp.where(job_ref[i] == 0, nj - 1, 0))
         return (layer_ref[0], 0, src_ref[i], 0, jj)
+
+    def fresh_at(i, j, layer_ref, src_ref, *_):
+        return (src_ref[i], 0, 0)  # an idle slot fetches nothing new
 
     def small(i, j, *_):
         return (i, j, 0, 0)
@@ -1992,36 +2044,52 @@ def _linear_decode(q, k, v, alpha, beta, state, layer, live, *,
     if rule == "delta":
         per_head += (blk(beta.astype(jnp.float32)[:, None]),)
     operands = (*keys, values, *per_head)
-    o, state = pl.pallas_call(
+    in_specs = [key_spec, key_spec, lane_spec, *[head_spec] * len(per_head)]
+    out_shape = [jax.ShapeDtypeStruct((b, 1, width), jnp.float32),
+                 jax.ShapeDtypeStruct(state.shape, state.dtype)]
+    out_specs = [lane_spec, state_spec]
+    tail, tail_specs, scratch = (state,), [state_spec], []
+    if ring is not None:
+        kernel = _ring_writing(kernel, len(operands))
+        scalars += (at,)
+        operands += (row.astype(ring.dtype),)
+        in_specs.append(pl.BlockSpec((1,) + ring.shape[4:], fresh_at))
+        tail += (ring,)
+        tail_specs.append(pl.BlockSpec(memory_space=pl.ANY))
+        out_shape.append(jax.ShapeDtypeStruct(ring.shape, ring.dtype))
+        out_specs.append(pl.BlockSpec(memory_space=pl.ANY))
+        scratch.append(pltpu.SemaphoreType.DMA((1,)))
+    first = len(scalars) + len(operands)
+    out = pl.pallas_call(
         kernel,
-        out_shape=(
-            jax.ShapeDtypeStruct((b, 1, width), jnp.float32),
-            jax.ShapeDtypeStruct(state.shape, state.dtype),
-        ),
+        out_shape=tuple(out_shape),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=len(scalars),
             grid=(b, nj),
-            in_specs=[key_spec, key_spec, lane_spec,
-                      *[head_spec] * len(per_head), state_spec],
-            out_specs=(lane_spec, state_spec),
+            in_specs=in_specs + tail_specs,
+            out_specs=tuple(out_specs),
+            scratch_shapes=scratch,
         ),
-        # the state is updated in place: the operand after the three
-        # scalars and the other inputs is the second result
-        input_output_aliases={3 + len(operands): 1},
+        # the state (and the ring) are updated in place: the operands
+        # after the scalars and the other inputs are the second (and
+        # third) results
+        input_output_aliases={first + n: 1 + n for n in range(len(tail))},
         # in order: an idle slot leans on the block the step before left
         compiler_params=_dim_semantics(interpret, ("arbitrary", "arbitrary")),
         interpret=interpret,
         name=_STATE_KERNEL[rule],
-    )(*scalars, *operands, state)
-    return o.reshape(b, width), state
+    )(*scalars, *operands, *tail)
+    return (out[0].reshape(b, width),) + tuple(out[1:])
 
 
 def linear_decode_dense(q, k, v, alpha, beta, state, layer=0, active=None,
-                        rule: str = "delta"):
+                        rule: str = "delta", ring=None, row=None, pos=None):
     """:func:`linear_decode` in ``jax.numpy``, on the same operands and
     layout, float32 elementwise (no matrix unit, so no rounding of the
     products on any backend): the path without the kernel, and its
-    yardstick, under either rule."""
+    yardstick, under either rule. With ``ring`` a scatter places the
+    live slots' ``row`` at ``pos`` as the kernel does, and the ring is
+    the third result."""
     b, dk, kh = q.shape
     h = alpha.shape[-1]
     dv = state.shape[-1] // h
@@ -2042,11 +2110,17 @@ def linear_decode_dense(q, k, v, alpha, beta, state, layer=0, active=None,
         s = (lanes(alpha[:, None]) * s_old
              + lanes(k, per) * v.astype(jnp.float32)[:, None])
         o = jnp.sum(s * lanes(q, per), axis=1)
+    live = jnp.ones((b,), bool) if active is None else jnp.asarray(
+        active, bool)
     if active is not None:
-        live = jnp.asarray(active, bool)
         s = jnp.where(live[:, None, None], s, s_old)
         o = jnp.where(live[:, None], o, 0.0)
-    return o, state.at[layer, 0].set(s)
+    if ring is None:
+        return o, state.at[layer, 0].set(s)
+    at = jnp.broadcast_to(jnp.reshape(pos, (-1,)) % ring.shape[3], (b,))
+    ring = ring.at[layer, 0, jnp.arange(b), jnp.where(
+        live, at, ring.shape[3])].set(row.astype(ring.dtype), mode="drop")
+    return o, state.at[layer, 0].set(s), ring
 
 
 def _walk_call(math, q, kvcache, kv_new, write_at, last, layer, *,

@@ -130,6 +130,7 @@ from deeplearning4j_tpu.models.transformer import (
     _chunk_builder,
     _decode_builder,
     _top_k_filter,
+    conv_row_write,
     decode_rows_live,
     decode_rows_streamed,
     full_cache_leaf,
@@ -390,6 +391,18 @@ def build_deact_program():
     return lambda active, slot: active.at[slot].set(False)
 
 
+def _at_slot(leaf, slot) -> tuple:
+    """Start indices of slot ``slot``'s slab in a cache leaf: every leaf
+    is (layers, planes, slots, ...), whatever a position's shape."""
+    return (0, 0, slot) + (0,) * (leaf.ndim - 3)
+
+
+def _slot_slab(leaf, slot):
+    """Slot ``slot``'s slab of a cache leaf, the slot axis kept."""
+    return lax.dynamic_slice(
+        leaf, _at_slot(leaf, slot), leaf.shape[:2] + (1,) + leaf.shape[3:])
+
+
 def build_prefill_program(do_prefill, init_caches, max_total: int):
     """Fused admission program for one prompt bucket: prefill-at-
     batch-1 over the padded prompt, slab insert at the slot index, and
@@ -413,7 +426,7 @@ def build_prefill_program(do_prefill, init_caches, max_total: int):
         )
         caches = jax.tree.map(
             lambda c, t: lax.dynamic_update_slice(
-                c, t, (0, 0, slot, 0, 0)
+                c, t, _at_slot(c, slot)
             ),
             caches, tmp,
         )
@@ -688,7 +701,7 @@ def build_insert_program():
                slot, pos0, max_new, eos_tok):
         caches = jax.tree.map(
             lambda c, t: lax.dynamic_update_slice(
-                c, t, (0, 0, slot, 0, 0)
+                c, t, _at_slot(c, slot)
             ),
             caches, tmp,
         )
@@ -712,15 +725,12 @@ def build_hit_insert_program():
     def hit(caches, logits, pos, active, budget, eos, region, seg_lg,
             seg, slot, pos0, max_new, eos_tok):
         slab = jax.tree.map(
-            lambda r: lax.dynamic_slice(
-                r, (0, 0, seg, 0, 0),
-                (r.shape[0], r.shape[1], 1, r.shape[3], r.shape[4]),
-            ),
+            lambda r: _slot_slab(r, seg),
             region,
         )
         caches = jax.tree.map(
             lambda c, t: lax.dynamic_update_slice(
-                c, t, (0, 0, slot, 0, 0)
+                c, t, _at_slot(c, slot)
             ),
             caches, slab,
         )
@@ -740,10 +750,7 @@ def build_seg_fetch_program():
 
     def fetch(region, seg):
         return jax.tree.map(
-            lambda r: lax.dynamic_slice(
-                r, (0, 0, seg, 0, 0),
-                (r.shape[0], r.shape[1], 1, r.shape[3], r.shape[4]),
-            ),
+            lambda r: _slot_slab(r, seg),
             region,
         )
 
@@ -757,15 +764,12 @@ def build_seg_store_program():
 
     def store(region, caches, seg, slot):
         slab = jax.tree.map(
-            lambda c: lax.dynamic_slice(
-                c, (0, 0, slot, 0, 0),
-                (c.shape[0], c.shape[1], 1, c.shape[3], c.shape[4]),
-            ),
+            lambda c: _slot_slab(c, slot),
             caches,
         )
         return jax.tree.map(
             lambda r, t: lax.dynamic_update_slice(
-                r, t, (0, 0, seg, 0, 0)
+                r, t, _at_slot(r, seg)
             ),
             region, slab,
         )
@@ -783,7 +787,7 @@ def build_seg_import_program():
     def imp(region, slab, seg):
         return jax.tree.map(
             lambda r, t: lax.dynamic_update_slice(
-                r, t, (0, 0, seg, 0, 0)
+                r, t, _at_slot(r, seg)
             ),
             region, slab,
         )
@@ -822,7 +826,7 @@ def build_batch_prefill_program(do_prefill, init_caches,
             )
             caches = jax.tree.map(
                 lambda c, t, r=r: lax.dynamic_update_slice(
-                    c, t, (0, 0, slots[r], 0, 0)
+                    c, t, _at_slot(c, slots[r])
                 ),
                 caches, slab,
             )
@@ -863,7 +867,7 @@ def build_batch_hit_program(fwd_chunk, nb: int):
             )
             caches = jax.tree.map(
                 lambda c, t, r=r: lax.dynamic_update_slice(
-                    c, t, (0, 0, slots[r], 0, 0)
+                    c, t, _at_slot(c, slots[r])
                 ),
                 caches, slab,
             )
@@ -1448,6 +1452,7 @@ class ServingEngine:
         )
         self.metrics.kv_row_write = kv_row_write(cfg)
         self.metrics.kv_cache_rows = kv_cache_rows(cfg)
+        self.metrics.conv_row_write = conv_row_write(cfg)
         if cfg.linear:
             # one (slot, layer) state block: key channels (key_head_dim,
             # a mamba layer's d_state) x the heads' value lanes, float32
@@ -1810,6 +1815,16 @@ class ServingEngine:
             "ones).",
             labelnames=("how",),
         ).set(1, how=self.metrics.kv_cache_rows)
+        if self.metrics.conv_row_write is not None:
+            reg.gauge(
+                "serve_conv_row_write",
+                "Who places a decode substep's convolution input in the "
+                "ring of a stack with state layers, decided when the "
+                "step programs are traced: kernel (the state kernel "
+                "copies the row into the ring it aliases) or xla (a "
+                "scatter: dense path). One a state_slot_updates pair.",
+                labelnames=("how",),
+            ).set(1, how=self.metrics.conv_row_write)
         reg.gauge(
             "serve_decode_horizon_current",
             "Decode substeps fused into the next horizon dispatch "

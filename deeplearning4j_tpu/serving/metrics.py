@@ -74,7 +74,9 @@ experts held here, pairs routed in all, and held experts that saw a token
 with linear_attention or mamba layers' book, counted the same way: the
 (live slot, state layer) pairs whose recurrent state the step programs
 advanced,
-each reading and writing one state block (``state_block_bytes``) — the
+each reading and writing one state block (``state_block_bytes``) and
+placing one convolution input in the ring (``conv_row_write`` says who
+places it, so no counter of its own is needed) — the
 state leaf holds no rows, so ``kv_rows_live`` / ``kv_rows_streamed``
 count the full layers' slab alone there; ``ttft_segment_seconds`` splits every request's time
 to first token at the three points the engine can see
@@ -188,6 +190,9 @@ class ServingMetrics:
         self.kv_row_write = "xla"
         # what a position's cache row is (transformer.kv_cache_rows)
         self.kv_cache_rows = "kv"
+        # who places a substep's convolution input in a state stack's
+        # ring (transformer.conv_row_write; None: no state layers)
+        self.conv_row_write = None
         self.n_finished = 0
         self.n_generated = 0
         # fault-tolerance counters (see serving.faults / engine docs):
@@ -1023,6 +1028,8 @@ class ServingMetrics:
                 out[name] = getattr(self, name)
         if self.state_block_bytes:
             out["state_slot_updates"] = self.state_slot_updates
+        if self.conv_row_write is not None:
+            out["conv_row_write"] = self.conv_row_write
         if self.n_ttft_segments:
             out["ttft_segments"] = {
                 "n": self.n_ttft_segments,

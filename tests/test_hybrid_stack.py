@@ -126,7 +126,8 @@ def test_the_state_leaf_is_float32_beside_a_bf16_slab():
     assert caches["state"].dtype == jnp.float32
     assert caches["state"].shape == (3, 1, 3, 16, 64)
     assert caches["conv"].dtype == jnp.float32
-    assert caches["conv"].shape == (3, 1, 3, 8, 128)  # a ring of 8 inputs
+    # a ring of K = 4 inputs, each 128 channels padded to one (8, 128) tile
+    assert caches["conv"].shape == (3, 1, 3, 4, 8, 128)
     assert caches["full"].shape == (1, 2, 3, 64, 64)
 
 
@@ -243,11 +244,13 @@ def test_padding_past_the_last_row_reaches_neither_state(cfg, params, n):
     for leaf in ("state", "conv"):
         _close(padded[leaf], exact[leaf], tol=1e-5)
     _close(lg1, lg0, tol=1e-5)
-    # the ring holds the inputs at positions n - 8 .. n - 1, row p % 8:
-    # none before position 0
-    if n < 8:
-        assert not np.asarray(exact["conv"])[:, 0, 0, n:].any()
-        assert np.asarray(exact["conv"])[:, 0, 0, :n].any()
+    # the ring holds the inputs at positions n - 4 .. n - 1, position p
+    # at p % 4: none before position 0, and zeros in a position's padding
+    ring = np.asarray(exact["conv"])
+    if n < 4:
+        assert not ring[:, 0, 0, n:].any()
+        assert ring[:, 0, 0, :n].any()
+    assert not ring[:, 0, 0, :, 1:].any()
 
 
 def test_forward_chunk_over_three_chunks_is_one_prefill(cfg, params):
@@ -411,6 +414,8 @@ def test_engine_books_state_updates_and_the_kind_of_row(cfg, served):
     engine, reqs = served
     s = engine.metrics.summary()
     assert s["kv_cache_rows"] == "kv+state" and "moe_experts_hit" not in s
+    # each state update also placed one convolution input in the ring
+    assert s["conv_row_write"] == "kernel"
     n_linear = len(cfg.layers_of("linear"))
     assert s["state_slot_updates"] % n_linear == 0
     substeps = s["state_slot_updates"] // n_linear

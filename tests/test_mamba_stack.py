@@ -137,7 +137,8 @@ def test_the_published_widths_build_whole():
     caches = jax.eval_shape(lambda: init_caches(64, 3072))
     assert caches["state"].shape == (36, 1, 64, 128, 4096)
     assert caches["state"].dtype == jnp.float32
-    assert caches["conv"].shape == (36, 1, 64, 8, 4352)
+    # a ring of K = 4 inputs, each 4,352 channels padded to 40 x 128
+    assert caches["conv"].shape == (36, 1, 64, 4, 40, 128)
     assert caches["full"].shape == (4, 2, 64, 3072, 512)
 
 
@@ -525,6 +526,8 @@ def test_engine_books_mamba_state_updates(cfg, served):
     engine, reqs, _ = served
     s = engine.metrics.summary()
     assert s["kv_cache_rows"] == "kv+state" and "moe_experts_hit" not in s
+    # each state update also placed one convolution input in the ring
+    assert s["conv_row_write"] == "kernel"
     assert s["state_slot_updates"] % 3 == 0  # three mamba layers
     substeps = s["state_slot_updates"] // 3
     assert sum(r.max_new - 1 for r in reqs) <= substeps <= sum(

@@ -450,6 +450,91 @@ def test_serve_step_places_rows_in_the_kernel_and_copies_no_cache(one_chip):
     assert mem.temp_size_in_bytes < cache_bytes // 48
 
 
+# a step program of each state stack at its cell's widths and slots, a
+# vocabulary of 2,048, K = 2: (configuration, slots, rows a slot, the
+# temporaries the step needed when XLA rewrote a ring of 8 positions a
+# tile whole every substep). Granite's keeps its 36 mamba layers: a ring
+# of a few layers fits VMEM, and XLA then stages the whole leaf there and
+# back, which the cell's 189 MB ring does not
+STATE_STEPS = {
+    "olmo": (dict(
+        d_model=3840, n_heads=30, n_layers=3, rope=True,
+        layer_types=("linear_attention",) * 2 + ("full_attention",),
+        norm_eps=1e-6, post_norm=True, qk_norm=True,
+        linear_num_key_heads=30, linear_num_value_heads=30,
+        linear_key_head_dim=96, linear_value_head_dim=192,
+        linear_conv_kernel_dim=4, linear_allow_neg_eigval=True,
+    ), 128, 2048, 145_675_776),
+    "granite": (dict(
+        d_model=2048, n_heads=32, n_kv_heads=8, n_layers=37, rope=False,
+        layer_types=("mamba",) * 36 + ("attention",), mamba_n_heads=64,
+        mamba_d_head=64, mamba_d_state=128, mamba_n_groups=1,
+        mamba_d_conv=4, mamba_expand=2, mamba_conv_bias=True,
+        embedding_multiplier=12.0, residual_multiplier=0.22,
+        attention_multiplier=0.015625, logits_scaling=8.0,
+        tie_word_embeddings=True,
+    ), 64, 3072, 163_793_920),
+}
+
+
+@pytest.mark.parametrize("stack", sorted(STATE_STEPS))
+def test_state_step_places_ring_rows_in_the_kernel_and_copies_no_ring(
+        stack, one_chip):
+    """A state stack's serve step compiled for a v5e: the donated state
+    leaf and ring are aliased to the results, the state kernel runs once
+    a state layer and substep, and nothing of the ring's size is made by
+    a scatter, a fusion or a copy: only parameters (the leaf, and the
+    ring inside the fusions that read a step's K - 1 positions) and the
+    kernel calls' third results carry it. The step's temporaries stay
+    below what a ring rewritten whole every substep needed."""
+    from deeplearning4j_tpu.models.transformer import _decode_builder
+    from deeplearning4j_tpu.serving.engine import build_step_program
+
+    model, slots, total, temp_before = STATE_STEPS[stack]
+    n = model["n_layers"]
+    cfg = TransformerConfig(
+        vocab_size=2048, d_ff=1024, max_len=total, use_flash=True,
+        compute_dtype=jnp.bfloat16, decode_kernel=True,
+        dense_layers=tuple(range(n)), **model)
+    fwd1, init_caches, _, cast = _decode_builder(cfg)
+    k = 2
+
+    def placed(tree):
+        return jax.tree.map(
+            lambda a: S(a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = placed(jax.eval_shape(
+        lambda key: cast(init_transformer(key, cfg)), jax.random.key(0)))
+    caches = placed(jax.eval_shape(lambda: init_caches(slots, total)))
+    avals = (
+        params, caches, S((slots, cfg.vocab_size), jnp.float32),
+        S((slots,), jnp.int32), S((slots,), jnp.bool_),
+        S((slots,), jnp.int32), S((slots,), jnp.int32),
+        S((slots, 2), jnp.uint32), S((slots,), jnp.int32),
+    )
+    avals = avals[:2] + placed(avals[2:])
+    compiled = jax.jit(
+        build_step_program(fwd1, k, 1.0, 40, False),
+        donate_argnums=(1, 2, 3, 4, 5),
+    ).lower(*avals).compile()
+    hlo = compiled.as_text()
+    n_state = len(cfg.layers_of("linear"))
+    assert hlo.count('custom_call_target="tpu_custom_call"') == n * k
+    ring = caches["conv"]
+    values = math.prod(ring.shape)
+    made = {
+        op for dims, op in re.findall(
+            r"= f32\[([0-9,]+)\]\{[^}]*\} ([a-z-]+)\(", hlo)
+        if math.prod(int(d) for d in dims.split(",")) == values
+    }
+    assert made == {"parameter", "get-tuple-element"}, made
+    mem = compiled.memory_analysis()
+    state_bytes = 4 * math.prod(caches["state"].shape)
+    assert mem.alias_size_in_bytes >= state_bytes + 4 * values
+    assert mem.temp_size_in_bytes < temp_before
+    assert n_state == ring.shape[0]
+
+
 def test_lowered_serve_steps_alias_the_cache_and_scatter_no_row():
     """The slab and the paged step at the serve geometry: the kernel's
     call carries the alias, and no scatter or update-slice of a slab's
@@ -553,48 +638,69 @@ def test_packed_flash_compiles_banded_and_unbanded_tiles_for_a_v5e(one_chip):
 # -- the linear decode kernel (PR 38): the state leaf updated in place --------
 
 # linear layers, slots, key_head_dim, heads, value_head_dim: the state leaf
-# of the olmo-hybrid-7b cell (1.7 GB of float32)
+# of the olmo-hybrid-7b cell (1.7 GB of float32); its convolution ring is 4
+# positions of 96 rows of 128 lanes (11,520 channels padded)
 LINEAR_GEOMETRY = (6, 128, 96, 30, 192)
+LINEAR_RING_ROWS = 96
 
 
-def _linear_avals(sharding=None, heads_per_step=None):
+def _ring_avals(s, layers, b, rows):
+    """The ring leaf, the slots' fresh inputs and their positions."""
+    return (s((layers, 1, b, 4, rows, 128)), s((b, rows, 128)),
+            s((b,), jnp.int32))
+
+
+def _linear_avals(sharding=None, heads_per_step=None, ring=True):
     layers, b, dk, h, dv = LINEAR_GEOMETRY
     f32 = jnp.float32
 
     def s(shape, dtype=f32):
         return S(shape, dtype, sharding=sharding)
 
-    def fn(q, k, v, a, be, state, act):
-        return pk.linear_decode(q, k, v, a, be, state, layer=layers - 1,
-                                active=act, heads_per_step=heads_per_step)
+    def fn(q, k, v, a, be, state, act, *conv):
+        return pk.linear_decode(
+            q, k, v, a, be, state, layer=layers - 1, active=act,
+            heads_per_step=heads_per_step,
+            **dict(zip(("ring", "row", "pos"), conv)))
 
-    return fn, (s((b, dk, h)), s((b, dk, h)), s((b, h * dv)), s((b, h)),
-                s((b, h)), s((layers, 1, b, dk, h * dv)),
-                s((b,), jnp.bool_))
+    avals = (s((b, dk, h)), s((b, dk, h)), s((b, h * dv)), s((b, h)),
+             s((b, h)), s((layers, 1, b, dk, h * dv)), s((b,), jnp.bool_))
+    if ring:
+        avals += _ring_avals(s, layers, b, LINEAR_RING_ROWS)
+    return fn, avals
 
 
+def _alias(result: int, operand: int) -> str:
+    return (f"output_operand_alias<output_tuple_indices = [{result}], "
+            f"operand_index = {operand}, operand_tuple_indices = []>")
+
+
+@pytest.mark.parametrize("ring", [False, True], ids=["state", "state+ring"])
 @pytest.mark.parametrize("heads_per_step", [2, 6, 30])
-def test_linear_decode_lowers_with_the_state_aliased(heads_per_step):
+def test_linear_decode_lowers_with_the_state_aliased(heads_per_step, ring):
     """Three prefetched vectors (layer, source slot, job) and five small
-    inputs, then the state leaf, which is the second result."""
-    fn, avals = _linear_avals(heads_per_step=heads_per_step)
+    inputs, then the state leaf, which is the second result; with the
+    ring, a fourth vector (each slot's ring position) and the fresh
+    inputs come in too, and the ring after the state is the third."""
+    fn, avals = _linear_avals(heads_per_step=heads_per_step, ring=ring)
     text = lower_tpu(fn, *avals)
-    assert ("output_operand_alias<output_tuple_indices = [1], "
-            "operand_index = 8, operand_tuple_indices = []>") in text
+    assert _alias(1, 10 if ring else 8) in text
+    assert (_alias(2, 11) in text) == ring
     assert "linear_decode" in text
 
 
 def test_linear_decode_compiles_in_place_for_a_v5e(one_chip):
     """Mosaic accepts the kernel at the cell's widths, 10 heads' 737 KB
     block a grid step, within HALF a v5e's default scoped VMEM (as the
-    latent kernel's test compiles it), and the donated 1.7 GB leaf goes
-    through without a copy."""
+    latent kernel's test compiles it), and the donated 1.7 GB state leaf
+    and the 151 MB ring go through without a copy."""
     fn, avals = _linear_avals(one_chip)
-    compiled = jax.jit(fn, donate_argnums=(5,)).lower(*avals).compile(
+    compiled = jax.jit(fn, donate_argnums=(5, 7)).lower(*avals).compile(
         compiler_options={"xla_tpu_scoped_vmem_limit_kib": "8192"})
     mem = compiled.memory_analysis()
     state_bytes = 4 * math.prod(avals[5].shape)
-    assert mem.alias_size_in_bytes == state_bytes
+    ring_bytes = 4 * math.prod(avals[7].shape)
+    assert mem.alias_size_in_bytes == state_bytes + ring_bytes
     assert mem.temp_size_in_bytes < state_bytes // 128
 
 
@@ -635,49 +741,62 @@ def test_hybrid_step_program_lowers_with_the_state_and_its_counter():
 
 
 # mamba layers, slots, d_state, heads, d_head: the state leaf of the
-# granite-4.0-h-micro cell (4.83 GB of float32), one key group
+# granite-4.0-h-micro cell (4.83 GB of float32), one key group; its ring is
+# 4 positions of 40 rows of 128 lanes (4,352 channels padded)
 SSD_GEOMETRY = (36, 64, 128, 64, 64)
+SSD_RING_ROWS = 40
 
 
-def _ssd_avals(sharding=None, heads_per_step=None, groups=1):
+def _ssd_avals(sharding=None, heads_per_step=None, groups=1, ring=True):
     layers, b, dk, h, dv = SSD_GEOMETRY
     f32 = jnp.float32
 
     def s(shape, dtype=f32):
         return S(shape, dtype, sharding=sharding)
 
-    def fn(q, k, v, a, state, act):
-        return pk.linear_decode(q, k, v, a, None, state, layer=layers - 1,
-                                active=act, heads_per_step=heads_per_step,
-                                rule="additive")
+    def fn(q, k, v, a, state, act, *conv):
+        return pk.linear_decode(
+            q, k, v, a, None, state, layer=layers - 1, active=act,
+            heads_per_step=heads_per_step, rule="additive",
+            **dict(zip(("ring", "row", "pos"), conv)))
 
-    return fn, (s((b, dk, groups)), s((b, dk, groups)), s((b, h * dv)),
-                s((b, h)), s((layers, 1, b, dk, h * dv)), s((b,), jnp.bool_))
+    avals = (s((b, dk, groups)), s((b, dk, groups)), s((b, h * dv)),
+             s((b, h)), s((layers, 1, b, dk, h * dv)), s((b,), jnp.bool_))
+    if ring:
+        avals += _ring_avals(s, layers, b, SSD_RING_ROWS)
+    return fn, avals
 
 
+@pytest.mark.parametrize("ring", [False, True], ids=["state", "state+ring"])
 @pytest.mark.parametrize("heads_per_step, groups", [
     (None, 1), (16, 1), (64, 1), (32, 8)])
-def test_ssd_decode_lowers_with_the_state_aliased(heads_per_step, groups):
+def test_ssd_decode_lowers_with_the_state_aliased(heads_per_step, groups,
+                                                  ring):
     """Three prefetched vectors (layer, source slot, job) and four small
     inputs (C, B, dt x, the decay), then the state leaf, which is the
-    second result; the call is named ``ssd_decode`` in the trace."""
-    fn, avals = _ssd_avals(heads_per_step=heads_per_step, groups=groups)
+    second result; with the ring, the ring positions and the fresh
+    inputs too, and the ring as the third result. The call is named
+    ``ssd_decode`` in the trace."""
+    fn, avals = _ssd_avals(heads_per_step=heads_per_step, groups=groups,
+                           ring=ring)
     text = lower_tpu(fn, *avals)
-    assert ("output_operand_alias<output_tuple_indices = [1], "
-            "operand_index = 7, operand_tuple_indices = []>") in text
+    assert _alias(1, 9 if ring else 7) in text
+    assert (_alias(2, 10) in text) == ring
     assert "ssd_decode" in text and "linear_decode\"" not in text
 
 
 def test_ssd_decode_compiles_in_place_for_a_v5e(one_chip):
     """Mosaic accepts the kernel at the cell's widths, 32 heads' 1 MB
     block a grid step, within half a v5e's default scoped VMEM, and the
-    donated 4.83 GB leaf goes through without a copy."""
+    donated 4.83 GB state leaf and the 189 MB ring go through without a
+    copy."""
     fn, avals = _ssd_avals(one_chip)
-    compiled = jax.jit(fn, donate_argnums=(4,)).lower(*avals).compile(
+    compiled = jax.jit(fn, donate_argnums=(4, 6)).lower(*avals).compile(
         compiler_options={"xla_tpu_scoped_vmem_limit_kib": "8192"})
     mem = compiled.memory_analysis()
     state_bytes = 4 * math.prod(avals[4].shape)
-    assert mem.alias_size_in_bytes == state_bytes
+    ring_bytes = 4 * math.prod(avals[6].shape)
+    assert mem.alias_size_in_bytes == state_bytes + ring_bytes
     assert mem.temp_size_in_bytes < state_bytes // 128
 
 
