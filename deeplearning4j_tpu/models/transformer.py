@@ -963,6 +963,30 @@ def kv_row_write(cfg: "TransformerConfig") -> str:
     return "kernel" if cfg.decode_kernel and not cfg.decode_int8 else "xla"
 
 
+def moe_grouped(cfg: "TransformerConfig", positions: int = 1) -> str:
+    """What computes the held experts' SwiGLU in a program whose layers
+    see ``positions`` positions a row: ``"streamed"``
+    (``moe_ragged_dot``: each hit expert's weights streamed once, its
+    few rows held in VMEM) in a decode step (one position a row) where
+    the decode kernels run, since a step's handful of rows an expert
+    would leave ``lax.ragged_dot``'s 128-row tiles nearly empty;
+    ``"ragged"`` (three ``lax.ragged_dot`` calls) otherwise: prefills
+    and chunks, whose programs (one a bucket) stay free of the kernel's
+    compile, and the dense path (``decode_kernel=False``: tp, LoRA
+    banks); ``"none"`` for a stack without routed layers. On a v5e at
+    both expert cells' widths the kernel is the faster up to 24 rows an
+    expert and level at 32 (scripts/moe_decode_bench.py; PERF.md
+    section 6), far beyond what a chip's slots route in a step. A fact
+    of the configuration and the shape, like :func:`kv_row_write`; the
+    engine reports it for its step programs."""
+    if not (cfg.gated and cfg.n_experts and any(
+            l not in cfg.dense_layers for l in range(cfg.n_layers))):
+        return "none"
+    if cfg.decode_kernel and positions == 1:
+        return "streamed"
+    return "ragged"
+
+
 def conv_row_write(cfg: "TransformerConfig") -> str | None:
     """Who places a decode substep's fresh convolution input in the
     ring of a stack with linear_attention or mamba layers: ``"kernel"``
@@ -2138,6 +2162,7 @@ def _gated_block(cfg: TransformerConfig, l: int, p, x, positions, attend,
             _w(p, "we_up", dt), _w(p, "we_down", dt), first=cfg.expert_first,
             k=cfg.moe_k, scale=cfg.moe_scale, score=cfg.moe_score,
             live=None if live is None else jnp.repeat(live, t),
+            grouped=moe_grouped(cfg, t),
         )
         y = y.reshape(b, t, d)
         if cfg.d_shared:

@@ -2377,6 +2377,306 @@ def flash_decode_attention_paged(
     )(jnp.asarray(tables, jnp.int32), *operands)
 
 
+# -- grouped expert SwiGLU (the held experts of a decode step) ----------------
+#
+# ``lax.ragged_dot`` tiles its rows by 128: at the 2-7 rows a hit expert
+# sees in a decode step every 512 x 512 weight tile is paired with a
+# mostly empty row tile that is fetched again for each weight tile, and
+# gate and up are two passes (PERF.md section 6). The kernel below
+# keeps an expert's few rows in VMEM and streams nothing but the weights
+# of the experts that have rows, each once, in one sequence of blocks.
+
+# Rows of one chunk: an expert's rows start at a multiple of it in the
+# kernel's row layout (Mosaic refuses a copy of rows from a dynamic start
+# it cannot prove a multiple of the (8, 128) tiling), and each resident
+# weight block multiplies the rows a chunk at a time. Chunks of 8 and 16
+# measured alike on a v5e; 8 pads the layout less.
+MOE_CHUNK = 8
+# Rows of one expert held at once (its f32 accumulators): an expert with
+# more rows is computed in several passes, each streaming its weights.
+MOE_UNIT_ROWS = 32
+# Bytes of one weight block (gate and up rows together, or down rows);
+# three are in flight or in use at once (_WALK_BUFFERS) of each kind.
+_MOE_BLOCK_BYTES = 1 << 20
+
+
+def moe_stream_rows(pairs: int, groups: int) -> int:
+    """Rows of :func:`moe_ragged_dot`'s layout for ``pairs`` rows in
+    ``groups`` groups: each group's rows rounded up to ``MOE_CHUNK``, at
+    most ``min(groups, pairs)`` of them not empty."""
+    rows = pairs + (MOE_CHUNK - 1) * min(groups, pairs)
+    return -(-rows // MOE_CHUNK) * MOE_CHUNK
+
+
+def moe_group_starts(sizes: jax.Array) -> jax.Array:
+    """(E,) int32: the first row of each group in :func:`moe_ragged_dot`'s
+    layout, the groups in order, each ``sizes[e]`` rows rounded up to
+    ``MOE_CHUNK``."""
+    chunks = -(-sizes // MOE_CHUNK)
+    return (MOE_CHUNK * (jnp.cumsum(chunks) - chunks)).astype(jnp.int32)
+
+
+def _moe_block_rows(width: int, row_bytes: int) -> int:
+    """Rows of a weight block over a ``width``-row matrix whose row
+    (with its partner's, gate beside up) is ``row_bytes``: the largest
+    multiple of 128 that divides ``width`` within ``_MOE_BLOCK_BYTES``,
+    at least 128; the whole matrix where 128 does not divide it (the
+    block's rows are lanes of the rows it multiplies)."""
+    if width % 128:
+        return width
+    fits = [r for r in range(128, width + 1, 128)
+            if width % r == 0 and r * row_bytes <= _MOE_BLOCK_BYTES]
+    return max(fits, default=128)
+
+
+def _moe_units(sizes: jax.Array, pairs: int):
+    """The passes of :func:`moe_ragged_dot`: ``(expert, first row,
+    chunks)`` int32 (3, U) and their count (1,). Group e takes
+    ``ceil(sizes[e] / MOE_UNIT_ROWS)`` passes of up to ``MOE_UNIT_ROWS``
+    rows; U bounds the count for any ``sizes`` that sum to ``pairs`` or
+    fewer."""
+    e_held = sizes.shape[0]
+    per = -(-sizes // MOE_UNIT_ROWS)
+    cum = jnp.cumsum(per)
+    u = jnp.arange(min(e_held, pairs) + pairs // MOE_UNIT_ROWS,
+                   dtype=jnp.int32)
+    expert = jnp.minimum(jnp.sum(cum[None, :] <= u[:, None], axis=1),
+                         e_held - 1)
+    m = u - (cum[expert] - per[expert])
+    rows = jnp.clip(sizes[expert] - m * MOE_UNIT_ROWS, 0, MOE_UNIT_ROWS)
+    units = jnp.stack([
+        expert,
+        moe_group_starts(sizes)[expert] + m * MOE_UNIT_ROWS,
+        -(-rows // MOE_CHUNK),
+    ]).astype(jnp.int32)
+    return units, cum[-1:].astype(jnp.int32)
+
+
+def _moe_stream_kernel(units_ref, n_ref, x_hbm, wg_hbm, wu_hbm, wd_hbm,
+                       o_hbm, xbuf, gbuf, dbuf, acc_g, acc_u, abuf, acc_d,
+                       obuf, xsem, osem, gsem, dsem, pend, *, d_block: int,
+                       f_block: int):
+    """All passes of :func:`moe_ragged_dot` in one invocation. A pass is
+    one expert's rows (up to ``MOE_UNIT_ROWS``, in ``MOE_CHUNK``-row
+    chunks): its gate and up blocks ((d_block, F) rows of both, read
+    together), then its down blocks ((f_block, D) rows). The blocks of
+    ALL passes form one sequence, and the copy of the block
+    ``_WALK_BUFFERS - 1`` places ahead starts before a block's
+    arithmetic, so the pipeline never drains between experts. Each kind
+    of block has ``_WALK_BUFFERS`` buffers; the block k-th of its kind
+    uses buffer k % _WALK_BUFFERS. A pass's rows come in while the pass
+    before it runs its down blocks (they are read by gate and up
+    alone); its results go out while the next pass runs, ``pend``
+    counting the chunks in flight."""
+    d, f = xbuf.shape[1], abuf.shape[1]
+    n_gu, n_d = d // d_block, f // f_block
+    nb = n_gu + n_d
+    n = n_ref[0]
+    dt = x_hbm.dtype
+
+    def rows(c):
+        return pl.ds(pl.multiple_of(c * MOE_CHUNK, MOE_CHUNK), MOE_CHUNK)
+
+    def x_copy(u, c):
+        start = pl.multiple_of(units_ref[1, u] + c * MOE_CHUNK, MOE_CHUNK)
+        return pltpu.make_async_copy(
+            x_hbm.at[pl.ds(start, MOE_CHUNK)], xbuf.at[rows(c)], xsem.at[0])
+
+    def o_copy(u, c):
+        start = pl.multiple_of(units_ref[1, u] + c * MOE_CHUNK, MOE_CHUNK)
+        return pltpu.make_async_copy(
+            obuf.at[rows(c)], o_hbm.at[pl.ds(start, MOE_CHUNK)], osem.at[0])
+
+    def gu_copies(u, b, slot):
+        at = pl.ds(pl.multiple_of(b * d_block, d_block), d_block)
+        e = units_ref[0, u]
+        return [
+            pltpu.make_async_copy(w.at[e, at], gbuf.at[slot, i],
+                                  gsem.at[slot, i])
+            for i, w in enumerate((wg_hbm, wu_hbm))
+        ]
+
+    def d_copy(u, b, slot):
+        at = pl.ds(pl.multiple_of(b * f_block, f_block), f_block)
+        return pltpu.make_async_copy(
+            wd_hbm.at[units_ref[0, u], at], dbuf.at[slot], dsem.at[slot])
+
+    def each_chunk(u, fn):
+        def body(c, carry):
+            fn(c)
+            return carry
+        jax.lax.fori_loop(0, units_ref[2, u], body, 0)
+
+    def fetch(g):
+        """Start the copy of block ``g`` of the sequence, if any."""
+        @pl.when(g < n * nb)
+        def _():
+            u, b = g // nb, g % nb
+
+            @pl.when(b < n_gu)
+            def _():
+                for cp in gu_copies(u, b, (u * n_gu + b) % _WALK_BUFFERS):
+                    cp.start()
+
+            @pl.when(b >= n_gu)
+            def _():
+                k = b - n_gu
+                d_copy(u, k, (u * n_d + k) % _WALK_BUFFERS).start()
+
+    def settle():
+        """Every write-back in flight has landed (they all move one
+        chunk, so one descriptor of that shape waits for any)."""
+        def body(c, carry):
+            o_copy(0, 0).wait()
+            return carry
+        jax.lax.fori_loop(0, pend[0], body, 0)
+        pend[0] = 0
+
+    pend[0] = 0
+
+    @pl.when(n > 0)
+    def _prime():
+        each_chunk(0, lambda c: x_copy(0, c).start())
+        for g in range(_WALK_BUFFERS - 1):
+            fetch(g)
+
+    def run(u, carry):
+        each_chunk(u, lambda c: x_copy(u, c).wait())
+
+        def gate_up(b, carry):
+            slot = (u * n_gu + b) % _WALK_BUFFERS
+            for cp in gu_copies(u, b, slot):
+                cp.wait()
+            fetch(u * nb + b + _WALK_BUFFERS - 1)
+            cols = pl.ds(pl.multiple_of(b * d_block, d_block), d_block)
+
+            def chunk(c):
+                xc = xbuf[rows(c), cols]
+                g = jnp.dot(xc, gbuf[slot, 0],
+                            preferred_element_type=jnp.float32)
+                up = jnp.dot(xc, gbuf[slot, 1],
+                             preferred_element_type=jnp.float32)
+                first = b == 0
+                acc_g[rows(c)] = jnp.where(first, g, acc_g[rows(c)] + g)
+                acc_u[rows(c)] = jnp.where(first, up, acc_u[rows(c)] + up)
+
+            each_chunk(u, chunk)
+            return carry
+
+        jax.lax.fori_loop(0, n_gu, gate_up, 0)
+
+        def middle(c):
+            # rounded where lax.ragged_dot returns the products
+            g = acc_g[rows(c)].astype(dt).astype(jnp.float32)
+            up = acc_u[rows(c)].astype(dt).astype(jnp.float32)
+            abuf[rows(c)] = (g * jax.nn.sigmoid(g) * up).astype(dt)
+
+        each_chunk(u, middle)
+
+        @pl.when(u + 1 < n)
+        def _():
+            each_chunk(u + 1, lambda c: x_copy(u + 1, c).start())
+
+        def down(b, carry):
+            slot = (u * n_d + b) % _WALK_BUFFERS
+            d_copy(u, b, slot).wait()
+            fetch(u * nb + n_gu + b + _WALK_BUFFERS - 1)
+            cols = pl.ds(pl.multiple_of(b * f_block, f_block), f_block)
+
+            def chunk(c):
+                y = jnp.dot(abuf[rows(c), cols], dbuf[slot],
+                            preferred_element_type=jnp.float32)
+                acc_d[rows(c)] = jnp.where(b == 0, y, acc_d[rows(c)] + y)
+
+            each_chunk(u, chunk)
+            return carry
+
+        jax.lax.fori_loop(0, n_d, down, 0)
+        settle()
+
+        def out(c):
+            obuf[rows(c)] = acc_d[rows(c)].astype(obuf.dtype)
+            o_copy(u, c).start()
+
+        each_chunk(u, out)
+        pend[0] = units_ref[2, u]
+        return carry
+
+    jax.lax.fori_loop(0, n, run, 0)
+    settle()
+
+
+def moe_ragged_dot(x: jax.Array, w_gate: jax.Array, w_up: jax.Array,
+                   w_down: jax.Array, sizes: jax.Array,
+                   interpret: bool | None = None) -> jax.Array:
+    """The SwiGLU of each expert over its own rows, for a handful of rows
+    an expert: ``out[r] = (silu(x[r] W_g,e) * (x[r] W_u,e)) W_d,e`` for
+    every row r of group e. What three ``lax.ragged_dot`` calls and the
+    product between them compute, with the rounding where they round
+    (gate and up to ``x``'s dtype, the down product accumulated in
+    float32), in one Pallas call that streams the weights of each expert
+    with rows once (``w_gate`` / ``w_up`` (E, D, F), ``w_down`` (E, F,
+    D), all in HBM) and never re-reads a row.
+
+    ``sizes`` (E,) int32: rows of each group. ``x`` (P, D) holds group
+    e's rows from row ``moe_group_starts(sizes)[e]`` on (each group
+    rounded up to ``MOE_CHUNK`` rows; P from :func:`moe_stream_rows`).
+    Returns (P, D) in ``x``'s dtype: the groups' rows computed, the
+    padding rows between them and rows past the last group undefined. An
+    expert without rows costs no copy and no step; one with more than
+    ``MOE_UNIT_ROWS`` rows is computed in passes, each streaming its
+    weights again (any skew is correct, the common case fast)."""
+    return _moe_call(
+        x, w_gate, w_up, w_down, sizes,
+        interpret=_default_interpret() if interpret is None else interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _moe_call(x, w_gate, w_up, w_down, sizes, *, interpret):
+    """:func:`moe_ragged_dot` as one jitted function of the shapes, so
+    that a step program that calls it once a layer and substep traces
+    the kernel once."""
+    p, d = x.shape
+    f = w_gate.shape[2]
+    assert p % MOE_CHUNK == 0 and MOE_UNIT_ROWS % MOE_CHUNK == 0, p
+    units, n = _moe_units(sizes.astype(jnp.int32), p)
+    item = w_gate.dtype.itemsize
+    d_block = _moe_block_rows(d, 2 * f * item)
+    f_block = _moe_block_rows(f, d * item)
+    m = MOE_UNIT_ROWS
+    f32 = jnp.float32
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    return pl.pallas_call(
+        functools.partial(_moe_stream_kernel, d_block=d_block,
+                          f_block=f_block),
+        out_shape=jax.ShapeDtypeStruct((p, d), x.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(1,),
+            in_specs=[hbm] * 4,
+            out_specs=hbm,
+            scratch_shapes=[
+                pltpu.VMEM((m, d), x.dtype),
+                pltpu.VMEM((_WALK_BUFFERS, 2, d_block, f), w_gate.dtype),
+                pltpu.VMEM((_WALK_BUFFERS, f_block, d), w_down.dtype),
+                pltpu.VMEM((m, f), f32),
+                pltpu.VMEM((m, f), f32),
+                pltpu.VMEM((m, f), x.dtype),
+                pltpu.VMEM((m, d), f32),
+                pltpu.VMEM((m, d), x.dtype),
+                pltpu.SemaphoreType.DMA((1,)),
+                pltpu.SemaphoreType.DMA((1,)),
+                pltpu.SemaphoreType.DMA((_WALK_BUFFERS, 2)),
+                pltpu.SemaphoreType.DMA((_WALK_BUFFERS,)),
+                pltpu.SMEM((1,), jnp.int32),
+            ],
+        ),
+        compiler_params=_dim_semantics(interpret, ("arbitrary",)),
+        interpret=interpret,
+        name="moe_ragged_dot",
+    )(units, n, x, w_gate, w_up, w_down)
+
+
 # -- fused embedding dot (word2vec HS read side) ------------------------------
 
 def _emb_dot_kernel(h_ref, w_ref, mask_ref, out_ref):

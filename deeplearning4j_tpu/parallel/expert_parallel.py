@@ -21,6 +21,7 @@ Design notes (TPU-first):
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import jax
@@ -324,7 +325,8 @@ def swiglu(h, w_gate, w_up, w_down):
 
 
 def moe_held_ffn(h, router, w_gate, w_up, w_down, *, first: int, k: int,
-                 scale: float, live=None, score: str = "softmax"):
+                 scale: float, live=None, score: str = "softmax",
+                 grouped: str = "ragged"):
     """The held experts' part of a routed SwiGLU layer, routed by
     :func:`route_top_k` (``score``: softmax or sigmoid, then top k).
 
@@ -335,7 +337,15 @@ def moe_held_ffn(h, router, w_gate, w_up, w_down, *, first: int, k: int,
     counts)``: ``y`` (N, D) = sum over the token's top-k experts THAT ARE
     HELD of ``w_e F_e(h)`` (nothing stands in for the others), and
     ``counts`` int32 (3,): token-expert pairs computed here, pairs routed
-    in all (k x live rows), held experts with at least one row."""
+    in all (k x live rows), held experts with at least one row.
+
+    ``grouped`` says what computes the experts' SwiGLU over the sorted
+    pairs (``models.transformer.moe_grouped`` chooses): ``"ragged"``,
+    three ``lax.ragged_dot`` calls; ``"streamed"``, one
+    ``moe_ragged_dot`` kernel, which streams each hit expert's weights
+    once and holds its few rows in VMEM (the pairs laid out with each
+    expert's rows at a multiple of ``MOE_CHUNK``). The same numbers
+    either way, to the rounding of the float32 sums."""
     n, d = h.shape
     e_held = w_gate.shape[0]
     ids, weights = route_top_k(h, router, k=k, scale=scale, score=score)
@@ -346,24 +356,34 @@ def moe_held_ffn(h, router, w_gate, w_up, w_down, *, first: int, k: int,
     local = jnp.where(here, ids - first, e_held).reshape(-1)
     order = jnp.argsort(local, stable=True)
     sizes = jnp.bincount(local, length=e_held + 1)[:e_held].astype(jnp.int32)
-    # rows of the grouped products: the sorted pairs, padded to an ODD
-    # number of 128-row tiles. XLA:TPU tiles a ragged dot's rows by the
-    # largest of 512 / 256 / 128 that divides them, and a tile computes
-    # all its rows for every group it touches: at 512 rows a tile a
-    # 1,024-token chunk's products were bound by that waste (2.7 ms a
-    # call against 1.3 ms for a decode step's 640 rows; PERF.md, PR 27)
-    tiles = -(-n * k // 128) | 1
-    rows = jnp.zeros((tiles * 128,), order.dtype).at[:n * k].set(order // k)
-    x = h[rows]  # (tiles * 128, D); the padding rows belong to no group
-    a = jax.nn.silu(lax.ragged_dot(x, w_gate, sizes)) * lax.ragged_dot(
-        x, w_up, sizes
-    )
-    out = lax.ragged_dot(a.astype(h.dtype), w_down, sizes)[:n * k]
-    # back to token order by a gather, then a sum over the token's k
-    # pairs in one fixed order (a scatter-add's is not); rows past the
-    # last group hold whatever the product left there, so they are
-    # selected away, not multiplied by zero
-    back = jnp.zeros_like(order).at[order].set(jnp.arange(n * k))
+    # back: the product's row of each pair in token order, for a gather
+    # and then a sum over the token's k pairs in one fixed order (a
+    # scatter-add's is not); rows of pairs on no held expert hold
+    # whatever the product left there, so they are selected away, not
+    # multiplied by zero
+    if grouped == "streamed":
+        out, back = _streamed(h, w_gate, w_up, w_down, local[order], order,
+                              sizes, k)
+    elif grouped == "ragged":
+        # rows of the grouped products: the sorted pairs, padded to an
+        # ODD number of 128-row tiles. XLA:TPU tiles a ragged dot's rows
+        # by the largest of 512 / 256 / 128 that divides them, and a tile
+        # computes all its rows for every group it touches: at 512 rows a
+        # tile a 1,024-token chunk's products were bound by that waste
+        # (2.7 ms a call against 1.3 ms for a decode step's 640 rows, on
+        # a v5e)
+        tiles = -(-n * k // 128) | 1
+        rows = jnp.zeros((tiles * 128,), order.dtype).at[:n * k].set(
+            order // k)
+        x = h[rows]  # (tiles * 128, D); the padding rows belong to no group
+        a = jax.nn.silu(lax.ragged_dot(x, w_gate, sizes)) * lax.ragged_dot(
+            x, w_up, sizes
+        )
+        out = lax.ragged_dot(a.astype(h.dtype), w_down, sizes)[:n * k]
+        back = jnp.zeros_like(order).at[order].set(jnp.arange(n * k))
+    else:
+        raise ValueError(
+            f"grouped is 'ragged' or 'streamed', got {grouped!r}")
     out = jnp.where(here[..., None], out[back].reshape(n, k, d), 0)
     y = jnp.einsum(
         "nkd,nk->nd", out, weights.astype(out.dtype),
@@ -374,3 +394,30 @@ def moe_held_ffn(h, router, w_gate, w_up, w_down, *, first: int, k: int,
         jnp.sum(here), k * n_live, jnp.sum(sizes > 0),
     ]).astype(jnp.int32)
     return y.astype(h.dtype), counts
+
+
+@functools.partial(jax.jit, static_argnames=("k",))
+def _streamed(h, w_gate, w_up, w_down, expert, order, sizes, k: int):
+    """The held experts' SwiGLU of the sorted pairs by ``moe_ragged_dot``.
+    ``expert`` (N*k,): the held expert of each sorted pair (``E_held``:
+    none). Returns ``(out, back)``: the kernel's rows and each pair's
+    row among them, in token order (0 for a pair on no held expert).
+    Jitted, so that a step program traces it once for all its expert
+    layers and substeps."""
+    from deeplearning4j_tpu.ops.pallas_kernels import (
+        moe_group_starts,
+        moe_ragged_dot,
+        moe_stream_rows,
+    )
+
+    pairs, e_held = expert.shape[0], sizes.shape[0]
+    p = moe_stream_rows(pairs, e_held)
+    held = expert < e_held
+    e = jnp.minimum(expert, e_held - 1)
+    # group e's sorted pairs start at row cumsum(sizes)[e] - sizes[e];
+    # in the kernel's layout at moe_group_starts(sizes)[e]
+    shift = moe_group_starts(sizes) - (jnp.cumsum(sizes) - sizes)
+    at = jnp.where(held, jnp.arange(pairs) + shift[e], p)
+    rows = jnp.zeros((p,), order.dtype).at[at].set(order // k, mode="drop")
+    out = moe_ragged_dot(h[rows], w_gate, w_up, w_down, sizes)
+    return out, jnp.zeros_like(order).at[order].set(jnp.where(held, at, 0))

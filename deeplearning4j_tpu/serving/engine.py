@@ -138,6 +138,7 @@ from deeplearning4j_tpu.models.transformer import (
     kv_row_write,
     make_paged_fwd1,
     min_bucket,
+    moe_grouped,
     paged_block_copy,
     paged_slot_gather,
     paged_slot_scatter,
@@ -1453,6 +1454,7 @@ class ServingEngine:
         self.metrics.kv_row_write = kv_row_write(cfg)
         self.metrics.kv_cache_rows = kv_cache_rows(cfg)
         self.metrics.conv_row_write = conv_row_write(cfg)
+        self.metrics.moe_grouped = moe_grouped(cfg)
         if cfg.linear:
             # one (slot, layer) state block: key channels (key_head_dim,
             # a mamba layer's d_state) x the heads' value lanes, float32
@@ -1815,6 +1817,14 @@ class ServingEngine:
             "ones).",
             labelnames=("how",),
         ).set(1, how=self.metrics.kv_cache_rows)
+        reg.gauge(
+            "serve_moe_grouped",
+            "What computes the held experts' SwiGLU in the step "
+            "programs, decided when they are traced: streamed (one "
+            "kernel streams each hit expert's weights once), ragged "
+            "(three lax.ragged_dot calls) or none (no routed layers).",
+            labelnames=("how",),
+        ).set(1, how=self.metrics.moe_grouped)
         if self.metrics.conv_row_write is not None:
             reg.gauge(
                 "serve_conv_row_write",

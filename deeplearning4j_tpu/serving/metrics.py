@@ -193,6 +193,9 @@ class ServingMetrics:
         # who places a substep's convolution input in a state stack's
         # ring (transformer.conv_row_write; None: no state layers)
         self.conv_row_write = None
+        # what computes the held experts' SwiGLU in the step programs
+        # (transformer.moe_grouped; "none": no routed layers)
+        self.moe_grouped = "none"
         self.n_finished = 0
         self.n_generated = 0
         # fault-tolerance counters (see serving.faults / engine docs):
@@ -916,6 +919,7 @@ class ServingMetrics:
             "topk_select": self.topk_select,
             "kv_row_write": self.kv_row_write,
             "kv_cache_rows": self.kv_cache_rows,
+            "moe_grouped": self.moe_grouped,
         }
         lookups = (self.n_prefix_hits_full + self.n_prefix_hits_partial
                    + self.n_prefix_misses)

@@ -31,6 +31,7 @@ from deeplearning4j_tpu.models.transformer import (  # noqa: E402
     decode_rows_live,
     decode_rows_streamed,
     init_transformer,
+    moe_grouped,
     transformer_generate,
 )
 from deeplearning4j_tpu.parallel.expert_parallel import (  # noqa: E402
@@ -129,6 +130,10 @@ def test_prefill_then_decode_matches_the_reference(
     """Rows of 9 to 64 tokens in one bucket of 64, then 8 decode steps
     through both leaves: every ring has wrapped (window 16)."""
     cfg = dataclasses.replace(cfg, decode_kernel=kernel)
+    # the decode steps' 4 rows take the streamed expert kernel, the
+    # prefill's 4 rows of 64 positions lax.ragged_dot
+    assert moe_grouped(cfg, 1) == ("streamed" if kernel else "ragged")
+    assert moe_grouped(cfg, 64) == "ragged"
     fwd1, init_caches, prefill, _ = _decode_builder(cfg)
     lens = np.asarray([9, 20, 40, 64], np.int32)
     caches, lg = jax.jit(prefill)(
@@ -383,7 +388,10 @@ def test_engine_books_the_expert_layers_and_both_leaves(cfg, served):
     assert 0 < s["moe_assignments_local"] < s["moe_assignments_total"]
     assert 0 < s["moe_experts_hit"] <= s["moe_assignments_local"]
     assert 0 < s["kv_rows_live"] <= s["kv_rows_streamed"]
+    # the step programs' experts went through the streamed kernel
+    assert s["moe_grouped"] == "streamed"
     text = engine.metrics.registry.render()
+    assert 'serve_moe_grouped{how="streamed"} 1' in text
     for name in ("serve_moe_assignments_local_total",
                  "serve_moe_assignments_total", "serve_moe_experts_hit_total"):
         assert name in text

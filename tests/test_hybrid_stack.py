@@ -414,6 +414,7 @@ def test_engine_books_state_updates_and_the_kind_of_row(cfg, served):
     engine, reqs = served
     s = engine.metrics.summary()
     assert s["kv_cache_rows"] == "kv+state" and "moe_experts_hit" not in s
+    assert s["moe_grouped"] == "none"
     # each state update also placed one convolution input in the ring
     assert s["conv_row_write"] == "kernel"
     n_linear = len(cfg.layers_of("linear"))

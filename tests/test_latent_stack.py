@@ -34,6 +34,7 @@ from deeplearning4j_tpu.models.transformer import (  # noqa: E402
     init_transformer,
     kv_cache_rows,
     kv_row_write,
+    moe_grouped,
     transformer_generate,
 )
 from deeplearning4j_tpu.ops.pallas_kernels import (  # noqa: E402
@@ -162,6 +163,10 @@ def test_prefill_then_decode_matches_the_reference(
     then 8 decode steps through the latent leaf (the absorbed form),
     against the reference's expanded full forward."""
     cfg = dataclasses.replace(cfg, decode_kernel=kernel)
+    # the decode steps' 4 rows take the streamed expert kernel, the
+    # prefill's 4 rows of 64 positions lax.ragged_dot
+    assert moe_grouped(cfg, 1) == ("streamed" if kernel else "ragged")
+    assert moe_grouped(cfg, 64) == "ragged"
     fwd1, init_caches, prefill, _ = _decode_builder(cfg)
     lens = np.asarray([9, 20, 40, 64], np.int32)
     caches, lg = jax.jit(prefill)(
@@ -642,8 +647,10 @@ def test_engine_books_rows_experts_and_the_kind_of_row(cfg, served):
     engine, reqs = served
     s = engine.metrics.summary()
     assert s["kv_cache_rows"] == "latent" and s["kv_row_write"] == "kernel"
+    assert s["moe_grouped"] == "streamed"
     text = engine.metrics.registry.render()
     assert 'serve_kv_cache_rows{how="latent"} 1' in text
+    assert 'serve_moe_grouped{how="streamed"} 1' in text
     assert 'serve_kv_cache_rows{how="kv"}' not in text
     routed = cfg.n_layers - len(cfg.dense_layers)
     substeps = s["moe_assignments_total"] // (cfg.moe_k * routed)

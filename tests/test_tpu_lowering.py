@@ -836,3 +836,43 @@ def test_mamba_step_program_lowers_with_the_state_and_its_counter():
     assert "ssd_decode" in text
     out = jax.eval_shape(step, *avals)
     assert out[5].shape == (slots + 1, k)
+
+
+# the expert cells' decode substep: token-expert pairs, held experts, d,
+# expert width (laguna-s-2.1 and openpangu-ultra-moe-718b, one layer)
+MOE_GEOMETRIES = {
+    "laguna": (640, 128, 3072, 1024),
+    "pangu": (1792, 8, 7680, 2048),
+}
+
+
+def _moe_avals(name, sharding=None):
+    pairs, e, d, f = MOE_GEOMETRIES[name]
+
+    def s(shape, dtype=jnp.bfloat16):
+        return S(shape, dtype, sharding=sharding)
+
+    return (s((pk.moe_stream_rows(pairs, e), d)), s((e, d, f)),
+            s((e, d, f)), s((e, f, d)), s((e,), jnp.int32))
+
+
+@pytest.mark.parametrize("name", sorted(MOE_GEOMETRIES))
+def test_streamed_experts_lower(name):
+    """Two prefetched vectors (the passes, their count), the rows and
+    the three weight stacks in HBM: one Mosaic call named for the
+    grouped products, which the benchmark's readers find by name."""
+    assert "moe_ragged_dot" in lower_tpu(pk.moe_ragged_dot,
+                                         *_moe_avals(name))
+
+
+@pytest.mark.parametrize("name", sorted(MOE_GEOMETRIES))
+def test_streamed_experts_compile_for_a_v5e(name, one_chip):
+    """Mosaic accepts the kernel at the cells' widths (the rows' 8-row
+    chunks at dynamic offsets, gate and up blocks of 256 / 128 rows and
+    down blocks of 128, three of each kind) within a v5e's default 16
+    MiB of scoped VMEM: Laguna's asks for about 7 MiB, openPangu's for
+    11.1 MiB (its down blocks are 1.97 MB)."""
+    compiled = jax.jit(pk.moe_ragged_dot).lower(
+        *_moe_avals(name, one_chip)).compile(
+        compiler_options={"xla_tpu_scoped_vmem_limit_kib": "16384"})
+    assert "moe_ragged_dot" in compiled.as_text()
