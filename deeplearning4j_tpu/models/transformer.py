@@ -911,6 +911,13 @@ def serving_tp_cache_sharding(mesh: Mesh, cfg: TransformerConfig):
 
 
 def _layer_norm(x, scale, bias, eps=1e-5):
+    if x.ndim == 3 and x.shape[1] == 1:
+        # one position a row (the decode step): normed as (B, D). Over
+        # (B, 1, D) XLA:TPU fuses the mean's sum apart from the residual
+        # add that makes x, five small fusions a norm where (B, D) takes
+        # three (the gpt2-large step compiled for a v5e: 161 fusions at
+        # two layers and K = 4 against 141)
+        return _layer_norm(x[:, 0], scale, bias, eps)[:, None]
     x32 = x.astype(jnp.float32)
     mu = jnp.mean(x32, axis=-1, keepdims=True)
     var = jnp.var(x32, axis=-1, keepdims=True)
@@ -1116,10 +1123,8 @@ def _flash_bwd_blocks(t: int) -> tuple[int, int] | tuple[None, None]:
 
 
 def _project_qkv(cfg: TransformerConfig, p, h_in):
-    """Shared QKV projection for all sequence-shaped forwards (training
-    block and bulk prefill): h_in (B, T, D) -> q (B, H, T, K) and the
-    UNexpanded k/v (B, H_kv, T, K). One implementation so GQA/MHA
-    layouts cannot drift between the paths."""
+    """The GPT block's QKV projection (:func:`_gpt_block`): h_in (B, T,
+    D) -> q (B, H, T, K) and the UNexpanded k/v (B, H_kv, T, K)."""
     if cfg.kv_heads != cfg.n_heads:
         q = jnp.einsum("btd,dhk->bhtk", h_in, _w(p, "wq", h_in.dtype))
         kv = jnp.einsum(
@@ -1296,6 +1301,104 @@ def _lora_delta(h_in, a, b):
     return jnp.einsum("btr,bro->bto", u, b.astype(h_in.dtype))
 
 
+def _rope_over_heads(positions, head_dim: int, dtype):
+    """:func:`_rope_tables` at ``positions`` (T,) shared or (B, T) per
+    row, laid over the head axis of (B, H, T, K) activations."""
+    cos, sin = _rope_tables(positions, head_dim, dtype)
+    if cos.ndim == 3:
+        return cos[:, None], sin[:, None]
+    return cos[None, None], sin[None, None]
+
+
+def _gpt_block(cfg: TransformerConfig, p, x, positions, attend, *,
+               packed: bool = False, tp_mesh=None, lora=None, adapter=None,
+               ffn=None):
+    """The GPT block over ``x`` (B, T, D), written once: training,
+    prefill, the decode step and the chunk each hand it their own
+    ``attend``, as the gated stacks hand theirs to :func:`_gated_block`.
+
+    LayerNorm, the QKV projection, a LoRA bank's q delta, the rotary,
+    ``attend(q, k, v) -> o``, the output projection and the residual;
+    LayerNorm, the FFN (the experts, the LoRA-gated MLP or the MLP) and
+    the residual. q is (B, H, T, K) and k, v the unexpanded (B, Hkv, T,
+    K), rotated; with ``packed`` (the training block under
+    ``flash_layout`` "packed") each is (B, T, H*K), as the packed flash
+    kernels take them. ``o`` comes back as (B, H, T, K) or with its
+    heads side by side, (B, T, H*K); either meets ``wo`` without a copy.
+
+    ``positions``, where the rotary turns q and k: (T,) shared or (B, T)
+    per row; None for 0 .. T-1, made here (training, inside its
+    checkpointed block); or the (cos, sin) tables over the heads
+    themselves (prefill makes them once, outside its scan).
+
+    ``lora`` (this layer's slice of an :func:`init_lora_bank` bank) with
+    ``adapter`` (B,) int adds each row's q delta before the rotary,
+    where a merged ``wq + AB`` would land it, and its MLP delta before
+    the gelu. Rows with adapter 0 SELECT the untouched base activations
+    (``jnp.where``, not an add of zeros), so their output is bitwise the
+    base model's. ``ffn(moe_params, h) -> (y, aux)`` is the experts'
+    tail: the sharded ``moe_ffn`` in training, serving's per-token
+    ``moe_reference`` by default; both GELU, or decode runs another model
+    than was trained. ``tp_mesh``: the exact-TP serving layout, whose
+    attention output and gelu hidden are gathered before the products
+    that contract them (:func:`_tp_replicate`). Returns (x, the experts'
+    aux term, zeros without them)."""
+    dt = x.dtype
+    h_in = _layer_norm(x, p["ln1_scale"], p["ln1_bias"])
+    if packed:
+        q, k, v = _project_qkv_packed(p, h_in)
+    else:
+        q, k, v = _project_qkv(cfg, p, h_in)
+        if lora is not None:
+            b, t = x.shape[:2]
+            dq = _lora_delta(
+                h_in,
+                jnp.take(lora["a_q"], adapter, axis=0),
+                jnp.take(lora["b_q"], adapter, axis=0),
+            ).reshape(b, t, cfg.n_heads, cfg.head_dim).transpose(0, 2, 1, 3)
+            q = jnp.where((adapter > 0)[:, None, None, None], q + dq, q)
+        if cfg.rope:
+            if not isinstance(positions, tuple):
+                positions = _rope_over_heads(
+                    jnp.arange(x.shape[1]) if positions is None
+                    else positions, cfg.head_dim, dt)
+            cos, sin = positions
+            q = _apply_rope(q, cos, sin)
+            k = _apply_rope(k, cos, sin)
+    o = _tp_replicate(attend(q, k, v), tp_mesh)
+    wo = _w(p, "wo", dt)
+    if o.ndim == 3:
+        x = x + jnp.einsum("btf,fd->btd", o, wo.reshape(-1, wo.shape[-1]))
+    else:
+        x = x + jnp.einsum("bhtk,hkd->btd", o, wo)
+    h_in = _layer_norm(x, p["ln2_scale"], p["ln2_bias"])
+    aux = jnp.zeros((), dt)
+    if cfg.n_experts:
+        moe_params = jax.tree.map(lambda a: a.astype(dt), p["moe"])
+        if ffn is not None:
+            y, aux = ffn(moe_params, h_in)
+        else:
+            from deeplearning4j_tpu.parallel.expert_parallel import (
+                moe_reference,
+            )
+
+            y = moe_reference(
+                moe_params, h_in.reshape(-1, h_in.shape[-1]), k=cfg.moe_k,
+                activation=jax.nn.gelu,
+            ).reshape(h_in.shape)
+    elif lora is not None:
+        dm = _lora_delta(
+            h_in,
+            jnp.take(lora["a_mlp"], adapter, axis=0),
+            jnp.take(lora["b_mlp"], adapter, axis=0),
+        )
+        y = _mlp(p, h_in, tp_mesh, delta1=dm,
+                 sel=(adapter > 0)[:, None, None])
+    else:
+        y = _mlp(p, h_in, tp_mesh)
+    return x + y, aux
+
+
 def transformer_apply(
     cfg: TransformerConfig, mesh: Mesh | None = None,
     upcast_logits: bool = True,
@@ -1329,6 +1432,7 @@ def transformer_apply(
             f"rope needs an even head_dim, got {cfg.head_dim} "
             f"(d_model {cfg.d_model} / n_heads {cfg.n_heads})"
         )
+    moe = None
     if cfg.n_experts:
         if cfg.n_experts != mesh.shape[mesh_lib.MODEL_AXIS]:
             raise ValueError(
@@ -1400,22 +1504,17 @@ def transformer_apply(
             )
         return flash
 
-    def attend_bhtd(p, h_in):
-        # attention sublayer — internally (B, H, T, K) layout so the
-        # flash kernel's (B*H, T, K) view is a free reshape; the bthd
-        # layout cost ~3ms/step of physical transposes at GPT-2-small
-        # scale (B=16, T=1024)
-        q_h, k_r, v_r = _project_qkv(cfg, p, h_in)
-        if cfg.rope:
-            t = q_h.shape[2]
-            cos, sin = _rope_tables(
-                jnp.arange(t), cfg.head_dim, q_h.dtype
-            )  # (T, hd/2)
-            cos = cos[None, None, :, :]
-            sin = sin[None, None, :, :]
-            q_h = _apply_rope(q_h, cos, sin)
-            k_r = _apply_rope(k_r, cos, sin)
-        k_h, v_h = _expand_kv(cfg, k_r, v_r)
+    def attend(q, k, v):
+        if packed:
+            # (B, T, H*K) from the projections through the kernels to
+            # wo: no layout op on an activation, forward or backward
+            # (the (B, H, T, K) path's layout copies were 8% of the
+            # gpt2-medium step: PERF.md, PR 32)
+            return flash_attend(q.shape[1])(q, k, v)
+        # (B, H, T, K), so the flash kernel's (B*H, T, K) view is a free
+        # reshape; the bthd layout cost ~3ms/step of physical transposes
+        # at GPT-2-small scale (B=16, T=1024)
+        k, v = _expand_kv(cfg, k, v)
         if cfg.sequence_parallel:
             # the ring path works on (B, T, H, K) — the sequence axis is
             # the sharded one; transposes here are per-shard and cheap
@@ -1424,9 +1523,9 @@ def transformer_apply(
             # backward pass.
             return checkpoint_name(
                 ring(
-                    q_h.transpose(0, 2, 1, 3),
-                    k_h.transpose(0, 2, 1, 3),
-                    v_h.transpose(0, 2, 1, 3),
+                    q.transpose(0, 2, 1, 3),
+                    k.transpose(0, 2, 1, 3),
+                    v.transpose(0, 2, 1, 3),
                 ).transpose(0, 2, 1, 3),
                 "attn_out",
             )
@@ -1434,43 +1533,13 @@ def transformer_apply(
             # no attn_out naming here: the kernel's own flash_out
             # residual is the saveable (naming both would store the
             # same tensor twice and cost ~450MB at GPT-2-small scale)
-            return flash_attend(q_h.shape[2])(q_h, k_h, v_h)
+            return flash_attend(q.shape[2])(q, k, v)
         return checkpoint_name(
-            attention(q_h, k_h, v_h, causal=True, layout="bhtd"),
-            "attn_out",
+            attention(q, k, v, causal=True, layout="bhtd"), "attn_out",
         )
 
     def block(x, p):
-        h_in = _layer_norm(x, p["ln1_scale"], p["ln1_bias"])
-        if packed:
-            # (B, T, H*K) from the projections through the kernels to
-            # wo: no layout op on an activation, forward or backward
-            # (the (B, H, T, K) path's layout copies were 8% of the
-            # gpt2-medium step: PERF.md, PR 32)
-            q, k, v = _project_qkv_packed(p, h_in)
-            o = flash_attend(x.shape[1])(q, k, v)
-            wo = _w(p, "wo", x.dtype)
-            attn = jnp.einsum(
-                "btf,fd->btd", o, wo.reshape(-1, wo.shape[-1])
-            )
-        else:
-            attn = jnp.einsum(
-                "bhtk,hkd->btd", attend_bhtd(p, h_in),
-                _w(p, "wo", x.dtype),
-            )
-        x = x + attn
-        # ffn sublayer: dense MLP or routed MoE
-        h_in = _layer_norm(x, p["ln2_scale"], p["ln2_bias"])
-        if cfg.n_experts:
-            moe_params = jax.tree.map(
-                lambda a: a.astype(x.dtype), p["moe"]
-            )
-            y, aux = moe(moe_params, h_in)
-            x = x + y
-        else:
-            x = x + _mlp(p, h_in)
-            aux = jnp.zeros((), x.dtype)
-        return x, aux
+        return _gpt_block(cfg, p, x, None, attend, packed=packed, ffn=moe)
 
     if cfg.remat:
         if cfg.remat_policy == "dots_no_batch":
@@ -2423,18 +2492,10 @@ def _gated_builder(cfg: TransformerConfig):
             logits = jnp.einsum(
                 "...d,dv->...v", x_last, _w(params, "head", x_last.dtype),
                 preferred_element_type=jnp.float32,
-            )  # bf16 operands, f32 accumulation: see _decode_builder
+            )  # bf16 operands, f32 accumulation: see _gpt_logits
         if cfg.logits_scaling != 1.0:
             logits = logits / cfg.logits_scaling
         return logits
-
-    def row_at(x, last_idx):  # (B, T, D) -> (B, D): the named row
-        if last_idx is None:
-            return x[:, -1]
-        if jnp.ndim(last_idx) == 1:
-            return jnp.take_along_axis(
-                x, last_idx[:, None, None], axis=1)[:, 0]
-        return lax.dynamic_index_in_dim(x, last_idx, axis=1, keepdims=False)
 
     def packed(x):  # (B, C, Hkv, K) -> (B, C, Hkv*K)
         return x.reshape(x.shape[0], x.shape[1], hk)
@@ -2795,7 +2856,7 @@ def _gated_builder(cfg: TransformerConfig):
 
         x = embed_of(params, prompt)
         x, _ = run_layers(params, x, positions, attend_of)
-        return caches, logits_of(params, row_at(x, last_idx))
+        return caches, logits_of(params, _row_at(x, last_idx))
 
     def forward_chunk(params, caches, toks, pos0, last_idx=None,
                       adapter=None):
@@ -2818,16 +2879,128 @@ def _gated_builder(cfg: TransformerConfig):
         )
         if last_idx is None:
             return logits_of(params, x), caches
-        return logits_of(params, row_at(x, last_idx)), caches
+        return logits_of(params, _row_at(x, last_idx)), caches
 
     return forward_one, init_caches, prefill, cast_params, forward_chunk
 
 
+def _row_at(x, last_idx):
+    """(B, T, D) -> (B, D): the row ``last_idx`` names (None: the last;
+    a scalar; or (B,), one a row), read by the copying gather that keeps
+    a row's values bitwise those of a B=1 call."""
+    if last_idx is None:
+        return x[:, -1]
+    if jnp.ndim(last_idx) == 1:
+        return jnp.take_along_axis(x, last_idx[:, None, None], axis=1)[:, 0]
+    return lax.dynamic_index_in_dim(x, last_idx, axis=1, keepdims=False)
+
+
+def _gpt_logits(params, x, tp_mesh=None):
+    """The GPT stack's final LayerNorm and head over (..., D) rows ->
+    float32 (..., V). The product takes bf16 (or dequantized int8)
+    OPERANDS — half / a quarter of the weight stream and the MXU's fast
+    path; decode is weight-streaming-bound — but accumulates in f32: a
+    bf16-output dot would quantize the logits to 8 mantissa bits,
+    creating arbitrary ties at the top-k threshold and in beam scores.
+    TP: the vocab-sharded logits gather here (an exact concatenation),
+    so everything sampling reads is replicated and bitwise TP=1's."""
+    x = _layer_norm(x, params["lnf_scale"], params["lnf_bias"])
+    logits = jnp.einsum(
+        "...d,dv->...v", x, _w(params, "head", x.dtype),
+        preferred_element_type=jnp.float32,
+    )
+    return _tp_replicate(logits, tp_mesh)
+
+
+def _write_kv_rows(cache, k, v, layer=None, pos0=0, positions=None):
+    """K and V (B, Hkv, C, K), rotated and unexpanded, into a GPT decode
+    cache as packed rows (``init_caches``): the compute dtype's slab, or
+    the int8 one's ``{"kv", "scale"}``, each row (one position's packed
+    heads, the finest grain the decode kernel rescales without per-head
+    bookkeeping) quantized with one float32 scale; ~0.3% of the logits
+    against a bf16 cache on random models. ``layer`` None: prefill's
+    own layer slice (2, B, Tpad, Hkv*K), from row 0. Otherwise rows
+    ``pos0 .. pos0 + C - 1`` of that layer of the stacked cache: one
+    ``dynamic_update_slice`` a leaf for a scalar ``pos0``, and for an
+    (B,) one (the serving engine's per-slot depths) a scatter a plane
+    and leaf at ``positions`` (B, C). Returns the cache."""
+    b, c = k.shape[0], k.shape[2]
+    rows = jnp.stack([
+        k.transpose(0, 2, 1, 3).reshape(b, c, -1),
+        v.transpose(0, 2, 1, 3).reshape(b, c, -1),
+    ])
+    if layer is not None:
+        rows = rows[None]  # (1, 2, B, C, Hkv*K)
+    int8 = isinstance(cache, dict)
+    if int8:
+        q_rows, s_rows = _quantize_int8(rows.astype(jnp.float32), (-1,))
+        leaves, new = dict(cache), {"kv": q_rows, "scale": s_rows}
+    else:
+        leaves, new = {"kv": cache}, {"kv": rows.astype(cache.dtype)}
+    if jnp.ndim(pos0):
+        bidx = jnp.arange(b)[:, None]
+        for plane in range(2):
+            for name, r in new.items():
+                leaves[name] = leaves[name].at[
+                    layer, plane, bidx, positions].set(r[0, plane])
+    else:
+        start = (0, 0, 0, 0) if layer is None else (layer, 0, 0, pos0, 0)
+        for name, r in new.items():
+            leaves[name] = lax.dynamic_update_slice(leaves[name], r, start)
+    return leaves if int8 else leaves["kv"]
+
+
+def _chunk_attend(cfg: TransformerConfig, held, layer, pos0, positions):
+    """The chunk's ``attend`` for layer ``layer`` of the GPT stack: the
+    C new rows go to the cache ``held["cache"]`` at ``positions`` (C,)
+    or (B, C) (from ``pos0``, scalar or (B,)), then every query reads
+    the layer's whole slab, masked past its own position. The dense
+    decode step (``decode_kernel=False``: TP, LoRA banks, the
+    speculative draft) runs it at C = 1."""
+    hkv, kd = cfg.kv_heads, cfg.head_dim
+    grp = cfg.n_heads // hkv
+
+    def attend(q, k, v):  # (B, H, C, K), (B, Hkv, C, K)
+        b, _, c, _ = q.shape
+        cache = held["cache"] = _write_kv_rows(
+            held["cache"], k, v, layer, pos0, positions)
+        if isinstance(cache, dict):
+            ck = (cache["kv"][layer, 0].astype(jnp.float32)
+                  * cache["scale"][layer, 0]).astype(q.dtype)
+            cv = (cache["kv"][layer, 1].astype(jnp.float32)
+                  * cache["scale"][layer, 1]).astype(q.dtype)
+        else:
+            ck, cv = cache[layer, 0], cache[layer, 1]
+        tpad = ck.shape[1]
+        ck4 = ck.reshape(b, tpad, hkv, kd)
+        cv4 = cv.reshape(b, tpad, hkv, kd)
+        qg = q.reshape(b, hkv, grp, c, kd)  # head = kv*G + g
+        att = jnp.einsum(
+            "bhgck,bthk->bhgct", qg, ck4
+        ) / jnp.sqrt(kd).astype(q.dtype)
+        # causal against the cache: (C, Tpad) shared, or (B, C, Tpad)
+        mask = jnp.arange(tpad)[None, :] <= positions[..., None]
+        att = jnp.where(
+            mask[:, None, None] if mask.ndim == 3 else mask[None, None, None],
+            att, -jnp.inf,
+        )
+        o = jnp.einsum("bhgct,bthk->bhgck", jax.nn.softmax(att, axis=-1),
+                       cv4)
+        return o.transpose(0, 3, 1, 2, 4).reshape(b, c, cfg.n_heads * kd)
+
+    return attend
+
+
 def _decode_builder(cfg: TransformerConfig, tp_mesh=None):
-    """Shared KV-cache decode machinery: returns
-    ``(forward_one, init_caches, prefill)`` used by sampling and beam
-    search. ``forward_one(params, caches, token, pos)`` advances one
-    position through all layers.
+    """Shared KV-cache decode machinery: returns ``(forward_one,
+    init_caches, prefill, cast_params)``, used by serving, sampling and
+    beam search. Each entry runs :func:`_gpt_block` and hands it its own
+    ``attend``: ``forward_one(params, caches, token, pos)`` advances one
+    position through all layers, the block at T = 1 with the decode
+    kernel's ``attend`` (on the dense path :func:`_chunk_attend`'s, at C
+    = 1); ``prefill`` runs the bucket through the block under one
+    ``lax.scan`` over the layers, its ``attend`` writing the rows from
+    row 0 and attending with flash or densely.
 
     ``tp_mesh`` (a 1-D model-axis mesh) builds the exact-TP serving
     variant: params placed per :func:`serving_tp_shardings`, caches per
@@ -2850,142 +3023,48 @@ def _decode_builder(cfg: TransformerConfig, tp_mesh=None):
             "(the Pallas kernel cannot be GSPMD-partitioned)"
         )
 
-    def quantize_kv_rows(rows):
-        """Per-row int8 quantization of new cache rows: ``rows``
-        (..., hk) -> (int8 rows, f32 scales (..., 1)). The row (one
-        position's packed heads) is the finest granularity the kernel
-        can rescale without per-head bookkeeping; measured logits error
-        vs bf16 cache is ~0.3% on random models."""
-        return _quantize_int8(rows.astype(jnp.float32), (-1,))
+    hkv, kd = cfg.kv_heads, cfg.head_dim
+    grp = cfg.n_heads // hkv
 
-    def write_kv_rows_int8(kv_all, i, pos, rows):
-        """Write one decode step's K/V ``rows`` (2, B, Hkv*K) into the
-        stacked int8 cache and its scale planes at layer ``i`` (the
-        bf16 / f32 cache's rows are placed by the decode kernel
-        itself). Scalar ``pos`` writes every batch row at the same
-        position with a single fused ``dynamic_update_slice`` (the
-        generate/beam path — XLA aliases it in place); an (B,) vector
-        scatters each row at its own position (the serving engine's
-        per-slot decode depths)."""
-        kv_buf, sc_buf = kv_all["kv"], kv_all["scale"]
-        q_rows, s_rows = quantize_kv_rows(rows)
-        if jnp.ndim(pos) == 0:
-            kv_buf = lax.dynamic_update_slice(
-                kv_buf, q_rows[None, :, :, None, :], (i, 0, 0, pos, 0)
-            )
-            sc_buf = lax.dynamic_update_slice(
-                sc_buf, s_rows[None, :, :, None, :], (i, 0, 0, pos, 0)
-            )
-            return {"kv": kv_buf, "scale": sc_buf}
-        bidx = jnp.arange(rows.shape[1])
-        for plane in range(2):
-            kv_buf = kv_buf.at[i, plane, bidx, pos].set(q_rows[plane])
-            sc_buf = sc_buf.at[i, plane, bidx, pos].set(s_rows[plane])
-        return {"kv": kv_buf, "scale": sc_buf}
-
-    def block_decode(x, p, kv_all, i, pos, lora=None, adapter=None,
-                     active=None):
-        # x: (B, D) one position; kv_all: the ONE stacked packed cache
-        # (nl, 2, B, Tpad, Hkv*K) (axis 1: K then V) — this layer's new
-        # K and V rows are placed by the decode kernel itself, in the
-        # block of its walk that holds them, the cache aliased in place
-        # through the call (int8: by XLA before the kernel, a
-        # dynamic_update_slice or a scatter). (The round-1 per-layer scan
-        # carried the whole cache stack and restacked it every layer:
-        # ~126ms/call of dynamic-update-slice + squeeze bookkeeping at
-        # GPT-2-small B=16, measured.) The packed minor dim is the perf
-        # story: a (B, T, H, K) cache tiles on (12, 64) -> (16, 128) and
-        # streams 2.67x the logical bytes every step (601us/step for the
-        # QK read alone, measured r2). Under GQA the cache holds only
-        # kv_heads — the memory win.
-        if not cfg.decode_kernel:
-            # the dense fallback IS the C=1 chunk block — one code path
-            # (no separate copy to drift), used under SPMD sharding,
-            # for debugging, and as speculative decoding's
-            # numerics-matched draft mode — and batched LoRA's decode
-            # path (adapter deltas ride the same chunk block)
-            y, kv_all = _block_chunk(
-                cfg, x[:, None, :], p, kv_all, i, pos, tp_mesh=tp_mesh,
-                lora=lora, adapter=adapter,
-            )
-            return y[:, 0], kv_all
-        b = x.shape[0]
-        kd = cfg.head_dim
-        grp = cfg.n_heads // cfg.kv_heads
-        h_in = _layer_norm(x, p["ln1_scale"], p["ln1_bias"])
-        if cfg.kv_heads != cfg.n_heads:
-            q = jnp.einsum("bd,dhk->bhk", h_in, _w(p, "wq", x.dtype))
-            kv = jnp.einsum("bd,dshk->sbhk", h_in, _w(p, "wkv", x.dtype))
-            k, v = kv[0], kv[1]
-        else:
-            qkv = jnp.einsum(
-                "bd,dshk->sbhk", h_in, _w(p, "wqkv", x.dtype)
-            )
-            q, k, v = qkv[0], qkv[1], qkv[2]
-        if cfg.rope:
-            cos, sin = _rope_tables(pos, cfg.head_dim, x.dtype)
-            if jnp.ndim(pos) == 1:
-                # per-slot positions (serving): (B, hd/2) tables, one
-                # rotation per batch row
-                cos, sin = cos[:, None, :], sin[:, None, :]
-            else:
-                cos, sin = cos[None, None], sin[None, None]  # (hd/2,)
-            q = _apply_rope(q, cos, sin)
-            k = _apply_rope(k, cos, sin)
+    def kernel_attend(held, layer, pos, positions, active):
+        """Layer ``layer``'s ``attend`` for one row a slot through the
+        decode kernel, which takes the stacked cache and selects the
+        layer inside (a slice here would copy the slab every layer:
+        custom calls take dense operands). The bf16 / f32 walk places
+        the new K and V rows itself, in the block that holds them, the
+        cache aliased through the call; the int8 slab's rows are
+        written by XLA first."""
         from deeplearning4j_tpu.ops.pallas_kernels import (
             flash_decode_attention,
             flash_decode_attention_write,
         )
 
-        # query head h = kv*G + g (the _expand_kv repeat order):
-        # group into (B, G, Hkv*K) so each group is packed head-major
-        qp = (
-            q.reshape(b, cfg.kv_heads, grp, kd)
-            .transpose(0, 2, 1, 3)
-            .reshape(b, grp, cfg.kv_heads * kd)
-        )
-        # the kernel takes the STACKED cache and selects the layer
-        # inside — slicing here would materialize a full-cache copy per
-        # layer (custom calls need dense operands)
-        kv_rows = [k.reshape(b, -1), v.reshape(b, -1)]  # (B, Hkv*K) each
-        if cfg.decode_int8:
-            kv_all = write_kv_rows_int8(kv_all, i, pos, jnp.stack(kv_rows))
-            o = flash_decode_attention(
-                qp, kv_all["kv"], pos, n_kv_heads=cfg.kv_heads, layer=i,
-                kv_scales=kv_all["scale"], active=active,
-            )
-        else:
-            o, kv_all = flash_decode_attention_write(
-                qp, kv_all, jnp.stack(kv_rows, axis=1), pos,
-                n_kv_heads=cfg.kv_heads, layer=i,
-                write_at=_decode_write_at(pos, False, kv_all),
-                active=active,
-            )
-        o_flat = (
-            o.reshape(b, grp, cfg.kv_heads, kd)
-            .transpose(0, 2, 1, 3)
-            .reshape(b, cfg.n_heads * kd)
-        )
-        x = x + o_flat @ _w(p, "wo", x.dtype).reshape(
-            cfg.n_heads * kd, -1
-        )
-        h_in = _layer_norm(x, p["ln2_scale"], p["ln2_bias"])
-        if cfg.n_experts:
-            from deeplearning4j_tpu.parallel.expert_parallel import (
-                moe_reference,
-            )
+        def attend(q, k, v):  # (B, H, 1, K), (B, Hkv, 1, K)
+            b = q.shape[0]
+            # query head h = kv*G + g (the _expand_kv repeat order):
+            # group into (B, G, Hkv*K) so each group is packed head-major
+            qp = q[:, :, 0].reshape(b, hkv, grp, kd).transpose(
+                0, 2, 1, 3).reshape(b, grp, hkv * kd)
+            if cfg.decode_int8:
+                held["cache"] = _write_kv_rows(
+                    held["cache"], k, v, layer, pos, positions)
+                o = flash_decode_attention(
+                    qp, held["cache"]["kv"], pos, n_kv_heads=hkv,
+                    layer=layer, kv_scales=held["cache"]["scale"],
+                    active=active,
+                )
+            else:
+                o, held["cache"] = flash_decode_attention_write(
+                    qp, held["cache"],
+                    jnp.stack([k.reshape(b, -1), v.reshape(b, -1)], axis=1),
+                    pos, n_kv_heads=hkv, layer=layer,
+                    write_at=_decode_write_at(pos, False, held["cache"]),
+                    active=active,
+                )
+            return o.reshape(b, grp, hkv, kd).transpose(0, 2, 1, 3).reshape(
+                b, 1, cfg.n_heads * kd)
 
-            moe_params = jax.tree.map(
-                lambda a: a.astype(x.dtype), p["moe"]
-            )
-            # activation must match moe_ffn's (gelu), or decode runs a
-            # different model than was trained
-            x = x + moe_reference(
-                moe_params, h_in, k=cfg.moe_k, activation=jax.nn.gelu
-            )
-        else:
-            x = x + _mlp(p, h_in)
-        return x, kv_all
+        return attend
 
     def forward_one(params, caches, token, pos, adapter=None, active=None):
         """One position through all layers; returns (logits, caches).
@@ -3009,12 +3088,13 @@ def _decode_builder(cfg: TransformerConfig, tp_mesh=None):
         the serving engine forces ``decode_kernel=False`` when a bank
         is loaded.
 
-        The layer loop is UNROLLED (n_layers static python loop): the
-        round-1 lax.scan spent a third of decode wall time in while-loop
-        bookkeeping alone (measured via hlo_stats), and its cache carry
-        defeated in-place updates.
+        The block runs at T = 1, the kernel's ``attend`` or (dense
+        path) the chunk's at C = 1. The layer loop is UNROLLED
+        (n_layers static python loop): the round-1 lax.scan spent a
+        third of decode wall time in while-loop bookkeeping alone
+        (measured via hlo_stats), and its cache carry defeated in-place
+        updates.
         """
-        kv_all = caches
         lora = params.get("lora") if adapter is not None else None
         if lora is not None and cfg.decode_kernel:
             raise ValueError(
@@ -3027,29 +3107,22 @@ def _decode_builder(cfg: TransformerConfig, tp_mesh=None):
         emb_pos = jnp.minimum(pos, cfg.max_len - 1)
         x = (params["embed"][token] + params["pos"][emb_pos]).astype(
             cfg.compute_dtype
-        )
+        )[:, None]
+        positions = jnp.reshape(pos, (-1, 1))  # (B or 1, 1)
+        held = {"cache": caches}
         for i in range(cfg.n_layers):
             p_i = jax.tree.map(lambda a: a[i], params["blocks"])
             l_i = (None if lora is None
                    else jax.tree.map(lambda a: a[i], lora))
-            x, kv_all = block_decode(
-                x, p_i, kv_all, i, pos, lora=l_i, adapter=adapter,
-                active=active,
+            if cfg.decode_kernel:
+                attend = kernel_attend(held, i, pos, positions, active)
+            else:
+                attend = _chunk_attend(cfg, held, i, pos, positions)
+            x, _ = _gpt_block(
+                cfg, p_i, x, positions, attend, tp_mesh=tp_mesh, lora=l_i,
+                adapter=adapter,
             )
-        x = _layer_norm(x, params["lnf_scale"], params["lnf_bias"])
-        # head matmul with bf16 (or dequantized-int8) OPERANDS — half/
-        # quarter the weight stream and the MXU fast path; decode is
-        # weight-streaming-bound — but f32 ACCUMULATION: a bf16-output
-        # dot would quantize the logits to 8 mantissa bits, creating
-        # arbitrary ties at the top-k threshold and in beam scores
-        logits = jnp.einsum(
-            "bd,dv->bv", x, _w(params, "head", x.dtype),
-            preferred_element_type=jnp.float32,
-        )
-        # TP: vocab-sharded logits gather here (exact concatenation) so
-        # the host-visible logits buffer — and everything sampling reads
-        # — is replicated and bitwise identical to TP=1
-        return _tp_replicate(logits, tp_mesh), kv_all
+        return _gpt_logits(params, x[:, 0], tp_mesh), held["cache"]
 
     def cast_params(params):
         """One-time cast of the streamed weights to the compute dtype.
@@ -3092,7 +3165,12 @@ def _decode_builder(cfg: TransformerConfig, tp_mesh=None):
         # so the length always factors into large 8-aligned blocks (a
         # Tpad like 8*prime would otherwise degenerate the kernel's
         # block search to 8-row blocks: ~100x the per-cell fixed cost).
-        # Packed (Tpad, Hkv*K) minor layout: see block_decode.
+        # The packed (Tpad, Hkv*K) minor layout is the perf story: a
+        # (B, T, H, K) cache tiles on (12, 64) -> (16, 128) and streams
+        # 2.67x the logical bytes every step (601us/step for the QK read
+        # alone, measured r2). Under GQA the cache holds only kv_heads —
+        # the memory win. A step's new rows are placed by the decode
+        # kernel itself (int8, the dense path: by XLA, _write_kv_rows).
         tpad = _decode_tpad(total)
         if cfg.decode_int8:
             # int8 rows + per-row f32 scales (trailing singleton keeps
@@ -3139,68 +3217,28 @@ def _decode_builder(cfg: TransformerConfig, tp_mesh=None):
             # uniform logits, as the round-1 per-position walk did
             return caches, jnp.zeros((b, cfg.vocab_size), jnp.float32)
         lora = params.get("lora") if adapter is not None else None
-        kv_all = caches  # (nl, 2, B, Tpad, Hkv*K) packed
         x = (params["embed"][prompt] + params["pos"][:tp]).astype(
             cfg.compute_dtype
         )
-        if cfg.rope:
-            cos, sin = _rope_tables(
-                jnp.arange(tp), cfg.head_dim, cfg.compute_dtype
-            )  # (Tp, hd/2)
-            cos_b = cos[None, None, :, :]
-            sin_b = sin[None, None, :, :]
+        # the rotary's tables, once for every layer
+        rope = (_rope_over_heads(jnp.arange(tp), cfg.head_dim,
+                                 cfg.compute_dtype) if cfg.rope else None)
+        # keep long-prompt prefill O(T) like training — dense attention
+        # would materialize (B, H, Tp, Tp) scores. Prompts of other
+        # lengths fall back to dense (inference inputs are arbitrary;
+        # training raises instead)
+        flash = cfg.use_flash and _flash_seq_ok(tp) and tp_mesh is None
 
         def layer(x, xs):
-            if lora is None:
-                p, kv = xs  # kv: (2, B, Tpad, Hkv*K); int8 mode: dict
-                lo = None
-            else:
-                p, lo, kv = xs
-            h_in = _layer_norm(x, p["ln1_scale"], p["ln1_bias"])
-            q, k_r, v_r = _project_qkv(cfg, p, h_in)
-            if lo is not None:
-                # same attach point as _block_chunk: q delta pre-RoPE,
-                # adapter-0 rows select the untouched base projection
-                dq = _lora_delta(
-                    h_in,
-                    jnp.take(lo["a_q"], adapter, axis=0),
-                    jnp.take(lo["b_q"], adapter, axis=0),
-                ).reshape(
-                    b, tp, cfg.n_heads, cfg.head_dim
-                ).transpose(0, 2, 1, 3)
-                q = jnp.where(
-                    (adapter > 0)[:, None, None, None], q + dq, q
-                )
-            if cfg.rope:
-                q = _apply_rope(q, cos_b, sin_b)
-                k_r = _apply_rope(k_r, cos_b, sin_b)
-            # cache holds the UNexpanded kv heads packed (B, T, Hkv*K)
-            kv_rows = jnp.stack(
-                [
-                    k_r.transpose(0, 2, 1, 3).reshape(b, tp, -1),
-                    v_r.transpose(0, 2, 1, 3).reshape(b, tp, -1),
-                ]
-            )
-            if cfg.decode_int8:
-                q_rows, s_rows = quantize_kv_rows(kv_rows)
-                kv = {
-                    "kv": lax.dynamic_update_slice(
-                        kv["kv"], q_rows, (0, 0, 0, 0)
-                    ),
-                    "scale": lax.dynamic_update_slice(
-                        kv["scale"], s_rows, (0, 0, 0, 0)
-                    ),
-                }
-            else:
-                kv = lax.dynamic_update_slice(
-                    kv, kv_rows.astype(kv.dtype), (0, 0, 0, 0)
-                )
-            k_h, v_h = _expand_kv(cfg, k_r, v_r)
-            if cfg.use_flash and _flash_seq_ok(tp) and tp_mesh is None:
-                # keep long-prompt prefill O(T) like training — dense
-                # attention would materialize (B, H, Tp, Tp) scores.
-                # Prompts of other lengths fall back to dense (inference
-                # inputs are arbitrary; training raises instead).
+            # kv: this layer's (2, B, Tpad, Hkv*K); int8 mode: a dict
+            p, lo, kv = xs if lora is not None else (xs[0], None, xs[1])
+            held = {}
+
+            def attend(q, k_r, v_r):
+                held["cache"] = _write_kv_rows(kv, k_r, v_r)
+                k_h, v_h = _expand_kv(cfg, k_r, v_r)
+                if not flash:
+                    return attention(q, k_h, v_h, causal=True, layout="bhtd")
                 from deeplearning4j_tpu.ops.pallas_kernels import (
                     flash_attention_trainable,
                 )
@@ -3208,67 +3246,21 @@ def _decode_builder(cfg: TransformerConfig, tp_mesh=None):
                 # forward-only (prefill never differentiates): no
                 # backward block overrides
                 bq, bk = _flash_blocks(tp)
-                o = flash_attention_trainable(
+                return flash_attention_trainable(
                     q, k_h, v_h, causal=True,
                     block_q=bq, block_k=bk, layout="bhtd",
                 )
-            else:
-                o = attention(q, k_h, v_h, causal=True, layout="bhtd")
-            # TP: gather the head-sharded attention output before the
-            # row projection so the h*k reduction keeps single-chip
-            # order (see _tp_replicate)
-            o = _tp_replicate(o, tp_mesh)
-            x = x + jnp.einsum("bhtk,hkd->btd", o, _w(p, "wo", x.dtype))
-            h_in = _layer_norm(x, p["ln2_scale"], p["ln2_bias"])
-            if cfg.n_experts:
-                from deeplearning4j_tpu.parallel.expert_parallel import (
-                    moe_reference,
-                )
 
-                moe_params = jax.tree.map(
-                    lambda a: a.astype(x.dtype), p["moe"]
-                )
-                # per-token dense routing, matching block_decode
-                flat = h_in.reshape(-1, h_in.shape[-1])
-                y = moe_reference(
-                    moe_params, flat, k=cfg.moe_k, activation=jax.nn.gelu
-                )
-                x = x + y.reshape(h_in.shape)
-            elif lo is not None:
-                dm = _lora_delta(
-                    h_in,
-                    jnp.take(lo["a_mlp"], adapter, axis=0),
-                    jnp.take(lo["b_mlp"], adapter, axis=0),
-                )
-                x = x + _mlp(p, h_in, tp_mesh, delta1=dm,
-                             sel=(adapter > 0)[:, None, None])
-            else:
-                x = x + _mlp(p, h_in, tp_mesh)
-            return x, kv
+            x, _ = _gpt_block(cfg, p, x, rope, attend, tp_mesh=tp_mesh,
+                              lora=lo, adapter=adapter)
+            return x, held["cache"]
 
         if lora is None:
-            xs = (params["blocks"], kv_all)
+            xs = (params["blocks"], caches)
         else:
-            xs = (params["blocks"], lora, kv_all)
-        x, kv_all = lax.scan(layer, x, xs)
-        if last_idx is None:
-            x_last = x[:, -1]
-        elif jnp.ndim(last_idx) == 1:
-            x_last = jnp.take_along_axis(
-                x, last_idx[:, None, None], axis=1
-            )[:, 0]
-        else:
-            x_last = lax.dynamic_index_in_dim(
-                x, last_idx, axis=1, keepdims=False
-            )
-        x = _layer_norm(
-            x_last, params["lnf_scale"], params["lnf_bias"]
-        )
-        logits = jnp.einsum(
-            "bd,dv->bv", x, _w(params, "head", x.dtype),
-            preferred_element_type=jnp.float32,
-        )  # bf16 operands, f32 accumulation — see forward_one
-        return kv_all, _tp_replicate(logits, tp_mesh)
+            xs = (params["blocks"], lora, caches)
+        x, caches = lax.scan(layer, x, xs)
+        return caches, _gpt_logits(params, _row_at(x, last_idx), tp_mesh)
 
     return forward_one, init_caches, prefill, cast_params
 
@@ -3684,144 +3676,6 @@ def _filtered_probs(logits, temperature: float, top_k: int | None,
     return jax.nn.softmax(logits / temperature, axis=-1)
 
 
-def _block_chunk(cfg: TransformerConfig, x, p, kv_all, i, pos0,
-                 tp_mesh=None, lora=None, adapter=None):
-    """One transformer block over C consecutive cached-decode positions
-    (x: (B, C, D), rows pos0..pos0+C-1): projection, RoPE, cache write,
-    dense masked attention against the cache, MLP/MoE tail. ONE
-    implementation serving both ``block_decode``'s non-kernel path
-    (C=1) and the speculative verify chunk — the dense decode numerics
-    cannot drift from the verify numerics because they are the same
-    code. ``pos0`` is a scalar start position or an (B,) vector of
-    per-row starts (the serving engine's per-slot decode depths).
-
-    ``lora`` (this layer's slice of an :func:`init_lora_bank` bank —
-    leaves (N, ...)) with ``adapter`` (B,) int rows adds each row's
-    low-rank q and MLP deltas, gathered by adapter index inside the
-    traced program so one dispatch serves mixed adapters. Rows with
-    adapter 0 SELECT the untouched base activations (``jnp.where``,
-    not an add of zeros) so their output is bitwise the base model's."""
-    b, c, _ = x.shape
-    kd = cfg.head_dim
-    grp = cfg.n_heads // cfg.kv_heads
-    vec_pos = jnp.ndim(pos0) == 1
-    # (C,) shared positions, or (B, C) per-row positions
-    positions = (pos0[:, None] if vec_pos else pos0) + jnp.arange(c)
-    h_in = _layer_norm(x, p["ln1_scale"], p["ln1_bias"])
-    q, k_r, v_r = _project_qkv(cfg, p, h_in)  # (B,H,C,K), (B,Hkv,C,K)
-    if lora is not None:
-        # q delta BEFORE RoPE — where a merged wq+AB would land it, so
-        # a slot's stream matches a single-adapter engine's flop order
-        dq = _lora_delta(
-            h_in,
-            jnp.take(lora["a_q"], adapter, axis=0),
-            jnp.take(lora["b_q"], adapter, axis=0),
-        ).reshape(b, c, cfg.n_heads, kd).transpose(0, 2, 1, 3)
-        q = jnp.where((adapter > 0)[:, None, None, None], q + dq, q)
-    if cfg.rope:
-        cos, sin = _rope_tables(positions, cfg.head_dim, x.dtype)
-        if vec_pos:  # (B, C, hd/2): per-row tables over the head axis
-            cos, sin = cos[:, None], sin[:, None]
-        else:  # (C, hd/2)
-            cos, sin = cos[None, None], sin[None, None]
-        q = _apply_rope(q, cos, sin)
-        k_r = _apply_rope(k_r, cos, sin)
-    kv_rows = jnp.stack(
-        [
-            k_r.transpose(0, 2, 1, 3).reshape(b, c, -1),
-            v_r.transpose(0, 2, 1, 3).reshape(b, c, -1),
-        ]
-    )[None]  # (1, 2, B, C, Hkv*K)
-    if cfg.decode_int8:
-        kv_buf, sc_buf = kv_all["kv"], kv_all["scale"]
-        q_rows, s_rows = _quantize_int8(
-            kv_rows.astype(jnp.float32), (-1,)
-        )
-        if vec_pos:
-            bidx = jnp.arange(b)[:, None]
-            for plane in range(2):
-                kv_buf = kv_buf.at[i, plane, bidx, positions].set(
-                    q_rows[0, plane]
-                )
-                sc_buf = sc_buf.at[i, plane, bidx, positions].set(
-                    s_rows[0, plane]
-                )
-        else:
-            kv_buf = lax.dynamic_update_slice(
-                kv_buf, q_rows, (i, 0, 0, pos0, 0)
-            )
-            sc_buf = lax.dynamic_update_slice(
-                sc_buf, s_rows, (i, 0, 0, pos0, 0)
-            )
-        kv_all = {"kv": kv_buf, "scale": sc_buf}
-        ck = (kv_buf[i, 0].astype(jnp.float32)
-              * sc_buf[i, 0]).astype(x.dtype)
-        cv = (kv_buf[i, 1].astype(jnp.float32)
-              * sc_buf[i, 1]).astype(x.dtype)
-    else:
-        if vec_pos:
-            bidx = jnp.arange(b)[:, None]
-            rows = kv_rows.astype(kv_all.dtype)
-            for plane in range(2):
-                kv_all = kv_all.at[i, plane, bidx, positions].set(
-                    rows[0, plane]
-                )
-        else:
-            kv_all = lax.dynamic_update_slice(
-                kv_all, kv_rows.astype(kv_all.dtype), (i, 0, 0, pos0, 0)
-            )
-        ck, cv = kv_all[i, 0], kv_all[i, 1]
-    tpad = ck.shape[1]
-    ck4 = ck.reshape(b, tpad, cfg.kv_heads, kd)
-    cv4 = cv.reshape(b, tpad, cfg.kv_heads, kd)
-    qg = q.reshape(b, cfg.kv_heads, grp, c, kd)  # head = kv*G + g
-    att = jnp.einsum(
-        "bhgck,bthk->bhgct", qg, ck4
-    ) / jnp.sqrt(kd).astype(x.dtype)
-    # causal against the cache: (C, Tpad) shared, or (B, C, Tpad)
-    mask = jnp.arange(tpad)[None, :] <= positions[..., None]
-    att = jnp.where(
-        mask[:, None, None] if vec_pos else mask[None, None, None], att,
-        -jnp.inf,
-    )
-    w_att = jax.nn.softmax(att, axis=-1)
-    o = jnp.einsum("bhgct,bthk->bhgck", w_att, cv4)
-    o_flat = o.transpose(0, 3, 1, 2, 4).reshape(
-        b, c, cfg.n_heads * kd
-    )
-    # TP: gather the head-sharded attention output before the row
-    # projection so the reduction keeps single-chip order
-    o_flat = _tp_replicate(o_flat, tp_mesh)
-    x = x + jnp.einsum(
-        "bch,hd->bcd", o_flat,
-        _w(p, "wo", x.dtype).reshape(cfg.n_heads * kd, -1),
-    )
-    h_in = _layer_norm(x, p["ln2_scale"], p["ln2_bias"])
-    if cfg.n_experts:
-        from deeplearning4j_tpu.parallel.expert_parallel import (
-            moe_reference,
-        )
-
-        moe_params = jax.tree.map(
-            lambda a: a.astype(x.dtype), p["moe"]
-        )
-        flat = h_in.reshape(-1, h_in.shape[-1])
-        y = moe_reference(
-            moe_params, flat, k=cfg.moe_k, activation=jax.nn.gelu
-        )
-        x = x + y.reshape(h_in.shape)
-    elif lora is not None:
-        dm = _lora_delta(
-            h_in,
-            jnp.take(lora["a_mlp"], adapter, axis=0),
-            jnp.take(lora["b_mlp"], adapter, axis=0),
-        )
-        x = x + _mlp(p, h_in, tp_mesh, delta1=dm,
-                     sel=(adapter > 0)[:, None, None])
-    else:
-        x = x + _mlp(p, h_in, tp_mesh)
-    return x, kv_all
-
 def _chunk_builder(cfg: TransformerConfig, tp_mesh=None):
     """Chunked cached forward — the verify side of speculative decoding:
     ``forward_chunk(params, caches, toks (B, C), pos0)`` advances C
@@ -3830,8 +3684,9 @@ def _chunk_builder(cfg: TransformerConfig, tp_mesh=None):
     caches). Decode is weight-stream-bound, so verifying C=k+1 draft
     positions costs ~one decode step of HBM traffic, not k: the C
     queries ride the same streamed weights as a single wide MXU dot.
-    Per-layer work delegates to :func:`_block_chunk` — the same code
-    ``block_decode``'s non-kernel path runs at C=1."""
+    Each layer is :func:`_gpt_block` with :func:`_chunk_attend`, which
+    the dense decode step runs at C = 1: the draft's numerics are the
+    verify chunk's."""
     if cfg.gated:
         return _gated_builder(cfg)[4]
 
@@ -3847,43 +3702,27 @@ def _chunk_builder(cfg: TransformerConfig, tp_mesh=None):
         x = (params["embed"][toks] + pos_rows[None]).astype(
             cfg.compute_dtype
         )
-        kv_all = caches
+        held = {"cache": caches}
         lora = params.get("lora") if adapter is not None else None
         for i in range(cfg.n_layers):
             p_i = jax.tree.map(lambda a, i=i: a[i], params["blocks"])
             l_i = (None if lora is None
                    else jax.tree.map(lambda a, i=i: a[i], lora))
-            x, kv_all = _block_chunk(
-                cfg, x, p_i, kv_all, i, pos0, tp_mesh=tp_mesh,
-                lora=l_i, adapter=adapter,
+            # (C,) shared positions, or (B, C) per row
+            positions = (
+                pos0[:, None] if jnp.ndim(pos0) else pos0) + jnp.arange(c)
+            x, _ = _gpt_block(
+                cfg, p_i, x, positions,
+                _chunk_attend(cfg, held, i, pos0, positions),
+                tp_mesh=tp_mesh, lora=l_i, adapter=adapter,
             )
         if last_idx is not None:
             # single-row logits (bucketed-prefill chunking: only the
             # true last token's row matters; skips the (C, V) head).
             # Vector last_idx = per-row last index, for the batched
             # suffix-prefill of prefix-cache hits.
-            if jnp.ndim(last_idx) == 1:
-                x_last = jnp.take_along_axis(
-                    x, last_idx[:, None, None], axis=1
-                )[:, 0]
-            else:
-                x_last = lax.dynamic_index_in_dim(
-                    x, last_idx, axis=1, keepdims=False
-                )
-            x_last = _layer_norm(
-                x_last, params["lnf_scale"], params["lnf_bias"]
-            )
-            logits = jnp.einsum(
-                "bd,dv->bv", x_last, _w(params, "head", x_last.dtype),
-                preferred_element_type=jnp.float32,
-            )
-            return _tp_replicate(logits, tp_mesh), kv_all
-        x = _layer_norm(x, params["lnf_scale"], params["lnf_bias"])
-        logits = jnp.einsum(
-            "bcd,dv->bcv", x, _w(params, "head", x.dtype),
-            preferred_element_type=jnp.float32,
-        )
-        return _tp_replicate(logits, tp_mesh), kv_all
+            x = _row_at(x, last_idx)
+        return _gpt_logits(params, x, tp_mesh), held["cache"]
 
     return forward_chunk
 

@@ -1305,8 +1305,8 @@ class ServingEngine:
             )
             if cfg.decode_kernel:
                 # the Pallas decode kernel has no adapter-gather path;
-                # the dense fallback is the same numerics (see
-                # block_decode)
+                # the dense fallback is the same numerics (the one GPT
+                # block, transformer.py: _gpt_block)
                 cfg = dataclasses.replace(cfg, decode_kernel=False)
         # multi-tenant serving config (see serving.tenancy): resolves
         # per-tenant slot caps at admission; quota charging happens in
@@ -1328,7 +1328,8 @@ class ServingEngine:
             if cfg.decode_kernel:
                 # the Pallas decode kernel is a custom call GSPMD
                 # cannot partition; the dense fallback is the same
-                # numerics (see block_decode)
+                # numerics (the one GPT block, transformer.py:
+                # _gpt_block)
                 cfg = dataclasses.replace(cfg, decode_kernel=False)
             self.tp_mesh = model_parallel_mesh(self.tp)
         self.cfg = cfg

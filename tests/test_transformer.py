@@ -416,10 +416,10 @@ def test_chunk_forward_matches_sequential_decode():
                 np.asarray(ch_logits[:, i]), np.asarray(seq_logits[i]),
                 atol=2e-3, err_msg=f"slot {i} int8={cfg.decode_int8}",
             )
-        # ...and against bulk prefill: block_chunk is a third copy of
-        # the transformer block (prefill's layer / block_decode's dense
-        # fallback are the others) — this pins chunk-vs-prefill so the
-        # copies cannot drift (cache rows written must be identical)
+        # ...and against bulk prefill: both run the one GPT block
+        # (_gpt_block), each with its own attend and cache write — this
+        # pins the chunk's rows against prefill's (the rows written
+        # must be identical)
         pf_caches, _ = _pf(cp(params), ic(2, 16), toks)
 
         def rows(c):
@@ -440,6 +440,79 @@ def test_chunk_forward_matches_sequential_decode():
             atol=6e-2 if cfg.decode_int8 else 2e-2,
             err_msg=f"cache rows int8={cfg.decode_int8}",
         )
+
+
+def _serve_every_entry(cfg, params, toks, n_prompt, adapter=None):
+    """Logits at positions n_prompt-1 .. T-2 two ways: prefill, then
+    decode steps at per-slot positions; and prefill, then the same
+    positions as one chunk. Also the cache after the steps."""
+    from deeplearning4j_tpu.models.transformer import (
+        _chunk_builder,
+        _decode_builder,
+    )
+
+    f1, ic, pf, cp = _decode_builder(cfg)
+    p = cp(params)
+    caches, first = pf(p, ic(2, 16), toks[:, :n_prompt], adapter=adapter)
+    steps = [first]
+    for t in range(n_prompt, toks.shape[1] - 1):
+        lg, caches = f1(p, caches, toks[:, t], jnp.full((2,), t, jnp.int32),
+                        adapter=adapter)
+        steps.append(lg)
+    chunk, _ = _chunk_builder(cfg)(
+        p, pf(p, ic(2, 16), toks[:, :n_prompt], adapter=adapter)[0],
+        toks[:, n_prompt:-1], n_prompt, adapter=adapter)
+    return (np.stack(steps, axis=1),
+            np.concatenate([np.asarray(first)[:, None], chunk], axis=1),
+            np.asarray(caches))
+
+
+@pytest.mark.parametrize("case", ["mha", "gqa-rope", "int8", "lora-adapter-0"])
+def test_every_serving_entry_of_the_gpt_block(case):
+    """The one GPT block (_gpt_block) through prefill, the decode step
+    and the chunk, float32: against transformer_apply's logits (the
+    int8 cache, with int8 weights, at test_int8_decode_quality_gate's
+    tolerance); with a LoRA bank loaded, a row whose adapter is 0 is
+    bitwise a run without the bank (the block selects, it adds no
+    zeros), and a row with adapter 1 is not."""
+    import dataclasses
+
+    from deeplearning4j_tpu.models.transformer import (
+        init_lora_bank,
+        quantize_decode_params,
+    )
+
+    n_prompt = 6
+    toks = _tokens(2, n_prompt + 3, seed=3)
+    if case == "lora-adapter-0":
+        cfg = _cfg(decode_kernel=False)
+        params = init_transformer(jax.random.key(4), cfg)
+        banked = dict(params,
+                      lora=init_lora_bank(jax.random.key(5), cfg, 3, 2))
+        base = _serve_every_entry(cfg, params, toks, n_prompt)
+        lora = _serve_every_entry(cfg, banked, toks, n_prompt,
+                                  adapter=jnp.asarray([0, 1], jnp.int32))
+        for b, l in zip(base[:2], lora[:2]):  # logits: rows 0, 1
+            np.testing.assert_array_equal(l[0], b[0])
+            assert np.abs(l[1] - b[1]).max() > 1e-4
+        # the cache (layers, 2, B, rows, Hkv*K): row 0's slab
+        np.testing.assert_array_equal(lora[2][:, :, 0], base[2][:, :, 0])
+        return
+    cfg = _cfg(**(dict(n_kv_heads=2, rope=True) if case == "gqa-rope"
+                  else {}))
+    params = init_transformer(jax.random.key(3), cfg)
+    want = np.asarray(transformer_apply(cfg)(params, toks)[0])
+    want = want[:, n_prompt - 1:-1]
+    if case == "int8":
+        params = quantize_decode_params(params, cfg)
+        cfg = dataclasses.replace(cfg, decode_int8=True)
+    for name, got in zip(("steps", "chunk"),
+                         _serve_every_entry(cfg, params, toks, n_prompt)):
+        err = float(np.max(np.abs(got - want)))
+        if case == "int8":
+            assert err < 0.06 * float(np.max(np.abs(want))) + 0.02, name
+        else:
+            assert err < 1e-4, (name, err)
 
 
 @pytest.mark.slow
